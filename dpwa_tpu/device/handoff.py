@@ -2,15 +2,18 @@
 
 The zero-copy frame path (docs/transport.md) delivers decoded payload
 views straight out of the receive ring; this module moves them onto the
-accelerator without re-materializing them on the way.  ``to_device``
-ingests a host array via dlpack when the view is eligible — C-contiguous
-and 64-byte aligned (``ALIGN``), which :class:`~dpwa_tpu.parallel.ingest
-.BufferRing` guarantees for lease-offset-0 views — so the crossing is a
-pointer adoption on the CPU backend and a single DMA on a real device,
+accelerator without re-materializing them on the way.  On the CPU
+backend ``to_device`` ingests a host array via dlpack when the view is
+eligible — C-contiguous and 64-byte aligned (``ALIGN``), which
+:class:`~dpwa_tpu.parallel.ingest.BufferRing` guarantees for
+lease-offset-0 views — so the crossing is a pointer adoption.  On an
+accelerator backend there is no host pointer to adopt (dlpack of a numpy
+view would leave the array on the CPU backend, away from the replica in
+HBM), so every view crosses by ``jax.device_put``: one host→device copy,
 never ``bytes -> ndarray -> device`` twice.  Ineligible views (unaligned
-codec offsets, non-contiguous slices) fall back to ``jax.device_put``,
-and the split is tallied so ``wire_snapshot()`` can show when frames
-stopped crossing clean.
+codec offsets, non-contiguous slices) take ``device_put`` too, and the
+split is tallied so ``wire_snapshot()`` can show when frames stopped
+crossing clean.
 
 Ownership contract (the dlpack half of the lease rules in
 ``parallel/ingest.py``): a zero-copy device array ALIASES the host
@@ -61,21 +64,22 @@ def dlpack_eligible(arr: np.ndarray) -> bool:
 
 def to_device(arr: np.ndarray):
     """Host array -> device array on the default device, crossing
-    exactly once.  dlpack (pointer adoption) when eligible, else
-    ``jax.device_put`` (one staging copy); either way the caller's view
-    is never routed through an intermediate ``bytes``/``ndarray``."""
+    exactly once.  dlpack (pointer adoption) when the default backend is
+    the CPU and the view is eligible, else ``jax.device_put`` (one copy
+    onto the default device); either way the caller's view is never
+    routed through an intermediate ``bytes``/``ndarray``."""
     global _H2D_ZERO_COPY, _H2D_COPIED, _H2D_BYTES
     import jax
     import jax.numpy as jnp
 
     zero_copy = False
-    if dlpack_eligible(arr):
+    if jax.default_backend() == "cpu" and dlpack_eligible(arr):
         try:
             out = jnp.from_dlpack(arr)
             zero_copy = True
         except (TypeError, ValueError, RuntimeError):
-            # Backend refuses this dtype/layout over dlpack (bf16 views,
-            # non-CPU platforms importing host memory): staging copy.
+            # The CPU client refuses this dtype/layout over dlpack (bf16
+            # views): staging copy.
             out = jax.device_put(arr)
     else:
         out = jax.device_put(arr)

@@ -43,8 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dpwa_tpu.utils.compat import axis_size
-
 _NEG_INF = -1e30  # finite stand-in: -inf lse would NaN the merge weights
 
 
@@ -279,7 +277,7 @@ def _expand_kv(t, H):
 
 
 def _ring_fwd_parts(q, k, v, axis_name, causal, impl):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     scale = float(1.0 / (D ** 0.5))
@@ -346,7 +344,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, impl):
 
 def _ring_flash_bwd(axis_name, causal, impl, res, g):
     q, k, v, out32, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     KV = k.shape[2]

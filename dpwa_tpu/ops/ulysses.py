@@ -35,8 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dpwa_tpu.utils.compat import axis_size
-
 
 def ulysses_attention_local(
     q: jnp.ndarray,
@@ -58,7 +56,7 @@ def ulysses_attention_local(
     attention on TPU when shapes allow; "dense"/"xla" forces the einsum
     reference; "flash" forces the kernel (TPU only).
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     B, T, H, D = q.shape
     KV = k.shape[2]
     if H % n:

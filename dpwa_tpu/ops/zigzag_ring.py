@@ -41,8 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dpwa_tpu.utils.compat import axis_size
-
 from dpwa_tpu.ops.flash_ring import (
     _NEG_INF,
     _expand_kv as _expand,
@@ -92,7 +90,7 @@ def zigzag_unshard(x, sp: int, axis: int = 1):
 def zigzag_positions_local(T_local: int, axis_name: str) -> jnp.ndarray:
     """This device's GLOBAL rope positions under the zigzag layout
     (call inside shard_map): concat(chunk i, chunk 2n-1-i)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     i = lax.axis_index(axis_name)
     C = T_local // 2
     return jnp.concatenate(
@@ -131,7 +129,7 @@ def zigzag_ring_attention_local(
 
 
 def _zz_fwd_parts(q, k, v, axis_name, impl):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     C = T // 2
@@ -212,7 +210,7 @@ def _zz_fwd(q, k, v, axis_name, impl):
 
 def _zz_bwd(axis_name, impl, res, g):
     q, k, v, out32, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     B, T, H, D = q.shape
     C = T // 2

@@ -28,8 +28,8 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from dpwa_tpu.utils.compat import shard_map
 
 from dpwa_tpu.config import DpwaConfig
 from dpwa_tpu.interpolation import Interpolation, PeerMeta, make_interpolation
@@ -252,6 +252,7 @@ class IciTransport:
                 P(self.axis_name),
                 (P(self.axis_name), P(self.axis_name), P(self.axis_name)),
             ),
+            check_vma=False,  # one setting for every map; see train._make_step
         )
 
         @jax.jit

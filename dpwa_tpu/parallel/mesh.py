@@ -35,19 +35,21 @@ def make_mesh(
     """A 1-D mesh whose axis length equals ``len(config.nodes)``."""
     n = config.n_peers
     if devices is None:
-        if len(jax.devices()) >= n:
-            devices = mesh_utils.create_device_mesh(
-                (n,), devices=jax.devices()[:n]
-            )
-        else:
+        devices = jax.devices()
+        if len(devices) < n:
             raise RuntimeError(
-                f"config names {n} peers but only {len(jax.devices())} JAX "
+                f"config names {n} peers but only {len(devices)} JAX "
                 f"devices are visible; set "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={n} for "
                 f"CPU emulation or use the TCP transport"
             )
-    else:
-        devices = np.asarray(devices)
+        if len(devices) == n:
+            # The whole slice: mesh_utils orders the ring along the torus.
+            devices = mesh_utils.create_device_mesh((n,), devices=devices)
+        else:
+            # A prefix need not be a sub-torus (mesh_utils refuses 3 chips
+            # of a 2x2): keep enumeration order.
+            devices = devices[:n]
     return Mesh(np.asarray(devices).reshape(n), (axis_name,))
 
 

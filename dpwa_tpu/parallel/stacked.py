@@ -189,17 +189,21 @@ def init_stacked_state(
             f"stacked params must have leading peer axis {n}, got {leading}"
         )
     # Own copies: the train step DONATES the state, so the state must not
-    # alias arrays the caller still holds.
-    own = lambda t: jax.tree.map(lambda v: jnp.array(v, copy=True), t)
-    params = own(stacked_params)
+    # alias arrays the caller still holds.  One program, not an op per
+    # leaf: on an accelerator every new leaf shape is a compilation.
+    @jax.jit
+    def build(params, model_state):
+        own = lambda t: jax.tree.map(jnp.copy, t)
+        params = own(params)
+        return params, jax.vmap(optimizer.init)(params), own(model_state)
+
+    params, opt_state, model_state = build(stacked_params, stacked_model_state)
     return StackedTrainState(
         params=params,
-        opt_state=jax.vmap(optimizer.init)(params),
+        opt_state=opt_state,
         clock=jnp.zeros(n, jnp.float32),
         step=jnp.int32(0),
-        model_state=own(stacked_model_state)
-        if stacked_model_state is not None
-        else None,
+        model_state=model_state,
         loss=jnp.zeros(n, jnp.float32),
     )
 

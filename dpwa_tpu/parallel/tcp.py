@@ -4017,7 +4017,7 @@ class TcpTransport:
         exchange's: the wire leg's own cumulative deadline (doubled
         under flowctl for a hedge's two sequential budgets) plus the
         per-byte allowance for the expected frame, so a healthy large
-        stream is never abandoned while a hung leg cannot wedge the
+        stream is never abandoned while a hung leg cannot stall the
         round — a lapsed join skips the merge like any failed fetch."""
         slot, self._prefetch_slot = self._prefetch_slot, None
         tr = self.tracer

@@ -32,7 +32,7 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import optax
-from dpwa_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dpwa_tpu.interpolation import PeerMeta
@@ -41,7 +41,7 @@ from dpwa_tpu.parallel.ici import (
     IciTransport,
     gossip_exchange_local,
 )
-from dpwa_tpu.parallel.mesh import peer_sharding
+from dpwa_tpu.parallel.mesh import peer_sharding, replicated_sharding
 from dpwa_tpu.utils.pytree import combine as pytree_combine
 from dpwa_tpu.utils.pytree import partition as pytree_partition
 
@@ -84,22 +84,23 @@ def init_gossip_state(
         raise ValueError(
             f"stacked params must have leading peer axis {n}, got {leading}"
         )
-    opt_state = jax.vmap(optimizer.init)(stacked_params)
     sh = peer_sharding(transport.mesh, transport.axis_name)
     # The train step donates the state, so it must not alias arrays the
-    # caller still holds.  device_put of HOST data always materializes
-    # fresh buffers; only an existing jax.Array (possibly already in the
-    # target sharding, where device_put can alias) needs the extra copy.
-    def own(v):
-        out = jax.device_put(v, sh)
-        return out.copy() if isinstance(v, jax.Array) else out
-
-    put = lambda t: jax.tree.map(own, t)
+    # caller still holds (an array already in the target sharding would
+    # otherwise come back as itself).  One call for each tree: a transfer
+    # per leaf is a dispatch per leaf.
+    put = lambda t: jax.device_put(t, sh, may_alias=False)
+    params = put(stacked_params)
     return GossipTrainState(
-        params=put(stacked_params),
-        opt_state=put(opt_state),
+        params=params,
+        # Built from the sharded params as one program, so the optimizer
+        # state is born on its own chip and never whole on the first.
+        opt_state=jax.jit(jax.vmap(optimizer.init), out_shardings=sh)(params),
         clock=jax.device_put(jnp.zeros(n, jnp.float32), sh),
-        step=jnp.int32(0),
+        # Committed and replicated, which is how the step hands it back: an
+        # uncommitted scalar here gives the second call another input
+        # signature, and the whole train step compiles a second time.
+        step=jax.device_put(jnp.int32(0), replicated_sharding(transport.mesh)),
         model_state=put(stacked_model_state)
         if stacked_model_state is not None
         else None,
@@ -119,8 +120,13 @@ def stack_params(params: PyTree, n_peers: int) -> PyTree:
 def init_params_per_peer(
     init_fn: Callable[[jax.Array], PyTree], key: jax.Array, n_peers: int
 ) -> PyTree:
-    """Independent random init per peer (diverged cold start)."""
-    return jax.vmap(init_fn)(jax.random.split(key, n_peers))
+    """Independent random init per peer (diverged cold start).
+
+    Jitted: a flax ``init`` runs the model's forward pass, and op by op on
+    an accelerator that is a compilation per layer (over two minutes for
+    ResNet-50 on a v5e, against a 60 s compile of the train step).  As one
+    program the forward pass is dead code and only the initializers run."""
+    return jax.jit(jax.vmap(init_fn))(jax.random.split(key, n_peers))
 
 
 def _make_step(
@@ -221,6 +227,13 @@ def _make_step(
             (partner[None], alpha[None], part[None]),
         )
 
+    # Unchecked map (check_vma=False), on every shard_map in the package.
+    # Under the checked map a ``pallas_call`` is refused unless its
+    # ``out_shape`` carries ``vma``, and the flash kernels a Llama
+    # ``loss_fn`` reaches on a TPU are the library's: they build their
+    # ``out_shape`` themselves, so the other road (vma on the kernels'
+    # outputs) would mean keeping copies of them.  Nothing here leaned on
+    # the check: every differentiated operand varies over ``axis``.
     mapped = shard_map(
         body,
         mesh=mesh,
@@ -230,6 +243,7 @@ def _make_step(
         out_specs=(
             P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
         ),
+        check_vma=False,
     )
 
     # Donated: each call consumes the input state's buffers (the caller
@@ -383,7 +397,8 @@ def make_gossip_eval_fn(
         return one(params, x, y)[None]
 
     mapped = shard_map(
-        body, mesh=mesh, in_specs=(P(axis), P(), P()), out_specs=P(axis)
+        body, mesh=mesh, in_specs=(P(axis), P(), P()), out_specs=P(axis),
+        check_vma=False,  # apply_fn may reach a Pallas kernel; see _make_step
     )
     return jax.jit(mapped)
 

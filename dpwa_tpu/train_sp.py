@@ -35,9 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
-
-from dpwa_tpu.utils.compat import shard_map_unchecked as shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dpwa_tpu.config import DpwaConfig
@@ -104,13 +102,12 @@ def _make_sp_step(
     with_state: bool,
     overlap: bool,
     sp_axis: str,
-    debug_sp_invariance: bool,
 ):
     """Shared builder behind both public sp step factories.
 
     Mirrors :func:`dpwa_tpu.train._make_step` with the sp additions: the
     loss arrives as a (sum, count) pair psummed over ``sp``; gradients
-    come back sp-invariant through the replicated-operand transpose; and
+    are psummed over ``sp`` too; and
     ``model_state`` is ``pmean``-ed over ``sp`` after the forward pass
     (each sp rank computes statistics on its own sequence block — the
     reduction is what keeps every rank of a replica bit-identical before
@@ -153,29 +150,13 @@ def _make_sp_step(
         else:
             (loss_sum, count), grads = grad_fn(params, local_batch)
             new_model_state = ()
-        # NO manual psum on grads: ``params`` enter replicated over
-        # ``sp`` (spec names only ``peers``), and the transpose rule for
-        # a replicated operand ALREADY sums its cotangents across the
-        # axis — ``grads`` comes back sp-invariant and equal to
-        # d(sum of all blocks' losses)/d(params).  (Ring-attention
-        # cross-block terms flow through the transposed ppermutes.)  A
-        # manual psum here would multiply the gradient by sp.
-        if debug_sp_invariance:
-            # Pin the no-manual-psum rule explicitly (ADVICE r2): the
-            # max relative deviation of this rank's grads from the sp
-            # mean must be ~0.  Exposed to the caller per peer; a JAX
-            # upgrade that breaks the transpose rule trips the gate
-            # test before it silently mistrains.
-            devs = [
-                jnp.max(
-                    jnp.abs(g - lax.pmean(g, sp_axis))
-                    / (jnp.abs(lax.pmean(g, sp_axis)) + 1e-8)
-                )
-                for g in jax.tree.leaves(grads)
-            ]
-            sp_grad_dev = jnp.max(jnp.stack(devs)).astype(jnp.float32)
-        else:
-            sp_grad_dev = jnp.float32(0.0)
+        # ``params`` enter replicated over ``sp``, so each rank holds the
+        # gradient of every block's loss through ITS OWN copy (ring and
+        # all-to-all cross-block terms arrive through the transposed
+        # collectives); the gradient of the shared parameters is their sum
+        # over ``sp``.  The map is unchecked (see below), so nothing
+        # inserts that sum for us.
+        grads = jax.tree.map(lambda g: lax.psum(g, sp_axis), grads)
         loss_sum = lax.psum(loss_sum, sp_axis)
         count = lax.psum(count, sp_axis)
         loss = (loss_sum / jnp.maximum(count, 1.0)).astype(jnp.float32)
@@ -230,12 +211,14 @@ def _make_sp_step(
             clock[None],
             loss[None],
             (partner[None], alpha[None], part[None]),
-            sp_grad_dev[None],
         )
 
     # A single spec is a valid pytree prefix for any batch structure whose
     # leaves are [n_peers, B, T] blocks.
     batch_spec = P(peers_axis, None, sp_axis)
+    # Unchecked for the reason train._make_step gives (the attention hops
+    # are library Pallas kernels on a TPU).  What the check used to supply
+    # here is the gradient psum above.
     mapped = shard_map(
         body,
         mesh=mesh,
@@ -255,8 +238,8 @@ def _make_sp_step(
             P(peers_axis),
             P(peers_axis),
             (P(peers_axis), P(peers_axis), P(peers_axis)),
-            P(peers_axis),
         ),
+        check_vma=False,
     )
 
     @functools.partial(jax.jit, donate_argnums=(0,))
@@ -266,7 +249,7 @@ def _make_sp_step(
             if state.loss is not None
             else jnp.zeros_like(state.clock)
         )
-        params, opt_state, model_state, clock, losses, info, sp_dev = mapped(
+        params, opt_state, model_state, clock, losses, info = mapped(
             state.params,
             state.opt_state,
             state.model_state if with_state else (),
@@ -283,7 +266,7 @@ def _make_sp_step(
             model_state=model_state if with_state else state.model_state,
             loss=losses,
         )
-        return new_state, losses, ExchangeInfo(*info), sp_dev
+        return new_state, losses, ExchangeInfo(*info)
 
     # CPU run-ahead bound: reuse the transport's detection (see the
     # rationale comment in IciTransport.__init__).
@@ -302,12 +285,10 @@ def _make_sp_step(
                 "state.model_state is None; pass stacked_model_state to "
                 "init_gossip_sp_state"
             )
-        new_state, losses, info, sp_dev = _step(state, batch)
+        out = _step(state, batch)
         if block_per_call:
-            jax.block_until_ready((new_state, losses, info, sp_dev))
-        if debug_sp_invariance:
-            return new_state, losses, info, sp_dev
-        return new_state, losses, info
+            jax.block_until_ready(out)
+        return out
 
     return train_step
 
@@ -319,7 +300,6 @@ def make_gossip_sp_train_step(
     exchange_filter: Optional[Callable[[str], bool]] = None,
     overlap: bool = False,
     sp_axis: str = SP_AXIS,
-    debug_sp_invariance: bool = False,
 ):
     """Jitted ``train_step(state, batch) -> (state, losses, info)`` on a
     ``(peers, sp)`` mesh.
@@ -335,16 +315,10 @@ def make_gossip_sp_train_step(
     5's actual long-context layout (BASELINE.json:11): LoRA adapters
     gossip over ``peers`` while the frozen base weights never enter the
     collective.  ``overlap`` ships the pre-update replica exactly as in
-    :func:`dpwa_tpu.train.make_gossip_train_step`.
-
-    ``debug_sp_invariance=True`` adds a fourth return — per-peer max
-    relative deviation of this step's gradients across sp ranks, which
-    must be ~0 (the no-manual-psum correctness invariant, pinned by
-    ``tests/test_sp_train.py``)."""
+    :func:`dpwa_tpu.train.make_gossip_train_step`."""
     return _make_sp_step(
         loss_fn, optimizer, transport, exchange_filter, with_state=False,
         overlap=overlap, sp_axis=sp_axis,
-        debug_sp_invariance=debug_sp_invariance,
     )
 
 
@@ -355,7 +329,6 @@ def make_gossip_sp_train_step_with_state(
     exchange_filter: Optional[Callable[[str], bool]] = None,
     overlap: bool = False,
     sp_axis: str = SP_AXIS,
-    debug_sp_invariance: bool = False,
 ):
     """Like :func:`make_gossip_sp_train_step`, for models with
     non-parameter variables.
@@ -369,7 +342,6 @@ def make_gossip_sp_train_step_with_state(
     return _make_sp_step(
         loss_fn, optimizer, transport, exchange_filter, with_state=True,
         overlap=overlap, sp_axis=sp_axis,
-        debug_sp_invariance=debug_sp_invariance,
     )
 
 

@@ -7,12 +7,11 @@ Every example (the reference keeps one per benchmark config,
   mesh (one device per peer, the real multi-chip layout); ``stacked`` runs
   every peer on ONE device as a stacked leading axis (the single-chip
   benchmarking mode, SURVEY.md §7 note: the dev box has one chip).
-- ``--devices auto|cpu|native`` — device policy.  For ``ici``: ``native``
-  requires a real accelerator mesh, ``cpu`` forces the emulated host mesh,
-  ``auto`` picks.  For ``stacked``: ``auto`` keeps jax's default device
-  (the real chip when present), ``cpu`` forces the CPU backend, ``native``
-  errors rather than silently reporting a CPU fallback's steps/sec as a
-  single-chip number.
+- ``--devices auto|native|cpu`` — device policy
+  (:mod:`dpwa_tpu.utils.devices`): ``auto`` and ``native`` both run on the
+  platform JAX selected and raise when it has fewer devices than the
+  transport needs (one per peer for ``ici``, one for ``stacked``); only
+  ``cpu`` gives the emulated host mesh.
 
 :func:`build_transport` returns the transport plus the matching
 state-init / train-step constructors, so an example's training loop is
@@ -55,6 +54,28 @@ def child_process_env(
     return env
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and no
+    directory is set in code.  Otherwise the cache lives at
+    ``<checkout>/.jax_cache``, derived from this package's location: the
+    path is part of the cache key, so it must not move between runs."""
+    import jax
+
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    cache_dir = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)
+        ))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def add_transport_args(ap: argparse.ArgumentParser) -> None:
     ap.add_argument(
         "--transport", choices=("ici", "stacked"), default="ici",
@@ -64,7 +85,8 @@ def add_transport_args(ap: argparse.ArgumentParser) -> None:
     )
     ap.add_argument(
         "--devices", default="auto", choices=("auto", "cpu", "native"),
-        help="device policy; see dpwa_tpu.utils.launch",
+        help="'auto'/'native': the platform jax selected, error when it is "
+        "short of devices; 'cpu': the emulated host mesh",
     )
     ap.add_argument(
         "--wire-dtype", default=None, choices=("f32", "bf16", "int8"),
@@ -97,30 +119,6 @@ class TransportBundle(NamedTuple):
     config: object = None  # the EFFECTIVE config (wire_dtype applied)
 
 
-def apply_device_policy(cfg, transport: str, devices: str) -> None:
-    """Enforce the ``--devices`` policy BEFORE jax initializes a backend."""
-    from dpwa_tpu.utils.devices import ensure_devices
-
-    if transport == "ici":
-        ensure_devices(cfg.n_peers, mode=devices)
-        return
-    # Stacked needs one device and should keep jax's native pick (the
-    # real chip) — ensure_devices' auto mode would force the emulated
-    # CPU mesh, which is for multi-device ICI runs.  The policy still
-    # applies: 'cpu' forces CPU, 'native' must not silently report a
-    # CPU fallback's steps/sec as a single-chip number.
-    if devices == "cpu":
-        ensure_devices(1, mode="cpu")
-    elif devices == "native":
-        import jax
-
-        if jax.devices()[0].platform == "cpu":
-            raise RuntimeError(
-                "--devices native: no accelerator available (jax picked "
-                "cpu); drop --devices or use --devices cpu explicitly"
-            )
-
-
 def build_transport(
     cfg,
     transport: str = "ici",
@@ -136,8 +134,11 @@ def build_transport(
     :func:`add_transport_args`) is applied HERE so a caller can never
     accept the flag yet silently ignore it; read the effective config
     back from ``bundle.config``."""
+    from dpwa_tpu.utils.devices import ensure_devices
+
     cfg = apply_wire_dtype(cfg, wire_dtype)
-    apply_device_policy(cfg, transport, devices)
+    ensure_devices(1 if transport == "stacked" else cfg.n_peers, mode=devices)
+    enable_compile_cache()
     if transport == "stacked":
         from dpwa_tpu.parallel.stacked import (
             StackedTransport,
@@ -158,9 +159,10 @@ def build_transport(
     from dpwa_tpu.train import init_gossip_state, make_gossip_train_step
 
     t = IciTransport(cfg, mesh=make_mesh(cfg))
-    # Stage batches peer-sharded for the mesh path (a whole batch committed
-    # to one device would be resharded inside the jitted shard_map, which
-    # the thread-starved forced-CPU mesh cannot always service).
+    # Stage batches peer-sharded for the mesh path: a whole batch committed
+    # to one device is resharded inside the jitted shard_map every step —
+    # a copy through the first chip on a real mesh, and more than the
+    # thread-starved forced-CPU mesh can always service.
     return TransportBundle(
         transport=t,
         init_state=init_gossip_state,

@@ -141,8 +141,11 @@ def main() -> None:
         for _ in range(args.seq_len - 1):
             seq.append((2 * seq[-1] + 1) % V)
         tokens = np.concatenate(seq, axis=-1)
-        inputs, targets, weights = mlm_mask_batch(tokens, rng)
-        return jnp.asarray(inputs), jnp.asarray(targets), jnp.asarray(weights)
+        # Straight from numpy to the layout the step consumes (peer-sharded
+        # on a mesh, the one device when stacked).
+        return jax.device_put(
+            mlm_mask_batch(tokens, rng), bundle.batch_sharding
+        )
 
     metrics = MetricsLogger(stream=sys.stdout, every=args.log_every)
     state, losses, info = step_fn(state, batch())

@@ -152,10 +152,11 @@ def main() -> None:
         seq = [starts]
         for _ in range(args.seq_len):
             seq.append((3 * seq[-1] + 1) % V)
-        toks = np.concatenate(seq, axis=-1)
-        return (
-            jnp.asarray(toks[..., :-1], jnp.int32),
-            jnp.asarray(toks[..., 1:], jnp.int32),
+        toks = np.concatenate(seq, axis=-1).astype(np.int32)
+        # Straight from numpy to the layout the step consumes (peer-sharded
+        # on a mesh, the one device when stacked).
+        return jax.device_put(
+            (toks[..., :-1], toks[..., 1:]), bundle.batch_sharding
         )
 
     metrics = MetricsLogger(stream=sys.stdout, every=args.log_every)

@@ -111,24 +111,19 @@ def run_single_process(args, stacked: bool) -> None:
     (``--transport ici``) or a stacked virtual-peer axis on one device
     (``--transport stacked``).  Same data, model, loop, and report."""
     from dpwa_tpu.config import load_config
+    from dpwa_tpu.utils.launch import build_transport
 
-    cfg = load_config(args.config)
-    if not stacked:
-        from dpwa_tpu.utils.devices import ensure_devices
-
-        ensure_devices(cfg.n_peers, mode=args.devices)
-    elif args.devices == "cpu":
-        from dpwa_tpu.utils.devices import ensure_devices
-
-        ensure_devices(1, mode="cpu")
-    elif args.devices == "native":
-        import jax
-
-        if jax.devices()[0].platform == "cpu":
-            raise RuntimeError(
-                "--devices native: no accelerator available (jax picked "
-                "cpu); drop --devices or use --devices cpu explicitly"
-            )
+    bundle = build_transport(
+        load_config(args.config),
+        "stacked" if stacked else "ici",
+        args.devices,
+    )
+    cfg, transport = bundle.config, bundle.transport
+    init_state, make_step = bundle.init_state, bundle.make_step
+    eval_transport = bundle.eval_transport
+    # Batches are staged in the layout the step consumes: peer-sharded
+    # over the mesh for ICI, single-device for stacked.
+    batch_sharding = bundle.batch_sharding
 
     import jax
     import jax.numpy as jnp
@@ -144,35 +139,6 @@ def run_single_process(args, stacked: bool) -> None:
     from dpwa_tpu.utils.pytree import tree_wire_bytes
 
     n = cfg.n_peers
-    if stacked:
-        from dpwa_tpu.parallel.stacked import (
-            StackedTransport,
-            init_stacked_state,
-            make_stacked_train_step,
-        )
-
-        transport = StackedTransport(cfg)
-        init_state, make_step = init_stacked_state, make_stacked_train_step
-        eval_transport = None
-    else:
-        from dpwa_tpu.parallel.ici import IciTransport
-        from dpwa_tpu.parallel.mesh import make_mesh
-        from dpwa_tpu.train import init_gossip_state, make_gossip_train_step
-
-        transport = IciTransport(cfg, mesh=make_mesh(cfg))
-        init_state, make_step = init_gossip_state, make_gossip_train_step
-        eval_transport = transport
-
-    # Stage batches in the layout the step consumes: peer-sharded over the
-    # mesh for ICI, single-device for stacked.  (A batch committed whole to
-    # one device would be resharded inside the jitted shard_map, which the
-    # thread-starved forced-CPU mesh cannot always service.)
-    batch_sharding = None
-    if not stacked:
-        from dpwa_tpu.parallel.mesh import peer_sharding
-
-        batch_sharding = peer_sharding(transport.mesh)
-
     x_tr, y_tr, x_te, y_te, dataset = load_mnist_or_digits()
     model = build_model(x_tr.shape[1:])
     init = lambda k: model.init(k, jnp.zeros((1,) + x_tr.shape[1:]))
@@ -250,15 +216,16 @@ def main() -> None:
     ap.add_argument("--save-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument(
-        "--platform", default="cpu",
-        help="TCP mode: jax platform per worker (default cpu)",
+        "--platform", default=None,
+        help="TCP mode: pin this worker's jax platform (run_tcp.sh passes "
+        "cpu: a chip belongs to one process, so co-hosted workers cannot "
+        "all take it); default: the platform jax selects",
     )
     ap.add_argument(
         "--devices", default="auto", choices=("auto", "cpu", "native"),
-        help="ici: 'native' requires a real accelerator mesh, 'cpu' forces "
-        "an emulated host mesh, 'auto' picks.  stacked: 'native' errors "
-        "unless an accelerator is present, 'cpu' forces the CPU backend, "
-        "'auto' keeps jax's default device",
+        help="ici/stacked: 'auto'/'native' run on the platform jax "
+        "selected and error when it is short of devices; 'cpu' gives the "
+        "emulated host mesh",
     )
     args = ap.parse_args()
     if args.resume and not args.checkpoint:

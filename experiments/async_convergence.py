@@ -343,7 +343,7 @@ def run_tcp(
     ]
     # Workers exit on their own after steps + grace (the grace sleep keeps
     # each Rx server alive for laggards' fetches).  The wait is wall-clock
-    # bounded so one wedged worker aborts the leg instead of hanging the
+    # bounded so one hung worker aborts the leg instead of hanging the
     # whole multi-seed study; a dead or hung worker never leaks the others
     # (they hold the port range).
     # Rendezvous + jit startup + generous step time.  ResNet-20 on this
@@ -382,9 +382,9 @@ def run_spmd(transport_kind: str, seed: int, steps: int) -> None:
     import numpy as np
 
     if transport_kind == "ici":
-        from dpwa_tpu.utils.devices import repoint_to_host_mesh
+        from dpwa_tpu.utils.devices import ensure_devices
 
-        repoint_to_host_mesh(N_PEERS)
+        ensure_devices(N_PEERS, mode="cpu")
     else:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax

@@ -62,7 +62,7 @@ def probe(impl: str, T: int, iters: int) -> float:
     import jax.numpy as jnp
     import numpy as np
 
-    from dpwa_tpu.utils.profiling import measure_sync_rtt, timed_loop
+    from dpwa_tpu.utils.profiling import timed_loop
 
     block = build_block(impl)
     x = jax.random.normal(
@@ -72,7 +72,7 @@ def probe(impl: str, T: int, iters: int) -> float:
     params = None
 
     if impl == "ring":
-        from dpwa_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
 
         mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
@@ -102,15 +102,11 @@ def probe(impl: str, T: int, iters: int) -> float:
             return jnp.sum(out.astype(jnp.float32) ** 2)
 
     grad_fn = jax.jit(jax.grad(loss))
-    rtt = measure_sync_rtt()
     per_iter, _ = timed_loop(
         lambda g, k: grad_fn(params, x),
-        lambda g: float(jax.tree.leaves(g)[0].sum()),
         grad_fn(params, x),
         iters,
         warmup=1,
-        sync_rtt=rtt,
-        label=f"{impl}-T{T}",
     )
     return float(per_iter)
 

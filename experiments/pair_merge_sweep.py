@@ -35,11 +35,9 @@ def main() -> int:
 
     from dpwa_tpu.ops.merge import involution_pairs, pallas_pair_merge
     from dpwa_tpu.parallel.schedules import _ring_even, _ring_odd
-    from dpwa_tpu.utils.profiling import measure_sync_rtt, timed_loop
+    from dpwa_tpu.utils.profiling import timed_loop
 
     print(f"backend: {jax.default_backend()}", file=sys.stderr)
-    sync_rtt = measure_sync_rtt()
-    print(f"sync RTT: {sync_rtt*1e3:.1f} ms (subtracted)", file=sys.stderr)
     on_tpu = jax.default_backend() == "tpu"
     n, d = args.peers, args.size
     pools = [_ring_even(n), _ring_odd(n)]
@@ -64,12 +62,9 @@ def main() -> int:
                         b, lefts[step % 2], rights[step % 2], alphas,
                         r_block=r_block, n_buf=n_buf, interpret=not on_tpu,
                     ),
-                    lambda b: float(b.sum()),
                     x,
                     args.iters,
                     warmup=2,
-                    sync_rtt=sync_rtt,
-                    label=f"sweep[{r_block},{n_buf}]",
                 )
             except Exception as e:  # noqa: BLE001 - report and keep sweeping
                 print(f"r_block={r_block} n_buf={n_buf}: FAILED {e}")

@@ -41,9 +41,9 @@ BATCH = 16
 def run() -> dict:
     import numpy as np
 
-    from dpwa_tpu.utils.devices import repoint_to_host_mesh
+    from dpwa_tpu.utils.devices import ensure_devices
 
-    repoint_to_host_mesh(N_PEERS)
+    ensure_devices(N_PEERS, mode="cpu")
     import jax
     import jax.numpy as jnp
     import optax

@@ -81,9 +81,9 @@ def train_digits_gossip(
     consensus-model accuracy)."""
     import numpy as np
 
-    from dpwa_tpu.utils.devices import repoint_to_host_mesh
+    from dpwa_tpu.utils.devices import ensure_devices
 
-    repoint_to_host_mesh(n)
+    ensure_devices(n, mode="cpu")
     import jax
     import jax.numpy as jnp
     import optax
@@ -175,8 +175,8 @@ def main() -> None:
         env = os.environ.copy()
         env["JAX_PLATFORMS"] = "cpu"
         # Append (not clobber): keep any operator-exported XLA flags.
-        # repoint_to_host_mesh in the child is the fallback; flags in the
-        # launch env are the reliable path (XLA parses them once).
+        # Flags in the launch env are the reliable path (XLA parses them
+        # once per process).
         count = f"--xla_force_host_platform_device_count={LAYOUTS[name]['n']}"
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + count).strip()
         proc = subprocess.run(

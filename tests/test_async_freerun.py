@@ -74,7 +74,7 @@ def _run_workers(tmp_path, attempt: int):
             )
         )
     # Workers exit on their own after steps + grace; bound the wait so a
-    # wedged worker is classified instead of hanging the pytest session.
+    # hung worker is classified instead of hanging the pytest session.
     stdouts = []
     try:
         for p in procs:

@@ -1,0 +1,364 @@
+"""Bring-up contracts that the chip run depends on, checked on the CPU mesh.
+
+Each test pins one thing `chip_smoke.py` needs to be true before a
+chip-minute is spent: the device policy never swaps an accelerator for the
+emulated mesh unasked, the compile cache is placed from outside, the smoke
+refuses a CPU, the Llama steps trace with a Pallas kernel inside the
+installed ``shard_map``, frames land on the default device, and the bench
+has no road that ends in a number without a chip."""
+
+import json
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from dpwa_tpu.config import make_local_config
+from dpwa_tpu.utils.devices import ensure_devices
+from dpwa_tpu.utils.launch import build_transport, enable_compile_cache
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, _ROOT)
+
+import bench  # noqa: E402
+import chip_smoke  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _no_cache_directory_set_in_code(monkeypatch, tmp_path):
+    """`build_transport` turns the compile cache on.  With the variable set
+    the helper sets no directory in code (and JAX read the variable at
+    import, before it was set), so the rest of the session compiles as
+    before instead of reading and writing `<checkout>/.jax_cache`."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+
+
+# ---------------------------------------------------------------------------
+# Device policy
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("mode", ["auto", "native"])
+def test_device_policy_raises_when_short_instead_of_emulating(mode):
+    have = len(jax.devices())
+    assert ensure_devices(have, mode=mode) == jax.devices()
+    with pytest.raises(RuntimeError, match="xla_force_host_platform"):
+        ensure_devices(have + 1, mode=mode)
+    # Nothing was repointed on the way to the error.
+    assert len(jax.devices()) == have
+
+
+def test_devices_cpu_still_gives_the_emulated_mesh(monkeypatch):
+    monkeypatch.setenv("XLA_FLAGS", "")
+    devices = ensure_devices(4, mode="cpu")
+    assert [d.platform for d in devices] == ["cpu"] * 4
+    assert os.environ["XLA_FLAGS"].endswith("device_count=4")
+    bundle = build_transport(make_local_config(4), "ici", "cpu")
+    assert bundle.transport.mesh.devices.size == 4
+
+
+def test_devices_cpu_refuses_a_backend_that_is_already_an_accelerator(
+    monkeypatch,
+):
+    class FakeChip:
+        platform = "tpu"
+
+    monkeypatch.setattr(jax, "devices", lambda: [FakeChip()] * 8)
+    with pytest.raises(RuntimeError, match="need 4 devices, have 8 .tpu."):
+        ensure_devices(4, mode="cpu")
+
+
+def test_device_policy_holds_no_backend_reset():
+    import dpwa_tpu.utils.devices as devices_mod
+
+    with open(devices_mod.__file__, encoding="utf-8") as f:
+        src = f.read()
+    assert "xla_bridge" not in src and "clear_backends" not in src
+    assert not hasattr(devices_mod, "repoint_to_host_mesh")
+
+
+def test_make_mesh_takes_a_prefix_that_is_not_a_sub_torus(monkeypatch):
+    """Three peers on a four-chip host: `mesh_utils.create_device_mesh`
+    refuses three chips of a 2x2 torus, so a prefix keeps enumeration
+    order.  The torus comes from libtpu's compile-only topology."""
+    from jax.experimental import topologies
+
+    from dpwa_tpu.parallel.mesh import make_mesh
+
+    try:
+        chips = topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"no TPU topology description: {e}")
+    monkeypatch.setattr(jax, "devices", lambda: list(chips))
+    ids = lambda n: [d.id for d in make_mesh(make_local_config(n)).devices]
+    assert ids(3) == [0, 1, 2]
+    # The whole slice is still ordered as a ring along the torus.
+    assert ids(4) == [0, 1, 3, 2]
+
+
+# ---------------------------------------------------------------------------
+# Compile cache
+# ---------------------------------------------------------------------------
+
+
+def test_compile_cache_obeys_the_environment(tmp_path):
+    before = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        first = enable_compile_cache()
+        assert first == os.path.join(_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == first
+        assert enable_compile_cache() == first  # no pid, clock or tempdir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py
+# ---------------------------------------------------------------------------
+
+
+def test_chip_smoke_refuses_a_cpu_without_the_rehearsal_argument(capsys):
+    assert jax.devices()[0].platform == "cpu"
+    assert chip_smoke.main([]) == chip_smoke.EXIT_NO_ACCELERATOR
+    out = capsys.readouterr()
+    assert out.out == "" and "not a TPU" in out.err
+
+
+def test_chip_smoke_rehearsal_leg_passes(tmp_path, capsys):
+    rc = chip_smoke.main(["--rehearse-cpu", "--legs", "host_device_merge"])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert rc == 0, lines
+    leg, cache, last = lines
+    assert leg["leg"] == "host_device_merge" and leg["status"] == "ok"
+    assert leg["handoff"]["h2d_transfers"] == 3
+    assert cache["leg"] == "compile_cache" and cache["dir"] == str(tmp_path)
+    assert last["ok"] is True and last["device"]["platform"] == "cpu"
+    assert set(last["device"]) == {"platform", "kind", "count"}
+
+
+@pytest.mark.slow
+def test_chip_smoke_full_rehearsal_passes(capsys):
+    rc = chip_smoke.main(["--rehearse-cpu"])
+    lines = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+    assert rc == 0, lines
+    assert [r["status"] for r in lines[:-2]] == ["ok"] * 7
+
+
+@pytest.mark.parametrize("transport", ["ici", "stacked"])
+def test_train_step_compiles_once(transport):
+    """The state a step hands back has the signature of the state it was
+    given (the scalar ``step`` used to come back committed and replicated,
+    and every ICI run compiled its train step twice — 49 s for ResNet-50 on
+    the v5e).  ``run_steps`` fails when a later call lowers a program."""
+    n = 2
+    bundle = build_transport(make_local_config(n), transport)
+    params = {"w": jnp.ones((n, 8, 4)), "b": jnp.zeros((n, 4))}
+    opt = optax.sgd(0.1, momentum=0.9)
+    state = bundle.init_state(params, opt, bundle.transport)
+
+    def loss_fn(p, batch):
+        x, y = batch
+        return jnp.mean((x @ p["w"] + p["b"] - y) ** 2)
+
+    step_fn = bundle.make_step(loss_fn, opt, bundle.transport)
+    batch = jax.device_put(
+        (np.ones((n, 2, 8), np.float32), np.ones((n, 2, 4), np.float32)),
+        bundle.batch_sharding,
+    )
+    _, losses, _, _, later = chip_smoke.run_steps(step_fn, state, batch, 2)
+    assert len(later) == 2 and losses[-1] < losses[0]
+
+
+# ---------------------------------------------------------------------------
+# A Pallas kernel inside the installed shard_map (the chip's Llama path)
+# ---------------------------------------------------------------------------
+
+_LLAMA = dict(
+    vocab_size=64, d_model=256, n_heads=2, n_kv_heads=1, d_ff=64,
+    n_layers=1, max_seq_len=512, lora_rank=2,
+)
+
+
+def _abstract_llama_state(n, sharding):
+    from dpwa_tpu.models.llama import Llama, LlamaConfig, lora_optimizer
+    from dpwa_tpu.train import GossipTrainState, init_params_per_peer
+
+    init_model = Llama(LlamaConfig(**_LLAMA))
+    params = jax.eval_shape(
+        lambda: init_params_per_peer(
+            lambda k: init_model.init(k, jnp.zeros((1, 8), jnp.int32)),
+            jax.random.key(0), n,
+        )
+    )
+    opt = lora_optimizer(
+        optax.sgd(0.1),
+        jax.tree.map(
+            lambda v: jax.ShapeDtypeStruct(v.shape[1:], v.dtype), params
+        ),
+    )
+    spec = lambda t: jax.tree.map(
+        lambda v: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding), t
+    )
+    per_peer = jax.ShapeDtypeStruct((n,), jnp.float32, sharding=sharding)
+    state = GossipTrainState(
+        params=spec(params),
+        opt_state=spec(jax.eval_shape(jax.vmap(opt.init), params)),
+        clock=per_peer,
+        step=jax.ShapeDtypeStruct((), jnp.int32),
+        loss=per_peer,
+    )
+    return opt, state
+
+
+def _kernels_traced(step_fn, state, batch) -> int:
+    jaxpr = jax.make_jaxpr(step_fn)(state, batch)
+    return str(jaxpr).count("pallas_call")
+
+
+def test_llama_1d_step_traces_with_the_flash_kernel_inside_shard_map():
+    from dpwa_tpu.models.llama import Llama, LlamaConfig, lora_filter
+    from dpwa_tpu.parallel.ici import IciTransport
+    from dpwa_tpu.parallel.mesh import peer_sharding
+    from dpwa_tpu.train import make_gossip_train_step
+
+    n, T = 2, 128
+    transport = IciTransport(make_local_config(n))
+    sh = peer_sharding(transport.mesh)
+    opt, state = _abstract_llama_state(n, sh)
+    model = Llama(LlamaConfig(**_LLAMA, attn_impl="flash"))
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(params, x), y
+        ).mean()
+
+    step_fn = make_gossip_train_step(
+        loss_fn, opt, transport, exchange_filter=lora_filter
+    )
+    tokens = jax.ShapeDtypeStruct((n, 1, T), jnp.int32, sharding=sh)
+    # Forward, dq and dkv kernels: refused outright by a checked map.
+    assert _kernels_traced(step_fn, state, (tokens, tokens)) >= 3
+
+
+@pytest.mark.parametrize(
+    "variant",
+    [
+        dict(),
+        dict(sp_layout="zigzag"),
+        dict(sp_strategy="a2a", attn_impl="flash"),
+    ],
+    ids=["ring", "zigzag", "a2a"],
+)
+def test_llama_2d_step_traces_with_pallas_hops_inside_shard_map(
+    monkeypatch, variant
+):
+    from dpwa_tpu.models.llama import Llama, LlamaConfig, lora_filter
+    from dpwa_tpu.parallel.ici import IciTransport
+    from dpwa_tpu.parallel.mesh import peer_sharding
+    from dpwa_tpu.train_sp import (
+        make_gossip_sp_train_step,
+        make_sp_mesh,
+        sp_batch_sharding,
+    )
+
+    # The dispatchers answer as on the chip, so the ring hops are the
+    # Pallas kernels; tracing needs no TPU, only lowering would.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n, sp, T_local = 2, 2, 256
+    cfg = make_local_config(n)
+    mesh = make_sp_mesh(cfg, sp)
+    transport = IciTransport(cfg, mesh=mesh)
+    opt, state = _abstract_llama_state(n, peer_sharding(mesh))
+    model = Llama(LlamaConfig(**_LLAMA, sp_axis="sp", **variant))
+
+    def sp_loss(params, batch):
+        x, y = batch
+        losses = optax.softmax_cross_entropy_with_integer_labels(
+            model.apply(params, x), y
+        )
+        return losses.sum(), jnp.float32(losses.size)
+
+    step_fn = make_gossip_sp_train_step(
+        sp_loss, opt, transport, exchange_filter=lora_filter
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (n, 1, sp * T_local), jnp.int32, sharding=sp_batch_sharding(mesh)
+    )
+    assert _kernels_traced(step_fn, state, (tokens, tokens)) >= 3
+
+
+# ---------------------------------------------------------------------------
+# Host frames onto the device
+# ---------------------------------------------------------------------------
+
+
+def _aligned(n: int) -> np.ndarray:
+    from dpwa_tpu.device.handoff import ALIGN
+
+    raw = np.zeros(n * 4 + ALIGN, np.uint8)
+    off = (-raw.ctypes.data) % ALIGN
+    out = raw[off:off + n * 4].view(np.float32)
+    out[:] = np.arange(n)
+    return out
+
+
+def test_to_device_lands_on_the_default_device(monkeypatch):
+    from dpwa_tpu.device import handoff
+
+    frame = _aligned(1024)
+    handoff.reset_handoff_stats()
+    adopted = handoff.to_device(frame)
+    assert adopted.devices() == {jax.devices()[0]}
+    assert handoff.handoff_stats()["h2d_zero_copy"] == 1
+    # On an accelerator backend a dlpack import would stay on the CPU
+    # backend, away from the replica: the frame is put on the device.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    put = handoff.to_device(frame)
+    assert put.devices() == {jax.devices()[0]}
+    stats = handoff.handoff_stats()
+    assert (stats["h2d_transfers"], stats["h2d_zero_copy"]) == (2, 1)
+    np.testing.assert_array_equal(np.asarray(put), frame)
+
+
+# ---------------------------------------------------------------------------
+# bench.py
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "device_leg",
+    [
+        (None, None),  # the child failed or hung
+        (0.8, {"platform": "cpu", "device_kind": "cpu", "device_count": 1,
+               "path": "xla-merge"}),
+    ],
+    ids=["leg-failed", "landed-on-cpu"],
+)
+def test_bench_exits_nonzero_without_a_device_number(
+    monkeypatch, capsys, device_leg
+):
+    def fake_run_leg(leg, extra, tag, timeout_s, env, json_tag=None):
+        if leg == "--device-leg":
+            return device_leg
+        return 0.25, {"spread_iqr_frac": 0.01}
+
+    monkeypatch.setattr(bench, "run_leg", fake_run_leg)
+    monkeypatch.setattr(
+        sys, "argv", ["bench.py", "--skip-wire", "--skip-serve"]
+    )
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    assert capsys.readouterr().out == ""  # no result line, nothing replayed

@@ -147,7 +147,7 @@ def test_flash_ring_bf16_inputs():
 def test_flash_ring_composes_with_peer_axis():
     """2-D (peers, sp) mesh: flash-ring inside each replica + gossip
     ppermute across peers — the long-context gossip layout."""
-    from dpwa_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from dpwa_tpu.ops.ring_attention import ring_attention_local

@@ -6,7 +6,7 @@ Covers the event-loop server's headline claims:
   on ONE loop thread (tests/fleet_worker.py drives the fleet);
 - the PR 5 malformed-frame corpus — truncations, bit-flipped magics,
   lying length fields, garbage, RST mid-request — always ends in a
-  closed connection and a live loop, never a wedge;
+  closed connection and a live loop, never a stall;
 - a 4-node soak under ``rx_server=reactor`` produces byte-identical
   merge trajectories to the threaded server;
 - chaos composes with the reactor: ``rx_server: reactor`` +
@@ -83,7 +83,7 @@ def test_reactor_serves_256_fetching_peers_bounded_wall():
         assert fleet["outcomes"] == {Outcome.SUCCESS: 512}
         # Bounded per-round wall: 512 fetches of a 16 KiB blob on
         # loopback finish in well under a minute even on a loaded CI
-        # box (observed ~1 s); a wedged loop would eat the full fetch
+        # box (observed ~1 s); a stalled loop would eat the full fetch
         # timeout per request instead.
         assert fleet["wall_s"] < 60.0
         # The client can see its last payload a beat before the loop

@@ -618,12 +618,12 @@ def test_supervisor_clean_exit_is_not_restarted():
 
 
 def test_supervisor_healthz_strikeout_restarts_worker():
-    """A wedged-but-alive worker (no /healthz listener) is killed and
+    """A hung-but-alive worker (no /healthz listener) is killed and
     restarted after consecutive probe strikes."""
     sup = Supervisor(
         [
             WorkerSpec(
-                name="wedged",
+                name="hung",
                 argv=[sys.executable, "-c", "import time; time.sleep(60)"],
                 healthz_port=1,  # reserved port: nothing ever listens
             )

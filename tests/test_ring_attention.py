@@ -128,7 +128,7 @@ def test_composes_with_gossip_peer_axis():
     for long-context gossip training."""
     from functools import partial
 
-    from dpwa_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from dpwa_tpu.ops.ring_attention import ring_attention_local

@@ -11,7 +11,7 @@ lands here: a small, stdlib-only process supervisor that
   init);
 - watches for exits, and optionally polls each worker's ``/healthz``
   endpoint (``health.healthz_port`` in the YAML config) to catch the
-  wedged-but-alive case a waitpid can't see;
+  hung-but-alive case a waitpid can't see;
 - restarts crashed workers with capped exponential backoff, setting
   ``DPWA_BOOTSTRAP=1`` in the child environment so the replacement
   rejoins by fetching a healthy donor's full state over the TCP STATE
