@@ -36,6 +36,7 @@ from dpwa_tpu.interpolation import Interpolation, PeerMeta, make_interpolation
 from dpwa_tpu.parallel import schedules
 from dpwa_tpu.parallel.mesh import PEER_AXIS, make_mesh
 from dpwa_tpu.parallel.schedules import Schedule, participation_draw
+from dpwa_tpu.utils import scopes
 
 PyTree = Any
 
@@ -57,6 +58,7 @@ def _perm_pairs(perm) -> Tuple[Tuple[int, int], ...]:
     return tuple((int(perm[i]), int(i)) for i in range(len(perm)))
 
 
+@scopes.scoped(scopes.EXCHANGE)
 def gossip_exchange_local(
     params: PyTree,
     meta: PeerMeta,

@@ -30,12 +30,14 @@ from dpwa_tpu.interpolation import PeerMeta, make_interpolation
 from dpwa_tpu.parallel import schedules
 from dpwa_tpu.parallel.ici import ExchangeInfo
 from dpwa_tpu.parallel.schedules import participation_draw
+from dpwa_tpu.utils import scopes
 from dpwa_tpu.utils.pytree import combine as pytree_combine
 from dpwa_tpu.utils.pytree import partition as pytree_partition
 
 PyTree = Any
 
 
+@scopes.scoped(scopes.EXCHANGE)
 def stacked_gossip_exchange(
     params: PyTree,
     meta: PeerMeta,
@@ -240,7 +242,9 @@ def make_stacked_train_step(
     layout parity with the ICI path, where the dependency-free collective
     genuinely overlaps compute.
     """
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=with_state)
+    grad_fn = jax.value_and_grad(
+        scopes.scoped_loss(loss_fn), has_aux=with_state
+    )
     schedule, interp = transport.schedule, transport.interp
 
     def check_state(state):
@@ -266,8 +270,9 @@ def make_stacked_train_step(
         else:
             loss, grads = grad_fn(params, batch)
             new_model_state = ()
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        new_params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            new_params = optax.apply_updates(params, updates)
         return new_params, updates, opt_state, new_model_state, loss
 
     @functools.partial(jax.jit, donate_argnums=(0,))
@@ -301,7 +306,8 @@ def make_stacked_train_step(
             )
             if overlap:
                 sel_updates, _ = pytree_partition(updates, exchange_filter)
-                merged_sel = optax.apply_updates(merged_sel, sel_updates)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    merged_sel = optax.apply_updates(merged_sel, sel_updates)
             _, rest = pytree_partition(params, exchange_filter)
             merged = pytree_combine(merged_sel, rest)
         else:
@@ -310,7 +316,8 @@ def make_stacked_train_step(
                 schedule=schedule, interp=interp,
             )
             if overlap:
-                merged = optax.apply_updates(merged, updates)
+                with jax.named_scope(scopes.OPTIMIZER):
+                    merged = optax.apply_updates(merged, updates)
         if overlap:
             merged_state = jax.tree.map(
                 lambda m, new, old: m + (new - old),

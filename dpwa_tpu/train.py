@@ -42,6 +42,7 @@ from dpwa_tpu.parallel.ici import (
     gossip_exchange_local,
 )
 from dpwa_tpu.parallel.mesh import peer_sharding, replicated_sharding
+from dpwa_tpu.utils import scopes
 from dpwa_tpu.utils.pytree import combine as pytree_combine
 from dpwa_tpu.utils.pytree import partition as pytree_partition
 
@@ -146,7 +147,9 @@ def _make_step(
     ``overlap`` selects which params the exchange ships (see
     :func:`make_gossip_train_step`): post-update (default, the lock-step
     emulation) or pre-update ``x_k`` (the collective overlaps fwd/bwd)."""
-    grad_fn = jax.value_and_grad(loss_fn, has_aux=with_state)
+    grad_fn = jax.value_and_grad(
+        scopes.scoped_loss(loss_fn), has_aux=with_state
+    )
     schedule, interp = transport.schedule, transport.interp
     axis, mesh = transport.axis_name, transport.mesh
     shard = lambda t: jax.tree.map(lambda v: v[0], t)
@@ -165,8 +168,9 @@ def _make_step(
         else:
             loss, grads = grad_fn(params, shard(batch))
             new_model_state = ()
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIMIZER):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         clock = clock[0] + 1.0
         if overlap:
             # Exchange the PRE-update replica with the PREVIOUS step's
@@ -204,11 +208,12 @@ def _make_step(
             # finished its step yet), the local gradient is never lost.
             # Model state gets the same treatment: merge(ms_k) + this
             # step's statistics delta.
-            if exchange_filter is not None:
-                sel_updates, _ = pytree_partition(updates, exchange_filter)
-                merged_sel = optax.apply_updates(merged_sel, sel_updates)
-            else:
-                merged_sel = optax.apply_updates(merged_sel, updates)
+            with jax.named_scope(scopes.OPTIMIZER):
+                if exchange_filter is not None:
+                    sel_updates, _ = pytree_partition(updates, exchange_filter)
+                    merged_sel = optax.apply_updates(merged_sel, sel_updates)
+                else:
+                    merged_sel = optax.apply_updates(merged_sel, updates)
             merged_state = jax.tree.map(
                 lambda m, new, old: m + (new - old),
                 merged_state, new_model_state, old_model_state,

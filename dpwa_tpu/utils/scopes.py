@@ -1,0 +1,42 @@
+"""Names for the parts of a train step, as ``jax.named_scope`` metadata.
+
+Three scopes give four phases.  A scope's name becomes part of the
+``op_name`` of every HLO instruction traced under it, and JAX wraps the name
+when it differentiates: with the loss call under ``dpwa.forward`` inside
+``jax.value_and_grad``, forward instructions carry ``jvp(dpwa.forward)`` and
+backward instructions ``transpose(jvp(dpwa.forward))``.  The names are
+metadata only: they change no arithmetic and cost nothing when no profiler
+runs.  ``benchmark/scopes.py`` reads them back from a device trace.
+
+Not a tracing system (that is :mod:`dpwa_tpu.obs`, on the host): only names.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+
+FORWARD = "dpwa.forward"
+OPTIMIZER = "dpwa.optimizer"
+EXCHANGE = "dpwa.exchange"
+
+
+def scoped(name: str):
+    """Decorator: every call of the function is traced under ``name``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
+
+def scoped_loss(loss_fn):
+    """``loss_fn`` with its whole call under :data:`FORWARD`; hand the result
+    to ``jax.value_and_grad`` and the backward pass names itself."""
+    return scoped(FORWARD)(loss_fn)

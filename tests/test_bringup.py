@@ -100,6 +100,102 @@ def test_make_mesh_takes_a_prefix_that_is_not_a_sub_torus(monkeypatch):
     assert ids(4) == [0, 1, 3, 2]
 
 
+@pytest.fixture(scope="module")
+def v5e_chips():
+    """The chips of a described v5e 2x2: libtpu compiles for them with none
+    attached.  Described here, inside a fixture of the one test file that
+    loads libtpu, and never at import."""
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc("v5e:2x2", "tpu").devices
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"no TPU topology description: {e}")
+
+
+@pytest.mark.parametrize("transport_name", ["stacked", "ici"])
+def test_the_tpu_compiler_keeps_the_scopes_on_its_fusions(
+    v5e_chips, transport_name
+):
+    """`benchmark/scopes.py` splits a step's device time by the `op_name` of
+    each executed instruction, so the `dpwa.*` scopes have to survive
+    XLA:TPU's fusion, on both step builders."""
+    import re
+
+    from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+    from jax.sharding import PartitionSpec as P
+
+    from dpwa_tpu.parallel.ici import IciTransport
+    from dpwa_tpu.parallel.stacked import (
+        StackedTrainState, StackedTransport, make_stacked_train_step,
+    )
+    from dpwa_tpu.train import GossipTrainState, make_gossip_train_step
+
+    n, cfg = 4, make_local_config(4, schedule="ring")
+    if transport_name == "stacked":
+        transport, State = StackedTransport(cfg), StackedTrainState
+        make_step = make_stacked_train_step
+        peer = replicated = SingleDeviceSharding(v5e_chips[0])
+    else:
+        mesh = Mesh(np.array(v5e_chips[:n]), ("peers",))
+        transport, State = IciTransport(cfg, mesh=mesh), GossipTrainState
+        make_step = make_gossip_train_step
+        peer, replicated = NamedSharding(mesh, P("peers")), NamedSharding(mesh, P())
+
+    def loss_fn(params, batch):
+        x, y = batch
+        hidden = jnp.tanh(x @ params["w1"])
+        return jnp.mean((hidden @ params["w2"] - y) ** 2)
+
+    optimizer = optax.sgd(0.1, momentum=0.9)
+    shaped = lambda shape, sh=peer: jax.ShapeDtypeStruct(
+        shape, jnp.float32, sharding=sh
+    )
+    params = {"w1": shaped((n, 128, 256)), "w2": shaped((n, 256, 128))}
+    state = State(
+        params=params,
+        opt_state=jax.tree.map(
+            lambda s: shaped(s.shape),
+            jax.eval_shape(jax.vmap(optimizer.init), params),
+        ),
+        clock=shaped((n,)),
+        step=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+        model_state=None, loss=shaped((n,)),
+    )
+    batch = (shaped((n, 64, 128)), shaped((n, 64, 128)))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        text = jax.jit(make_step(loss_fn, optimizer, transport)).lower(
+            state, batch
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    named = lambda hlo: [
+        (re.search(r'op_name="([^"]*)"', line) or [None, ""])[1]
+        for line in hlo.splitlines() if " fusion(" in line
+    ]
+    # The entry computation's fusions run as instructions of their own (a
+    # fusion inside a fused computation does not, and has no name).
+    entry = named(text[text.index("\nENTRY "):])
+    assert len(entry) >= 4, text[-3000:]
+    assert sum(bool(name) for name in entry) >= 0.8 * len(entry), entry
+    # Forward and backward are each on some fusion, and on one chip the
+    # exchange's gather is too.  The optimizer's arithmetic, and across chips
+    # this toy's merge, may be fused into another's and keep their names
+    # inside; there the exchange is the collective, checked below.
+    for part in ("jvp(dpwa.forward)", "transpose(jvp(dpwa.forward))"):
+        assert any(part in name for name in entry), (part, entry)
+    if transport_name == "stacked":
+        assert any("dpwa.exchange" in name for name in entry), entry
+    assert "dpwa.optimizer" in text and "dpwa.exchange" in text
+    if transport_name == "ici":
+        moved = re.findall(
+            r'collective-permute(?:-start)?\([^\n]*op_name="([^"]*)"', text
+        )
+        assert moved and all("dpwa.exchange" in name for name in moved)
+
+
 # ---------------------------------------------------------------------------
 # Compile cache
 # ---------------------------------------------------------------------------
