@@ -21,6 +21,7 @@ unless ``jax.devices()[0].platform == "tpu"``.  Each leg prints one JSON line
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 import json
 import os
@@ -501,7 +502,10 @@ def pair_merge_leg(sz: Sizes, platform: str) -> dict:
         pallas_pair_merge,
         xla_pairwise_merge,
     )
-    from dpwa_tpu.ops.ulysses import single_device_attention
+    from dpwa_tpu.ops.ulysses import (
+        _flash_block_sizes,
+        single_device_attention,
+    )
     from dpwa_tpu.parallel.schedules import _ring_even, _ring_odd
 
     n, d = 8, sz.merge_d
@@ -554,6 +558,7 @@ def pair_merge_leg(sz: Sizes, platform: str) -> dict:
     return dict(
         merge_d=d, merge_max_abs_err=worst, attention_t=T,
         attention_max_abs_err=attn_err, interpret=interpret,
+        attention_blocks=dataclasses.asdict(_flash_block_sizes(T, D)),
     )
 
 

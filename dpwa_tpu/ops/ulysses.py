@@ -91,13 +91,52 @@ def ulysses_attention_local(
     )
 
 
+def _largest_block(T: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``T`` and is at most
+    ``cap``; 128 itself where T has no larger such divisor."""
+    top = max(min(T, cap), 128)
+    return max(b for b in range(128, top + 1, 128) if T % b == 0)
+
+
+def _flash_block_sizes(T: int, D: int):
+    """Block sizes for the library's flash forward, dkv and dq kernels at
+    sequence length ``T`` and head size ``D``, from the sweep on the v5e in
+    PERF.md (section 6, PR 25): q and k-major blocks up to 1024 with inner
+    blocks up to 512, each the largest multiple of 128 that divides ``T``.
+
+    dq alone grows along q only, to 2048: the library materialises ``di``
+    in HBM at ``[B, H, T, block_k_major_dq]`` float32, so a wider k-major
+    there raises the step's peak memory (1.2 % at 256 in the T 512 cell).
+    Every kernel's tiles grow with ``D``: above 256 the caps shrink by
+    ``ceil(D / 256)`` so that they still fit the scoped VMEM."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    shrink = -(-D // 256)
+    major = _largest_block(T, 1024 // shrink)
+    minor = _largest_block(major, 512 // shrink)
+    return BlockSizes(
+        block_q=major, block_k_major=major, block_k=minor, block_b=1,
+        block_q_major_dkv=major, block_k_major_dkv=major,
+        block_q_dkv=minor, block_k_dkv=minor,
+        block_q_dq=_largest_block(T, 2048 // shrink),
+        block_k_major_dq=128, block_k_dq=128,
+    )
+
+
 def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
     """THE single-device attention of the framework, shared by the Llama
     model's non-sp path and the a2a strategy's per-device compute:
     [B, T, h, D] layout, GQA expanded here if still grouped.  ``impl``:
     "flash" forces the Pallas kernel, "auto" uses it on TPU when shapes
     fit its tiling (T and head_dim multiples of 128), anything else runs
-    the masked-softmax einsum with f32 accumulation."""
+    the masked-softmax einsum with f32 accumulation.
+
+    The library's forward, dkv and dq kernels run with the block sizes
+    :func:`_flash_block_sizes` picks from this call's own ``T`` and
+    ``head_dim`` (the library's default is 128 for every block, which at
+    T 4096 pays a grid step's overhead eight times for each step's
+    arithmetic); the sweep on the v5e behind the rule is PERF.md,
+    section 6, PR 25."""
     B, T, h, D = q.shape
     if k.shape[2] != h:
         rep = h // k.shape[2]
@@ -120,6 +159,7 @@ def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
             v.transpose(0, 2, 1, 3),
             causal=causal,
             sm_scale=float(1.0 / (D ** 0.5)),
+            block_sizes=_flash_block_sizes(T, D),
         )
         return out.transpose(0, 2, 1, 3)
     s = jnp.einsum(

@@ -7,6 +7,7 @@ refuses a CPU, the Llama steps trace with a Pallas kernel inside the
 installed ``shard_map``, frames land on the default device, and the bench
 has no road that ends in a number without a chip."""
 
+import contextlib
 import json
 import os
 import sys
@@ -113,6 +114,18 @@ def v5e_chips():
         pytest.skip(f"no TPU topology description: {e}")
 
 
+@contextlib.contextmanager
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
 @pytest.mark.parametrize("transport_name", ["stacked", "ici"])
 def test_the_tpu_compiler_keeps_the_scopes_on_its_fusions(
     v5e_chips, transport_name
@@ -163,14 +176,10 @@ def test_the_tpu_compiler_keeps_the_scopes_on_its_fusions(
         model_state=None, loss=shaped((n,)),
     )
     batch = (shaped((n, 64, 128)), shaped((n, 64, 128)))
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
+    with _no_compile_cache():
         text = jax.jit(make_step(loss_fn, optimizer, transport)).lower(
             state, batch
         ).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
     named = lambda hlo: [
         (re.search(r'op_name="([^"]*)"', line) or [None, ""])[1]
         for line in hlo.splitlines() if " fusion(" in line
@@ -194,6 +203,61 @@ def test_the_tpu_compiler_keeps_the_scopes_on_its_fusions(
             r'collective-permute(?:-start)?\([^\n]*op_name="([^"]*)"', text
         )
         assert moved and all("dpwa.exchange" in name for name in moved)
+
+
+@pytest.mark.parametrize(
+    "B,T,head_dim",
+    [
+        (1, 256, 128),  # run.py's model check in both decoder cells
+        (1, 384, 128),  # one block of 384
+        (1, 640, 128),  # majors of 640 over inner blocks of 128
+        (1, 1536, 128),  # 768 over 384
+        (8, 512, 128),  # mistral7b-lora-stacked2-t512
+        (2, 1024, 128),  # chip_smoke.py
+        (1, 4096, 128),  # mistral7b-lora-stacked2-t4096
+        (1, 16384, 128),
+        (1, 512, 256),
+        (1, 4096, 256),
+        (1, 4096, 512),  # tiles grow with the head size: the caps shrink
+    ],
+)
+def test_the_chosen_flash_blocks_fit_the_v5e(v5e_chips, B, T, head_dim):
+    """Mosaic compiles forward, dkv and dq with the blocks
+    `single_device_attention` chooses (a candidate that does not fit the
+    scoped VMEM is refused here, with no chip), under `vmap` over peers as
+    the stacked step runs them, and the backward kernels carry the chosen
+    sizes in their names, which is how a trace shows them."""
+    import dataclasses
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops.ulysses import (
+        _flash_block_sizes, single_device_attention,
+    )
+
+    attn = jax.vmap(
+        functools.partial(single_device_attention, causal=True, impl="flash")
+    )
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32))
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda heads: jax.ShapeDtypeStruct(
+        (2, B, T, heads, head_dim), jnp.bfloat16, sharding=one
+    )
+    with _no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shaped(8), shaped(2), shaped(2)
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    b = dataclasses.asdict(_flash_block_sizes(T, head_dim))
+    for name in (
+        "flash_mha_bwd_dkv_block_q_major_{block_q_major_dkv}"
+        "_block_q_{block_q_dkv}_block_k_major_{block_k_major_dkv}"
+        "_block_k_{block_k_dkv}",
+        "flash_mha_bwd_dq_block_q_major_{block_q_dq}"
+        "_block_k_major_{block_k_major_dq}_block_k_{block_k_dq}",
+    ):
+        assert name.format(**b) in text, name.format(**b)
 
 
 # ---------------------------------------------------------------------------
