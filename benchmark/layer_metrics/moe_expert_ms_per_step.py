@@ -1,0 +1,15 @@
+"""Device self time of one step under ``dpwa.moe.experts``: the grouped
+matmuls of the sparse-expert layer with their adapters, forward and backward
+together, on the chip that sets the pace (``benchmark/moe_scopes.py``)."""
+
+LAYER = "expert layer"
+UNIT = "ms"
+MOVES = "samples_per_s"
+SOURCE = "device_trace"
+
+
+def reduce(trace, record):
+    from benchmark import moe_scopes
+
+    seconds = moe_scopes.scope_seconds_per_step(trace, record, "experts")
+    return None if seconds is None else 1e3 * seconds

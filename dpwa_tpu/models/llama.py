@@ -60,8 +60,23 @@ class LlamaConfig:
     # "flash" forces the kernel (raises off-TPU), "dense" forces einsum.
     # The sp path is unaffected (ring attention is already blockwise).
     attn_impl: str = "auto"
+    # Sparse experts (the OLMoE block): ``n_experts`` > 0 replaces the dense
+    # MLP by ``n_experts`` SwiGLU experts of width ``d_ff`` each, of which a
+    # token takes its router's top ``n_experts_per_tok`` (dropless:
+    # ops/moe.py).  ``qk_norm`` applies an RMSNorm to the whole projected q
+    # and k before the split into heads.  ``router_aux_loss_coef`` weighs
+    # the load-balancing term in :func:`moe_loss`.
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    qk_norm: bool = False
+    router_aux_loss_coef: float = 0.0
 
     def __post_init__(self):
+        if self.n_experts and not 0 < self.n_experts_per_tok <= self.n_experts:
+            raise ValueError(
+                f"n_experts_per_tok must lie in 1..{self.n_experts}, got "
+                f"{self.n_experts_per_tok}"
+            )
         if self.attn_impl not in ("auto", "flash", "dense"):
             raise ValueError(
                 f"attn_impl must be auto|flash|dense, got {self.attn_impl!r}"
@@ -191,8 +206,11 @@ class Attention(nn.Module):
         dense = lambda feats, name: LoRADense(
             feats, cfg.lora_rank, cfg.lora_alpha, cfg.dtype, name=name
         )
-        q = dense(H * D, "wq")(x).reshape(B, T, H, D)
-        k = dense(KV * D, "wk")(x).reshape(B, T, KV, D)
+        q, k = dense(H * D, "wq")(x), dense(KV * D, "wk")(x)
+        if cfg.qk_norm:
+            q = RMSNorm(dtype=cfg.dtype, name="q_norm")(q)
+            k = RMSNorm(dtype=cfg.dtype, name="k_norm")(k)
+        q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
         v = dense(KV * D, "wv")(x).reshape(B, T, KV, D)
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
@@ -268,6 +286,76 @@ class MLP(nn.Module):
         return dense(cfg.d_model, "w_down")(nn.silu(gate) * up)
 
 
+class ExpertDense(nn.Module):
+    """The stacked weights of one projection of every expert: ``kernel
+    [E, in, out]`` with ``lora_a [E, in, r]`` / ``lora_b [E, r, out]`` beside
+    it, under the names :class:`LoRADense` uses, so ``lora_filter`` picks the
+    adapters.  Returns them; ``ops/moe.py`` multiplies."""
+
+    n_experts: int
+    in_features: int
+    features: int
+    rank: int
+
+    @nn.compact
+    def __call__(self):
+        shape = (self.n_experts, self.in_features, self.features)
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)), shape
+        )
+        if self.rank <= 0:
+            return kernel, None, None
+        lora_a = self.param(
+            "lora_a", nn.initializers.normal(stddev=0.02),
+            shape[:2] + (self.rank,),
+        )
+        lora_b = self.param(
+            "lora_b", nn.initializers.zeros,
+            (self.n_experts, self.rank, self.features),
+        )
+        return kernel, lora_a, lora_b
+
+
+class MoE(nn.Module):
+    """Top-k of ``n_experts`` SwiGLU experts of width ``d_ff``, dropless
+    (``ops/moe.py``).  Sows each call's routing into the ``intermediates``
+    collection (``experts [N, k]``, ``counts [E]``, ``prob_mean [E]``, router
+    ``logits [N, E]``): what :func:`moe_loss` and a reference that verifies
+    the routing read; nothing is computed for it when the collection is not
+    asked for."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from dpwa_tpu.ops import moe
+        from dpwa_tpu.utils import scopes
+
+        cfg = self.cfg
+        B, T, D = x.shape
+        E, k = cfg.n_experts, cfg.n_experts_per_tok
+        router = self.param("router", nn.initializers.lecun_normal(), (D, E))
+        expert = lambda d_in, d_out, name: ExpertDense(
+            E, d_in, d_out, cfg.lora_rank, name=name
+        )()
+        tokens = x.reshape(B * T, D)
+        with jax.named_scope(scopes.MOE_ROUTE):
+            weights, experts, logits = moe.route(tokens, router, k)
+            self.sow("intermediates", "experts", experts)
+            self.sow("intermediates", "logits", logits)
+            self.sow("intermediates", "counts",
+                     moe.assignment_counts(experts, E))
+            self.sow("intermediates", "prob_mean",
+                     jax.nn.softmax(logits, axis=-1).mean(0))
+        out = moe.moe_ffn(
+            tokens, (weights, experts),
+            expert(D, cfg.d_ff, "w_gate"), expert(D, cfg.d_ff, "w_up"),
+            expert(cfg.d_ff, D, "w_down"),
+            cfg.lora_alpha / max(cfg.lora_rank, 1), cfg.dtype,
+        )
+        return out.reshape(B, T, D)
+
+
 class Block(nn.Module):
     cfg: LlamaConfig
 
@@ -277,7 +365,7 @@ class Block(nn.Module):
         x = x + Attention(cfg, name="attn")(
             RMSNorm(dtype=cfg.dtype, name="attn_norm")(x), positions
         )
-        x = x + MLP(cfg, name="mlp")(
+        x = x + (MoE if cfg.n_experts > 0 else MLP)(cfg, name="mlp")(
             RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
         )
         return x
@@ -314,6 +402,37 @@ class Llama(nn.Module):
             cfg.vocab_size, use_bias=False, dtype=jnp.float32, name="lm_head"
         )(x)
         return logits
+
+
+def routing_of(intermediates) -> dict:
+    """The sown routing of every expert layer, layers stacked in order:
+    ``{"experts": [L, N, k], "counts": [L, E], "prob_mean": [L, E], "logits":
+    [L, N, E]}``, from the ``intermediates`` collection that
+    ``Llama.apply(..., mutable=["intermediates"])`` returns."""
+    layers = intermediates["intermediates"]
+    names = sorted(layers, key=lambda name: int(name.rsplit("_", 1)[1]))
+    return {
+        key: jnp.stack([layers[name]["mlp"][key][0] for name in names])
+        for key in ("experts", "counts", "prob_mean", "logits")
+    }
+
+
+def moe_loss(model: "Llama", params, tokens, targets):
+    """Cross-entropy plus ``router_aux_loss_coef`` x the load-balancing term
+    (``ops/moe.load_balancing_loss``, pooled over layers): the training loss
+    of a sparse-expert configuration, still ``-> scalar`` for a step builder."""
+    import optax
+
+    from dpwa_tpu.ops import moe
+
+    logits, sown = model.apply(params, tokens, mutable=["intermediates"])
+    routing = routing_of(sown)
+    loss = optax.softmax_cross_entropy_with_integer_labels(
+        logits, targets
+    ).mean()
+    return loss + model.cfg.router_aux_loss_coef * moe.load_balancing_loss(
+        routing["counts"], routing["prob_mean"]
+    )
 
 
 def lora_mask(params) -> object:

@@ -20,6 +20,12 @@ import jax
 FORWARD = "dpwa.forward"
 OPTIMIZER = "dpwa.optimizer"
 EXCHANGE = "dpwa.exchange"
+# Inside the forward scope, the two halves of a sparse-expert layer
+# (``ops/moe.py``): router, softmax, top-k, sort, gathers and combine; and
+# the grouped matmuls with their adapters.  They nest under ``dpwa.forward``,
+# so the phases above book them as forward / backward as before.
+MOE_ROUTE = "dpwa.moe.route"
+MOE_EXPERTS = "dpwa.moe.experts"
 
 
 def scoped(name: str):

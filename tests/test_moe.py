@@ -1,0 +1,386 @@
+"""The sparse-expert layer (``ops/moe.py``, ``models/llama.py`` MoE /
+QK-norm) against its plain reference (``benchmark/references/moe_decoder.py``)
+at toy sizes on the CPU: experts 8, top 2, width 64."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.reference import MODEL_TOLERANCE  # noqa: E402
+from benchmark.references import moe_decoder as plain  # noqa: E402
+from dpwa_tpu.models.llama import (  # noqa: E402
+    Llama, LlamaConfig, lora_filter, lora_optimizer, moe_loss, routing_of,
+)
+from dpwa_tpu.ops import moe  # noqa: E402
+
+E, K, D, F, V, T = 8, 2, 64, 64, 128, 32
+CONFIG = dict(
+    hidden_size=D, intermediate_size=F, num_attention_heads=4,
+    num_key_value_heads=4, num_experts=E, num_experts_per_tok=K,
+    num_hidden_layers=2, vocab_size=V, rms_norm_eps=1e-5, rope_theta=10000,
+    assumed=dict(lora=dict(rank=4, alpha=16.0), router_aux_loss_coef=0.01),
+)
+
+
+def model_of(dtype=jnp.float32, **changes):
+    fields = dict(
+        vocab_size=V, d_model=D, n_layers=2, n_heads=4, n_kv_heads=4, d_ff=F,
+        max_seq_len=T, rope_theta=10000.0, lora_rank=4, lora_alpha=16.0,
+        dtype=dtype, n_experts=E, n_experts_per_tok=K, qk_norm=True,
+        router_aux_loss_coef=0.01,
+    )
+    return Llama(LlamaConfig(**{**fields, **changes}))
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    """(params, tokens, targets): every leaf perturbed, so that LoRA B and
+    the norms' scales matter to a comparison."""
+    tokens = jax.random.randint(jax.random.key(0), (2, T), 0, V)
+    params = model_of().init(jax.random.key(1), tokens)
+    leaves, treedef = jax.tree.flatten(params)
+    keys = jax.random.split(jax.random.key(2), len(leaves))
+    params = treedef.unflatten([
+        v + 0.05 * jax.random.normal(k, v.shape, v.dtype)
+        for v, k in zip(leaves, keys)
+    ])
+    return params, tokens, jnp.roll(tokens, -1, axis=1)
+
+
+def relative(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.sqrt(np.mean((got - want) ** 2)) / np.sqrt(np.mean(want ** 2))
+
+
+def adapters(tree):
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return {
+        jax.tree_util.keystr(path): leaf for path, leaf in flat
+        if lora_filter(jax.tree_util.keystr(path))
+    }
+
+
+def test_float32_equals_the_reference_with_its_own_routing(seeded):
+    params, tokens, targets = seeded
+    model = model_of()
+    assert relative(
+        model.apply(params, tokens), plain.forward(CONFIG, params, tokens)
+    ) < 1e-4
+    loss, grads = jax.value_and_grad(
+        lambda p: moe_loss(model, p, tokens, targets)
+    )(params)
+    want, want_grads = jax.value_and_grad(
+        lambda p: plain.loss(CONFIG, p, tokens, targets)
+    )(params)
+    assert abs(float(loss) - float(want)) < 1e-5 * float(want)
+    got, want_grads = adapters(grads), adapters(want_grads)
+    assert len(got) == 2 * (4 + 3) * 2  # a and b, 4 + 3 projections, 2 layers
+    for name, grad in got.items():
+        assert relative(grad, want_grads[name]) < 1e-4, name
+        assert float(jnp.abs(grad).max()) > 0, name
+
+
+def test_load_balancing_term_is_one_when_uniform_and_is_in_the_loss(seeded):
+    counts = jnp.full((2, E), 10)
+    assert float(moe.load_balancing_loss(counts, jnp.full((2, E), 1 / E))) == (
+        pytest.approx(1.0)
+    )
+    params, tokens, targets = seeded
+    with_term = moe_loss(model_of(), params, tokens, targets)
+    without = moe_loss(
+        model_of(router_aux_loss_coef=0.0), params, tokens, targets
+    )
+    # Skewed routing reads above 1; the coefficient is 0.01.
+    assert 0.01 <= float(with_term - without) < 0.01 * E
+
+
+def test_bfloat16_with_verified_routing_is_inside_the_tolerance(seeded):
+    params, tokens, _ = seeded
+    model = model_of(jnp.bfloat16)
+    logits, sown = model.apply(params, tokens, mutable=["intermediates"])
+    routing = routing_of(sown)["experts"]
+    want, details = plain.forward_with_routing(CONFIG, params, tokens, routing)
+    error = relative(logits, want)
+    assert 1e-5 < error < MODEL_TOLERANCE
+    # The program's sets pass at ROUTING_EPS, and by a margin.
+    assert float(details["margin"].max()) < plain.ROUTING_EPS / 2
+
+
+def test_a_set_that_is_not_a_top_k_is_refused(seeded):
+    params, tokens, _ = seeded
+    own = plain.forward_with_routing(CONFIG, params, tokens)[1]["logits"]
+    honest = jax.lax.top_k(own, K)[1]
+    assert bool(jnp.all(jnp.isfinite(
+        plain.forward(CONFIG, params, tokens, honest)
+    )))
+    # One token of layer 1 takes its worst expert in place of its second.
+    wrong = honest.at[1, 5, 1].set(jnp.argmin(own[1, 5]))
+    assert not bool(jnp.all(jnp.isfinite(
+        plain.forward(CONFIG, params, tokens, wrong)
+    )))
+    # The same expert twice is not a set of k.
+    twice = honest.at[0, 3, 1].set(honest[0, 3, 0])
+    assert not bool(jnp.all(jnp.isfinite(
+        plain.forward(CONFIG, params, tokens, twice)
+    )))
+
+
+def _renormalised(route):
+    def routed(x, router_kernel, k):
+        weights, experts, logits = route(x, router_kernel, k)
+        return weights / weights.sum(-1, keepdims=True), experts, logits
+
+    return routed
+
+
+def _with_capacity(route, factor=1.0):
+    """A capacity-factor dispatch: an expert takes its first ``factor x N x
+    k / E`` assignments in token order and the overflow is dropped."""
+
+    def routed(x, router_kernel, k):
+        weights, experts, logits = route(x, router_kernel, k)
+        n_experts = logits.shape[-1]
+        capacity = int(factor * experts.size / n_experts)
+        one_hot = jax.nn.one_hot(experts.reshape(-1), n_experts, dtype=jnp.int32)
+        position = (jnp.cumsum(one_hot, 0) * one_hot).sum(-1).reshape(experts.shape)
+        return jnp.where(position <= capacity, weights, 0.0), experts, logits
+
+    return routed
+
+
+def _skewed(params):
+    """The router's first two columns tripled: uneven routing, so that a
+    capacity of the mean overflows."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, v: v.at[:, :2].multiply(3.0)
+        if "router" in jax.tree_util.keystr(path) else v, params,
+    )
+
+
+VARIANTS = ["top_7_of_8", "renormalised_weights", "no_qk_norm",
+            "capacity_drops_overflow", "experts_without_adapters"]
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_missing_mathematics_is_outside_the_tolerance(seeded, variant, monkeypatch):
+    params, tokens, _ = seeded
+    config, model = CONFIG, model_of()
+    if variant == "top_7_of_8":
+        # A top-8-of-16 reference against a program that takes 7.
+        config = dict(CONFIG, num_experts=16, num_experts_per_tok=8)
+        model = model_of(n_experts=16, n_experts_per_tok=8)
+        params = model.init(jax.random.key(4), tokens)
+        program = model_of(n_experts=16, n_experts_per_tok=7)
+    elif variant == "renormalised_weights":
+        monkeypatch.setattr(moe, "route", _renormalised(moe.route))
+        program = model
+    elif variant == "no_qk_norm":
+        program = model_of(qk_norm=False)
+    elif variant == "capacity_drops_overflow":
+        params = _skewed(params)
+        monkeypatch.setattr(moe, "route", _with_capacity(moe.route))
+        program = model
+    else:
+        params_without = jax.tree_util.tree_map_with_path(
+            lambda path, v: jnp.zeros_like(v)
+            if "mlp" in jax.tree_util.keystr(path)
+            and "lora_b" in jax.tree_util.keystr(path) else v, params,
+        )
+        program = model
+    want = plain.forward(config, params, tokens)
+    if variant == "experts_without_adapters":
+        got = program.apply(params_without, tokens)
+    else:
+        got = program.apply(params, tokens)
+    assert relative(got, want) > MODEL_TOLERANCE
+    monkeypatch.undo()
+    # The same comparison with nothing missing is inside, by far.
+    assert relative(model.apply(params, tokens), want) < 1e-4
+
+
+def _layer_weights(key, rank=4):
+    keys = jax.random.split(key, 9)
+    shapes = [(D, F), (D, F), (F, D)]
+    return [
+        (jax.random.normal(keys[3 * i], (E,) + s) / s[0] ** 0.5,
+         jax.random.normal(keys[3 * i + 1], (E, s[0], rank)) * 0.1,
+         jax.random.normal(keys[3 * i + 2], (E, rank, s[1])) * 0.1)
+        for i, s in enumerate(shapes)
+    ]
+
+
+def _dense_layer(x, weights, experts, layer):
+    named = {
+        name: dict(kernel=w[0], lora_a=w[1], lora_b=w[2])
+        for name, w in zip(("w_gate", "w_up", "w_down"), layer)
+    }
+    return plain.dense_experts(
+        x, named, plain.combine_of(weights, experts, E), 2.0
+    )
+
+
+@pytest.mark.parametrize("experts_of", [
+    lambda n: jnp.full((n, 1), 3),  # every token to one expert, k = 1
+    lambda n: jnp.tile(jnp.array([[3, 5]]), (n, 1)),  # two experts take all
+    lambda n: jnp.stack([jnp.zeros(n, int), 1 + jnp.arange(n) % 7], 1),
+], ids=["all_to_one", "all_to_two", "first_choice_all_to_one"])
+def test_no_token_is_dropped_at_any_skew(experts_of):
+    n = 48
+    x = jax.random.normal(jax.random.key(0), (n, D))
+    layer = _layer_weights(jax.random.key(1))
+    experts = experts_of(n).astype(jnp.int32)
+    weights = jax.random.uniform(jax.random.key(2), experts.shape) + 0.1
+    got = moe.moe_ffn(x, (weights, experts), *layer, 2.0, jnp.float32)
+    assert relative(got, _dense_layer(x, weights, experts, layer)) < 1e-5
+    stats = moe.routing_stats(experts, E)
+    assert int(stats["dropped"]) == 0
+    assert int(stats["assignments"].sum()) == experts.size
+    assert float(stats["max_over_mean"]) == pytest.approx(
+        int(stats["assignments"].max()) * E / experts.size
+    )
+
+
+def test_grouped_matmul_gradients_equal_the_dense_ones():
+    m, k, n = 40, 16, 24
+    lhs = jax.random.normal(jax.random.key(0), (m, k))
+    rhs = jax.random.normal(jax.random.key(1), (E, k, n))
+    sizes = jnp.array([0, 7, 1, 0, 12, 20, 0, 0], jnp.int32)
+    group = jnp.repeat(jnp.arange(E), sizes, total_repeat_length=m)
+    dense = lambda a, b: jnp.einsum("mk,mkn->mn", a, b[group])
+    cot = jax.random.normal(jax.random.key(2), (m, n))
+    got = jax.grad(
+        lambda a, b: jnp.sum(moe.grouped_matmul(a, b, sizes) * cot), (0, 1)
+    )(lhs, rhs)
+    want = jax.grad(lambda a, b: jnp.sum(dense(a, b) * cot), (0, 1))(lhs, rhs)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+
+
+def test_the_tpu_kernels_equal_ragged_dot(monkeypatch):
+    """The path the chip takes (the library's Pallas kernels, interpreted
+    here; steered in the test as the dispatcher asks the backend), against
+    the path everything else takes: values and both gradients, an empty
+    group among them, alone and with two peers folded into the groups."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k, n = 1024, 256, 16
+    lhs = jax.random.normal(jax.random.key(0), (2, m, k))
+    rhs = jax.random.normal(jax.random.key(1), (2, 4, k, n))
+    sizes = jnp.array([[300, 0, 212, 512], [0, 1024, 0, 0]], jnp.int32)
+    cot = jax.random.normal(jax.random.key(2), (m, n))
+    one = jax.value_and_grad(
+        lambda a, b, s: jnp.sum(moe.grouped_matmul(a, b, s) * cot), (0, 1)
+    )
+    want = jax.vmap(one)(lhs, rhs, sizes)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pltpu.force_tpu_interpret_mode():
+        assert "pallas_call" in str(jax.make_jaxpr(one)(lhs[0], rhs[0], sizes[0]))
+        alone = one(lhs[0], rhs[0], sizes[0])
+        folded = jax.vmap(one)(lhs, rhs, sizes)
+    for got, ref in zip(jax.tree.leaves(folded), jax.tree.leaves(want)):
+        np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
+    for got, ref in zip(jax.tree.leaves(alone), jax.tree.leaves(want)):
+        np.testing.assert_allclose(got, ref[0], rtol=1e-4, atol=1e-3)
+
+
+def test_vmap_over_two_peers_equals_a_loop_over_them(seeded):
+    params, tokens, targets = seeded
+    model = model_of()
+    stacked = jax.tree.map(lambda v: jnp.stack([v, 1.02 * v]), params)
+    batch = (jnp.stack([tokens, tokens[::-1]]),
+             jnp.stack([targets, targets[::-1]]))
+    grad_fn = jax.value_and_grad(lambda p, t, y: moe_loss(model, p, t, y))
+    losses, grads = jax.jit(jax.vmap(grad_fn))(stacked, *batch)
+    for i in range(2):
+        loss, grad = grad_fn(
+            jax.tree.map(lambda v: v[i], stacked), batch[0][i], batch[1][i]
+        )
+        assert float(losses[i]) == pytest.approx(float(loss), rel=1e-6)
+        for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(grad)):
+            np.testing.assert_allclose(got[i], want, rtol=2e-4, atol=1e-6)
+
+
+def test_the_peer_axis_folds_into_the_group_axis():
+    """Under vmap the grouped matmul is one call with n x E groups."""
+    lhs = jnp.ones((2, 16, 8))
+    rhs = jnp.ones((2, E, 8, 4))
+    sizes = jnp.array([[16] + [0] * 7, [0] * 7 + [16]], jnp.int32)
+    jaxpr = str(jax.make_jaxpr(jax.vmap(moe.grouped_matmul))(lhs, rhs, sizes))
+    assert jaxpr.count("ragged_dot_general") == 1
+    assert f"i32[{2 * E}]" in jaxpr and "f32[32,8]" in jaxpr
+
+
+def test_dense_path_is_what_it_was():
+    """``n_experts == 0`` and no ``qk_norm``: the parameter tree of the dense
+    decoder and a seeded toy loss, pinned at the parent commit (db9195b)."""
+    cfg = LlamaConfig(vocab_size=64, d_model=32, n_layers=2, n_heads=4,
+                      n_kv_heads=2, d_ff=64, max_seq_len=32, lora_rank=4)
+    model = Llama(cfg)
+    tokens = jax.random.randint(jax.random.key(7), (2, 16), 0, 64)
+    params = model.init(jax.random.key(0), tokens)
+    names = {
+        jax.tree_util.keystr(p)
+        for p, _ in jax.tree_util.tree_flatten_with_path(params)[0]
+    }
+    layer = {
+        f"['params']['layer_0']['{block}']['{w}']['{leaf}']"
+        for block, ws in (("attn", ("wq", "wk", "wv", "wo")),
+                          ("mlp", ("w_gate", "w_up", "w_down")))
+        for w in ws for leaf in ("kernel", "lora_a", "lora_b")
+    } | {f"['params']['layer_0']['{n}']['scale']"
+         for n in ("attn_norm", "mlp_norm")}
+    assert {n for n in names if "layer_0" in n} == layer
+    assert len(names) == 2 * len(layer) + 3
+    params = jax.tree.map(
+        lambda v: v + 0.05 * jnp.cos(
+            jnp.arange(v.size, dtype=jnp.float32).reshape(v.shape)
+        ), params,
+    )
+    loss = optax.softmax_cross_entropy_with_integer_labels(
+        model.apply(params, tokens), jnp.roll(tokens, -1, 1)
+    ).mean()
+    assert float(loss) == pytest.approx(4.6457109451293945, rel=1e-6)
+
+
+@pytest.mark.parametrize("transport", ["ici", "stacked"])
+def test_one_step_of_the_toy_model_on_both_transports(transport):
+    from dpwa_tpu.config import make_local_config
+    from dpwa_tpu.train import init_params_per_peer
+    from dpwa_tpu.utils.launch import build_transport
+
+    n = 2
+    model = model_of()
+    bundle = build_transport(make_local_config(n, schedule="ring"), transport,
+                             "native")
+    stacked = init_params_per_peer(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32)),
+        jax.random.key(0), n,
+    )
+    optimizer = lora_optimizer(
+        optax.adam(1e-2), jax.tree.map(lambda v: v[0], stacked)
+    )
+    state = bundle.init_state(stacked, optimizer, bundle.transport)
+    step = bundle.make_step(
+        lambda p, batch: moe_loss(model, p, *batch), optimizer,
+        bundle.transport, exchange_filter=lora_filter, overlap=False,
+    )
+    tokens = jax.random.randint(jax.random.key(1), (n, 2, T), 0, V)
+    batch = (tokens, jnp.roll(tokens, -1, axis=-1))
+    if bundle.batch_sharding is not None:
+        batch = jax.device_put(batch, bundle.batch_sharding)
+    before = jax.tree.map(np.asarray, adapters(state.params))
+    state, losses, info = step(state, batch)
+    assert np.all(np.isfinite(np.asarray(losses)))
+    assert np.asarray(info.participated).all()
+    after = adapters(state.params)
+    moved = [n for n in before if not np.array_equal(before[n], after[n])]
+    assert any("mlp" in n for n in moved) and any("attn" in n for n in moved)
