@@ -105,14 +105,12 @@ def mlm_mask_batch(
 
 def mlm_loss_fn(model: BertMLM):
     """Per-peer masked-LM loss for the gossip train step."""
-    import optax
+    from dpwa_tpu.ops.cross_entropy import softmax_cross_entropy
 
     def loss_fn(params, batch):
         inputs, targets, weights = batch
         logits = model.apply(params, inputs)
-        losses = optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets
-        )
+        losses = softmax_cross_entropy(logits, targets)
         return (losses * weights).sum() / jnp.maximum(weights.sum(), 1.0)
 
     return loss_fn

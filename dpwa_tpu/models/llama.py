@@ -421,15 +421,14 @@ def moe_loss(model: "Llama", params, tokens, targets):
     """Cross-entropy plus ``router_aux_loss_coef`` x the load-balancing term
     (``ops/moe.load_balancing_loss``, pooled over layers): the training loss
     of a sparse-expert configuration, still ``-> scalar`` for a step builder."""
-    import optax
-
     from dpwa_tpu.ops import moe
+    from dpwa_tpu.ops.cross_entropy import softmax_cross_entropy
+    from dpwa_tpu.utils import scopes
 
     logits, sown = model.apply(params, tokens, mutable=["intermediates"])
     routing = routing_of(sown)
-    loss = optax.softmax_cross_entropy_with_integer_labels(
-        logits, targets
-    ).mean()
+    with jax.named_scope(scopes.LOSS):
+        loss = softmax_cross_entropy(logits, targets).mean()
     return loss + model.cfg.router_aux_loss_coef * moe.load_balancing_loss(
         routing["counts"], routing["prob_mean"]
     )

@@ -26,6 +26,8 @@ EXCHANGE = "dpwa.exchange"
 # so the phases above book them as forward / backward as before.
 MOE_ROUTE = "dpwa.moe.route"
 MOE_EXPERTS = "dpwa.moe.experts"
+# Likewise nested: the cross-entropy over the vocabulary and its gradient.
+LOSS = "dpwa.loss"
 
 
 def scoped(name: str):

@@ -96,6 +96,7 @@ def main() -> None:
         lora_filter,
         lora_optimizer,
     )
+    from dpwa_tpu.ops.cross_entropy import softmax_cross_entropy
     from dpwa_tpu.train import init_params_per_peer
     from dpwa_tpu.utils.pytree import (
         partition,
@@ -123,9 +124,7 @@ def main() -> None:
     def loss_fn(params, batch):
         tokens, targets = batch
         logits = model.apply(params, tokens)
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets
-        ).mean()
+        return softmax_cross_entropy(logits, targets).mean()
 
     step_fn = bundle.make_step(
         loss_fn, opt, transport, exchange_filter=lora_filter

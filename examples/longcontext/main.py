@@ -78,6 +78,7 @@ def main() -> None:
     import optax
 
     from dpwa_tpu.models.llama import Llama, LlamaConfig
+    from dpwa_tpu.ops.cross_entropy import softmax_cross_entropy
     from dpwa_tpu.parallel.ici import IciTransport
     from dpwa_tpu.train import init_gossip_state, init_params_per_peer
     from dpwa_tpu.train_sp import (
@@ -134,9 +135,7 @@ def main() -> None:
 
     def sp_loss(params, batch):
         x, y = batch
-        losses = optax.softmax_cross_entropy_with_integer_labels(
-            model.apply(params, x), y
-        )
+        losses = softmax_cross_entropy(model.apply(params, x), y)
         return losses.sum(), jnp.float32(losses.size)
 
     step_fn = make_gossip_sp_train_step(
