@@ -1851,6 +1851,10 @@ def make_local_config(
 ) -> DpwaConfig:
     """Programmatic config for tests/benchmarks: n local peers on 127.0.0.1.
 
+    Node ``i`` listens on ``base_port + i``; ``base_port=0`` gives EVERY node
+    port 0, so the OS picks each server's port and the caller reads it back
+    (``TcpTransport.port``) and wires the ring with ``set_peer_port``.
+
     ``health`` / ``chaos`` / ``recovery`` / ``membership`` / ``trust`` /
     ``flowctl`` / ``obs`` accept a config object or a plain dict (the
     YAML-block shorthand)."""
@@ -1881,7 +1885,10 @@ def make_local_config(
         topology = TopologyConfig(**topology)
     return DpwaConfig(
         nodes=tuple(
-            NodeSpec(name=f"node{i}", host="127.0.0.1", port=base_port + i)
+            NodeSpec(
+                name=f"node{i}", host="127.0.0.1",
+                port=base_port + i if base_port else 0,
+            )
             for i in range(n_peers)
         ),
         protocol=ProtocolConfig(

@@ -108,22 +108,43 @@ def fake_quant_wire(v, seed: int, step, sender, leaf: int = 0):
     return dequantize(q, scale, v.shape)
 
 
-def fake_quant_tree(params, seed: int, step, sender):
-    """Apply :func:`fake_quant_wire` to every f32 leaf of a pytree, with
-    the leaf's flatten-order index folded into its key.  Both SPMD
-    transports build their shipped copy through THIS function, so their
-    per-leaf keys — and therefore their merges — are bit-identical."""
+def quantize_tree(params, seed: int, step, sender):
+    """One peer's shipped copy as the wire carries it: every f32 leaf of
+    ``params`` becomes its ``(int8 q, f32 scales)`` pair, keyed (seed, step,
+    sender, leaf) — the leaf's flatten-order index keeps same-shaped leaves
+    from sharing rounding noise.  A pair is a subtree, so the codes and the
+    tiny scale vectors move as separate leaves; other dtypes ride as they
+    are.  Both SPMD layouts ship THIS tree
+    (:func:`dpwa_tpu.parallel.exchange.gossip_exchange`)."""
     import jax
     import jax.numpy as jnp
 
     leaves, treedef = jax.tree.flatten(params)
     out = [
-        fake_quant_wire(v, seed, step, sender, leaf=i)
+        quantize(v, wire_key(seed, step, sender, leaf=i))
         if v.dtype == jnp.float32
         else v
         for i, v in enumerate(leaves)
     ]
     return jax.tree.unflatten(treedef, out)
+
+
+def dequantize_tree(like, received):
+    """What the receiver reads out of a :func:`quantize_tree` tree; ``like``
+    is any tree of the shipped one's shapes and dtypes (its own replica)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.tree.map(
+        lambda v, w: dequantize(*w, v.shape) if v.dtype == jnp.float32 else w,
+        like, received,
+    )
+
+
+def fake_quant_tree(params, seed: int, step, sender):
+    """Quantize-dequantize every f32 leaf exactly as the wire would: the
+    reference the transports' int8 merges are tested against."""
+    return dequantize_tree(params, quantize_tree(params, seed, step, sender))
 
 
 # --------------------------------------------------------------------------

@@ -22,43 +22,27 @@ collective itself.
 
 from __future__ import annotations
 
-import functools
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dpwa_tpu.config import DpwaConfig
-from dpwa_tpu.interpolation import Interpolation, PeerMeta, make_interpolation
-from dpwa_tpu.parallel import schedules
+from dpwa_tpu.interpolation import Interpolation, PeerMeta
+from dpwa_tpu.parallel.exchange import (
+    ExchangeInfo,
+    MeshLayout,
+    gossip_exchange,
+    round_rules,
+)
 from dpwa_tpu.parallel.mesh import PEER_AXIS, make_mesh
-from dpwa_tpu.parallel.schedules import Schedule, participation_draw
-from dpwa_tpu.utils import scopes
+from dpwa_tpu.parallel.schedules import Schedule
 
 PyTree = Any
 
 
-class ExchangeInfo(NamedTuple):
-    """Per-peer diagnostics from one gossip round (stacked over peers)."""
-
-    partner: jnp.ndarray  # int32[n] — pairing in effect this step
-    alpha: jnp.ndarray  # float32[n] — merge coefficient actually applied
-    participated: jnp.ndarray  # bool[n]
-
-
-def _perm_pairs(perm) -> Tuple[Tuple[int, int], ...]:
-    """ppermute (source, dest) pairs so device i receives from perm[i].
-
-    Valid for pairwise involutions AND one-sided pull maps: ``ppermute``
-    only requires each *destination* to appear once; a popular source may
-    feed several pullers."""
-    return tuple((int(perm[i]), int(i)) for i in range(len(perm)))
-
-
-@scopes.scoped(scopes.EXCHANGE)
 def gossip_exchange_local(
     params: PyTree,
     meta: PeerMeta,
@@ -67,117 +51,15 @@ def gossip_exchange_local(
     schedule: Schedule,
     interp: Interpolation,
     axis_name: str = PEER_AXIS,
-):
-    """The per-device gossip body. Call INSIDE shard_map/pjit over
+) -> Tuple[PyTree, ExchangeInfo]:
+    """The per-device gossip round: :func:`exchange.gossip_exchange` with one
+    peer a position on ``axis_name``.  Call INSIDE shard_map/pjit over
     ``axis_name``; ``params`` leaves and ``meta`` scalars are this device's
-    local (unstacked) values.
-
-    Returns (merged_params, (partner, alpha, participated)) for this device.
-    """
-    me = lax.axis_index(axis_name)
-    pool = jnp.asarray(schedule.pool)  # [K, n] baked-in constant
-    branch = schedule.branch_traced(step)
-    partner = pool[branch, me]
-
-    def make_branch(perm):
-        pairs = _perm_pairs(perm)
-
-        def apply(operand):
-            return jax.tree.map(
-                lambda v: lax.ppermute(v, axis_name, perm=pairs), operand
-            )
-
-        return apply
-
-    # Compressed wire: only the SHIPPED copy is compressed — bf16 halves
-    # the ICI/DCN bytes; int8 quarters them for real (the collective
-    # moves the ``(int8 q, f32 scales)`` encoding, NOT a dequantized f32
-    # copy — the receiver decodes after the ppermute); the local replica
-    # and the merge math stay f32 (the partner's contribution arrives
-    # rounded, scaled by α).  Stochastic rounding keeps the quantizer
-    # unbiased (ops/quantize.py).
-    decode_remote = None
-    if schedule.wire_dtype == "bf16":
-        wire_params = jax.tree.map(
-            lambda v: v.astype(jnp.bfloat16)
-            if v.dtype == jnp.float32
-            else v,
-            params,
-        )
-    elif schedule.wire_dtype == "int8":
-        from dpwa_tpu.ops import quantize as qz
-
-        # Each device quantizes ITS OWN copy (sender-keyed, per-leaf) —
-        # the stacked twin derives the same (step, sender, leaf) keys and
-        # dequantize commutes with its gather elementwise, so the two
-        # transports stay bit-identical.
-        leaves, treedef = jax.tree.flatten(params)
-        enc = [
-            qz.quantize(v, qz.wire_key(schedule.seed, step, me, leaf=i))
-            if v.dtype == jnp.float32
-            else v
-            for i, v in enumerate(leaves)
-        ]
-        # (q, scales) tuples become subtrees: ppermute moves the int8
-        # codes and the tiny f32 scale vectors as separate leaves.
-        wire_params = jax.tree.unflatten(treedef, enc)
-
-        def decode_remote(remote_tree):
-            flat = jax.tree.leaves(remote_tree)
-            out, j = [], 0
-            for v in leaves:
-                if v.dtype == jnp.float32:
-                    q, s = flat[j], flat[j + 1]
-                    j += 2
-                    out.append(qz.dequantize(q, s, v.shape))
-                else:
-                    out.append(flat[j])
-                    j += 1
-            return jax.tree.unflatten(treedef, out)
-
-    else:
-        wire_params = params
-    remote_params, remote_meta = lax.switch(
-        branch,
-        [make_branch(p) for p in schedule.pool],
-        (wire_params, meta),
+    local (unstacked) values, and so is what it returns."""
+    return gossip_exchange(
+        params, meta, step, schedule=schedule, interp=interp,
+        layout=MeshLayout(axis_name),
     )
-    if decode_remote is not None:
-        remote_params = decode_remote(remote_params)
-
-    # Pull mode: the pull is one-sided, so the puller draws alone (the
-    # reference's per-process fetch decision); pairwise: both members of a
-    # pair share one draw keyed on min(i, partner).
-    pair_id = me if schedule.mode == "pull" else jnp.minimum(me, partner)
-    if schedule.fetch_probability >= 1.0:
-        drawn = jnp.bool_(True)
-    else:
-        drawn = participation_draw(
-            schedule.seed, step, pair_id, schedule.fetch_probability
-        )
-    if schedule.drop_probability > 0.0:
-        # Fault injection: masked merge (α=0) is the SPMD form of the
-        # reference's timed-out fetch (SURVEY.md §5).
-        drawn = jnp.logical_and(
-            drawn,
-            jnp.logical_not(
-                schedules.fault_draw(
-                    schedule.seed, step, pair_id, schedule.drop_probability
-                )
-            ),
-        )
-    participated = jnp.logical_and(drawn, partner != me)
-    alpha = jnp.where(participated, interp(meta, remote_meta), 0.0)
-    alpha = alpha.astype(jnp.float32)
-
-    def merge(x, y):
-        a = alpha.astype(jnp.promote_types(x.dtype, jnp.float32))
-        return ((1.0 - a) * x.astype(a.dtype) + a * y.astype(a.dtype)).astype(
-            x.dtype
-        )
-
-    merged = jax.tree.map(merge, params, remote_params)
-    return merged, (partner, alpha, participated)
 
 
 class IciTransport:
@@ -197,13 +79,7 @@ class IciTransport:
         axis_name: str = PEER_AXIS,
     ):
         self.config = config
-        self.schedule = schedules.build_schedule(config)
-        self.interp = make_interpolation(
-            config.interpolation,
-            max_abs_loss=(
-                config.recovery.rescue_bound() if config.recovery.enabled else None
-            ),
-        )
+        self.schedule, self.interp = round_rules(config)
         self.axis_name = axis_name
         self.mesh = mesh if mesh is not None else make_mesh(config, axis_name=axis_name)
         (axis_size,) = (self.mesh.shape[axis_name],)
@@ -229,38 +105,22 @@ class IciTransport:
         def body(params, meta, step):
             # shard_map hands us a leading peer axis of local size 1;
             # strip it so interpolation sees true scalars, then restore.
-            params1 = jax.tree.map(lambda v: v[0], params)
-            meta1 = jax.tree.map(lambda v: v[0], meta)
-            merged, (partner, alpha, part) = gossip_exchange_local(
-                params1,
-                meta1,
-                step,
-                schedule=schedule,
-                interp=interp,
-                axis_name=axis,
+            merged, info = gossip_exchange_local(
+                *jax.tree.map(lambda v: v[0], (params, meta)), step,
+                schedule=schedule, interp=interp, axis_name=axis,
             )
-            merged = jax.tree.map(lambda v: v[None], merged)
-            return merged, (
-                partner[None],
-                alpha[None],
-                part[None],
-            )
+            return jax.tree.map(lambda v: v[None], (merged, tuple(info)))
 
         mapped = shard_map(
-            body,
-            mesh=self.mesh,
-            in_specs=(P(self.axis_name), P(self.axis_name), P()),
-            out_specs=(
-                P(self.axis_name),
-                (P(self.axis_name), P(self.axis_name), P(self.axis_name)),
-            ),
+            body, mesh=self.mesh, in_specs=(P(axis), P(axis), P()),
+            out_specs=P(axis),
             check_vma=False,  # one setting for every map; see train._make_step
         )
 
         @jax.jit
         def exchange(params, meta, step):
-            merged, (partner, alpha, part) = mapped(params, meta, step)
-            return merged, ExchangeInfo(partner, alpha, part)
+            merged, info = mapped(params, meta, step)
+            return merged, ExchangeInfo(*info)
 
         return exchange
 

@@ -6,8 +6,12 @@ The reference's hot loop (SURVEY.md §3.2) is::
     adapter.update(loss)                           # publish, fetch, merge
 
 Here the entire loop — per-peer forward/backward, optax update, AND the
-gossip exchange — is **one jitted ``shard_map`` program** over the ``peers``
-mesh axis (SURVEY.md §3.5).  Manual SPMD, deliberately: auto sharding
+gossip exchange — is one jitted program, written once
+(:func:`gossip_train_step`) and laid over peers three ways: a ``shard_map``
+over the ``peers`` mesh axis (this module, SURVEY.md §3.5), the same with
+sequences sharded inside a peer (:mod:`dpwa_tpu.train_sp`), or a leading
+array axis on one device (:mod:`dpwa_tpu.parallel.stacked`).  On a mesh it is
+**one jitted ``shard_map`` program**.  Manual SPMD, deliberately: auto sharding
 propagation through vmapped convolutions makes GSPMD introduce all-gathers
 of the per-peer replicas, which is both a performance bug (the whole point
 of gossip is that nothing is globally gathered) and a deadlock on
@@ -130,173 +134,142 @@ def init_params_per_peer(
     return jax.jit(jax.vmap(init_fn))(jax.random.split(key, n_peers))
 
 
-def _make_step(
-    loss_fn,
-    optimizer: optax.GradientTransformation,
-    transport: IciTransport,
-    exchange_filter: Optional[Callable[[str], bool]],
-    with_state: bool,
-    overlap: bool = False,
+def apply_gradients(optimizer, grads, opt_state, params):
+    """``(new_params, updates, opt_state)`` under ``dpwa.optimizer``: the
+    optimizer's half of every local update."""
+    with jax.named_scope(scopes.OPTIMIZER):
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), updates, opt_state
+
+
+def local_update(
+    loss_fn, optimizer: optax.GradientTransformation, with_state: bool
 ):
-    """Shared builder behind both public step factories.
+    """One peer's plain local step: ``(params, opt_state, model_state,
+    batch) -> (new_params, updates, opt_state, new_model_state, loss)``, the
+    forward pass under ``dpwa.forward`` so the backward pass names itself.
+    The stacked step ``vmap``s it; the mesh step calls it on the device's
+    own arrays; a layout with its own gradients (:mod:`dpwa_tpu.train_sp`)
+    writes its own around :func:`apply_gradients`.
 
-    When ``with_state`` is False, ``model_state`` is threaded through as an
-    empty pytree ``()`` — zero leaves, so it adds nothing to the compiled
-    program — keeping one body/shard_map/_step implementation for both.
-
-    ``overlap`` selects which params the exchange ships (see
-    :func:`make_gossip_train_step`): post-update (default, the lock-step
-    emulation) or pre-update ``x_k`` (the collective overlaps fwd/bwd)."""
+    Without ``with_state``, ``model_state`` is threaded through as an empty
+    pytree ``()`` — zero leaves, so it adds nothing to the compiled
+    program — keeping one step body for both."""
     grad_fn = jax.value_and_grad(
         scopes.scoped_loss(loss_fn), has_aux=with_state
     )
-    schedule, interp = transport.schedule, transport.interp
-    axis, mesh = transport.axis_name, transport.mesh
-    shard = lambda t: jax.tree.map(lambda v: v[0], t)
-    unshard = lambda t: jax.tree.map(lambda v: v[None], t)
+
+    def update(params, opt_state, model_state, batch):
+        # ``grad_fn`` is called from here and not through one more helper:
+        # the chip's host traces the model's forward the slower the deeper
+        # the Python frames above it (PERF.md section 6, PR 29).
+        if with_state:
+            (loss, new_model_state), grads = grad_fn(
+                params, model_state, batch
+            )
+        else:
+            (loss, grads), new_model_state = grad_fn(params, batch), ()
+        new_params, updates, opt_state = apply_gradients(
+            optimizer, grads, opt_state, params
+        )
+        return new_params, updates, opt_state, new_model_state, loss
+
+    return update
+
+
+def gossip_train_step(
+    update, exchange, *, exchange_filter: Optional[Callable[[str], bool]],
+    overlap: bool, with_state: bool, lay_out=lambda body: body,
+    block_per_call: bool = False,
+):
+    """THE train step, behind every public step factory: local update, then
+    the gossip round, over values whose peer layout it does not know.  After
+    ``update`` every operation is elementwise on trees, so the same body
+    serves one peer's arrays inside ``shard_map`` and ``[n, ...]`` stacks
+    under plain ``jit``.
+
+    ``update`` is :func:`local_update` laid over peers, ``exchange(tree,
+    meta, step)`` the round in the same layout, and ``lay_out(body)`` what
+    puts the body there (nothing for stacks; ``shard_map`` in
+    :func:`_make_step`).  ``overlap`` selects which replica the exchange
+    ships (see :func:`make_gossip_train_step`): post-update (default, the
+    lock-step emulation) or pre-update ``x_k`` (the collective overlaps
+    fwd/bwd)."""
+    select = lambda tree: (
+        tree if exchange_filter is None
+        else pytree_partition(tree, exchange_filter)[0]
+    )
 
     def body(params, opt_state, model_state, clock, prev_loss, step, batch):
-        # Local (per-device) values: strip the size-1 peer block axis.
-        params, opt_state = shard(params), shard(opt_state)
-        old_params, old_model_state = params, model_state
-        if with_state:
-            model_state = shard(model_state)
-            old_model_state = model_state
-            (loss, new_model_state), grads = grad_fn(
-                params, model_state, shard(batch)
-            )
-        else:
-            loss, grads = grad_fn(params, shard(batch))
-            new_model_state = ()
-        with jax.named_scope(scopes.OPTIMIZER):
-            updates, opt_state = optimizer.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        clock = clock[0] + 1.0
+        new_params, updates, opt_state, new_model_state, loss = update(
+            params, opt_state, model_state, batch
+        )
+        clock = clock + 1.0
         if overlap:
-            # Exchange the PRE-update replica with the PREVIOUS step's
-            # loss (the last value this peer "published", exactly what the
-            # reference's Rx thread would serve, SURVEY.md §3.3).  Every
-            # collective operand — x_k, clock, stale loss — is ready at
-            # step entry, so nothing gates the ppermute on this step's
-            # fwd/bwd and XLA can overlap the DMA with compute.  The
-            # model_state (fwd-produced) is also shipped stale; its
-            # this-step delta is re-applied to the merge below.
-            exchange_params, exchange_state = old_params, old_model_state
-            meta = PeerMeta(clock, prev_loss[0])
+            # Ship what was ready at step entry — x_k, its model_state, the
+            # last loss this peer "published" — so nothing gates the
+            # ppermute (or the gather's HBM reads) on this step's fwd/bwd.
+            # Why, and what it means: make_gossip_train_step's docstring.
+            shipped, shipped_state = params, model_state
+            meta = PeerMeta(clock, prev_loss)
         else:
-            exchange_params, exchange_state = params, new_model_state
+            shipped, shipped_state = new_params, new_model_state
             meta = PeerMeta(clock, loss.astype(jnp.float32))
-        if exchange_filter is not None:
-            selected, _ = pytree_partition(exchange_params, exchange_filter)
-            (merged_sel, merged_state), (partner, alpha, part) = (
-                gossip_exchange_local(
-                    (selected, exchange_state), meta, step,
-                    schedule=schedule, interp=interp, axis_name=axis,
-                )
-            )
-        else:
-            (merged_sel, merged_state), (partner, alpha, part) = (
-                gossip_exchange_local(
-                    (exchange_params, exchange_state), meta, step,
-                    schedule=schedule, interp=interp, axis_name=axis,
-                )
-            )
+        (merged, merged_state), info = exchange(
+            (select(shipped), shipped_state), meta, step
+        )
         if overlap:
-            # x_{k+1} = merge(x_k) + own update: the merge contributed the
-            # partner's pre-update replica (exactly what a free-running
-            # reference peer would have pulled from a partner that had not
-            # finished its step yet), the local gradient is never lost.
-            # Model state gets the same treatment: merge(ms_k) + this
-            # step's statistics delta.
+            # x_{k+1} = merge(x_k) + own update: the local gradient is
+            # never lost.  Model state gets the same treatment: merge(ms_k)
+            # + this step's statistics delta.
             with jax.named_scope(scopes.OPTIMIZER):
-                if exchange_filter is not None:
-                    sel_updates, _ = pytree_partition(updates, exchange_filter)
-                    merged_sel = optax.apply_updates(merged_sel, sel_updates)
-                else:
-                    merged_sel = optax.apply_updates(merged_sel, updates)
+                merged = optax.apply_updates(merged, select(updates))
             merged_state = jax.tree.map(
                 lambda m, new, old: m + (new - old),
-                merged_state, new_model_state, old_model_state,
+                merged_state, new_model_state, model_state,
             )
         if exchange_filter is not None:
-            _, rest = pytree_partition(params, exchange_filter)
-            merged = pytree_combine(merged_sel, rest)
-        else:
-            merged = merged_sel
-        return (
-            unshard(merged),
-            unshard(opt_state),
-            unshard(merged_state),
-            clock[None],
-            loss[None],
-            (partner[None], alpha[None], part[None]),
-        )
+            # Everything else never moves — neither over ICI nor DCN.
+            _, rest = pytree_partition(new_params, exchange_filter)
+            merged = pytree_combine(merged, rest)
+        return merged, opt_state, merged_state, clock, loss, tuple(info)
 
-    # Unchecked map (check_vma=False), on every shard_map in the package.
-    # Under the checked map a ``pallas_call`` is refused unless its
-    # ``out_shape`` carries ``vma``, and the flash kernels a Llama
-    # ``loss_fn`` reaches on a TPU are the library's: they build their
-    # ``out_shape`` themselves, so the other road (vma on the kernels'
-    # outputs) would mean keeping copies of them.  Nothing here leaned on
-    # the check: every differentiated operand varies over ``axis``.
-    mapped = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(axis), P(axis), P(axis), P(axis), P(axis), P(), P(axis),
-        ),
-        out_specs=(
-            P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
-        ),
-        check_vma=False,
-    )
+    laid_out = lay_out(body)
 
     # Donated: each call consumes the input state's buffers (the caller
     # rebinds `state, … = step(state, …)`).  Without donation every
     # in-flight step holds a fresh params+opt copy and a deep async
     # dispatch queue can swamp the HBM allocator.
     @functools.partial(jax.jit, donate_argnums=(0,))
-    def _step(state: GossipTrainState, batch):
+    def _step(state, batch):
         prev_loss = (
-            state.loss
-            if state.loss is not None
-            else jnp.zeros_like(state.clock)
+            jnp.zeros_like(state.clock) if state.loss is None else state.loss
         )
-        params, opt_state, model_state, clock, losses, info = mapped(
-            state.params,
-            state.opt_state,
+        params, opt_state, model_state, clock, losses, info = laid_out(
+            state.params, state.opt_state,
             state.model_state if with_state else (),
-            state.clock,
-            prev_loss,
-            state.step,
-            batch,
+            state.clock, prev_loss, state.step, batch,
         )
-        new_state = GossipTrainState(
-            params=params,
-            opt_state=opt_state,
-            clock=clock,
+        new_state = state._replace(
+            params=params, opt_state=opt_state, clock=clock,
             step=state.step + 1,
             model_state=model_state if with_state else state.model_state,
             loss=losses,
         )
         return new_state, losses, ExchangeInfo(*info)
 
-    # Same CPU run-ahead bound as IciTransport.exchange (see the rationale
-    # comment there) — reuse its detection so the rule lives in one place.
-    block_per_call = transport._block_per_call
-
-    def train_step(state: GossipTrainState, batch):
+    def train_step(state, batch):
+        # Silently frozen BatchNorm statistics are worse than an error.
         if not with_state and state.model_state is not None:
             raise ValueError(
-                "state carries model_state but this step was built with "
-                "make_gossip_train_step, which would never update it; use "
-                "make_gossip_train_step_with_state instead"
+                "state carries model_state but this step was built without "
+                "it and would never update it; build the step with model "
+                "state (with_state=True or the *_with_state factory)"
             )
         if with_state and state.model_state is None:
             raise ValueError(
-                "step built with make_gossip_train_step_with_state but "
-                "state.model_state is None; pass stacked_model_state to "
-                "init_gossip_state"
+                "step built with model state but state.model_state is None; "
+                "pass stacked_model_state when the state is initialised"
             )
         out = _step(state, batch)
         if block_per_call:
@@ -304,6 +277,65 @@ def _make_step(
         return out
 
     return train_step
+
+
+def _make_step(
+    update,
+    transport: IciTransport,
+    exchange_filter: Optional[Callable[[str], bool]],
+    with_state: bool,
+    overlap: bool = False,
+    batch_spec=None,
+):
+    """Shared builder behind the public mesh step factories, here and in
+    :mod:`dpwa_tpu.train_sp`: :func:`gossip_train_step` with one peer a
+    position on the transport's mesh axis.  ``update`` (here
+    :func:`local_update`) and ``batch_spec`` (default: peers on axis 0) are
+    what a layout with more axes inside a peer brings of its own."""
+    axis = transport.axis_name
+
+    def over_mesh(body):
+        def per_device(params, opt_state, model_state, clock, loss, step, batch):
+            # Local (per-device) values: strip the size-1 peer block axis.
+            own = lambda t: jax.tree.map(lambda v: v[0], t)
+            out = body(
+                *own((params, opt_state, model_state, clock, loss)), step,
+                own(batch),
+            )
+            return jax.tree.map(lambda v: v[None], out)
+
+        # Unchecked map (check_vma=False), on every shard_map in the
+        # package.  Under the checked map a ``pallas_call`` is refused
+        # unless its ``out_shape`` carries ``vma``, and the flash kernels a
+        # Llama ``loss_fn`` reaches on a TPU are the library's: they build
+        # their ``out_shape`` themselves, so the other road (vma on the
+        # kernels' outputs) would mean keeping copies of them.  Nothing
+        # here leaned on the check: every differentiated operand varies
+        # over ``axis``.
+        return shard_map(
+            per_device,
+            mesh=transport.mesh,
+            in_specs=(
+                P(axis), P(axis), P(axis), P(axis), P(axis), P(),
+                P(axis) if batch_spec is None else batch_spec,
+            ),
+            out_specs=P(axis),
+            check_vma=False,
+        )
+
+    return gossip_train_step(
+        update,
+        functools.partial(
+            gossip_exchange_local, schedule=transport.schedule,
+            interp=transport.interp, axis_name=axis,
+        ),
+        exchange_filter=exchange_filter, overlap=overlap,
+        with_state=with_state, lay_out=over_mesh,
+        # CPU run-ahead bound as IciTransport.exchange (see the rationale
+        # comment there) — reuse its detection so the rule lives in one
+        # place.
+        block_per_call=transport._block_per_call,
+    )
 
 
 def make_gossip_train_step(
@@ -331,7 +363,7 @@ def make_gossip_train_step(
     nothing gates the ppermute on this step's fwd/bwd — so on a real
     multi-device mesh XLA can schedule the collective-permute's ICI DMA
     concurrently with compute instead of serializing it after the
-    optimizer.  (On the single-chip stacked twin there is no second
+    optimizer.  (On the single-chip stacked layout there is no second
     engine to hide the gather behind; measured recovery there is ~1 % —
     artifacts/stacked_exchange_profile.json.)  Semantically this is one
     step of partner staleness: exactly what a free-running reference
@@ -343,8 +375,8 @@ def make_gossip_train_step(
     Raises at call time if ``state.model_state`` is set — that state would
     silently stop updating; use :func:`make_gossip_train_step_with_state`."""
     return _make_step(
-        loss_fn, optimizer, transport, exchange_filter, with_state=False,
-        overlap=overlap,
+        local_update(loss_fn, optimizer, False), transport, exchange_filter,
+        with_state=False, overlap=overlap,
     )
 
 
@@ -368,8 +400,8 @@ def make_gossip_train_step_with_state(
     wait on) and this step's statistics delta is re-applied to the merged
     result, mirroring the params' merge-then-update rule."""
     return _make_step(
-        loss_fn, optimizer, transport, exchange_filter, with_state=True,
-        overlap=overlap,
+        local_update(loss_fn, optimizer, True), transport, exchange_filter,
+        with_state=True, overlap=overlap,
     )
 
 
@@ -415,9 +447,9 @@ def make_host_train_step(
     """Jitted single-replica host step: ``step_fn(params, opt_state, x,
     y) -> (params, opt_state, loss)``.
 
-    The multi-PROCESS twin of :func:`make_gossip_train_step`: where the
-    SPMD loop fuses every peer's fwd/bwd/optimizer and the exchange into
-    one ``shard_map`` program, the chaos-certified harness
+    :func:`local_update` for the multi-PROCESS path: where the SPMD loop
+    fuses every peer's fwd/bwd/optimizer and the exchange into one
+    program, the chaos-certified harness
     (:mod:`dpwa_tpu.run`, docs/training.md) runs one OS process per
     peer — each takes this local step, then hands the result to
     ``DpwaTcpAdapter.update`` for the TCP exchange (the reference's
@@ -425,11 +457,14 @@ def make_host_train_step(
     One definition serves the harness and the examples' ``--certify``
     arms, so the certified loop and the benched loop cannot drift."""
 
+    update = local_update(
+        lambda params, batch: loss_fn(params, *batch), optimizer, False
+    )
+
     @jax.jit
     def step_fn(params, opt_state, x, y):
-        loss, grads = jax.value_and_grad(loss_fn)(params, x, y)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+        params, _, opt_state, _, loss = update(params, opt_state, (), (x, y))
+        return params, opt_state, loss
 
     return step_fn
 

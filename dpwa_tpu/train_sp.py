@@ -15,40 +15,34 @@ is ONE ``shard_map`` program.  Layout:
   ``sp`` sub-axis (ICI-local when sp maps to intra-host chips), the
   pairing ``ppermute`` rides ``peers``.
 
-The gossip semantics (schedule pools, participation/fault draws,
-interpolation, pull mode, bf16 wire) are exactly
-:func:`dpwa_tpu.parallel.ici.gossip_exchange_local` — replicated over the
-``sp`` axis, every sp rank of a replica executes the identical exchange.
-The step composes with the full 1-D feature set
-(:mod:`dpwa_tpu.train`): ``exchange_filter`` (config 5's long-context
-LoRA layout — adapters gossip over ``peers`` while the frozen base rides
-only the sp collectives), ``model_state`` (sp-reduced so replicas stay
-consistent), and ``overlap`` (ship the pre-update replica).
+The step IS the 1-D step (:func:`dpwa_tpu.train.gossip_train_step` through
+``train._make_step``); this module brings only what the ``sp`` axis adds, the
+local gradients reduced over ``sp`` and the batch spec.  So the gossip round
+(schedule pools, participation/fault draws, interpolation, pull mode, wire
+encodings) is :func:`dpwa_tpu.parallel.ici.gossip_exchange_local`, replicated
+over the ``sp`` axis — every sp rank of a replica executes the identical
+exchange — and ``exchange_filter`` (config 5's long-context LoRA layout:
+adapters gossip over ``peers`` while the frozen base rides only the sp
+collectives), ``model_state`` (sp-reduced so replicas stay consistent) and
+``overlap`` (ship the pre-update replica) come with it.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax, shard_map
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dpwa_tpu.config import DpwaConfig
-from dpwa_tpu.interpolation import PeerMeta
-from dpwa_tpu.parallel.ici import (
-    ExchangeInfo,
-    IciTransport,
-    gossip_exchange_local,
-)
+from dpwa_tpu.parallel.ici import IciTransport
 from dpwa_tpu.parallel.mesh import PEER_AXIS
-from dpwa_tpu.train import GossipTrainState
-from dpwa_tpu.utils.pytree import combine as pytree_combine
-from dpwa_tpu.utils.pytree import partition as pytree_partition
+from dpwa_tpu.train import _make_step, apply_gradients, init_gossip_state
+from dpwa_tpu.utils import scopes
 
 PyTree = Any
 SP_AXIS = "sp"
@@ -79,19 +73,9 @@ def make_sp_mesh(
     return Mesh(arr, (PEER_AXIS, sp_axis))
 
 
-def init_gossip_sp_state(
-    stacked_params: PyTree,
-    optimizer: optax.GradientTransformation,
-    transport: IciTransport,
-    stacked_model_state: PyTree = None,
-) -> GossipTrainState:
-    """Identical to :func:`dpwa_tpu.train.init_gossip_state` — the peer
-    sharding on a 2-D mesh replicates every leaf over ``sp`` for free."""
-    from dpwa_tpu.train import init_gossip_state
-
-    return init_gossip_state(
-        stacked_params, optimizer, transport, stacked_model_state
-    )
+# The peer sharding on a 2-D mesh replicates every leaf over ``sp`` for free,
+# so the 1-D initialiser serves as it is.
+init_gossip_sp_state = init_gossip_state
 
 
 def _make_sp_step(
@@ -103,194 +87,62 @@ def _make_sp_step(
     overlap: bool,
     sp_axis: str,
 ):
-    """Shared builder behind both public sp step factories.
+    """Shared builder behind both public sp step factories:
+    :func:`dpwa_tpu.train._make_step` with this layout's two arguments, the
+    local gradients below and the batch spec ``P(peers, None, sp)``.
 
-    Mirrors :func:`dpwa_tpu.train._make_step` with the sp additions: the
-    loss arrives as a (sum, count) pair psummed over ``sp``; gradients
-    are psummed over ``sp`` too; and
-    ``model_state`` is ``pmean``-ed over ``sp`` after the forward pass
-    (each sp rank computes statistics on its own sequence block — the
-    reduction is what keeps every rank of a replica bit-identical before
-    the exchange)."""
-    mesh, peers_axis = transport.mesh, transport.axis_name
+    The loss arrives as a (sum, count) pair psummed over ``sp``; gradients
+    are psummed over ``sp`` too; and ``model_state`` is ``pmean``-ed over
+    ``sp`` after the forward pass (each sp rank computes statistics on its
+    own sequence block — the reduction is what keeps every rank of a
+    replica bit-identical before the exchange)."""
+    mesh = transport.mesh
     if sp_axis not in mesh.shape:
         raise ValueError(
             f"transport mesh {dict(mesh.shape)} has no {sp_axis!r} axis; "
             "build it with make_sp_mesh"
         )
-    schedule, interp = transport.schedule, transport.interp
-    if with_state:
-        # loss_fn returns ((loss_sum, count), new_model_state); grad needs
-        # a scalar primal, so fold count in with the aux.
-        def _scalarized(params, model_state, batch):
-            (loss_sum, count), new_ms = loss_fn(params, model_state, batch)
-            return loss_sum, (count, new_ms)
+    scoped = scopes.scoped_loss(loss_fn)
 
-        grad_fn = jax.value_and_grad(_scalarized, has_aux=True)
-    else:
-        grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-    shard = lambda t: jax.tree.map(lambda v: v[0], t)
-    unshard = lambda t: jax.tree.map(lambda v: v[None], t)
-
-    def body(params, opt_state, model_state, clock, prev_loss, step, batch):
-        params, opt_state = shard(params), shard(opt_state)
-        old_params, old_model_state = params, model_state
-        local_batch = shard(batch)
+    # grad needs a scalar primal, so fold count in with the aux.
+    def scalarized(params, model_state, batch):
         if with_state:
-            model_state = shard(model_state)
-            (loss_sum, (count, new_model_state)), grads = grad_fn(
-                params, model_state, local_batch
-            )
-            # Each sp rank saw only its sequence block: reduce the updated
-            # statistics across ``sp`` so the replica stays consistent.
-            new_model_state = jax.tree.map(
-                lambda v: lax.pmean(v, sp_axis), new_model_state
-            )
-            old_model_state = model_state
+            (loss_sum, count), new_ms = scoped(params, model_state, batch)
         else:
-            (loss_sum, count), grads = grad_fn(params, local_batch)
-            new_model_state = ()
+            (loss_sum, count), new_ms = scoped(params, batch), ()
+        return loss_sum, (count, new_ms)
+
+    grad_fn = jax.value_and_grad(scalarized, has_aux=True)
+    over_sp = lambda reduce, t: jax.tree.map(lambda v: reduce(v, sp_axis), t)
+
+    def update(params, opt_state, model_state, batch):
+        (loss_sum, (count, new_model_state)), grads = grad_fn(
+            params, model_state, batch
+        )
+        # Each sp rank saw only its sequence block: reduce the updated
+        # statistics across ``sp`` so the replica stays consistent.
+        new_model_state = over_sp(lax.pmean, new_model_state)
         # ``params`` enter replicated over ``sp``, so each rank holds the
         # gradient of every block's loss through ITS OWN copy (ring and
         # all-to-all cross-block terms arrive through the transposed
         # collectives); the gradient of the shared parameters is their sum
-        # over ``sp``.  The map is unchecked (see below), so nothing
-        # inserts that sum for us.
-        grads = jax.tree.map(lambda g: lax.psum(g, sp_axis), grads)
-        loss_sum = lax.psum(loss_sum, sp_axis)
-        count = lax.psum(count, sp_axis)
-        loss = (loss_sum / jnp.maximum(count, 1.0)).astype(jnp.float32)
-        grads = jax.tree.map(
-            lambda g: g / jnp.maximum(count, 1.0).astype(g.dtype), grads
+        # over ``sp``.  The map is unchecked (see train._make_step), so
+        # nothing inserts that sum for us.
+        grads, loss_sum, count = over_sp(lax.psum, (grads, loss_sum, count))
+        count = jnp.maximum(count, 1.0)
+        grads = jax.tree.map(lambda g: g / count.astype(g.dtype), grads)
+        loss = (loss_sum / count).astype(jnp.float32)
+        new_params, updates, opt_state = apply_gradients(
+            optimizer, grads, opt_state, params
         )
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
-        clock = clock[0] + 1.0
-        if overlap:
-            # Ship the PRE-update replica with the PREVIOUS step's loss —
-            # every collective operand is ready at step entry, so the
-            # peers-axis ppermute needs nothing from this step's fwd/bwd
-            # (same semantics as the 1-D overlap: one step of partner
-            # staleness, exactly the reference's stale Rx publish).
-            exchange_params, exchange_state = old_params, old_model_state
-            meta = PeerMeta(clock, prev_loss[0])
-        else:
-            exchange_params, exchange_state = params, new_model_state
-            meta = PeerMeta(clock, loss)
-        if exchange_filter is not None:
-            exchange_params, _ = pytree_partition(
-                exchange_params, exchange_filter
-            )
-        (merged_sel, merged_state), (partner, alpha, part) = (
-            gossip_exchange_local(
-                (exchange_params, exchange_state), meta, step,
-                schedule=schedule, interp=interp, axis_name=peers_axis,
-            )
-        )
-        if overlap:
-            # x_{k+1} = merge(x_k) + own update; model_state analogously
-            # re-applies this step's statistics delta to the merge.
-            if exchange_filter is not None:
-                sel_updates, _ = pytree_partition(updates, exchange_filter)
-                merged_sel = optax.apply_updates(merged_sel, sel_updates)
-            else:
-                merged_sel = optax.apply_updates(merged_sel, updates)
-            merged_state = jax.tree.map(
-                lambda m, new, old: m + (new - old),
-                merged_state, new_model_state, old_model_state,
-            )
-        if exchange_filter is not None:
-            _, rest = pytree_partition(params, exchange_filter)
-            merged = pytree_combine(merged_sel, rest)
-        else:
-            merged = merged_sel
-        return (
-            unshard(merged),
-            unshard(opt_state),
-            unshard(merged_state),
-            clock[None],
-            loss[None],
-            (partner[None], alpha[None], part[None]),
-        )
+        return new_params, updates, opt_state, new_model_state, loss
 
-    # A single spec is a valid pytree prefix for any batch structure whose
-    # leaves are [n_peers, B, T] blocks.
-    batch_spec = P(peers_axis, None, sp_axis)
-    # Unchecked for the reason train._make_step gives (the attention hops
-    # are library Pallas kernels on a TPU).  What the check used to supply
-    # here is the gradient psum above.
-    mapped = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            P(),
-            batch_spec,
-        ),
-        out_specs=(
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            P(peers_axis),
-            (P(peers_axis), P(peers_axis), P(peers_axis)),
-        ),
-        check_vma=False,
+    return _make_step(
+        update, transport, exchange_filter, with_state, overlap,
+        # A single spec is a valid pytree prefix for any batch structure
+        # whose leaves are [n_peers, B, T] blocks.
+        batch_spec=P(transport.axis_name, None, sp_axis),
     )
-
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _step(state: GossipTrainState, batch):
-        prev_loss = (
-            state.loss
-            if state.loss is not None
-            else jnp.zeros_like(state.clock)
-        )
-        params, opt_state, model_state, clock, losses, info = mapped(
-            state.params,
-            state.opt_state,
-            state.model_state if with_state else (),
-            state.clock,
-            prev_loss,
-            state.step,
-            batch,
-        )
-        new_state = GossipTrainState(
-            params=params,
-            opt_state=opt_state,
-            clock=clock,
-            step=state.step + 1,
-            model_state=model_state if with_state else state.model_state,
-            loss=losses,
-        )
-        return new_state, losses, ExchangeInfo(*info)
-
-    # CPU run-ahead bound: reuse the transport's detection (see the
-    # rationale comment in IciTransport.__init__).
-    block_per_call = transport._block_per_call
-
-    def train_step(state: GossipTrainState, batch):
-        if not with_state and state.model_state is not None:
-            raise ValueError(
-                "state carries model_state but this step was built with "
-                "make_gossip_sp_train_step, which would never update it; "
-                "use make_gossip_sp_train_step_with_state instead"
-            )
-        if with_state and state.model_state is None:
-            raise ValueError(
-                "step built with make_gossip_sp_train_step_with_state but "
-                "state.model_state is None; pass stacked_model_state to "
-                "init_gossip_sp_state"
-            )
-        out = _step(state, batch)
-        if block_per_call:
-            jax.block_until_ready(out)
-        return out
-
-    return train_step
 
 
 def make_gossip_sp_train_step(

@@ -97,39 +97,3 @@ def test_eval_with_merged_stats_is_finite():
         train=False,  # inference: use the (merged) running stats
     )
     assert jnp.all(jnp.isfinite(logits))
-
-
-def test_plain_step_rejects_model_state():
-    """make_gossip_train_step would silently never update model_state; it
-    must refuse states that carry one."""
-    import pytest
-
-    from dpwa_tpu.train import make_gossip_train_step
-
-    n = 2
-    cfg = make_local_config(n, schedule="ring")
-    transport = IciTransport(cfg, mesh=make_mesh(cfg, jax.devices()[:n]))
-    model = CifarResNet(depth=8, norm_type="batch")
-    variables = model.init(jax.random.key(0), jnp.zeros((2, 8, 8, 3)))
-    opt = optax.sgd(0.01)
-    state = init_gossip_state(
-        stack_params(variables["params"], n),
-        opt,
-        transport,
-        stacked_model_state=stack_params(variables["batch_stats"], n),
-    )
-
-    def loss_fn(params, batch):
-        x, y = batch
-        logits = model.apply(
-            {"params": params, "batch_stats": variables["batch_stats"]}, x,
-            train=False,
-        )
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, y
-        ).mean()
-
-    step_fn = make_gossip_train_step(loss_fn, opt, transport)
-    batch = (jnp.ones((n, 2, 8, 8, 3)), jnp.zeros((n, 2), jnp.int32))
-    with pytest.raises(ValueError, match="model_state"):
-        step_fn(state, batch)

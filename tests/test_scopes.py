@@ -1,7 +1,8 @@
 """The step names its own parts: ``dpwa.forward`` / ``dpwa.optimizer`` /
-``dpwa.exchange`` in the ``op_name`` of the lowered step of both step builders
-that benchmark cells run, on both transports' exchange alone, and nothing
-else changed by them.  CPU only, a toy model, no socket."""
+``dpwa.exchange`` in the ``op_name`` of the lowered step of all three step
+builders (they share one step body and one exchange body, so the
+sequence-parallel step carries them too), on the transports' exchange alone,
+and nothing else changed by them.  CPU only, a toy model, no socket."""
 
 import contextlib
 import re
@@ -22,6 +23,7 @@ from dpwa_tpu.parallel.stacked import (
     make_stacked_train_step,
 )
 from dpwa_tpu.train import init_gossip_state, make_gossip_train_step
+from dpwa_tpu.train_sp import make_gossip_sp_train_step, make_sp_mesh
 from dpwa_tpu.utils import scopes
 
 N = 4
@@ -29,7 +31,9 @@ FORWARD, BACKWARD = "jvp(dpwa.forward)", "transpose(jvp(dpwa.forward))"
 # One instruction of HLO text: its opcode and, where it has one, its op_name.
 INSTRUCTION = re.compile(r"^\s*(?:ROOT )?\S+ = \S+ ([a-z][a-z-]*)\(")
 OP_NAME = re.compile(r'op_name="([^"]*)"')
-BUILDERS = ["stacked", "ici"]
+BUILDERS = ["stacked", "ici", "sp"]
+MESH_BUILDERS = ["ici", "sp"]
+SP = 2
 FILTERS = [None, "dense"]
 
 
@@ -55,16 +59,27 @@ def loss_fn(params, batch):
     return optax.softmax_cross_entropy_with_integer_labels(logits, y).mean()
 
 
+def sp_loss_fn(params, batch):
+    """``loss_fn`` as the sequence-parallel step wants it: (sum, count) over
+    this rank's block.  A rank holds ``8 / SP`` of an image's rows; the toy
+    model pads them back to the height its dense layer was made for."""
+    x, y = batch
+    x = jnp.pad(x, ((0, 0), (0, 8 - x.shape[1]), (0, 0), (0, 0)))
+    return loss_fn(params, (x, y[:, 0])) * x.shape[0], jnp.float32(x.shape[0])
+
+
 def transport_of(builder):
     cfg = make_local_config(N, schedule="random", pool_size=4, seed=1)
     if builder == "stacked":
         return StackedTransport(cfg)
-    return IciTransport(cfg, mesh=make_mesh(cfg))
+    mesh = make_mesh(cfg) if builder == "ici" else make_sp_mesh(cfg, SP)
+    return IciTransport(cfg, mesh=mesh)
 
 
 def lowered_step(builder, only=None, overlap=False):
     """The toy step of one builder, lowered on the CPU (four forced devices
-    for the mesh); ``only`` exchanges the leaves whose path starts with it."""
+    for the mesh, eight for peers x sp); ``only`` exchanges the leaves whose
+    path starts with it."""
     transport = transport_of(builder)
     optimizer = optax.sgd(0.1, momentum=0.9)
     params = jax.vmap(init)(jax.random.split(jax.random.key(0), N))
@@ -76,11 +91,19 @@ def lowered_step(builder, only=None, overlap=False):
             loss_fn, optimizer, transport, exchange_filter=exchange_filter,
             overlap=overlap,
         )
-    else:
+    elif builder == "ici":
         state = init_gossip_state(params, optimizer, transport)
         step = make_gossip_train_step(
             loss_fn, optimizer, transport, exchange_filter=exchange_filter,
             overlap=overlap,
+        )
+    else:
+        # Every leaf [n, B, T, ...] with T over sp: a label per rank.
+        batch = (batch[0], jnp.zeros((N, 2, SP), jnp.int32))
+        state = init_gossip_state(params, optimizer, transport)
+        step = make_gossip_sp_train_step(
+            sp_loss_fn, optimizer, transport,
+            exchange_filter=exchange_filter, overlap=overlap,
         )
     return jax.jit(step).lower(state, batch)
 
@@ -141,6 +164,16 @@ def test_lowered_step_holds_all_four_phases(lowered, builder, only):
         "shard_map/jvp(dpwa.forward)"
     )
     assert any(wrapped in name or name.startswith("jvp(") for name in names)
+    if builder == "sp":
+        # What is the sp step's own stays outside the shared scopes: the
+        # reductions over sp are no part of forward, optimizer or exchange.
+        reduces = [
+            name for op, name in instructions(
+                lowered(builder, only).as_text(dialect="hlo", debug_info=True)
+            )
+            if op == "all-reduce"
+        ]
+        assert reduces and not any("dpwa." in name for name in reduces)
 
 
 @pytest.mark.parametrize("only", FILTERS)
@@ -169,11 +202,14 @@ def test_matmuls_and_convolutions_lie_under_forward_or_backward(
 
 @pytest.mark.parametrize("overlap", [False, True])
 @pytest.mark.parametrize("only", FILTERS)
-def test_every_collective_permute_lies_under_exchange(lowered, only, overlap):
+@pytest.mark.parametrize("builder", MESH_BUILDERS)
+def test_every_collective_permute_lies_under_exchange(
+    lowered, builder, only, overlap
+):
     found = [
         name
         for op, name in instructions(
-            lowered("ici", only, overlap).as_text(
+            lowered(builder, only, overlap).as_text(
                 dialect="hlo", debug_info=True
             )
         )

@@ -161,18 +161,6 @@ def test_sp_rope_positions_are_global():
     )
 
 
-def test_sp_step_rejects_model_state():
-    cfg = make_local_config(N_PEERS, schedule="ring")
-    mesh = make_sp_mesh(cfg, SP)
-    t = IciTransport(cfg, mesh=mesh)
-    opt = optax.sgd(0.1)
-    state = init_gossip_sp_state(_init_params(), opt, t)
-    state = state._replace(model_state={"stats": jnp.zeros(3)})
-    step = make_gossip_sp_train_step(lambda p, b: (0.0, 1.0), opt, t)
-    with pytest.raises(ValueError, match="model_state"):
-        step(state, (jnp.zeros((N_PEERS, B, T), jnp.int32),) * 2)
-
-
 def test_sp_lora_subset_exchange_matches_1d():
     """Config 5's actual long-context layout (BASELINE.json:11): LoRA
     adapters gossip over ``peers`` while sequences shard over ``sp``.

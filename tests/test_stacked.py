@@ -56,6 +56,11 @@ def stacked_meta(n, clocks=None, losses=None):
         dict(schedule="ring", mode="pull"),
         dict(schedule="random", mode="pull", pool_size=4,
              fetch_probability=0.6, seed=9),
+        # The layouts share the wire's encode and decode and differ in the
+        # fetch between them: codes and scales by gather or by ppermute.
+        dict(schedule="random", pool_size=4, seed=3, wire_dtype="bf16"),
+        dict(schedule="ring", mode="pull", fetch_probability=0.6, seed=9,
+             wire_dtype="int8"),
     ],
 )
 def test_exchange_parity_with_ici(cfg_kwargs):
@@ -184,28 +189,68 @@ def test_stacked_train_converges_and_contracts():
     assert np.abs(w - w.mean(axis=0)).max() < 0.05
 
 
-def test_stacked_train_step_model_state_misuse_raises():
+def _misuse_case(builder, with_state):
+    """(step, init_state) of one builder over a 4-peer ring; the step was
+    built with or without model state."""
+    from dpwa_tpu.train import make_gossip_train_step_with_state
+    from dpwa_tpu.train_sp import (
+        init_gossip_sp_state,
+        make_gossip_sp_train_step,
+        make_gossip_sp_train_step_with_state,
+        make_sp_mesh,
+    )
+
     n = 4
     cfg = make_local_config(n, schedule="ring")
-    stk = StackedTransport(cfg)
     opt = optax.sgd(0.1)
+    stateful = lambda p, s, b: (_mlp_loss(p, b), s)
+    if builder == "stacked":
+        transport = StackedTransport(cfg)
+        step = make_stacked_train_step(
+            stateful if with_state else _mlp_loss, opt, transport,
+            with_state=with_state,
+        )
+        init = init_stacked_state
+    elif builder == "ici":
+        transport = IciTransport(cfg, mesh=make_mesh(cfg))
+        step = (
+            make_gossip_train_step_with_state(stateful, opt, transport)
+            if with_state
+            else make_gossip_train_step(_mlp_loss, opt, transport)
+        )
+        init = init_gossip_state
+    else:
+        transport = IciTransport(cfg, mesh=make_sp_mesh(cfg, 2))
+        step = (
+            make_gossip_sp_train_step_with_state(stateful, opt, transport)
+            if with_state
+            else make_gossip_sp_train_step(_mlp_loss, opt, transport)
+        )
+        init = init_gossip_sp_state
     params = stack_params(_mlp_init(jax.random.key(0)), n)
-    batch = _batches(n, steps=1)[0]
-    # with_state=False but state carries model_state: must raise, not
-    # silently freeze the stats (mirrors the SPMD guard in train.py).
-    step_fn = make_stacked_train_step(_mlp_loss, opt, stk)
-    state = init_stacked_state(
-        params, opt, stk, stacked_model_state={"bn": jnp.zeros((n, 3))}
+    return step, lambda **kw: init(params, opt, transport, **kw)
+
+
+@pytest.mark.parametrize("builder", ["stacked", "ici", "sp"])
+@pytest.mark.parametrize(
+    "with_state, error",
+    [
+        # The state carries model_state and the step would never update it:
+        # silently frozen BatchNorm statistics, so it must raise.
+        (False, "state carries model_state but this step was built without"),
+        # The step wants model state and the state has none.
+        (True, "step built with model state but state.model_state is None"),
+    ],
+)
+def test_train_step_model_state_misuse_raises(builder, with_state, error):
+    """One guard for every builder (``train.gossip_train_step``), both ways."""
+    step, init = _misuse_case(builder, with_state)
+    state = (
+        init() if with_state
+        else init(stacked_model_state={"bn": jnp.zeros((4, 3))})
     )
-    with pytest.raises(ValueError, match="model_state"):
-        step_fn(state, batch)
-    # with_state=True but no model_state in the state: clear error too.
-    step_fn_ws = make_stacked_train_step(
-        lambda p, s, b: (_mlp_loss(p, b), s), opt, stk, with_state=True
-    )
-    state_plain = init_stacked_state(params, opt, stk)
-    with pytest.raises(ValueError, match="model_state"):
-        step_fn_ws(state_plain, batch)
+    with pytest.raises(ValueError, match=error):
+        step(state, _batches(4, steps=1)[0])
 
 
 def test_stacked_checkpoint_roundtrip_and_cross_layout_resume(tmp_path):

@@ -113,6 +113,27 @@ def close_all(ts):
         t.close()
 
 
+def test_base_port_zero_leaves_every_port_to_the_os():
+    # base_port=0 used to give node i port 0 + i: node 0 was bound by the
+    # OS, node 1 bound 127.0.0.1:1, node 2 bound :2, and two test workers
+    # building rings at once collided ("Address already in use").
+    assert [n.port for n in make_local_config(3, base_port=0).nodes] == [0] * 3
+    assert [n.port for n in make_local_config(3, base_port=7).nodes] == [
+        7, 8, 9,
+    ]
+    rings = [make_ring(3), make_ring(3)]  # side by side, both alive
+    try:
+        ports = [t.port for ring in rings for t in ring]
+        assert len(set(ports)) == 6 and all(p > 1023 for p in ports), ports
+        for ring in rings:
+            assert [t._ports[i][1] for t in ring for i in range(3)] == [
+                t.port for t in ring
+            ] * 3
+    finally:
+        for ring in rings:
+            close_all(ring)
+
+
 @_RX_SERVERS
 def test_publish_fetch_roundtrip(server_cls):
     server = server_cls("127.0.0.1", 0)
