@@ -11,7 +11,7 @@ The layer (the published ``OlmoeSparseMoeBlock``), for tokens ``x [N, D]``:
 computed, at any routing skew: there is no capacity factor, no token is
 dropped and no group is padded to a fixed size.  The assignments are sorted
 by expert, the tokens' rows are gathered into that order (``[N x k, D]``, an
-expert's rows contiguous), and each projection is one *grouped* matmul: row
+expert's rows contiguous), and each projection is a *grouped* matmul: row
 ``i`` is multiplied by the weights of the expert whose group it lies in,
 ``group_sizes [E]`` (summing to exactly ``N x k``) saying where groups end.
 
@@ -39,6 +39,26 @@ activations (48 TFLOP/s with it) and loses the instruction's ``op_name``, so
 no scope finds it in a trace; ``megablox`` at ``(256, 1024, 1024)`` runs 89 to
 100 TFLOP/s in all three and keeps its name; at its default ``(128, 128,
 128)`` it runs 9 (PERF.md section 6, PR 27, has the sweep).
+
+**What the adapters share** (PR 30).  A rank-r factor's grouped matmul reads
+or writes a whole sorted ``[N x k, D]`` array for r columns and runs at the
+memory rate, so its cost is how often a wide array is read or written, and
+:func:`moe_ffn` lets an adapter have a pass of its own only where the algebra
+gives it no other.  (1) Gate's and up's A sides have one input, so they are
+one grouped matmul by ``[A_gate | A_up]`` (:func:`gate_and_up`): one pass
+over the rows forward, one ``tgmm`` to ``[E, D, 2r]`` and one ``gmm`` to the
+rows backward, where there were two of each.  (2) The dispatch gathers the
+sorted rows from the tokens themselves (``x[order // k]``), not from a
+written ``jnp.repeat(x, k)``.  (3) The layer's output is linear in the down
+projection, so the down adapter's B side is applied after the combine, in
+token order: ``y += lora_scale x z B_all`` with ``z [N, E x r]`` holding
+``w[n, j] x (hidden A_e)[n, j]`` at the columns of the expert e of choice j,
+one dense matmul a peer in place of a grouped one that writes ``[N x k, D]``,
+an add over it, and two backward calls that read the output's gradient.  The
+same products, summed in another order; no precision changes.  What decides
+is what is passed: (1) needs ``lora_a`` of one shape on both of gate and up
+(else :func:`expert_projection` for each, as the dense-expert tests run it),
+(3) an adapter on ``w_down``.
 """
 
 from __future__ import annotations
@@ -189,6 +209,28 @@ def _permute_rows_bwd(inverse, grad):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _dispatch_rows(x, order, inverse, k: int):
+    """Tokens ``x [N, D]`` into expert order, ``[N x k, D]``: sorted row i is
+    the token of flat assignment ``order[i]``, ``x[order // k]``, gathered
+    from the tokens themselves (``jnp.repeat(x, k)`` is never written).  The
+    gradient is a gather too: ``grad[inverse]`` is token-major, ``[N, k, D]``,
+    and a token's k rows are summed."""
+    return x[order // k]
+
+
+def _dispatch_rows_fwd(x, order, inverse, k):
+    return x[order // k], inverse
+
+
+def _dispatch_rows_bwd(k, inverse, grad):
+    per_choice = grad[inverse].reshape(-1, k, grad.shape[-1])
+    return per_choice.sum(1), None, None
+
+
+_dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
+
+
 def route(x, router_kernel, k: int):
     """Router of ``x [N, D]``: ``(weights [N, k] float32, experts [N, k]
     int32, logits [N, E] float32)``.  Logits and softmax are float32 at the
@@ -236,29 +278,79 @@ def expert_projection(rows, weights, group_sizes, lora_scale, dtype):
     return out
 
 
+def gate_and_up(rows, w_gate, w_up, group_sizes, lora_scale, dtype):
+    """Both input-side projections of every expert on its own rows, as
+    :func:`expert_projection` twice gives them.  Where both carry adapters of
+    one rank their A sides are one grouped matmul: ``rows`` is read once for
+    ``[A_gate | A_up]`` (``[E, D, 2r]``; the kernels pad a narrow side to 128
+    lanes, so rank 2r costs a call what rank r does), and backward once to
+    the adapters and once to the rows, where two adapters made two passes
+    over the same wide array each way."""
+    a_gate, a_up = w_gate[1], w_up[1]
+    if a_gate is None or a_up is None or a_gate.shape != a_up.shape:
+        return tuple(
+            expert_projection(rows, w, group_sizes, lora_scale, dtype)
+            for w in (w_gate, w_up)
+        )
+    a_cat = jnp.concatenate([a_gate, a_up], axis=-1).astype(dtype)
+    down_cat = grouped_matmul(rows, a_cat, group_sizes)
+    return tuple(
+        grouped_matmul(rows, kernel.astype(dtype), group_sizes)
+        + grouped_matmul(down, lora_b.astype(dtype), group_sizes) * lora_scale
+        for down, (kernel, _, lora_b)
+        in zip(jnp.split(down_cat, 2, axis=-1), (w_gate, w_up))
+    )
+
+
+def _down_adapter_on_tokens(down, routed, lora_b, lora_scale, dtype):
+    """The down adapter's B side after the combine.  The layer's output is
+    linear in the down projection, so the adapter's share of it is
+    ``lora_scale x z B_all``: given ``down [N, k, r]`` (``hidden A_e``, back
+    in token order), ``z[n, e] = w[n, j] down[n, j]`` for the j with
+    ``experts[n, j] == e`` (zero elsewhere: ``[N, E x r]``), and ``B_all`` is
+    ``lora_b`` as ``[E x r, D]``: one dense matmul a peer, where a grouped
+    one wrote ``[N x k, D]`` only to be added, gathered and summed over k."""
+    weights, experts = routed
+    n_experts, r, d_out = lora_b.shape
+    placed = jax.nn.one_hot(experts, n_experts, dtype=dtype) * (
+        weights.astype(dtype)[..., None]
+    )
+    z = jnp.einsum("nke,nkr->ner", placed, down)
+    return jnp.dot(
+        z.reshape(-1, n_experts * r),
+        lora_b.astype(dtype).reshape(n_experts * r, d_out),
+    ) * lora_scale
+
+
 def moe_ffn(x, routed, w_gate, w_up, w_down, lora_scale: float, dtype):
     """The expert layer on tokens ``x [N, D]`` given ``routed = (weights,
     experts)`` of :func:`route`: dispatch, SwiGLU experts, combine.  Each of
-    ``w_gate / w_up / w_down`` is a triple as in :func:`expert_projection`."""
+    ``w_gate / w_up / w_down`` is a triple as in :func:`expert_projection`.
+    What the adapters share is chosen by what is passed (the module
+    docstring's last paragraph)."""
     weights, experts = routed
     n, k = experts.shape
     with jax.named_scope(scopes.MOE_ROUTE):
         order, inverse, group_sizes = dispatch_plan(experts, w_gate[0].shape[0])
-        # Token i's row k times over, then into expert order.
-        rows = _permute_rows(
-            jnp.repeat(x.astype(dtype), k, axis=0), order, inverse
-        )
+        rows = _dispatch_rows(x.astype(dtype), order, inverse, k)
+    kernel_down, a_down, b_down = w_down
     with jax.named_scope(scopes.MOE_EXPERTS):
-        project = functools.partial(
-            expert_projection, group_sizes=group_sizes,
-            lora_scale=lora_scale, dtype=dtype,
+        gate, up = gate_and_up(
+            rows, w_gate, w_up, group_sizes, lora_scale, dtype
         )
-        hidden = jax.nn.silu(project(rows, w_gate)) * project(rows, w_up)
-        out = project(hidden, w_down)
+        hidden = jax.nn.silu(gate) * up
+        out = grouped_matmul(hidden, kernel_down.astype(dtype), group_sizes)
+        if a_down is not None:
+            down = grouped_matmul(hidden, a_down.astype(dtype), group_sizes)
     with jax.named_scope(scopes.MOE_ROUTE):
-        per_choice = _permute_rows(out, inverse, order).reshape(n, k, -1)
-        return jnp.einsum(
-            "nkd,nk->nd", per_choice, weights.astype(dtype)
+        to_tokens = lambda v: _permute_rows(v, inverse, order).reshape(n, k, -1)
+        y = jnp.einsum("nkd,nk->nd", to_tokens(out), weights.astype(dtype))
+        if a_down is None:
+            return y
+        down = to_tokens(down)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        return y + _down_adapter_on_tokens(
+            down, routed, b_down, lora_scale, dtype
         )
 
 

@@ -166,8 +166,19 @@ def _skewed(params):
     )
 
 
+def _up_reads_gates_columns(gate_and_up):
+    """The merged A side with up's slice taken at gate's columns of
+    ``down_cat``: up's adapter sees ``rows A_gate``."""
+
+    def projections(rows, w_gate, w_up, *args):
+        return gate_and_up(rows, w_gate, (w_up[0], w_gate[1], w_up[2]), *args)
+
+    return projections
+
+
 VARIANTS = ["top_7_of_8", "renormalised_weights", "no_qk_norm",
-            "capacity_drops_overflow", "experts_without_adapters"]
+            "capacity_drops_overflow", "experts_without_adapters",
+            "up_adapter_from_gates_columns"]
 
 
 @pytest.mark.parametrize("variant", VARIANTS)
@@ -188,6 +199,11 @@ def test_missing_mathematics_is_outside_the_tolerance(seeded, variant, monkeypat
     elif variant == "capacity_drops_overflow":
         params = _skewed(params)
         monkeypatch.setattr(moe, "route", _with_capacity(moe.route))
+        program = model
+    elif variant == "up_adapter_from_gates_columns":
+        monkeypatch.setattr(
+            moe, "gate_and_up", _up_reads_gates_columns(moe.gate_and_up)
+        )
         program = model
     else:
         params_without = jax.tree_util.tree_map_with_path(
@@ -247,6 +263,244 @@ def test_no_token_is_dropped_at_any_skew(experts_of):
     assert float(stats["max_over_mean"]) == pytest.approx(
         int(stats["assignments"].max()) * E / experts.size
     )
+
+
+def _per_projection_layer(x, routed, w_gate, w_up, w_down, lora_scale, dtype):
+    """The layer as it was before the adapters shared their passes (PR 29):
+    the tokens repeated k times and permuted, ``expert_projection`` for each
+    of the three, the down adapter's output added in expert order."""
+    weights, experts = routed
+    n, k = experts.shape
+    order, inverse, sizes = moe.dispatch_plan(experts, w_gate[0].shape[0])
+    rows = moe._permute_rows(
+        jnp.repeat(x.astype(dtype), k, axis=0), order, inverse
+    )
+    project = lambda v, w: moe.expert_projection(v, w, sizes, lora_scale, dtype)
+    hidden = jax.nn.silu(project(rows, w_gate)) * project(rows, w_up)
+    out = moe._permute_rows(project(hidden, w_down), inverse, order)
+    return jnp.einsum(
+        "nkd,nk->nd", out.reshape(n, k, -1), weights.astype(dtype)
+    )
+
+
+def _skewed_routing(key, n):
+    """Two experts take most first choices and one expert nothing; weights
+    as a softmax's top-k gives them (positive, not normalised)."""
+    logits = jax.random.normal(key, (n, E)).at[:, :2].add(3.0)
+    weights, experts = jax.lax.top_k(
+        jax.nn.softmax(logits.at[:, 6].add(-50.0), -1), K
+    )
+    return weights, experts.astype(jnp.int32)
+
+
+def _assert_trees_close(got, want, rtol=2e-5):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        scale = float(jnp.abs(w).max())
+        assert scale > 0
+        np.testing.assert_allclose(g, w, rtol=rtol, atol=rtol * scale)
+
+
+def _assert_equal_over_peers(fn, reference, make_args, peers):
+    """``fn`` against ``reference`` on ``make_args(i)`` (arrays only):
+    unbatched for ``peers`` 0, else under ``vmap`` over that many stacked
+    argument sets, each peer against the reference on its own."""
+    if not peers:
+        return _assert_trees_close(fn(*make_args(0)), reference(*make_args(0)))
+    each = [make_args(i) for i in range(peers)]
+    got = jax.vmap(fn)(*jax.tree.map(lambda *v: jnp.stack(v), *each))
+    for i, args in enumerate(each):
+        _assert_trees_close(jax.tree.map(lambda v: v[i], got), reference(*args))
+
+
+PEERS = pytest.mark.parametrize("peers", [0, 2], ids=["unbatched", "vmap2"])
+GROUP_SIZES = ([0, 7, 1, 0, 12, 20, 0, 0], [5, 5, 5, 5, 5, 5, 5, 5])
+
+
+@PEERS
+def test_merged_gate_and_up_equal_two_expert_projections(peers):
+    """Values and the gradients to the rows, both ``lora_a``, both
+    ``lora_b``."""
+    n = 40
+
+    def make_args(i):
+        w_gate, w_up, _ = _layer_weights(jax.random.key(10 + i))
+        keys = jax.random.split(jax.random.key(20 + i))
+        return (jax.random.normal(keys[0], (n, D)), (w_gate[1:], w_up[1:]),
+                (w_gate[0], w_up[0]), jnp.array(GROUP_SIZES[i], jnp.int32),
+                jax.random.normal(keys[1], (2, n, F)))
+
+    def graded(projections):
+        def loss(rows, adapters, kernels, sizes, cots):
+            w_gate, w_up = [(k,) + ab for k, ab in zip(kernels, adapters)]
+            gate, up = projections(rows, w_gate, w_up, sizes, 2.0, jnp.float32)
+            return jnp.sum(gate * cots[0]) + jnp.sum(up * cots[1]), (gate, up)
+
+        return jax.value_and_grad(loss, (0, 1), has_aux=True)
+
+    twice = lambda rows, w_gate, w_up, *rest: tuple(
+        moe.expert_projection(rows, w, *rest) for w in (w_gate, w_up)
+    )
+    _assert_equal_over_peers(
+        graded(moe.gate_and_up), graded(twice), make_args, peers
+    )
+
+
+@PEERS
+def test_dispatch_from_the_tokens_equals_permuting_their_repeats(peers):
+    n = 24
+
+    def make_args(i):
+        keys = jax.random.split(jax.random.key(30 + i))
+        _, experts = _skewed_routing(keys[0], n)
+        order, inverse, _ = moe.dispatch_plan(experts, E)
+        return (jax.random.normal(keys[0], (n, D)), order, inverse,
+                jax.random.normal(keys[1], (n * K, D)))
+
+    def graded(dispatch):
+        return jax.value_and_grad(
+            lambda x, order, inverse, cot: (
+                lambda rows: (jnp.sum(rows * cot), rows)
+            )(dispatch(x, order, inverse)), has_aux=True,
+        )
+
+    _assert_equal_over_peers(
+        graded(lambda x, o, i: moe._dispatch_rows(x, o, i, K)),
+        graded(lambda x, o, i: moe._permute_rows(jnp.repeat(x, K, 0), o, i)),
+        make_args, peers,
+    )
+
+
+@PEERS
+def test_down_adapter_on_the_tokens_equals_the_expert_order_one(peers):
+    """At a skewed routing: values and the gradients to ``hidden``,
+    ``A_down``, ``B_down`` and the routing weights."""
+    n = 24
+
+    def make_args(i):
+        keys = jax.random.split(jax.random.key(40 + i), 3)
+        weights, experts = _skewed_routing(keys[0], n)
+        _, lora_a, lora_b = _layer_weights(keys[1])[2]
+        return (jax.random.normal(keys[2], (n * K, F)), lora_a, lora_b,
+                weights, experts, jax.random.normal(keys[0], (n, D)))
+
+    def graded(adapter):
+        def loss(hidden, lora_a, lora_b, weights, experts, cot):
+            order, inverse, sizes = moe.dispatch_plan(experts, E)
+            down = moe.grouped_matmul(hidden, lora_a, sizes)
+            y = adapter(down, lora_b, weights, experts, order, inverse, sizes)
+            return jnp.sum(y * cot), y
+
+        return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)
+
+    def on_tokens(down, lora_b, weights, experts, order, inverse, sizes):
+        down = moe._permute_rows(down, inverse, order).reshape(n, K, -1)
+        return moe._down_adapter_on_tokens(
+            down, (weights, experts), lora_b, 2.0, jnp.float32
+        )
+
+    def in_expert_order(down, lora_b, weights, experts, order, inverse, sizes):
+        out = moe.grouped_matmul(down, lora_b, sizes) * 2.0
+        per_choice = moe._permute_rows(out, inverse, order).reshape(n, K, -1)
+        return jnp.einsum("nkd,nk->nd", per_choice, weights)
+
+    _assert_equal_over_peers(
+        graded(on_tokens), graded(in_expert_order), make_args, peers
+    )
+
+
+ADAPTED = {"all": (1, 1, 1), "gate_only": (1, 0, 1), "up_only": (0, 1, 1),
+           "down_only": (0, 0, 1), "none": (0, 0, 0), "not_down": (1, 1, 0),
+           "ranks_differ": (1, 2, 1)}
+
+
+def _adapted_layer(key, which):
+    """Layer weights with adapters on the projections ``which`` marks (2: at
+    another rank than gate's)."""
+    return [
+        w if has == 1 else (w[0], None, None) if not has
+        else _layer_weights(key, rank=6)[1]
+        for w, has in zip(_layer_weights(key), ADAPTED[which])
+    ]
+
+
+@PEERS
+@pytest.mark.parametrize("which", list(ADAPTED))
+def test_layer_equals_the_per_projection_one(which, peers):
+    """Whatever carries an adapter, at a skewed routing: values and the
+    gradients to the tokens, every adapter and the routing weights."""
+    n = 24
+
+    def make_args(i):
+        keys = jax.random.split(jax.random.key(50 + i), 3)
+        weights, experts = _skewed_routing(keys[0], n)
+        return (jax.random.normal(keys[1], (n, D)), weights,
+                _adapted_layer(keys[2], which), experts,
+                jax.random.normal(keys[0], (n, D)))
+
+    def graded(layer):
+        def loss(x, weights, w, experts, cot):
+            y = layer(x, (weights, experts), *w, 2.0, jnp.float32)
+            return jnp.sum(y * cot), y
+
+        return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+
+    _assert_equal_over_peers(
+        graded(moe.moe_ffn), graded(_per_projection_layer), make_args, peers
+    )
+
+
+def _grouped_products(layer, which):
+    """(forward kind, weight kind): the grouped products left, after dead
+    code is dropped, in the jaxpr of a layer's gradient to its adapters and
+    to the tokens.  The forward kind is rows by their group's matrix (to the
+    activations too, by its transpose); the weight kind contracts the rows
+    of two operands group by group."""
+    from jax.interpreters import partial_eval as pe
+
+    n = 24
+    weights, experts = _skewed_routing(jax.random.key(0), n)
+    x = jax.random.normal(jax.random.key(1), (n, D))
+    w = _adapted_layer(jax.random.key(2), which)
+    kernels = [v[0] for v in w]
+
+    def loss(x, adapters):
+        w = [(k,) + tuple(ab) for k, ab in zip(kernels, adapters)]
+        # Squared, so that the backward pass needs every forward product.
+        return jnp.sum(layer(x, (weights, experts), *w, 2.0, jnp.float32) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, (0, 1)))(x, [v[1:] for v in w]).jaxpr
+    text = str(pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))[0])
+    assert text.count("ragged_dot_general") == (
+        text.count("rhs_group_dimensions=(0,)")
+        + text.count("rhs_group_dimensions=()")
+    )
+    return (text.count("rhs_group_dimensions=(0,)"),
+            text.count("rhs_group_dimensions=()"))
+
+
+@pytest.mark.parametrize("which, fewer", [
+    ("all", (4, 2)),  # 2 and 1 by gate's and up's A sides, 2 and 1 by down's B
+    ("not_down", (2, 1)),  # the A sides alone
+    # Nothing to merge on the input side: the down adapter's B side alone.
+    ("gate_only", (2, 1)), ("up_only", (2, 1)), ("down_only", (2, 1)),
+    ("ranks_differ", (2, 1)),
+    ("none", (0, 0)),
+])
+def test_the_adapters_share_their_grouped_products(which, fewer):
+    """So that a later edit cannot silently split the pair again: against
+    the per-projection layer, gate's and up's A sides cost two grouped
+    products fewer of the forward kind (one forward, one to the activations)
+    and one fewer of the weight kind; the down adapter's B side, applied
+    after the combine, the same again."""
+    was = _grouped_products(_per_projection_layer, which)
+    now = _grouped_products(moe.moe_ffn, which)
+    adapters = sum(1 for has in ADAPTED[which] if has)
+    # 3 kernels forward and to the activations; an adapter is two products
+    # forward, two to the activations and two to its factors.
+    assert was == (6 + 4 * adapters, 2 * adapters)
+    assert (was[0] - now[0], was[1] - now[1]) == fewer
 
 
 def test_grouped_matmul_gradients_equal_the_dense_ones():
