@@ -17,6 +17,7 @@ the exchange (``dpwa_tpu.utils.pytree.partition``)."""
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import flax.linen as nn
@@ -70,8 +71,75 @@ class LlamaConfig:
     n_experts_per_tok: int = 0
     qk_norm: bool = False
     router_aux_loss_coef: float = 0.0
+    norm_eps: float = 1e-5
+    # Latent attention (MLA, the published DeepSeek-V3 block, unabsorbed):
+    # ``kv_lora_rank`` > 0 replaces :class:`Attention` by
+    # :class:`LatentAttention`: q through a rank-``q_lora_rank`` bottleneck
+    # with its RMSNorm, k and v through a rank-``kv_lora_rank`` one, heads of
+    # ``qk_nope_head_dim`` + ``qk_rope_head_dim`` for q and k (rope on the
+    # second part only, one rope key shared by every head) and of
+    # ``v_head_dim`` for v.  ``rope_scaling`` blends the rope frequencies
+    # (:func:`rope_frequencies`) and sets the softmax scale.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional["YarnScaling"] = None
+    # The first ``n_dense_layers`` blocks take a dense MLP of width
+    # ``d_ff_dense`` whatever ``n_experts`` says.
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0
+    # Beside the routed experts, ``n_shared_experts`` experts of width
+    # ``d_ff`` that every token takes (one MLP of their summed width).
+    n_shared_experts: int = 0
+    # The gate: scores are the "softmax" or the "sigmoid" of the router
+    # logits; ``norm_topk_prob`` divides a token's chosen scores by their
+    # sum; ``routed_scaling_factor`` multiplies the weights.
+    router_scoring: str = "softmax"
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    # One chip's share of the experts (expert parallelism seen from one of
+    # its chips): the router scores all ``n_experts``, this replica holds
+    # ``experts_held`` of them from ``expert_offset`` on and computes their
+    # part of the layer; what the absent experts would add is left out.
+    # None holds them all.
+    experts_held: Optional[int] = None
+    expert_offset: int = 0
+    # ``jax.checkpoint`` around every block: a block's activations are
+    # recomputed in the backward pass and only its input is saved.
+    remat: bool = False
+    # The type base leaves (kernels, norms, embedding, head) are created in;
+    # adapters and the router are float32 whatever it says.
+    param_dtype: jnp.dtype = jnp.float32
+    # The type activations have between matmuls (None: ``dtype``): the
+    # residual stream, what the norms and projections put out, the
+    # elementwise work.  float32 under a bfloat16 ``dtype`` rounds a value
+    # once, where it enters a matmul (``ops/wide.py``), which makes the model
+    # one function whatever the compiler fuses: the same under ``vmap`` over
+    # peers as unbatched, to the last bit but for the order of a sum.
+    # Written for the latent-attention block; :class:`Attention` refuses it.
+    activation_dtype: Optional[jnp.dtype] = None
 
     def __post_init__(self):
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_scoring must be softmax|sigmoid, got "
+                f"{self.router_scoring!r}"
+            )
+        held = self.held_experts
+        if self.n_experts and not (
+            0 < held and 0 <= self.expert_offset
+            and self.expert_offset + held <= self.n_experts
+        ):
+            raise ValueError(
+                f"experts {self.expert_offset}..{self.expert_offset + held} "
+                f"are not among the {self.n_experts}"
+            )
+        if self.kv_lora_rank and self.sp_axis is not None:
+            raise ValueError("latent attention has no sequence-parallel path")
+        if self.activation_dtype is not None and not self.kv_lora_rank:
+            raise ValueError("activation_dtype needs latent attention")
         if self.n_experts and not 0 < self.n_experts_per_tok <= self.n_experts:
             raise ValueError(
                 f"n_experts_per_tok must lie in 1..{self.n_experts}, got "
@@ -110,6 +178,42 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
+    @property
+    def stream_dtype(self) -> jnp.dtype:
+        return self.activation_dtype or self.dtype
+
+    @property
+    def held_experts(self) -> int:
+        return self.n_experts if self.experts_held is None else self.experts_held
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """A ``rope_scaling`` group of type ``yarn``, under its published keys."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def magnitude(factor: float, mscale: float) -> float:
+        return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def embedding_scale(self) -> float:
+        """What the rope's cos and sin are multiplied by."""
+        return self.magnitude(self.factor, self.mscale) / self.magnitude(
+            self.factor, self.mscale_all_dim
+        )
+
+    @property
+    def softmax_scale(self) -> float:
+        """What the ``1 / sqrt(d)`` of the scores is multiplied by."""
+        return self.magnitude(self.factor, self.mscale_all_dim) ** 2
+
 
 def llama3_8b_config(lora_rank: int = 16) -> LlamaConfig:
     """The real Llama-3-8B dimensions (public architecture constants)."""
@@ -143,16 +247,27 @@ class LoRADense(nn.Module):
     rank: int
     alpha: float = 16.0
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32  # of the base kernel alone
+    # The type of the result where it is not ``dtype``: the operands are
+    # rounded to ``dtype`` and nothing else is (``ops/wide.py``).
+    out_dtype: Optional[jnp.dtype] = None
 
     @nn.compact
     def __call__(self, x):
+        from dpwa_tpu.ops.wide import wide_dot
+
+        if self.out_dtype is None:
+            dot = lambda a, b: a @ b.astype(self.dtype)
+        else:
+            dot = lambda a, b: wide_dot(a, b, self.dtype, self.out_dtype)
         in_features = x.shape[-1]
         kernel = self.param(
             "kernel",
             nn.initializers.lecun_normal(),
             (in_features, self.features),
+            self.param_dtype,
         )
-        y = x @ kernel.astype(self.dtype)
+        y = dot(x, kernel)
         if self.rank > 0:
             lora_a = self.param(
                 "lora_a",
@@ -163,36 +278,76 @@ class LoRADense(nn.Module):
                 "lora_b", nn.initializers.zeros, (self.rank, self.features)
             )
             scale = self.alpha / self.rank
-            y = y + (x @ lora_a.astype(self.dtype)) @ lora_b.astype(
-                self.dtype
-            ) * scale
+            y = y + dot(dot(x, lora_a), lora_b) * scale
         return y
 
 
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
+    param_dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+        )
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
         return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype) * scale
 
 
-def rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """Rotary embedding over the last (head_dim) axis. x: [..., T, H, D]."""
-    d = x.shape[-1]
-    freqs = 1.0 / (
-        theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+def rope_frequencies(
+    d: int, theta: float, scaling: Optional[YarnScaling] = None
+) -> jnp.ndarray:
+    """The ``d / 2`` rotary frequencies ``theta^(-2i/d)``; with a yarn
+    ``scaling``, blended with their ``1 / factor``: pairs that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    pairs that turn less than ``beta_slow`` times are interpolated, and a
+    linear ramp over the pair index joins the two."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    if scaling is None:
+        return freqs
+
+    def pair_turning(turns):  # the (real) pair index that turns so often
+        return d * math.log(
+            scaling.original_max_position_embeddings / (turns * 2 * math.pi)
+        ) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_turning(scaling.beta_fast)), 0)
+    high = min(math.ceil(pair_turning(scaling.beta_slow)), d - 1)
+    ramp = jnp.clip(
+        (jnp.arange(d // 2, dtype=jnp.float32) - low)
+        / max(high - low, 1e-3), 0.0, 1.0,
     )
+    return freqs / scaling.factor * ramp + freqs * (1.0 - ramp)
+
+
+def rope(
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+    scaling: Optional[YarnScaling] = None,
+) -> jnp.ndarray:
+    """Rotary embedding over the last (head_dim) axis. x: [..., T, H, D]."""
+    freqs = rope_frequencies(x.shape[-1], theta, scaling)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [T, D/2]
     cos = jnp.cos(angles)[..., None, :]  # [T, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        cos, sin = (z * scaling.embedding_scale for z in (cos, sin))
     x1, x2 = x[..., ::2], x[..., 1::2]
     out1 = x1 * cos - x2 * sin
     out2 = x1 * sin + x2 * cos
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+def _dense(cfg: LlamaConfig, features: int, name: str) -> LoRADense:
+    return LoRADense(
+        features, cfg.lora_rank, cfg.lora_alpha, cfg.dtype, cfg.param_dtype,
+        cfg.activation_dtype, name=name,
+    )
+
+
+def _norm(cfg: LlamaConfig, name: str) -> RMSNorm:
+    return RMSNorm(cfg.norm_eps, cfg.stream_dtype, cfg.param_dtype, name=name)
 
 
 class Attention(nn.Module):
@@ -203,13 +358,10 @@ class Attention(nn.Module):
         cfg = self.cfg
         B, T, _ = x.shape
         H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
-        dense = lambda feats, name: LoRADense(
-            feats, cfg.lora_rank, cfg.lora_alpha, cfg.dtype, name=name
-        )
+        dense = lambda feats, name: _dense(cfg, feats, name)
         q, k = dense(H * D, "wq")(x), dense(KV * D, "wk")(x)
         if cfg.qk_norm:
-            q = RMSNorm(dtype=cfg.dtype, name="q_norm")(q)
-            k = RMSNorm(dtype=cfg.dtype, name="k_norm")(k)
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
         v = dense(KV * D, "wv")(x).reshape(B, T, KV, D)
         q = rope(q, positions, cfg.rope_theta)
@@ -272,18 +424,67 @@ class Attention(nn.Module):
         return dense(cfg.d_model, "wo")(out)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention as a training step runs it (unabsorbed;
+    no latent cache): ``c_q = RMSNorm(x W_qa)``, ``q = c_q W_qb`` in heads of
+    ``[q_nope | q_pe]``; ``[c_kv | k_pe] = x W_kva``, ``RMSNorm(c_kv) W_kvb``
+    in heads of ``[k_nope | v]``; rope on ``q_pe`` a head and on the one
+    ``k_pe``, which every head's key shares; causal ``softmax(q k^T s) v``
+    with ``s = rope_scaling.softmax_scale / sqrt(qk dim)``; ``W_o`` on the
+    concatenated values.  Adapters on all five projections."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from dpwa_tpu.ops.ulysses import single_device_attention
+        from dpwa_tpu.ops.wide import narrow
+        from dpwa_tpu.utils import scopes
+
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H = cfg.n_heads
+        nope, pe, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        scaling = cfg.rope_scaling
+        with jax.named_scope(scopes.ATTN_LATENT):
+            c_q = _norm(cfg, "q_norm")(_dense(cfg, cfg.q_lora_rank, "wq_a")(x))
+            q = _dense(cfg, H * (nope + pe), "wq_b")(c_q)
+            q_nope, q_pe = jnp.split(q.reshape(B, T, H, nope + pe), [nope], -1)
+            c_kv, k_pe = jnp.split(
+                _dense(cfg, cfg.kv_lora_rank + pe, "wkv_a")(x),
+                [cfg.kv_lora_rank], -1,
+            )
+            kv = _dense(cfg, H * (nope + dv), "wkv_b")(
+                _norm(cfg, "kv_norm")(c_kv)
+            )
+            k_nope, v = jnp.split(kv.reshape(B, T, H, nope + dv), [nope], -1)
+            q_pe = rope(q_pe, positions, cfg.rope_theta, scaling)
+            k_pe = rope(k_pe[:, :, None], positions, cfg.rope_theta, scaling)
+            q = jnp.concatenate([q_nope, q_pe], -1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(k_pe, (B, T, H, pe))], -1
+            )
+            sm_scale = (nope + pe) ** -0.5
+            if scaling is not None:
+                sm_scale *= scaling.softmax_scale
+            q, k, v = (narrow(z, cfg.dtype) for z in (q, k, v))
+            out = single_device_attention(
+                q, k, v, causal=True, impl=cfg.attn_impl, sm_scale=sm_scale
+            )
+            return _dense(cfg, cfg.d_model, "wo")(out.reshape(B, T, H * dv))
+
+
 class MLP(nn.Module):
     cfg: LlamaConfig
+    d_ff: Optional[int] = None  # None: the configuration's ``d_ff``
 
     @nn.compact
     def __call__(self, x):
         cfg = self.cfg
-        dense = lambda feats, name: LoRADense(
-            feats, cfg.lora_rank, cfg.lora_alpha, cfg.dtype, name=name
-        )
-        gate = dense(cfg.d_ff, "w_gate")(x)
-        up = dense(cfg.d_ff, "w_up")(x)
-        return dense(cfg.d_model, "w_down")(nn.silu(gate) * up)
+        d_ff = self.d_ff or cfg.d_ff
+        gate = _dense(cfg, d_ff, "w_gate")(x)
+        up = _dense(cfg, d_ff, "w_up")(x)
+        return _dense(cfg, cfg.d_model, "w_down")(nn.silu(gate) * up)
 
 
 class ExpertDense(nn.Module):
@@ -296,12 +497,14 @@ class ExpertDense(nn.Module):
     in_features: int
     features: int
     rank: int
+    param_dtype: jnp.dtype = jnp.float32  # of the base kernel alone
 
     @nn.compact
     def __call__(self):
         shape = (self.n_experts, self.in_features, self.features)
         kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)), shape
+            "kernel", nn.initializers.lecun_normal(batch_axis=(0,)), shape,
+            self.param_dtype,
         )
         if self.rank <= 0:
             return kernel, None, None
@@ -318,11 +521,18 @@ class ExpertDense(nn.Module):
 
 class MoE(nn.Module):
     """Top-k of ``n_experts`` SwiGLU experts of width ``d_ff``, dropless
-    (``ops/moe.py``).  Sows each call's routing into the ``intermediates``
-    collection (``experts [N, k]``, ``counts [E]``, ``prob_mean [E]``, router
-    ``logits [N, E]``): what :func:`moe_loss` and a reference that verifies
-    the routing read; nothing is computed for it when the collection is not
-    asked for."""
+    (``ops/moe.py``), with ``n_shared_experts`` shared experts beside them
+    that every token takes.  Where the configuration holds a share of the
+    experts (``experts_held``), the router still scores all of them and the
+    weights are normalised over a token's whole choice; the layer's routed
+    part is the held experts' alone.
+
+    Sows each call's routing into the ``intermediates`` collection
+    (``router_input [N, D]``, ``experts [N, k]``, ``counts [E]``, ``prob_mean
+    [E]``, router ``logits [N, E]``, and of the held experts ``held_counts [held]``, ``held_share``
+    = their part of all N x k assignments, ``held_max_over_mean``): what
+    :func:`moe_loss` and a reference that verifies the routing read; nothing
+    is computed for it when the collection is not asked for."""
 
     cfg: LlamaConfig
 
@@ -334,41 +544,60 @@ class MoE(nn.Module):
         cfg = self.cfg
         B, T, D = x.shape
         E, k = cfg.n_experts, cfg.n_experts_per_tok
+        held, offset = cfg.held_experts, cfg.expert_offset
         router = self.param("router", nn.initializers.lecun_normal(), (D, E))
         expert = lambda d_in, d_out, name: ExpertDense(
-            E, d_in, d_out, cfg.lora_rank, name=name
+            held, d_in, d_out, cfg.lora_rank, cfg.param_dtype, name=name
         )()
         tokens = x.reshape(B * T, D)
         with jax.named_scope(scopes.MOE_ROUTE):
-            weights, experts, logits = moe.route(tokens, router, k)
+            weights, experts, logits = moe.route(
+                tokens, router, k, cfg.router_scoring, cfg.norm_topk_prob,
+                cfg.routed_scaling_factor,
+            )
+            counts = moe.assignment_counts(experts, E)
+            self.sow("intermediates", "router_input", tokens)
             self.sow("intermediates", "experts", experts)
             self.sow("intermediates", "logits", logits)
-            self.sow("intermediates", "counts",
-                     moe.assignment_counts(experts, E))
+            self.sow("intermediates", "counts", counts)
             self.sow("intermediates", "prob_mean",
-                     jax.nn.softmax(logits, axis=-1).mean(0))
+                     moe.router_scores(logits, cfg.router_scoring).mean(0))
+            here = counts[offset:offset + held]
+            self.sow("intermediates", "held_counts", here)
+            self.sow("intermediates", "held_share", here.sum() / experts.size)
+            self.sow("intermediates", "held_max_over_mean",
+                     here.max() * held / jnp.maximum(here.sum(), 1))
         out = moe.moe_ffn(
             tokens, (weights, experts),
             expert(D, cfg.d_ff, "w_gate"), expert(D, cfg.d_ff, "w_up"),
             expert(cfg.d_ff, D, "w_down"),
             cfg.lora_alpha / max(cfg.lora_rank, 1), cfg.dtype,
+            None if held == E else offset, cfg.activation_dtype,
         )
+        if cfg.n_shared_experts:
+            with jax.named_scope(scopes.MOE_SHARED):
+                out = out + MLP(
+                    cfg, cfg.n_shared_experts * cfg.d_ff, name="shared"
+                )(tokens)
         return out.reshape(B, T, D)
 
 
 class Block(nn.Module):
     cfg: LlamaConfig
+    index: int = 0  # of the layer: the first ``n_dense_layers`` are dense
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        x = x + Attention(cfg, name="attn")(
-            RMSNorm(dtype=cfg.dtype, name="attn_norm")(x), positions
+        attention = LatentAttention if cfg.kv_lora_rank else Attention
+        x = x + attention(cfg, name="attn")(
+            _norm(cfg, "attn_norm")(x), positions
         )
-        x = x + (MoE if cfg.n_experts > 0 else MLP)(cfg, name="mlp")(
-            RMSNorm(dtype=cfg.dtype, name="mlp_norm")(x)
-        )
-        return x
+        if self.index < cfg.n_dense_layers:
+            ffn = MLP(cfg, cfg.d_ff_dense, name="mlp")
+        else:
+            ffn = (MoE if cfg.n_experts > 0 else MLP)(cfg, name="mlp")
+        return x + ffn(_norm(cfg, "mlp_norm")(x))
 
 
 class Llama(nn.Module):
@@ -380,9 +609,12 @@ class Llama(nn.Module):
     def __call__(self, tokens):
         cfg = self.cfg
         B, T = tokens.shape
+        # The rows are looked up in ``dtype`` and only they are widened: an
+        # ``Embed`` of the activations' type would convert the whole table.
         x = nn.Embed(
-            cfg.vocab_size, cfg.d_model, dtype=cfg.dtype, name="embed"
-        )(tokens)
+            cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="embed",
+        )(tokens).astype(cfg.stream_dtype)
         positions = jnp.arange(T)
         if cfg.sp_axis is not None:
             if cfg.sp_layout == "zigzag":
@@ -395,25 +627,36 @@ class Llama(nn.Module):
                 # Inside shard_map: ``tokens`` is this device's contiguous
                 # sequence block; rope needs the GLOBAL positions.
                 positions = positions + jax.lax.axis_index(cfg.sp_axis) * T
+        # The barrier that keeps XLA from merging the recomputation with the
+        # forward pass stands on the activations alone (``x``, ``positions``),
+        # not on the block's variables: a barrier on a frozen kernel makes a
+        # caller that slices one replica out of a stacked tree
+        # (``lax.map`` over peers) copy that replica's whole base.
+        block = Block
+        if cfg.remat:
+            block = nn.remat(Block, prevent_cse=(False, False, True, True))
         for i in range(cfg.n_layers):
-            x = Block(cfg, name=f"layer_{i}")(x, positions)
-        x = RMSNorm(dtype=cfg.dtype, name="final_norm")(x)
+            x = block(cfg, i, name=f"layer_{i}")(x, positions)
+        x = _norm(cfg, "final_norm")(x)
         logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=jnp.float32, name="lm_head"
+            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+            param_dtype=cfg.param_dtype, name="lm_head",
         )(x)
         return logits
 
 
 def routing_of(intermediates) -> dict:
     """The sown routing of every expert layer, layers stacked in order:
-    ``{"experts": [L, N, k], "counts": [L, E], "prob_mean": [L, E], "logits":
-    [L, N, E]}``, from the ``intermediates`` collection that
-    ``Llama.apply(..., mutable=["intermediates"])`` returns."""
+    ``{"router_input": [L, N, D], "experts": [L, N, k], "counts": [L, E],
+    "prob_mean": [L, E], "logits": [L, N, E], "held_counts": [L, held], "held_share": [L],
+    "held_max_over_mean": [L]}``, from the ``intermediates`` collection that
+    ``Llama.apply(..., mutable=["intermediates"])`` returns.  A dense layer
+    sows nothing and is not among them."""
     layers = intermediates["intermediates"]
     names = sorted(layers, key=lambda name: int(name.rsplit("_", 1)[1]))
     return {
         key: jnp.stack([layers[name]["mlp"][key][0] for name in names])
-        for key in ("experts", "counts", "prob_mean", "logits")
+        for key in layers[names[0]]["mlp"]
     }
 
 

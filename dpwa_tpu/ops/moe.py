@@ -7,6 +7,13 @@ The layer (the published ``OlmoeSparseMoeBlock``), for tokens ``x [N, D]``:
     (w, S) = top_k(p)                          no renormalisation of w
     y      = sum_{e in S} w_e * W_down_e(silu(W_gate_e x) * W_up_e x)
 
+(:func:`route` also gates by sigmoid scores, renormalised over the chosen and
+scaled: the DeepSeek-V3 gate.)  **A share of the experts** (``offset``):
+the weights handed in are those of experts ``[offset, offset + held)`` of
+the E the router chose among, one chip's share under expert parallelism,
+and ``y`` sums over ``e in S`` that are held; ``w`` is what the gate gave,
+normalised over all of S.  Nothing stands in for the absent experts.
+
 **Dropless** means every one of the ``N x k`` (token, expert) assignments is
 computed, at any routing skew: there is no capacity factor, no token is
 dropped and no group is padded to a fixed size.  The assignments are sorted
@@ -32,7 +39,9 @@ of batch dimensions should be 0").  Under ``shard_map``
 activations; ``tgmm`` to the weights) with tiles picked from the shapes by
 :func:`_tiling`; everywhere else ``lax.ragged_dot``, which XLA:CPU expands
 densely (fine at test sizes).  Nothing but the backend and the shapes
-chooses: no argument, config field, flag or environment variable.  On the
+chooses: no argument, config field, flag or environment variable.  (A share
+of the experts, :func:`held_matmul`, runs the kernels under ``vmap`` and
+plain masked products unbatched: the comment above it says why.)  On the
 v5e ``ragged_dot`` runs XLA's own grouped kernel at 70 TFLOP/s on the cell's
 shapes, needs a 0.5 GB transposed copy of the kernels for the gradient to the
 activations (48 TFLOP/s with it) and loses the instruction's ``op_name``, so
@@ -64,11 +73,13 @@ is what is passed: (1) needs ``lora_a`` of one shape on both of gate and up
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax import custom_batching, lax
 
+from dpwa_tpu.ops.wide import narrow
 from dpwa_tpu.utils import scopes
 
 HIGHEST = lax.Precision.HIGHEST
@@ -167,28 +178,164 @@ def _tgmm(lhs, grad, group_sizes):
     )
 
 
-@jax.custom_vjp
-def grouped_matmul(lhs, rhs, group_sizes):
-    """``[M, K] x [G, K, N] -> [M, N]``: rows ``lhs`` sorted by group, row i
-    multiplied by ``rhs[g]`` of the group g it lies in; ``group_sizes [G]``
-    (int32) sums to M.  Differentiable in ``lhs`` and ``rhs``; under ``vmap``
-    the batch axis becomes more groups (module docstring)."""
-    return _gmm(lhs, rhs, group_sizes)
+def _differentiable(gmm, gmm_transposed, tgmm):
+    """A grouped matmul differentiable in ``lhs`` and ``rhs`` from its three
+    products: forward, to the activations, to the weights."""
+
+    @jax.custom_vjp
+    def matmul(lhs, rhs, group_sizes):
+        return gmm(lhs, rhs, group_sizes)
+
+    def fwd(lhs, rhs, group_sizes):
+        return gmm(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(residuals, grad):
+        lhs, rhs, group_sizes = residuals
+        with jax.named_scope(scopes.MOE_EXPERTS):
+            d_lhs = gmm_transposed(grad, rhs, group_sizes)
+            d_rhs = tgmm(lhs, grad, group_sizes)
+        return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+
+    matmul.defvjp(fwd, bwd)
+    return matmul
 
 
-def _grouped_matmul_fwd(lhs, rhs, group_sizes):
-    return _gmm(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+grouped_matmul = _differentiable(_gmm, _gmm_transposed, _tgmm)
+grouped_matmul.__doc__ = """``[M, K] x [G, K, N] -> [M, N]``: rows ``lhs`` sorted by group, row i
+multiplied by ``rhs[g]`` of the group g it lies in; ``group_sizes [G]``
+(int32) sums to M.  Differentiable in ``lhs`` and ``rhs``; under ``vmap``
+the batch axis becomes more groups (module docstring)."""
 
 
-def _grouped_matmul_bwd(residuals, grad):
-    lhs, rhs, group_sizes = residuals
-    with jax.named_scope(scopes.MOE_EXPERTS):
-        d_lhs = _gmm_transposed(grad, rhs, group_sizes)
-        d_rhs = _tgmm(lhs, grad, group_sizes)
-    return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
+# A share of the experts.  The rows bound for absent experts are sorted last
+# and lie in no group, so ``group_sizes`` ends before the rows do, and folded
+# over peers a peer's groups would no longer start where its rows do.
+#
+# Outside ``vmap`` the three products are written plainly: every held expert
+# on every row, masked by the row's group (``G`` times the arithmetic of a
+# grouped kernel, in XLA's own dots, accumulated in float32 and rounded once
+# as a kernel's result is), and where the kernels could have run a warning
+# says so.  That is the form a loop over peers runs, and it is chosen for
+# what it holds, not for its speed: a caller that cuts one replica out of a
+# stacked tree feeds XLA's dots the cut itself, where a kernel's operand has
+# to be written out first, a whole replica's expert kernels beside the live
+# state (PERF.md section 4), and nothing here can tell that caller from one
+# that holds its replica whole.  ``vmap`` over a peer axis of one runs the
+# kernels.
+#
+# Under ``vmap`` on a TPU the products are the ``megablox`` kernels.  They
+# already serve weights that are a shard of the groups (``group_offset``: a
+# leading group is skipped, and with more groups than weights the rows not
+# visited come out zero), so the rule makes one call a peer on the folded
+# rows: that peer's groups behind a leading group of the rows before them,
+# the other peers' groups empty, each call writing into the result of the
+# one before (``existing_out``, in place).  No array is sliced or stacked but
+# ``tgmm``'s adapter-sized results.
 
 
-grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+def _membership(group_sizes, rows: int, dtype):
+    """``[G, M]``: 1 where row m lies in group g; a row past the groups lies
+    in none."""
+    ends = jnp.cumsum(group_sizes)[:, None]
+    row = jnp.arange(rows)[None, :]
+    return ((row >= ends - group_sizes[:, None]) & (row < ends)).astype(dtype)
+
+
+def _plain_gmm(lhs, rhs, group_sizes, transpose_rhs=False):
+    """``[M, K] x [G, K, N] -> [M, N]`` (``rhs [G, N, K]`` transposed); a row
+    past the groups comes out zero.  Accumulated in float32 and rounded once,
+    as a kernel's result is."""
+    member = _membership(group_sizes, lhs.shape[0], lhs.dtype)
+    return narrow(jnp.einsum(
+        "gm,mk,gnk->mn" if transpose_rhs else "gm,mk,gkn->mn",
+        member, lhs, rhs, preferred_element_type=jnp.float32,
+    ), lhs.dtype)
+
+
+def _plain_tgmm(lhs, grad, group_sizes):
+    """``[M, K] x [M, N] -> [G, K, N]``: each group's ``lhs^T grad``."""
+    member = _membership(group_sizes, lhs.shape[0], lhs.dtype)
+    return narrow(jnp.einsum(
+        "gm,mk,mn->gkn", member, lhs, grad,
+        preferred_element_type=jnp.float32,
+    ), lhs.dtype)
+
+
+def _kernel_gmm(lhs, rhs, group_sizes, skip, out, transpose_rhs=False):
+    """The groups' rows of ``lhs``, which start at row ``skip``, by ``rhs``;
+    the other rows are ``out``'s, or zero."""
+    sizes = jnp.concatenate([jnp.full((1,), skip, jnp.int32), group_sizes])
+    tiling = _tiling(*(rhs.shape[:0:-1] if transpose_rhs else rhs.shape[1:]))
+    return _kernels().gmm(
+        lhs, rhs, sizes, lhs.dtype, tiling, group_offset=jnp.int32(1),
+        existing_out=out, transpose_rhs=transpose_rhs,
+    )
+
+
+def _kernel_tgmm(lhs, grad, group_sizes, skip):
+    sizes = jnp.concatenate([jnp.full((1,), skip, jnp.int32), group_sizes])
+    return _kernels().tgmm(
+        lhs.swapaxes(0, 1), grad, sizes, lhs.dtype,
+        _tiling(lhs.shape[1], grad.shape[1]), group_offset=jnp.int32(1),
+        num_actual_groups=group_sizes.shape[0],
+    )
+
+
+def _peer_by_peer(plain, kernel, rows_out: bool):
+    """``plain(a, b, group_sizes)`` with the vmap rule of the comment above:
+    ``kernel`` a peer on the folded rows where the kernels run, else
+    ``plain`` a peer.  An operand the vmap did not batch is repeated."""
+    def alone(a, b, group_sizes):
+        if _use_kernels(a.shape[0]):
+            warnings.warn(
+                "held_matmul outside vmap runs every held expert on every "
+                "row in XLA's own dots (the number of held experts times "
+                "the arithmetic); vmap over a peer axis, of one if need "
+                "be, runs the grouped kernels", stacklevel=2,
+            )
+        return plain(a, b, group_sizes)
+
+    # (Three arguments exactly: custom_vmap would trace a keyword's default.)
+    fn = custom_batching.custom_vmap(alone)
+
+    @fn.def_vmap
+    def over_peers(axis_size, in_batched, a, b, group_sizes):
+        a, b, group_sizes = (
+            x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+            for x, batched in zip((a, b, group_sizes), in_batched)
+        )
+        rows = a.shape[1]
+        peers = range(axis_size)
+        if not _use_kernels(axis_size * rows):
+            return jnp.stack([
+                plain(a[p], b[p], group_sizes[p]) for p in peers
+            ]), True
+        fold = lambda x: x.reshape((-1,) + x.shape[2:])
+        if not rows_out:
+            return jnp.stack([
+                kernel(fold(a), fold(b), group_sizes[p], p * rows)
+                for p in peers
+            ]), True
+        out = None
+        for p in peers:
+            own = jnp.zeros_like(group_sizes).at[p].set(group_sizes[p])
+            out = kernel(fold(a), fold(b), fold(own), p * rows, out)
+        return out.reshape((axis_size, rows) + out.shape[1:]), True
+
+    return fn
+
+
+held_matmul = _differentiable(
+    _peer_by_peer(_plain_gmm, _kernel_gmm, True),
+    _peer_by_peer(
+        functools.partial(_plain_gmm, transpose_rhs=True),
+        functools.partial(_kernel_gmm, transpose_rhs=True), True,
+    ),
+    _peer_by_peer(_plain_tgmm, _kernel_tgmm, False),
+)
+held_matmul.__doc__ = """:func:`grouped_matmul` for a share of the experts: ``group_sizes [G]``
+may sum to less than M, and the rows past the groups come out zero (their
+gradient too)."""
 
 
 @jax.custom_vjp
@@ -231,16 +378,30 @@ def _dispatch_rows_bwd(k, inverse, grad):
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
-def route(x, router_kernel, k: int):
+def router_scores(logits, scoring: str = "softmax"):
+    """``[N, E]`` scores of the router logits: their softmax over the
+    experts, or each logit's sigmoid."""
+    if scoring == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def route(x, router_kernel, k: int, scoring: str = "softmax",
+          norm_topk_prob: bool = False, scale: float = 1.0):
     """Router of ``x [N, D]``: ``(weights [N, k] float32, experts [N, k]
-    int32, logits [N, E] float32)``.  Logits and softmax are float32 at the
-    highest matmul precision whatever ``x``'s type; the top-k weights are the
-    softmax's own values, not renormalised."""
+    int32, logits [N, E] float32)``.  Logits and scores are float32 at the
+    highest matmul precision whatever ``x``'s type; the weights are the top-k
+    scores' own values, divided by their sum with ``norm_topk_prob``, times
+    ``scale``."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=HIGHEST,
     )
-    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+    weights, experts = lax.top_k(router_scores(logits, scoring), k)
+    if norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return weights, experts.astype(jnp.int32), logits
 
 
@@ -250,35 +411,47 @@ def assignment_counts(experts, n_experts: int):
     return jnp.zeros((n_experts,), jnp.int32).at[experts.reshape(-1)].add(1)
 
 
-def dispatch_plan(experts, n_experts: int):
+def dispatch_plan(experts, n_experts: int, offset=None):
     """From ``experts [N, k]``: ``(order, inverse, group_sizes)``.  ``order
     [N x k]`` lists the flat assignments (token i's j-th choice is i*k + j)
     sorted by expert, stably; ``inverse`` is its inverse permutation;
     ``group_sizes [E]`` counts each expert's assignments and sums to N x k:
-    nothing is dropped and nothing padded."""
+    nothing is dropped and nothing padded.
+
+    With ``offset`` the ``n_experts`` are the share ``[offset, offset +
+    n_experts)`` held here: the assignments to them come first, by expert,
+    every one of them (the static row bound stays N x k, so no imbalance can
+    drop one); those bound for absent experts are sorted behind them and
+    counted in no group, so ``group_sizes`` sums to what landed here."""
+    if offset is not None:
+        local = experts - offset
+        experts = jnp.where((local >= 0) & (local < n_experts), local, n_experts)
     flat = experts.reshape(-1)
     order = jnp.argsort(flat, stable=True).astype(jnp.int32)
     inverse = jnp.zeros_like(order).at[order].set(
         jnp.arange(order.shape[0], dtype=jnp.int32)
     )
+    if offset is not None:
+        return order, inverse, assignment_counts(experts, n_experts + 1)[:-1]
     return order, inverse, assignment_counts(experts, n_experts)
 
 
-def expert_projection(rows, weights, group_sizes, lora_scale, dtype):
+def expert_projection(rows, weights, group_sizes, lora_scale, dtype,
+                      matmul=grouped_matmul):
     """One projection of every expert on its own rows: the frozen kernel's
     grouped matmul plus ``lora_scale x (rows A_e) B_e``.  ``weights`` is
-    ``(kernel [E, K, N], lora_a [E, K, r] | None, lora_b [E, r, N] | None)``."""
+    ``(kernel [E, K, N], lora_a [E, K, r] | None, lora_b [E, r, N] | None)``;
+    ``matmul`` is :func:`held_matmul` where the experts are a share."""
     kernel, lora_a, lora_b = weights
-    out = grouped_matmul(rows, kernel.astype(dtype), group_sizes)
+    out = matmul(rows, kernel.astype(dtype), group_sizes)
     if lora_a is not None:
-        down = grouped_matmul(rows, lora_a.astype(dtype), group_sizes)
-        out = out + grouped_matmul(
-            down, lora_b.astype(dtype), group_sizes
-        ) * lora_scale
+        down = matmul(rows, lora_a.astype(dtype), group_sizes)
+        out = out + matmul(down, lora_b.astype(dtype), group_sizes) * lora_scale
     return out
 
 
-def gate_and_up(rows, w_gate, w_up, group_sizes, lora_scale, dtype):
+def gate_and_up(rows, w_gate, w_up, group_sizes, lora_scale, dtype,
+                matmul=grouped_matmul):
     """Both input-side projections of every expert on its own rows, as
     :func:`expert_projection` twice gives them.  Where both carry adapters of
     one rank their A sides are one grouped matmul: ``rows`` is read once for
@@ -289,20 +462,29 @@ def gate_and_up(rows, w_gate, w_up, group_sizes, lora_scale, dtype):
     a_gate, a_up = w_gate[1], w_up[1]
     if a_gate is None or a_up is None or a_gate.shape != a_up.shape:
         return tuple(
-            expert_projection(rows, w, group_sizes, lora_scale, dtype)
+            expert_projection(rows, w, group_sizes, lora_scale, dtype, matmul)
             for w in (w_gate, w_up)
         )
     a_cat = jnp.concatenate([a_gate, a_up], axis=-1).astype(dtype)
-    down_cat = grouped_matmul(rows, a_cat, group_sizes)
+    down_cat = matmul(rows, a_cat, group_sizes)
     return tuple(
-        grouped_matmul(rows, kernel.astype(dtype), group_sizes)
-        + grouped_matmul(down, lora_b.astype(dtype), group_sizes) * lora_scale
+        matmul(rows, kernel.astype(dtype), group_sizes)
+        + matmul(down, lora_b.astype(dtype), group_sizes) * lora_scale
         for down, (kernel, _, lora_b)
         in zip(jnp.split(down_cat, 2, axis=-1), (w_gate, w_up))
     )
 
 
-def _down_adapter_on_tokens(down, routed, lora_b, lora_scale, dtype):
+def _entering(dtype, out_dtype):
+    """How a value comes to a matmul: converted to ``dtype``, and with an
+    ``out_dtype`` by a rounding the compiler keeps (``ops/wide.narrow``)."""
+    if out_dtype is None:
+        return lambda v: v.astype(dtype)
+    return lambda v: narrow(v, dtype)
+
+
+def _down_adapter_on_tokens(down, routed, lora_b, lora_scale, dtype,
+                            out_dtype=None):
     """The down adapter's B side after the combine.  The layer's output is
     linear in the down projection, so the adapter's share of it is
     ``lora_scale x z B_all``: given ``down [N, k, r]`` (``hidden A_e``, back
@@ -312,45 +494,73 @@ def _down_adapter_on_tokens(down, routed, lora_b, lora_scale, dtype):
     one wrote ``[N x k, D]`` only to be added, gathered and summed over k."""
     weights, experts = routed
     n_experts, r, d_out = lora_b.shape
+    enter = _entering(dtype, out_dtype)
     placed = jax.nn.one_hot(experts, n_experts, dtype=dtype) * (
-        weights.astype(dtype)[..., None]
+        enter(weights)[..., None]
     )
     z = jnp.einsum("nke,nkr->ner", placed, down)
     return jnp.dot(
         z.reshape(-1, n_experts * r),
-        lora_b.astype(dtype).reshape(n_experts * r, d_out),
+        enter(lora_b).reshape(n_experts * r, d_out),
+        preferred_element_type=out_dtype,
     ) * lora_scale
 
 
-def moe_ffn(x, routed, w_gate, w_up, w_down, lora_scale: float, dtype):
+def moe_ffn(x, routed, w_gate, w_up, w_down, lora_scale: float, dtype,
+            offset=None, out_dtype=None):
     """The expert layer on tokens ``x [N, D]`` given ``routed = (weights,
     experts)`` of :func:`route`: dispatch, SwiGLU experts, combine.  Each of
     ``w_gate / w_up / w_down`` is a triple as in :func:`expert_projection`.
     What the adapters share is chosen by what is passed (the module
-    docstring's last paragraph)."""
+    docstring's last paragraph).
+
+    With ``offset`` the weights are those of the experts ``[offset, offset +
+    E)`` alone, a share of those the router chose among, and the result is
+    their part of the layer: an assignment to an absent expert is gathered
+    with the rest (the row bound is N x k) and sorted last, enters no
+    grouped matmul, and adds zero to the combine.
+
+    The grouped matmuls take and give ``dtype``.  With ``out_dtype`` the
+    SwiGLU between them and the combine are computed in that type and the
+    result is of it: a value is rounded to ``dtype`` where it enters a matmul
+    and nowhere else (``ops/wide.py``)."""
     weights, experts = routed
     n, k = experts.shape
+    matmul = grouped_matmul if offset is None else held_matmul
+    enter = _entering(dtype, out_dtype)
     with jax.named_scope(scopes.MOE_ROUTE):
-        order, inverse, group_sizes = dispatch_plan(experts, w_gate[0].shape[0])
-        rows = _dispatch_rows(x.astype(dtype), order, inverse, k)
+        order, inverse, group_sizes = dispatch_plan(
+            experts, w_gate[0].shape[0], offset
+        )
+        rows = _dispatch_rows(enter(x), order, inverse, k)
     kernel_down, a_down, b_down = w_down
     with jax.named_scope(scopes.MOE_EXPERTS):
         gate, up = gate_and_up(
-            rows, w_gate, w_up, group_sizes, lora_scale, dtype
+            rows, w_gate, w_up, group_sizes, lora_scale, dtype, matmul
         )
-        hidden = jax.nn.silu(gate) * up
-        out = grouped_matmul(hidden, kernel_down.astype(dtype), group_sizes)
+        if out_dtype is None:
+            hidden = jax.nn.silu(gate) * up
+        else:
+            hidden = enter(
+                jax.nn.silu(gate.astype(out_dtype)) * up.astype(out_dtype)
+            )
+        out = matmul(hidden, kernel_down.astype(dtype), group_sizes)
         if a_down is not None:
-            down = grouped_matmul(hidden, a_down.astype(dtype), group_sizes)
+            down = matmul(hidden, a_down.astype(dtype), group_sizes)
     with jax.named_scope(scopes.MOE_ROUTE):
         to_tokens = lambda v: _permute_rows(v, inverse, order).reshape(n, k, -1)
-        y = jnp.einsum("nkd,nk->nd", to_tokens(out), weights.astype(dtype))
+        y = jnp.einsum(
+            "nkd,nk->nd", to_tokens(out), enter(weights),
+            preferred_element_type=out_dtype,
+        )
         if a_down is None:
             return y
         down = to_tokens(down)
+    if offset is not None:  # an absent expert's index matches no column
+        routed = (weights, experts - offset)
     with jax.named_scope(scopes.MOE_EXPERTS):
         return y + _down_adapter_on_tokens(
-            down, routed, b_down, lora_scale, dtype
+            down, routed, b_down, lora_scale, dtype, out_dtype
         )
 
 

@@ -123,13 +123,25 @@ def _flash_block_sizes(T: int, D: int):
     )
 
 
-def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
+def single_device_attention(
+    q, k, v, *, causal: bool, impl: str = "auto", sm_scale=None
+):
     """THE single-device attention of the framework, shared by the Llama
     model's non-sp path and the a2a strategy's per-device compute:
-    [B, T, h, D] layout, GQA expanded here if still grouped.  ``impl``:
-    "flash" forces the Pallas kernel, "auto" uses it on TPU when shapes
-    fit its tiling (T and head_dim multiples of 128), anything else runs
-    the masked-softmax einsum with f32 accumulation.
+    [B, T, h, D] layout, GQA expanded here if still grouped.  ``q`` and ``k``
+    share one head size and ``v`` may have another (latent attention: 192
+    and 128); ``sm_scale`` multiplies the scores (``1 / sqrt(q's head size)``
+    where not given).  ``impl``: "flash" forces the Pallas kernel, "auto"
+    uses it on TPU when shapes fit its tiling (T a multiple of 128, and the
+    head sizes multiples of 128 or different from each other), anything else
+    runs the masked-softmax einsum with f32 accumulation.
+
+    The library's kernels take one head size, a multiple of 128.  Where the
+    head sizes differ, q, k and v are zero-padded to the next such multiple
+    and the output is sliced back: exact, because a zero column adds nothing
+    to a score or to a value, and paid for in the kernels' arithmetic (192 /
+    128 run as 256 / 256).  That shape never falls to the einsum silently on
+    a TPU whose tiling T fits.
 
     The library's forward, dkv and dq kernels run with the block sizes
     :func:`_flash_block_sizes` picks from this call's own ``T`` and
@@ -138,6 +150,7 @@ def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
     arithmetic); the sweep on the v5e behind the rule is PERF.md,
     section 6, PR 25."""
     B, T, h, D = q.shape
+    Dv = v.shape[-1]
     if k.shape[2] != h:
         rep = h // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
@@ -145,7 +158,7 @@ def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
     use_flash = impl == "flash" or (
         impl == "auto"
         and jax.default_backend() == "tpu"
-        and D % 128 == 0
+        and (D % 128 == 0 or D != Dv)
         and T % 128 == 0
     )
     if use_flash:
@@ -153,20 +166,30 @@ def single_device_attention(q, k, v, *, causal: bool, impl: str = "auto"):
             flash_attention,
         )
 
+        if D != Dv:
+            padded = -(-max(D, Dv) // 128) * 128
+            q, k, v = (
+                jnp.pad(x, [(0, 0)] * 3 + [(0, padded - x.shape[-1])])
+                for x in (q, k, v)
+            )
         out = flash_attention(
             q.transpose(0, 2, 1, 3),
             k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3),
             causal=causal,
-            sm_scale=float(1.0 / (D ** 0.5)),
-            block_sizes=_flash_block_sizes(T, D),
-        )
-        return out.transpose(0, 2, 1, 3)
+            sm_scale=float(1.0 / (D ** 0.5) if sm_scale is None else sm_scale),
+            block_sizes=_flash_block_sizes(T, q.shape[-1]),
+        ).transpose(0, 2, 1, 3)
+        return out if D == Dv else out[..., :Dv]
     s = jnp.einsum(
         "bthd,bshd->bhts",
         q.astype(jnp.float32),
         k.astype(jnp.float32),
-    ) / jnp.sqrt(D).astype(jnp.float32)
+    )
+    if sm_scale is None:
+        s = s / jnp.sqrt(D).astype(jnp.float32)
+    else:
+        s = s * jnp.float32(sm_scale)
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
         s = jnp.where(mask[None, None], s, -jnp.inf)

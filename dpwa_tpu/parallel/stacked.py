@@ -114,20 +114,24 @@ def init_stacked_state(
     transport: StackedTransport,
     stacked_model_state: PyTree = None,
 ) -> StackedTrainState:
+    """The initial state of a stacked run.  **Takes ownership** of
+    ``stacked_params`` and ``stacked_model_state`` (they are donated: do not
+    use them afterwards)."""
     n = transport.config.n_peers
     leading = {leaf.shape[0] for leaf in jax.tree.leaves(stacked_params)}
     if leading != {n}:
         raise ValueError(
             f"stacked params must have leading peer axis {n}, got {leading}"
         )
-    # Own copies: the train step DONATES the state, so the state must not
-    # alias arrays the caller still holds.  One program, not an op per
-    # leaf: on an accelerator every new leaf shape is a compilation.
-    @jax.jit
+    # The state OWNS what it is given: ``stacked_params`` and
+    # ``stacked_model_state`` are donated, so the state's leaves are the
+    # caller's buffers (no second copy of a model that fills the chip is
+    # ever held) and the caller's arrays are deleted; a caller that still
+    # needs its values copies them before the call.  One program, not an op
+    # per leaf: on an accelerator every new leaf shape is a compilation.
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
     def build(params, model_state):
-        own = lambda t: jax.tree.map(jnp.copy, t)
-        params = own(params)
-        return params, jax.vmap(optimizer.init)(params), own(model_state)
+        return params, jax.vmap(optimizer.init)(params), model_state
 
     params, opt_state, model_state = build(stacked_params, stacked_model_state)
     return StackedTrainState(

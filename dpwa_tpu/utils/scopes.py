@@ -26,6 +26,13 @@ EXCHANGE = "dpwa.exchange"
 # so the phases above book them as forward / backward as before.
 MOE_ROUTE = "dpwa.moe.route"
 MOE_EXPERTS = "dpwa.moe.experts"
+# Beside them, the shared expert that every token takes (a dense SwiGLU); the
+# two names above keep their meaning for the experts a replica holds.
+MOE_SHARED = "dpwa.moe.shared"
+# Latent attention whole (``models/llama.LatentAttention``): the down and up
+# projections with their norms, rope, the attention core, the output
+# projection.
+ATTN_LATENT = "dpwa.attn.latent"
 # Likewise nested: the cross-entropy over the vocabulary and its gradient.
 LOSS = "dpwa.loss"
 

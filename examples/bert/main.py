@@ -123,7 +123,7 @@ def main() -> None:
     state = bundle.init_state(stacked, opt, transport)
     step_fn = bundle.make_step(mlm_loss_fn(model), opt, transport)
     payload = tree_wire_bytes(
-        jax.tree.map(lambda v: v[0], stacked),
+        jax.tree.map(lambda v: v[0], state.params),
         cfg.protocol.wire_dtype,
     )
     print(

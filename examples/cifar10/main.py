@@ -149,7 +149,7 @@ def main() -> None:
 
     step_fn = make_step(loss_fn, opt, transport)
     payload = tree_wire_bytes(
-        jax.tree.map(lambda v: v[0], stacked),
+        jax.tree.map(lambda v: v[0], state.params),
         cfg.protocol.wire_dtype,
     )
     metrics = MetricsLogger(stream=sys.stdout, every=args.log_every)

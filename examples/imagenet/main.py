@@ -85,7 +85,7 @@ def main() -> None:
 
     step_fn = bundle.make_step(loss_fn, opt, transport)
     payload = tree_wire_bytes(
-        jax.tree.map(lambda v: v[0], stacked),
+        jax.tree.map(lambda v: v[0], state.params),
         cfg.protocol.wire_dtype,
     )
     print(

@@ -129,7 +129,7 @@ def main() -> None:
     step_fn = bundle.make_step(
         loss_fn, opt, transport, exchange_filter=lora_filter
     )
-    one = jax.tree.map(lambda v: v[0], stacked)
+    one = jax.tree.map(lambda v: v[0], state.params)
     lora_sel, _ = partition(one, lora_filter)
     total = tree_size_bytes(one)
     lora_bytes = tree_wire_bytes(

@@ -147,7 +147,7 @@ def run_single_process(args, stacked: bool) -> None:
     state = init_state(stacked_params, opt, transport)
     step_fn = make_step(make_loss(model), opt, transport)
     payload = tree_wire_bytes(
-        jax.tree.map(lambda v: v[0], stacked_params),
+        jax.tree.map(lambda v: v[0], state.params),
         cfg.protocol.wire_dtype,
     )
 

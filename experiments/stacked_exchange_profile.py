@@ -57,8 +57,10 @@ def profile_config(name, init_fn, loss_fn, batch_fn, n, exchange_filter,
     cfg = make_local_config(n, schedule="ring")
     transport = StackedTransport(cfg)
     stacked = init_params_per_peer(init_fn, jax.random.key(0), n)
+    # init_stacked_state takes ownership of what it is given.
+    own = lambda tree: jax.tree.map(jnp.copy, tree)
     opt = optax.sgd(0.1, momentum=0.9)
-    state = init_stacked_state(stacked, opt, transport)
+    state = init_stacked_state(own(stacked), opt, transport)
 
     # (a) the real train step: local update + exchange, one program.
     step_fn = make_stacked_train_step(
@@ -105,19 +107,19 @@ def profile_config(name, init_fn, loss_fn, batch_fn, n, exchange_filter,
         loss_fn, opt, transport, exchange_filter=exchange_filter,
         overlap=True,
     )
-    state_o = init_stacked_state(stacked, opt, transport)
+    state_o = init_stacked_state(own(stacked), opt, transport)
     t_overlap, out = timed_loop(
         lambda c, k: overlap_step(c[0], batch)[:2],
         (state_o, jnp.zeros(n)), iters,
     )
     del state_o, out
-    state2 = init_stacked_state(stacked, opt, transport)
+    state2 = init_stacked_state(own(stacked), opt, transport)
     t_local, out = timed_loop(
         lambda c, k: local_step(c[0], batch),
         (state2, jnp.zeros(n)), iters,
     )
     del state2, out
-    state3 = init_stacked_state(stacked, opt, transport)
+    state3 = init_stacked_state(own(stacked), opt, transport)
     if exchange_filter is not None:
         exchanged3, _ = partition(state3.params, exchange_filter)
     else:

@@ -260,6 +260,75 @@ def test_the_chosen_flash_blocks_fit_the_v5e(v5e_chips, B, T, head_dim):
         assert name.format(**b) in text, name.format(**b)
 
 
+@pytest.mark.parametrize("T", [256, 512, 1024])
+def test_latent_attention_heads_go_to_the_kernels_padded(v5e_chips, T):
+    """Latent attention's q and k heads of 192 and v heads of 128 take the
+    kernel path (`impl="auto"`, answering as the chip), padded to 256:
+    Mosaic compiles forward, dkv and dq for 64 heads under `vmap` over two
+    peers, and the result keeps v's head size."""
+    import functools
+    import re
+    from unittest import mock
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops.ulysses import single_device_attention
+
+    attn = jax.vmap(functools.partial(
+        single_device_attention, causal=True, sm_scale=0.1309
+    ))
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32))
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda d: jax.ShapeDtypeStruct(
+        (2, 1, T, 64, d), jnp.bfloat16, sharding=one
+    )
+    with _no_compile_cache(), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        out = jax.eval_shape(attn, shaped(192), shaped(192), shaped(128))
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shaped(192), shaped(192), shaped(128)
+        ).compile().as_text()
+    assert out.shape == (2, 1, T, 64, 128)
+    assert text.count("tpu_custom_call") >= 3
+    # What the kernels are handed: heads first, every head size 256.
+    assert re.search(r"bf16\[(\d+,)*64,%d,256\]" % T, text)
+
+
+def test_a_share_of_the_experts_compiles_a_call_a_peer(v5e_chips):
+    """`ops/moe.held_matmul` at the published widths under `vmap` over two
+    peers: 8 held experts a peer, 4,096 rows a peer of which the groups
+    cover a few, forward and both gradients.  Mosaic compiles the `megablox`
+    kernels with a skipped leading group (`group_offset`) writing into one
+    folded result (`existing_out`): a call a peer, no slice of the rows."""
+    from unittest import mock
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops import moe
+
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one
+    )
+
+    def loss(rows, kernel, sizes):
+        out = jax.vmap(moe.held_matmul)(rows, kernel, sizes)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with _no_compile_cache(), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            shaped((2, 4096, 7168)), shaped((2, 8, 7168, 2048)),
+            shaped((2, 8), jnp.int32),
+        ).compile().as_text()
+    # gmm forward is dead code under grad of a sum; to the rows and to the
+    # weights: two kernels a peer.
+    assert text.count("tpu_custom_call") >= 4
+    assert "bf16[8192,7168]" in text  # the folded rows
+
+
 # ---------------------------------------------------------------------------
 # Compile cache
 # ---------------------------------------------------------------------------

@@ -135,8 +135,8 @@ def test_a_set_that_is_not_a_top_k_is_refused(seeded):
 
 
 def _renormalised(route):
-    def routed(x, router_kernel, k):
-        weights, experts, logits = route(x, router_kernel, k)
+    def routed(x, router_kernel, k, *gate):
+        weights, experts, logits = route(x, router_kernel, k, *gate)
         return weights / weights.sum(-1, keepdims=True), experts, logits
 
     return routed
@@ -146,8 +146,8 @@ def _with_capacity(route, factor=1.0):
     """A capacity-factor dispatch: an expert takes its first ``factor x N x
     k / E`` assignments in token order and the overflow is dropped."""
 
-    def routed(x, router_kernel, k):
-        weights, experts, logits = route(x, router_kernel, k)
+    def routed(x, router_kernel, k, *gate):
+        weights, experts, logits = route(x, router_kernel, k, *gate)
         n_experts = logits.shape[-1]
         capacity = int(factor * experts.size / n_experts)
         one_hot = jax.nn.one_hot(experts.reshape(-1), n_experts, dtype=jnp.int32)

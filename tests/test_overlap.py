@@ -29,6 +29,8 @@ from dpwa_tpu.train import (
 )
 
 N = 8
+# init_stacked_state takes ownership of (donates) what it is given.
+_own = lambda tree: jax.tree.map(jnp.copy, tree)
 
 
 def quad_loss(params, batch):
@@ -51,7 +53,7 @@ def test_overlap_semantics_exact_stacked():
     cfg = make_local_config(N, schedule="ring")
     transport = StackedTransport(cfg)
     opt = optax.sgd(0.1)
-    state = init_stacked_state(stacked, opt, transport)
+    state = init_stacked_state(_own(stacked), opt, transport)
     step = make_stacked_train_step(quad_loss, opt, transport, overlap=True)
     new_state, losses, info = step(state, batch)
 
@@ -75,7 +77,7 @@ def test_overlap_ici_stacked_parity():
     opt = optax.sgd(0.05, momentum=0.9)
 
     st = StackedTransport(cfg)
-    s_state = init_stacked_state(stacked, opt, st)
+    s_state = init_stacked_state(_own(stacked), opt, st)
     s_step = make_stacked_train_step(quad_loss, opt, st, overlap=True)
 
     it = IciTransport(cfg, mesh=make_mesh(cfg))
@@ -108,7 +110,7 @@ def test_overlap_preserves_mean_plus_updates():
     cfg = make_local_config(N, schedule="ring")
     transport = StackedTransport(cfg)
     opt = optax.sgd(0.1)
-    state = init_stacked_state(stacked, opt, transport)
+    state = init_stacked_state(_own(stacked), opt, transport)
     step = make_stacked_train_step(quad_loss, opt, transport, overlap=True)
     new_state, _, _ = step(state, batch)
     grads = jax.vmap(jax.grad(quad_loss))(stacked, batch)
@@ -139,7 +141,7 @@ def test_overlap_lora_subset_base_frozen():
     cfg = make_local_config(N, schedule="ring")
     transport = StackedTransport(cfg)
     opt = optax.sgd(0.1)
-    state = init_stacked_state(stacked, opt, transport)
+    state = init_stacked_state(_own(stacked), opt, transport)
     step = make_stacked_train_step(
         loss_fn, opt, transport,
         exchange_filter=lambda path: "lora" in path,

@@ -347,7 +347,8 @@ def test_stacked_exchange_filter_keeps_rest_frozen():
     step_fn = make_stacked_train_step(
         _mlp_loss, opt, stk, exchange_filter=lambda p: p.startswith("w1")
     )
-    state = init_stacked_state(params, opt, stk)
+    # The state takes ownership of what it is given: hand it a copy.
+    state = init_stacked_state(jax.tree.map(jnp.copy, params), opt, stk)
     batch = _batches(n, steps=1)[0]
     new_state, _, info = step_fn(state, batch)
     assert bool(np.asarray(info.participated).any())
