@@ -530,9 +530,20 @@ class MoE(nn.Module):
     Sows each call's routing into the ``intermediates`` collection
     (``router_input [N, D]``, ``experts [N, k]``, ``counts [E]``, ``prob_mean
     [E]``, router ``logits [N, E]``, and of the held experts ``held_counts [held]``, ``held_share``
-    = their part of all N x k assignments, ``held_max_over_mean``): what
-    :func:`moe_loss` and a reference that verifies the routing read; nothing
-    is computed for it when the collection is not asked for."""
+    = their part of all N x k assignments, ``held_max_over_mean``,
+    ``held_over_cap``): what :func:`moe_loss` and a reference that verifies
+    the routing read; nothing is computed for it when the collection is not
+    asked for.
+
+    ``held_over_cap`` is 1 where this call's held assignments outnumber the
+    share's row cap (``ops/moe.row_cap``: four times what uniform routing
+    sends it), else 0, and 0 where the shapes give no cap.  A share within
+    its cap computes one window of that many sorted rows; one over it
+    computes as many windows as its assignments fill, and in a stacked step
+    (``vmap`` over peers) every peer walks as many as the fullest: exact as
+    ever, each further window at about the first one's price.  A share that
+    reads 1 often holds hot experts; a trace shows the window's instructions
+    run more than once in that layer's call."""
 
     cfg: LlamaConfig
 
@@ -567,12 +578,14 @@ class MoE(nn.Module):
             self.sow("intermediates", "held_share", here.sum() / experts.size)
             self.sow("intermediates", "held_max_over_mean",
                      here.max() * held / jnp.maximum(here.sum(), 1))
+            self.sow("intermediates", "held_over_cap",
+                     moe.over_cap(here.sum(), experts.size, held, E))
         out = moe.moe_ffn(
             tokens, (weights, experts),
             expert(D, cfg.d_ff, "w_gate"), expert(D, cfg.d_ff, "w_up"),
             expert(cfg.d_ff, D, "w_down"),
             cfg.lora_alpha / max(cfg.lora_rank, 1), cfg.dtype,
-            None if held == E else offset, cfg.activation_dtype,
+            None if held == E else offset, cfg.activation_dtype, E,
         )
         if cfg.n_shared_experts:
             with jax.named_scope(scopes.MOE_SHARED):
@@ -649,7 +662,7 @@ def routing_of(intermediates) -> dict:
     """The sown routing of every expert layer, layers stacked in order:
     ``{"router_input": [L, N, D], "experts": [L, N, k], "counts": [L, E],
     "prob_mean": [L, E], "logits": [L, N, E], "held_counts": [L, held], "held_share": [L],
-    "held_max_over_mean": [L]}``, from the ``intermediates`` collection that
+    "held_max_over_mean": [L], "held_over_cap": [L]}``, from the ``intermediates`` collection that
     ``Llama.apply(..., mutable=["intermediates"])`` returns.  A dense layer
     sows nothing and is not among them."""
     layers = intermediates["intermediates"]

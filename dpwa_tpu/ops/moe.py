@@ -68,6 +68,22 @@ same products, summed in another order; no precision changes.  What decides
 is what is passed: (1) needs ``lora_a`` of one shape on both of gate and up
 (else :func:`expert_projection` for each, as the dense-expert tests run it),
 (3) an adapter on ``w_down``.
+
+**The rows a share works on** (PR 34).  A share that holds ``held`` of the
+router's ``E`` experts is sent ``N x k x held / E`` assignments on average,
+and only an imbalance no shape can rule out sends it all ``N x k``.  So a
+share walks its sorted rows a *window* at a time (:func:`_capped_ffn`):
+:func:`row_cap` rows, four times that average, gathered, multiplied and
+combined by one static program.  The held assignments are sorted first, so
+the first window holds them all while the share's count is within the cap,
+and it is the only one computed; a share sent more computes as many further
+windows as its count needs and adds their parts.  How many is one scalar for
+all peers (:func:`_most_of_peers`), so under ``vmap`` the walk stays one loop
+and a peer inside its cap adds windows of empty groups.  Every held row
+passes through the same grouped matmuls, in the same groups, whatever window
+it falls in: nothing is dropped and nothing depends on the cap but time (and,
+past the first window, the last bit: a group that straddles two windows has
+its adapters' gradient rounded to ``dtype`` once a window, then added).
 """
 
 from __future__ import annotations
@@ -378,6 +394,95 @@ def _dispatch_rows_bwd(k, inverse, grad):
 _dispatch_rows.defvjp(_dispatch_rows_fwd, _dispatch_rows_bwd)
 
 
+def row_cap(assignments: int, held: int, n_routed: int):
+    """The rows of one window of a share that holds ``held`` of ``n_routed``
+    experts, of ``assignments`` = N x k a peer: four times what uniform
+    routing sends it, rounded up to the 512 rows that :func:`_use_kernels`
+    wants.  (Four, not two: a trained layer's routing drifts, and calls of
+    2.3 times the uniform count on average, 4.5 at most, were seen where the
+    router itself was frozen: PERF.md section 6, PR 34.)  None where that is
+    no less than all the assignments (small shapes, a share that is most of
+    the experts) or does not divide them: the share then works on all N x k
+    rows at once."""
+    cap = -(-4 * assignments * held // (n_routed * 512)) * 512
+    return cap if cap < assignments and assignments % cap == 0 else None
+
+
+def over_cap(held_rows, assignments: int, held: int, n_routed: int):
+    """Whether a share sent ``held_rows`` assignments walks past its first
+    window (int32, 0 or 1); 0 where the shapes give no cap."""
+    cap = row_cap(assignments, held, n_routed) or assignments
+    return (held_rows > cap).astype(jnp.int32)
+
+
+@custom_batching.custom_vmap
+def _most_of_peers(count):
+    """``count`` as it is; under ``vmap`` over peers one count for them all,
+    the largest, and *unbatched*: a loop bounded by it stays one loop (bounded
+    by a batched count it runs every body masked, to the slowest peer)."""
+    return count
+
+
+@_most_of_peers.def_vmap
+def _most_of_peers_over_peers(axis_size, in_batched, count):
+    return jnp.max(count), False
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine_rows(out, row_weights, tokens, n: int, out_dtype):
+    """The combine from sorted rows: ``y[t] = sum of row_weights[i] x out[i]``
+    over the rows i with ``tokens[i] == t``, ``[n, D]``, as one product by the
+    weights placed at ``[t, i]``: the terms and their float32 accumulation
+    are the einsum's over a token's k choices (a choice with no row here adds
+    nothing in either).  The gradient gathers: row i takes ``d_y[tokens[i]]``,
+    nothing is widened to ``[N, k, D]``."""
+    placed = jax.nn.one_hot(tokens, n, dtype=row_weights.dtype, axis=0) * (
+        row_weights[None, :]
+    )
+    return jnp.dot(
+        placed, out, precision=HIGHEST, preferred_element_type=out_dtype
+    )
+
+
+def _combine_rows_fwd(out, row_weights, tokens, n, out_dtype):
+    return _combine_rows(out, row_weights, tokens, n, out_dtype), (
+        out, row_weights, tokens
+    )
+
+
+def _combine_rows_bwd(n, out_dtype, residuals, grad):
+    out, row_weights, tokens = residuals
+    picked = grad[tokens]
+    d_out = picked * row_weights[:, None].astype(grad.dtype)
+    d_weights = jnp.sum(picked * out.astype(grad.dtype), -1)
+    return d_out.astype(out.dtype), d_weights.astype(row_weights.dtype), None
+
+
+_combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+@jax.custom_vjp
+def _rows_to_choices(v, assignments, position):
+    """A window of sorted rows, ``v [R, w]``, back in assignment order,
+    ``[N x k, w]``: assignment a takes row ``position[a]`` of the window, or
+    zero where that lies outside it.  The gradient is a gather of R rows,
+    ``grad[assignments]``, those being the window's rows' own."""
+    rows = v.shape[0]
+    inside = (position >= 0) & (position < rows)
+    return jnp.where(inside[:, None], v[jnp.clip(position, 0, rows - 1)], 0)
+
+
+def _rows_to_choices_fwd(v, assignments, position):
+    return _rows_to_choices(v, assignments, position), assignments
+
+
+def _rows_to_choices_bwd(assignments, grad):
+    return grad[assignments], None, None
+
+
+_rows_to_choices.defvjp(_rows_to_choices_fwd, _rows_to_choices_bwd)
+
+
 def router_scores(logits, scoring: str = "softmax"):
     """``[N, E]`` scores of the router logits: their softmax over the
     experts, or each logit's sigmoid."""
@@ -420,9 +525,9 @@ def dispatch_plan(experts, n_experts: int, offset=None):
 
     With ``offset`` the ``n_experts`` are the share ``[offset, offset +
     n_experts)`` held here: the assignments to them come first, by expert,
-    every one of them (the static row bound stays N x k, so no imbalance can
-    drop one); those bound for absent experts are sorted behind them and
-    counted in no group, so ``group_sizes`` sums to what landed here."""
+    every one of them (the order is of all N x k, so no imbalance can drop
+    one); those bound for absent experts are sorted behind them and counted
+    in no group, so ``group_sizes`` sums to what landed here."""
     if offset is not None:
         local = experts - offset
         experts = jnp.where((local >= 0) & (local < n_experts), local, n_experts)
@@ -507,61 +612,203 @@ def _down_adapter_on_tokens(down, routed, lora_b, lora_scale, dtype,
 
 
 def moe_ffn(x, routed, w_gate, w_up, w_down, lora_scale: float, dtype,
-            offset=None, out_dtype=None):
+            offset=None, out_dtype=None, n_routed=None):
     """The expert layer on tokens ``x [N, D]`` given ``routed = (weights,
     experts)`` of :func:`route`: dispatch, SwiGLU experts, combine.  Each of
     ``w_gate / w_up / w_down`` is a triple as in :func:`expert_projection`.
     What the adapters share is chosen by what is passed (the module
-    docstring's last paragraph).
+    docstring's last paragraph but one).
 
     With ``offset`` the weights are those of the experts ``[offset, offset +
     E)`` alone, a share of those the router chose among, and the result is
-    their part of the layer: an assignment to an absent expert is gathered
-    with the rest (the row bound is N x k) and sorted last, enters no
-    grouped matmul, and adds zero to the combine.
+    their part of the layer: an assignment to an absent expert is sorted
+    behind the held ones, enters no grouped matmul, and adds zero to the
+    combine.  ``n_routed`` is how many experts the router chose among; with
+    it the share works on its sorted rows a window of :func:`row_cap` at a
+    time, as many windows as hold its assignments (the module docstring's
+    last paragraph); without it, or where the shapes give no cap, on all
+    N x k rows at once.  Both are exact.
 
     The grouped matmuls take and give ``dtype``.  With ``out_dtype`` the
     SwiGLU between them and the combine are computed in that type and the
     result is of it: a value is rounded to ``dtype`` where it enters a matmul
     and nowhere else (``ops/wide.py``)."""
     weights, experts = routed
+    held = w_gate[0].shape[0]
+    with jax.named_scope(scopes.MOE_ROUTE):
+        plan = dispatch_plan(experts, held, offset)
+    how = (lora_scale, dtype, offset is not None, out_dtype)
+    cap = None
+    if offset is not None:  # an absent expert's index matches no column
+        experts = experts - offset
+        if n_routed is not None:
+            cap = row_cap(experts.size, held, n_routed)
+    operands = (x, weights, experts, plan, (w_gate, w_up, w_down))
+    if cap is None:
+        return _all_rows(how, *operands)
+    return _capped_ffn(how, cap, *operands)
+
+
+def _swiglu_experts(rows, group_sizes, w_gate, w_up, w_down, how):
+    """The experts on their sorted rows: ``(hidden, out, down)``, the SwiGLU's
+    result, its down projection by the frozen kernels, and ``hidden A_down``
+    (None without an adapter on ``w_down``)."""
+    lora_scale, dtype, share, out_dtype = how
+    matmul = held_matmul if share else grouped_matmul
+    gate, up = gate_and_up(
+        rows, w_gate, w_up, group_sizes, lora_scale, dtype, matmul
+    )
+    if out_dtype is None:
+        hidden = jax.nn.silu(gate) * up
+    else:
+        hidden = narrow(
+            jax.nn.silu(gate.astype(out_dtype)) * up.astype(out_dtype), dtype
+        )
+    kernel_down, a_down, _ = w_down
+    out = matmul(hidden, kernel_down.astype(dtype), group_sizes)
+    down = None
+    if a_down is not None:
+        down = matmul(hidden, a_down.astype(dtype), group_sizes)
+    return hidden, out, down
+
+
+def _all_rows(how, x, weights, experts, plan, expert_weights):
+    """:func:`moe_ffn` given its dispatch ``plan``, over all N x k sorted
+    rows at once.  ``experts`` count from the first one held."""
+    lora_scale, dtype, _, out_dtype = how
+    order, inverse, group_sizes = plan
     n, k = experts.shape
-    matmul = grouped_matmul if offset is None else held_matmul
     enter = _entering(dtype, out_dtype)
     with jax.named_scope(scopes.MOE_ROUTE):
-        order, inverse, group_sizes = dispatch_plan(
-            experts, w_gate[0].shape[0], offset
-        )
         rows = _dispatch_rows(enter(x), order, inverse, k)
-    kernel_down, a_down, b_down = w_down
     with jax.named_scope(scopes.MOE_EXPERTS):
-        gate, up = gate_and_up(
-            rows, w_gate, w_up, group_sizes, lora_scale, dtype, matmul
-        )
-        if out_dtype is None:
-            hidden = jax.nn.silu(gate) * up
-        else:
-            hidden = enter(
-                jax.nn.silu(gate.astype(out_dtype)) * up.astype(out_dtype)
-            )
-        out = matmul(hidden, kernel_down.astype(dtype), group_sizes)
-        if a_down is not None:
-            down = matmul(hidden, a_down.astype(dtype), group_sizes)
+        _, out, down = _swiglu_experts(rows, group_sizes, *expert_weights, how)
     with jax.named_scope(scopes.MOE_ROUTE):
         to_tokens = lambda v: _permute_rows(v, inverse, order).reshape(n, k, -1)
         y = jnp.einsum(
             "nkd,nk->nd", to_tokens(out), enter(weights),
             preferred_element_type=out_dtype,
         )
-        if a_down is None:
+        if down is None:
             return y
         down = to_tokens(down)
-    if offset is not None:  # an absent expert's index matches no column
-        routed = (weights, experts - offset)
     with jax.named_scope(scopes.MOE_EXPERTS):
         return y + _down_adapter_on_tokens(
-            down, routed, b_down, lora_scale, dtype, out_dtype
+            down, (weights, experts), expert_weights[2][2], lora_scale, dtype,
+            out_dtype,
         )
+
+
+def _cut_to_window(group_sizes, start, cap: int):
+    """How many rows of each group lie among the sorted rows ``[start, start
+    + cap)``: group sizes again, summing to at most ``cap``, whose first
+    group starts at the window's first row."""
+    ends = jnp.cumsum(group_sizes)
+    in_window = lambda row: jnp.clip(row, start, start + cap)
+    return in_window(ends) - in_window(ends - group_sizes)
+
+
+def _window(how, cap, start, x, weights, experts, plan, expert_weights):
+    """The part of a share's layer that its sorted rows ``[start, start +
+    cap)`` make: those rows gathered from the tokens, each group cut to what
+    of it lies in the window, the experts on them, their weighted sum into
+    the tokens.  The parts of all windows that hold a held assignment add up
+    to :func:`_all_rows`'s result."""
+    lora_scale, dtype, _, out_dtype = how
+    order, inverse, group_sizes = plan
+    n, k = experts.shape
+    enter = _entering(dtype, out_dtype)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        assignments = lax.dynamic_slice(order, (start,), (cap,))
+        tokens = assignments // k  # of each row of the window
+        sizes = _cut_to_window(group_sizes, start, cap)
+        # A product by 0 and 1 moves whole rows exactly (each a value times
+        # 1, accumulated in float32), and so does its transpose, the
+        # gradient: a sum of a token's rows where a scatter-add would stand.
+        rows = jnp.dot(
+            jax.nn.one_hot(tokens, n, dtype=dtype), enter(x), precision=HIGHEST
+        )
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        _, out, down = _swiglu_experts(rows, sizes, *expert_weights, how)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        y = _combine_rows(
+            out, enter(weights).reshape(-1)[assignments], tokens, n, out_dtype
+        )
+        if down is None:
+            return y
+        down = _rows_to_choices(down, assignments, inverse - start)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        return y + _down_adapter_on_tokens(
+            down.reshape(n, k, -1), (weights, experts), expert_weights[2][2],
+            lora_scale, dtype, out_dtype,
+        )
+
+
+# One window's program and its gradient, each under ``jax.jit``: every window
+# of every expert layer of a model calls them with the same shapes, so they
+# are traced, differentiated and lowered once (XLA inlines them).
+_window_part = jax.jit(_window, static_argnums=(0, 1))
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _window_cotangents(how, cap, start, operands, grad):
+    """What ``grad`` on a window's part sends back to ``(x, weights, expert
+    weights)``.  Nothing of the window's forward is kept: it is a checkpoint
+    of its own, recomputed here for the pullback."""
+    x, weights, experts, plan, expert_weights = operands
+    recomputed = jax.checkpoint(
+        lambda x, weights, expert_weights: _window(
+            how, cap, start, x, weights, experts, plan, expert_weights
+        ), prevent_cse=False,  # inside a loop's body already
+    )
+    return jax.vjp(recomputed, x, weights, expert_weights)[1](grad)
+
+
+def _over_windows(cap: int, plan, part):
+    """``part(start)`` summed over the windows that hold a held assignment of
+    any peer (none: zeros).  One loop whatever the count, so that
+    a model holds the window's program once; its body has one name, so a
+    trace shows a further window as the body's instructions run once more
+    in that step, and ``held_over_cap`` says which calls had one."""
+    windows = _most_of_peers(-(-plan[2].sum() // cap))
+    nothing = jax.tree.map(jnp.zeros_like, jax.eval_shape(part, jnp.int32(0)))
+    return lax.fori_loop(
+        0, windows,
+        lambda w, total: jax.tree.map(jnp.add, total, part(w * cap)),
+        nothing,
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _capped_ffn(how, cap, x, weights, experts, plan, expert_weights):
+    """A share's layer where its shapes give a row cap: walked a window of
+    ``cap`` sorted rows at a time (:func:`_over_windows`).
+
+    The walk's length depends on the routing, so it has no derivative of its
+    own, and a loop that kept each window's residuals would hold all N x k
+    rows again.  The gradient is written by hand instead: the residuals are
+    the operands, and the backward pass walks the same windows, each
+    recomputing its forward for its cotangents, which add up (in the
+    parameters' own type) as the parts did."""
+    operands = (x, weights, experts, plan, expert_weights)
+    return _over_windows(
+        cap, plan, lambda start: _window_part(how, cap, start, *operands)
+    )
+
+
+def _capped_ffn_fwd(how, cap, *operands):
+    return _capped_ffn(how, cap, *operands), operands
+
+
+def _capped_ffn_bwd(how, cap, operands, grad):
+    d_x, d_weights, d_expert_weights = _over_windows(
+        cap, operands[3],
+        lambda start: _window_cotangents(how, cap, start, operands, grad),
+    )
+    return d_x, d_weights, None, None, d_expert_weights
+
+
+_capped_ffn.defvjp(_capped_ffn_fwd, _capped_ffn_bwd)
 
 
 def load_balancing_loss(counts, prob_means):
@@ -576,13 +823,26 @@ def load_balancing_loss(counts, prob_means):
     return counts.shape[-1] * jnp.sum(f * prob_means.mean(0))
 
 
-def routing_stats(experts, n_experts: int) -> dict:
+def routing_stats(experts, n_experts: int, share=None) -> dict:
     """What a routing did, for tests and chip runs: assignments an expert,
     the fullest expert over the mean, and the assignments dropped (the
-    dispatch has nowhere to drop one: N x k less the groups' sum)."""
+    dispatch has nowhere to drop one: N x k less the groups' sum).  For a
+    ``share = (offset, held)`` of the experts also ``held``, the assignments
+    that land on it, its row ``cap`` (:func:`row_cap`; N x k where the shapes
+    give none) and ``over_cap``: whether this routing sends the share past
+    its first window."""
     group_sizes = assignment_counts(experts, n_experts)
-    return dict(
+    stats = dict(
         assignments=group_sizes,
         max_over_mean=group_sizes.max() * n_experts / experts.size,
         dropped=experts.size - group_sizes.sum(),
     )
+    if share is not None:
+        offset, held = share
+        here = group_sizes[offset:offset + held].sum()
+        stats.update(
+            held=here,
+            cap=row_cap(experts.size, held, n_experts) or experts.size,
+            over_cap=over_cap(here, experts.size, held, n_experts),
+        )
+    return stats

@@ -650,3 +650,316 @@ def test_a_share_outside_vmap_says_what_it_costs(monkeypatch):
     with pytest.warns(UserWarning, match="vmap over a peer axis"):
         out = moe.held_matmul(lhs, rhs, jnp.array([3, 2], jnp.int32))
     assert bool(jnp.all(out[:5] == 4.0)) and bool(jnp.all(out[5:] == 0.0))
+
+
+# ---- a share's row cap: windows of sorted rows and one scalar (PR 34)
+
+# 256 tokens x top 8 = 2,048 assignments a peer; experts 8..15 of 128 held:
+# four times the expected 128 rows is a window of 512, four to a peer.
+CAPPED = dict(n_experts=128, k=8, offset=8, held=8, tokens=256)
+SHARE = (CAPPED["offset"], CAPPED["held"])
+
+
+def test_the_cap_follows_from_shapes_alone():
+    assert moe.row_cap(4096, 8, 192) == 1024  # the cell: 4 x 170.7 -> 1,024
+    assert moe.row_cap(2048, 8, 192) == 512  # its model check, 256 tokens
+    assert moe.row_cap(2048, 8, 128) == 512
+    assert moe.row_cap(65536, 8, 192) is None  # 4 x 2730.7 -> 11,264: 5.8 windows
+    assert moe.row_cap(65536, 8, 256) == 8192
+    # No cap where it would be no less than all the rows (small shapes, a
+    # share that is most of the experts) or would not divide them.
+    assert moe.row_cap(256, 4, 8) is None
+    assert moe.row_cap(1024, 16, 32) is None
+    assert moe.row_cap(512, 1, 64) is None
+    assert moe.row_cap(1280, 8, 128) is None  # 320 -> 512, and 2.5 windows
+    cases = [(1000, 2048, 8, 128), (512, 2048, 8, 128), (513, 2048, 8, 128),
+             (256, 256, 4, 8)]
+    assert [
+        int(moe.over_cap(jnp.int32(rows), *shape)) for rows, *shape in cases
+    ] == [1, 0, 1, 0]
+
+
+def routing_for(key, tokens, k, n_experts, only=None):
+    """``[tokens, k]`` distinct experts a token: of all, or of ``only``."""
+    pool = jnp.arange(n_experts) if only is None else jnp.asarray(only)
+    return jnp.stack([
+        jax.random.permutation(kk, pool)[:k]
+        for kk in jax.random.split(key, tokens)
+    ]).astype(jnp.int32)
+
+
+def dense_share(x, weights, experts, layer, lora_scale, offset):
+    """The share's part of the layer with no dispatch at all: every held
+    expert on every token, weighted by what the gate gave that pair."""
+    proj = lambda v, triple, e: v @ triple[0][e] + (
+        v @ triple[1][e]
+    ) @ triple[2][e] * lora_scale
+    out = 0.0
+    for e in range(layer[0][0].shape[0]):
+        gate = proj(x, layer[0], e)
+        y = proj(jax.nn.silu(gate) * proj(x, layer[1], e), layer[2], e)
+        out = out + y * (weights * (experts == offset + e)).sum(-1)[:, None]
+    return out
+
+
+# How a peer's tokens choose: of all 128 experts (some 128 of its 2,048
+# assignments held: one window); of the 8 held and 8 more (about half held:
+# two or three windows); of the 8 held alone (all 2,048: four windows).
+ROUTINGS = dict(
+    uniform=None, warm=list(range(4, 20)), hot=list(range(8, 16)),
+)
+
+
+def capped_case(routings=("uniform", "uniform")):
+    """Weights, tokens, gate weights and a routing a peer."""
+    c = CAPPED
+    peers = len(routings)
+    layers = [expert_weights(jax.random.key(70 + p), c["held"]) for p in range(peers)]
+    x = jax.random.normal(jax.random.key(80), (peers, c["tokens"], 64))
+    weights = jax.random.uniform(
+        jax.random.key(81), (peers, c["tokens"], c["k"])
+    ) + 0.5
+    experts = jnp.stack([
+        routing_for(
+            jax.random.key(90 + p), c["tokens"], c["k"], c["n_experts"],
+            ROUTINGS[kind],
+        ) for p, kind in enumerate(routings)
+    ])
+    return layers, x, weights, experts
+
+
+def share_loss(n_routed):
+    def loss(x, weights, experts, layer):
+        out = moe.moe_ffn(
+            x, (weights, experts), *layer, 2.0, jnp.float32,
+            CAPPED["offset"], None, n_routed,
+        )
+        return jnp.sum(out * jnp.cos(out)), out
+    return jax.value_and_grad(loss, argnums=(0, 1, 3), has_aux=True)
+
+
+def reference_loss(x, weights, experts, layer):
+    out = dense_share(x, weights, experts, layer, 2.0, CAPPED["offset"])
+    return jnp.sum(out * jnp.cos(out)), out
+
+
+def over_peers(fn, how, layers, *stacked_args):
+    if how == "vmap":
+        return jax.vmap(fn)(
+            *stacked_args, jax.tree.map(lambda *v: jnp.stack(v), *layers)
+        )
+    results = [
+        fn(*(a[p] for a in stacked_args), layers[p]) for p in range(len(layers))
+    ]
+    return jax.tree.map(lambda *v: jnp.stack(v), *results)
+
+
+@pytest.mark.parametrize("how", ["vmap", "loop"])
+@pytest.mark.parametrize("routings, windows", [
+    (("uniform", "uniform"), (1, 1)), (("hot", "hot"), (4, 4)),
+    (("uniform", "hot"), (1, 4)), (("warm", "uniform"), (None, 1)),
+], ids=["all_inside_the_cap", "all_over_the_cap", "one_over_one_under",
+        "one_part_over"])
+def test_the_windowed_share_equals_all_rows_at_once_and_the_reference(
+    how, routings, windows
+):
+    """The layer walked a window of 512 sorted rows at a time, the N x k
+    program and a reference with no dispatch: the value, and the gradients
+    to the tokens, to the gate's weights and to every adapter and kernel,
+    under ``vmap`` over two peers and in a loop over them.  A peer whose
+    tokens all choose the held experts needs all four windows; under ``vmap``
+    the other walks them with it, over empty groups, and both stay exact."""
+    layers, x, weights, experts = capped_case(routings)
+    for routed, want in zip(experts, windows):
+        held_rows = int(moe.routing_stats(routed, 128, SHARE)["held"])
+        assert -(-held_rows // 512) == (want or -(-held_rows // 512))
+        assert want is not None or 512 < held_rows < 1536
+    capped = over_peers(share_loss(128), how, layers, x, weights, experts)
+    full = over_peers(share_loss(None), how, layers, x, weights, experts)
+    want = over_peers(
+        jax.value_and_grad(reference_loss, argnums=(0, 1, 3), has_aux=True),
+        how, layers, x, weights, experts,
+    )
+    for got, same, ref in zip(*map(jax.tree.leaves, (capped, full, want))):
+        assert float(jnp.abs(ref).max()) > 0
+        assert relative(got, same) < 1e-5
+        assert relative(got, ref) < 1e-4
+
+
+def loops_in(jaxpr):
+    """Every ``while`` equation, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "while":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += loops_in(sub)
+    return found
+
+
+def test_under_vmap_one_unbatched_count_bounds_one_walk(monkeypatch):
+    """The vmapped layer and its gradient each hold one loop whose bound (and
+    counter) has no peer axis, so it runs as many windows as the fullest
+    peer needs and no body is masked.  With the count left a peer's own
+    (``_most_of_peers`` made the identity) the bound is batched and ``vmap``
+    masks every body: that is what the three lines are for."""
+    layers, x, weights, experts = capped_case()
+    stacked = jax.tree.map(lambda *v: jnp.stack(v), *layers)
+
+    def integer_shapes():
+        jaxpr = jax.make_jaxpr(jax.vmap(share_loss(128)))(
+            x, weights, experts, stacked
+        ).jaxpr
+        return [
+            [v.aval.shape for v in eqn.invars
+             if jnp.issubdtype(v.aval.dtype, jnp.integer) and v.aval.ndim < 2]
+            for eqn in loops_in(jaxpr)
+        ]
+
+    shapes = integer_shapes()
+    assert len(shapes) == 2  # forward, and the backward pass's own
+    assert all(shape == () for loop in shapes for shape in loop)
+    monkeypatch.setattr(moe, "_most_of_peers", lambda count: count)
+    assert all((2,) in loop for loop in integer_shapes())
+
+
+def test_without_a_cap_there_is_no_walk():
+    """Where four times the expected rows is no less than N x k (the toy model's
+    256 rows; a share of half the experts) the layer is the N x k program
+    alone, as it is without ``n_routed``."""
+    layer = expert_weights(jax.random.key(9), 4)
+    x = jax.random.normal(jax.random.key(10), (32, 64))
+    experts = routing_for(jax.random.key(11), 32, 8, 8)
+    weights = jnp.ones((32, 8))
+    ffn = lambda n_routed: jax.make_jaxpr(lambda x: moe.moe_ffn(
+        x, (weights, experts), *layer, 2.0, jnp.float32, 2, None, n_routed
+    ))(x)
+    assert loops_in(ffn(8).jaxpr) == []
+    assert str(ffn(8)) == str(ffn(None))
+
+
+def test_a_capped_share_is_one_program_with_or_without_vmap():
+    """A peer alone (a loop over peers, one peer a chip) and a peer under
+    ``vmap`` hold the same walk, one loop forward and one backward: what a
+    share computes does not depend on how it is called."""
+    layers, x, weights, experts = capped_case(("hot",))
+    args = (x[0], weights[0], experts[0], layers[0])
+    alone = jax.make_jaxpr(share_loss(128))(*args)
+    assert len(loops_in(alone.jaxpr)) == 2
+    one_peer = jax.make_jaxpr(jax.vmap(share_loss(128)))(
+        *jax.tree.map(lambda v: v[None], args)
+    )
+    assert len(loops_in(one_peer.jaxpr)) == 2
+
+
+def test_held_rows_come_out_the_same_to_the_bit_in_every_window():
+    """The held rows are the same rows in the same groups through the same
+    products whatever window they fall in: ``hidden``, the down projection
+    and the down adapter's A side of each window agree bit for bit with the
+    N x k program's on the window's rows, bfloat16 rows with float32 between
+    the matmuls as the cell has them; rows in no group give 0 in both."""
+    c = CAPPED
+    layers, x, weights, experts = capped_case(("warm",))
+    how = (2.0, jnp.bfloat16, True, jnp.float32)
+    order, inverse, sizes = moe.dispatch_plan(experts[0], c["held"], c["offset"])
+    held_rows = int(sizes.sum())
+    assert 512 < held_rows < 1536  # the groups end inside a later window
+    xe = x[0].astype(jnp.bfloat16)
+    full = moe._swiglu_experts(xe[order // c["k"]], sizes, *layers[0], how)
+    for start in range(0, 2048, 512):
+        rows = jnp.dot(
+            jax.nn.one_hot(order[start:start + 512] // c["k"], c["tokens"], dtype=xe.dtype),
+            xe,
+        )
+        assert bool(jnp.all(rows == xe[order // c["k"]][start:start + 512]))
+        cut = moe._cut_to_window(sizes, start, 512)
+        here = max(0, min(held_rows - start, 512))
+        assert int(cut.sum()) == here
+        part = moe._swiglu_experts(rows, cut, *layers[0], how)
+        for name, a, b in zip(("hidden", "out", "down"), part, full):
+            a, b = (np.asarray(v, np.float32) for v in (a, b[start:start + 512]))
+            assert np.array_equal(a[:here], b[:here]), (name, start)
+            if name != "hidden":
+                assert np.abs(a[here:]).max(initial=0) == 0
+                assert np.abs(b[here:]).max(initial=0) == 0
+            assert here == 0 or np.abs(a[:here]).max() > 0
+    assert int(sum(
+        moe._cut_to_window(sizes, s, 512).sum() for s in range(0, 2048, 512)
+    )) == held_rows
+
+
+def capped_model(**changes):
+    return model_of(
+        n_experts=128, n_experts_per_tok=8, experts_held=8, expert_offset=8,
+        **changes,
+    )
+
+
+def test_remat_on_and_off_agree_through_the_walk(seeded):
+    """The toy model with 128 experts, 8 held, top 8 at T 128: 2,048
+    assignments a call, windows of 512, under ``vmap`` over two peers as a
+    stacked step runs it.  The layer's gradient is hand-written around the
+    walk (each window recomputes its forward), inside a checkpointed block
+    or not; a peer alone holds the same walk and agrees."""
+    tokens = jax.random.randint(jax.random.key(0), (2, 128), 0, CONFIG["vocab_size"])
+    targets = jnp.roll(tokens, -1, axis=1)
+    params = perturbed(capped_model().init(jax.random.key(1), tokens))
+    peers = lambda tree: jax.tree.map(lambda v: jnp.stack([v, v * 1.01]), tree)
+    loss_of = lambda remat: lambda p, tok, tgt: moe_loss(
+        capped_model(remat=remat), p, tok, tgt
+    )
+    stacked = (peers(params), jnp.stack([tokens, tokens[::-1]]),
+               jnp.stack([targets, targets[::-1]]))
+    results = [
+        jax.vmap(jax.value_and_grad(loss_of(remat)))(*stacked)
+        for remat in (True, False)
+    ]
+    (loss_on, grads_on), (loss_off, grads_off) = results
+    np.testing.assert_allclose(loss_on, loss_off, rtol=1e-6)
+    for name, grad in adapters(grads_on).items():
+        assert relative(grad, adapters(grads_off)[name]) < 1e-5, name
+        assert float(jnp.abs(grad).max()) > 0, name
+    jaxpr = jax.make_jaxpr(jax.vmap(jax.grad(loss_of(True))))(*stacked).jaxpr
+    assert len(loops_in(jaxpr)) >= 4  # two expert layers, both passes
+    # A peer alone: the same walk, the same numbers.
+    alone = jax.make_jaxpr(jax.grad(loss_of(True)))(params, tokens, targets)
+    assert len(loops_in(alone.jaxpr)) == len(loops_in(jaxpr))
+    loss_alone, grads_alone = jax.value_and_grad(loss_of(True))(
+        params, tokens, targets
+    )
+    assert float(loss_alone) == pytest.approx(float(loss_on[0]), rel=1e-6)
+    for name, grad in adapters(grads_alone).items():
+        assert relative(grad, adapters(grads_on)[name][0]) < 1e-5, name
+    # And against the reference given the same share of 128 experts.
+    config = dict(
+        CONFIG, n_routed_experts=8, num_experts_per_tok=8,
+        published=dict(CONFIG["published"], n_routed_experts=128),
+        assumed=dict(CONFIG["assumed"], expert_offset=8),
+    )
+    assert relative(
+        capped_model().apply(params, tokens), plain.forward(config, params, tokens)
+    ) < 1e-4
+
+
+@pytest.mark.parametrize("hot", [False, True])
+def test_the_counters_say_whether_a_share_overflowed(hot):
+    _, _, _, experts = capped_case(("hot" if hot else "uniform",))
+    stats = moe.routing_stats(experts[0], 128, SHARE)
+    assert int(stats["cap"]) == 512
+    assert int(stats["held"]) == int(stats["assignments"][8:16].sum())
+    assert bool(stats["over_cap"]) == hot
+    assert int(stats["dropped"]) == 0
+    # The layer's own sown counter; for the hot case a router whose logits
+    # are highest for the held experts whatever the token.
+    cfg = capped_model().cfg
+    y = jnp.ones((2, 128, cfg.d_model))
+    params = MoE(cfg).init(jax.random.key(13), y)
+    if hot:
+        to_held = jnp.where((jnp.arange(128) >= 8) & (jnp.arange(128) < 16), 1.0, -1.0)
+        params["params"]["router"] = jnp.broadcast_to(to_held, (cfg.d_model, 128))
+    _, sown = MoE(cfg).apply(params, y, mutable=["intermediates"])
+    said = {k: v[0] for k, v in sown["intermediates"].items()}
+    assert int(said["held_over_cap"]) == int(hot)
+    assert (int(said["held_counts"].sum()) > 512) == hot
+    if hot:
+        assert int(said["held_counts"].sum()) == 2 * 128 * 8
