@@ -34,7 +34,9 @@ class LlamaConfig:
     n_kv_heads: Optional[int] = None  # GQA; None = MHA
     d_ff: int = 1376
     max_seq_len: int = 2048
-    rope_theta: float = 500000.0
+    # None: no rotary embedding (a model whose recurrent layers carry the
+    # order of the tokens gives its attention layers no positions).
+    rope_theta: Optional[float] = 500000.0
     lora_rank: int = 0  # 0 = no LoRA
     lora_alpha: float = 16.0
     dtype: jnp.dtype = jnp.float32
@@ -110,7 +112,8 @@ class LlamaConfig:
     # recomputed in the backward pass and only its input is saved.
     remat: bool = False
     # The type base leaves (kernels, norms, embedding, head) are created in;
-    # adapters and the router are float32 whatever it says.
+    # adapters, the router and a Mamba mixer's ``A_log``, ``D`` and
+    # ``dt_bias`` are float32 whatever it says.
     param_dtype: jnp.dtype = jnp.float32
     # The type activations have between matmuls (None: ``dtype``): the
     # residual stream, what the norms and projections put out, the
@@ -120,8 +123,36 @@ class LlamaConfig:
     # peers as unbatched, to the last bit but for the order of a sum.
     # Written for the latent-attention block; :class:`Attention` refuses it.
     activation_dtype: Optional[jnp.dtype] = None
+    # State-space layers (the Jamba block): ``attn_layer_period`` > 0 keeps
+    # attention in the layers ``i % attn_layer_period == attn_layer_offset``
+    # and gives every other layer a :class:`MambaMixer` of ``mamba_expand x
+    # d_model`` channels, ``mamba_d_state`` states a channel, a causal
+    # convolution ``mamba_d_conv`` wide and a rank-``mamba_dt_rank`` step
+    # size.  0: every layer is attention.
+    attn_layer_period: int = 0
+    attn_layer_offset: int = 0
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_dt_rank: int = 0
+    mamba_expand: int = 2
+    # The head is the embedding (``logits = x E^T``): no ``lm_head`` leaf.
+    tie_embeddings: bool = False
 
     def __post_init__(self):
+        if self.attn_layer_period:
+            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+                raise ValueError(
+                    f"attn_layer_offset {self.attn_layer_offset} is not in "
+                    f"0..{self.attn_layer_period - 1}"
+                )
+            if min(self.mamba_d_state, self.mamba_d_conv,
+                   self.mamba_dt_rank, self.mamba_expand) < 1:
+                raise ValueError("a Mamba layer needs its four sizes")
+            if self.sp_axis is not None:
+                raise ValueError(
+                    "a Mamba layer has no sequence-parallel path: the scan "
+                    "hands no state from one rank to the next"
+                )
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"router_scoring must be softmax|sigmoid, got "
@@ -177,6 +208,10 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
+
+    def is_attention_layer(self, index: int) -> bool:
+        period = self.attn_layer_period
+        return not period or index % period == self.attn_layer_offset
 
     @property
     def stream_dtype(self) -> jnp.dtype:
@@ -364,8 +399,9 @@ class Attention(nn.Module):
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
         v = dense(KV * D, "wv")(x).reshape(B, T, KV, D)
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        if cfg.rope_theta is not None:
+            q = rope(q, positions, cfg.rope_theta)
+            k = rope(k, positions, cfg.rope_theta)
         if cfg.sp_axis is not None:
             # Sequence-parallel: exact ring attention over the sp mesh
             # axis — K/V blocks rotate by ppermute, online softmax
@@ -472,6 +508,78 @@ class LatentAttention(nn.Module):
                 q, k, v, causal=True, impl=cfg.attn_impl, sm_scale=sm_scale
             )
             return _dense(cfg, cfg.d_model, "wo")(out.reshape(B, T, H * dv))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The inverse softplus of a log-uniform draw in [0.001, 0.1] (the Mamba
+    paper's initial step sizes)."""
+    low, high = math.log(1e-3), math.log(1e-1)
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, low, high))
+    return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
+
+def _conv_init(taps: int):
+    """Uniform in +-1 / sqrt(taps): a depthwise convolution's fan-in."""
+    bound = taps ** -0.5
+    return lambda key, shape, dtype=jnp.float32: jax.random.uniform(
+        key, shape, jnp.float32, -bound, bound
+    ).astype(dtype)
+
+
+class MambaMixer(nn.Module):
+    """The Mamba-1 mixer with Jamba's three inner norms, ``u [B, T, D]``:
+
+        [x, z] = u W_in;  x = silu(conv(x))         causal, depthwise, bias
+        [dt, Bm, Cm] = x W_x, an RMSNorm each
+        delta = softplus(dt W_dt + b_dt);  A = -exp(A_log)
+        y = selective_scan(x, delta, A, Bm, Cm, D)  (``ops/ssm.py``)
+        out = (y * silu(z)) W_out
+
+    Adapters on the four projections (five matrices: ``in_proj`` is the x
+    and the z one side by side); ``conv_kernel``, ``conv_bias``, ``dt_bias``,
+    ``A_log``, ``D`` and the norms are base leaves.  ``delta`` and everything
+    inside the scan are float32 whatever ``dtype`` says."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, u):
+        from dpwa_tpu.ops import ssm
+        from dpwa_tpu.utils import scopes
+
+        cfg = self.cfg
+        E = cfg.mamba_expand * cfg.d_model
+        N, R, K = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_d_conv
+        with jax.named_scope(scopes.SSM):
+            x, z = jnp.split(_dense(cfg, 2 * E, "in_proj")(u), 2, -1)
+            x = nn.silu(ssm.causal_conv1d(
+                x,
+                self.param("conv_kernel", _conv_init(K), (K, E),
+                           cfg.param_dtype),
+                self.param("conv_bias", _conv_init(K), (E,), cfg.param_dtype),
+            ))
+            dt, Bm, Cm = jnp.split(
+                _dense(cfg, R + 2 * N, "x_proj")(x), [R, R + N], -1
+            )
+            dt, Bm, Cm = (
+                _norm(cfg, name)(v) for name, v in
+                (("dt_norm", dt), ("b_norm", Bm), ("c_norm", Cm))
+            )
+            delta = jax.nn.softplus(
+                _dense(cfg, E, "dt_proj")(dt).astype(jnp.float32)
+                + self.param("dt_bias", _dt_bias_init, (E,))
+            )
+            A_log = self.param(
+                "A_log",
+                lambda key, shape: jnp.broadcast_to(
+                    jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), shape
+                ),
+                (E, N),
+            )
+            D = self.param("D", nn.initializers.ones, (E,))
+            with jax.named_scope(scopes.SSM_SCAN):
+                y = ssm.selective_scan(x, delta, -jnp.exp(A_log), Bm, Cm, D)
+            return _dense(cfg, cfg.d_model, "out_proj")(y * nn.silu(z))
 
 
 class MLP(nn.Module):
@@ -597,15 +705,20 @@ class MoE(nn.Module):
 
 class Block(nn.Module):
     cfg: LlamaConfig
-    index: int = 0  # of the layer: the first ``n_dense_layers`` are dense
+    # Of the layer: the first ``n_dense_layers`` are dense, and with an
+    # ``attn_layer_period`` it says which layers keep attention.
+    index: int = 0
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
-        attention = LatentAttention if cfg.kv_lora_rank else Attention
-        x = x + attention(cfg, name="attn")(
-            _norm(cfg, "attn_norm")(x), positions
-        )
+        if cfg.is_attention_layer(self.index):
+            attention = LatentAttention if cfg.kv_lora_rank else Attention
+            x = x + attention(cfg, name="attn")(
+                _norm(cfg, "attn_norm")(x), positions
+            )
+        else:
+            x = x + MambaMixer(cfg, name="mamba")(_norm(cfg, "mamba_norm")(x))
         if self.index < cfg.n_dense_layers:
             ffn = MLP(cfg, cfg.d_ff_dense, name="mlp")
         else:
@@ -624,10 +737,11 @@ class Llama(nn.Module):
         B, T = tokens.shape
         # The rows are looked up in ``dtype`` and only they are widened: an
         # ``Embed`` of the activations' type would convert the whole table.
-        x = nn.Embed(
+        embed = nn.Embed(
             cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="embed",
-        )(tokens).astype(cfg.stream_dtype)
+        )
+        x = embed(tokens).astype(cfg.stream_dtype)
         positions = jnp.arange(T)
         if cfg.sp_axis is not None:
             if cfg.sp_layout == "zigzag":
@@ -651,6 +765,11 @@ class Llama(nn.Module):
         for i in range(cfg.n_layers):
             x = block(cfg, i, name=f"layer_{i}")(x, positions)
         x = _norm(cfg, "final_norm")(x)
+        if cfg.tie_embeddings:  # x E^T in float32, as the head below
+            return jax.lax.dot_general(
+                x.astype(jnp.float32), embed.embedding.astype(jnp.float32),
+                (((x.ndim - 1,), (1,)), ((), ())),
+            )
         logits = nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=jnp.float32,
             param_dtype=cfg.param_dtype, name="lm_head",

@@ -1,0 +1,436 @@
+"""The two sequence operations of a Mamba-1 mixer: a causal depthwise
+convolution and the selective scan, with the scan's gradient written by hand.
+
+The scan, per sequence, with ``x``, ``delta`` ``[T, E]``, ``A`` ``[E, N]``,
+``Bm``, ``Cm`` ``[T, N]``, ``D`` ``[E]`` and a state ``s`` ``[E, N]``:
+
+    s_t = exp(delta_t (x) A) * s_{t-1} + (delta_t * x_t) (x) Bm_t      s_0 = 0
+    y_t = s_t . Cm_t + D * x_t
+
+The decay ``exp(delta_t[e] A[e, n])`` depends on channel *and* state index,
+so the recurrence has no matmul form, and the state over time is ``T x E x N``
+values (1.34 GB a sequence at 4096 x 5120 x 16): nothing here writes it out.
+
+**On a TPU** the scan is two Pallas kernels.  Both lay the state out as ``[N,
+channel block]`` float32, channels on lanes, and walk time inside the kernel,
+a chunk of ``chunk_length(T)`` steps a grid step, over a grid of (sequence,
+channel block, chunk) whose last axis runs in order with the state kept in
+VMEM between chunks.  The forward kernel writes ``y`` and the state *entering*
+each chunk (``[T / chunk, N, E]``, the only residual beside the inputs).  The
+backward kernel walks the chunks last to first: it recomputes a chunk's
+states from its boundary into VMEM, then walks the chunk backwards carrying
+``dL/ds`` the other way.  ``dBm`` and ``dCm`` are sums over channels: the
+kernel writes one partial sum a channel block and they are added outside.
+There is no division by a cumulative decay anywhere (``exp(-sum delta A)``
+overflows float32 inside one chunk at the published initial values), and the
+recurrence, ``exp`` and every accumulation are float32 whatever type ``x``
+has.
+
+**Off the TPU** the same function is its plain twin, a ``lax.scan`` a token
+under autodiff.  The backend decides, as in ``single_device_attention``.
+
+**Under ``vmap``** (the stacked step's peer axis) a ``custom_vmap`` rule
+folds the peer axis into the kernels' sequence axis, ``A`` and ``D`` indexed
+by the peer a sequence belongs to; unbatched (a loop over peers) it is the
+same kernels on one peer.  Both do the same arithmetic in the same order.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import custom_batching, lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from dpwa_tpu.utils import scopes
+
+F32 = jnp.float32
+
+
+def causal_conv1d(x, w, b):
+    """Depthwise causal convolution over time: ``out_t = b + sum_j w[j] *
+    x_{t - (K - 1) + j}`` with ``x_{<0} = 0``.  ``x [..., T, E]``, ``w [K,
+    E]``, ``b [E]``; ``K`` shifted multiply-adds in float32, the result in
+    ``x``'s type."""
+    taps, steps = w.shape[0], x.shape[-2]
+    wide = x.astype(F32)
+    padded = jnp.pad(wide, [(0, 0)] * (x.ndim - 2) + [(taps - 1, 0), (0, 0)])
+    out = b.astype(F32)
+    for j in range(taps):
+        out = out + w[j].astype(F32) * lax.slice_in_dim(
+            padded, j, j + steps, axis=-2
+        )
+    return out.astype(x.dtype)
+
+
+def chunk_length(steps: int) -> int:
+    """Time steps a grid step walks: the largest of 128, 64, ... 8 that
+    divides ``steps`` (0: no chunk does, and the kernels are not used)."""
+    return next((c for c in (128, 64, 32, 16, 8) if steps % c == 0), 0)
+
+
+def channel_block(channels: int) -> int:
+    """Channels a grid step holds the state of: 512 where that divides them
+    (eight vector registers of state at ``N`` 16), else 256, 128, or all of
+    them where no multiple of the lane width does."""
+    return next((c for c in (512, 256, 128) if channels % c == 0), channels)
+
+
+def _unroll(chunk: int) -> int:
+    """Time steps a turn of the kernels' loops holds: on a v5e at the
+    published widths 8 took three quarters of the time of 4, which took a
+    third of 1 (PERF.md section 6, PR 35)."""
+    return min(chunk, 8)
+
+
+def _use_kernels(steps: int, channels: int) -> bool:
+    return (
+        jax.default_backend() == "tpu" and channels % 128 == 0
+        and chunk_length(steps) > 0
+    )
+
+
+def selective_scan(x, delta, A, Bm, Cm, D):
+    """``y [B, T, E]`` of the recurrence in the module docstring, for ``x``,
+    ``delta [B, T, E]``, ``A [E, N]``, ``Bm``, ``Cm [B, T, N]``, ``D [E]``;
+    differentiable in all six.  ``y`` has ``x``'s type; each gradient has its
+    argument's."""
+    if _use_kernels(x.shape[1], x.shape[2]):
+        return kernel_scan(x, delta, A, Bm, Cm, D)
+    return plain_scan(x, delta, A, Bm, Cm, D)
+
+
+def plain_scan(x, delta, A, Bm, Cm, D):
+    """The recurrence a token at a time (``lax.scan``), float32, gradients by
+    autodiff: what runs off the TPU."""
+    wide = lambda v: v.astype(F32)
+    A, D = wide(A), wide(D)
+
+    def step(s, inputs):
+        x_t, d_t, b_t, c_t = inputs  # [B, E], [B, E], [B, N], [B, N]
+        s = jnp.exp(d_t[..., None] * A) * s + (
+            (d_t * x_t)[..., None] * b_t[:, None, :]
+        )
+        return s, (s * c_t[:, None, :]).sum(-1) + D * x_t
+
+    over_time = lambda v: jnp.swapaxes(wide(v), 0, 1)
+    s0 = jnp.zeros((x.shape[0],) + A.shape, F32)
+    _, y = lax.scan(step, s0, tuple(map(over_time, (x, delta, Bm, Cm))))
+    return jnp.swapaxes(y, 0, 1).astype(x.dtype)
+
+
+# The kernels.  Their arguments are in "kernel layout": ``x``, ``delta`` (and
+# ``dy``) ``[S, T, E]`` over S sequences; ``a [G, N, E]`` and ``d [G, 1, E]``
+# over G groups of S / G sequences each (the peers of a stacked step), ``A``
+# transposed so that channels lie on lanes; ``bt``, ``ct [S, N, T]`` float32,
+# transposed so that a time step's ``N`` values are a column.
+
+
+def _column(rows, pick):
+    """Column ``t`` of ``rows [N, chunk]`` as ``[N, 1]``, ``pick`` being the
+    mask of lane ``t``: a select and a lane reduction, no dynamic lane
+    index."""
+    return jnp.sum(jnp.where(pick, rows, 0.0), axis=1, keepdims=True)
+
+
+def _advance(s, a, d_t, x_t, b_t):
+    """One step of the recurrence on ``s [N, Eb]``: ``d_t``, ``x_t [1, Eb]``
+    rows, ``b_t [N, 1]`` a column.  Forward and recomputation share it, so a
+    recomputed state is the forward's to the bit."""
+    return jnp.exp(d_t * a) * s + (d_t * x_t) * b_t
+
+
+def _walk(steps: int, unroll: int, step, carry):
+    """``carry = step(t, carry)`` for t in 0..steps-1, ``unroll`` steps a turn
+    of the loop (Mosaic unrolls a ``fori_loop`` wholly or not at all)."""
+
+    def turn(i, carry):
+        for u in range(unroll):
+            carry = step(i * unroll + u, carry)
+        return carry
+
+    return lax.fori_loop(0, steps // unroll, turn, carry)
+
+
+def _forward_kernel(
+    x_ref, dt_ref, a_ref, bt_ref, ct_ref, d_ref, y_ref, hs_ref,
+    s_ref, xf_ref, df_ref, yf_ref, *, chunk, unroll,
+):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    hs_ref[...] = s_ref[...]
+    # Rows are read one at a time below: from float32 copies, whose rows are
+    # whole sublanes whatever type came in.
+    xf_ref[...] = x_ref[...].astype(F32)
+    df_ref[...] = dt_ref[...].astype(F32)
+    a, bt, ct = a_ref[...], bt_ref[...], ct_ref[...]
+    lane = lax.broadcasted_iota(jnp.int32, bt.shape, 1)
+
+    def step(t, s):
+        row = pl.ds(t, 1)
+        pick = lane == t
+        s = _advance(
+            s, a, df_ref[row, :], xf_ref[row, :], _column(bt, pick)
+        )
+        yf_ref[row, :] = jnp.sum(
+            s * _column(ct, pick), axis=0, keepdims=True
+        )
+        return s
+
+    s_ref[...] = _walk(chunk, unroll, step, s_ref[...])
+    y_ref[...] = (yf_ref[...] + d_ref[...] * xf_ref[...]).astype(y_ref.dtype)
+
+
+def _backward_kernel(
+    x_ref, dt_ref, a_ref, bt_ref, ct_ref, d_ref, hs_ref, dy_ref,
+    dx_ref, ddt_ref, da_ref, dbt_ref, dct_ref, dd_ref,
+    g_ref, states_ref, xf_ref, df_ref, dyf_ref, dxf_ref, ddf_ref,
+    *, chunk, unroll,
+):
+    # Grid step c of the last axis is chunk (chunks - 1 - c): the index maps
+    # turn the order round, and ``g_ref`` carries a_{t+1} * dL/ds_{t+1} from
+    # the chunk after this one.
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        g_ref[...] = jnp.zeros_like(g_ref)
+        da_ref[...] = jnp.zeros_like(da_ref)
+        dd_ref[...] = jnp.zeros_like(dd_ref)
+
+    xf_ref[...] = x_ref[...].astype(F32)
+    df_ref[...] = dt_ref[...].astype(F32)
+    dyf_ref[...] = dy_ref[...].astype(F32)
+    a, bt, ct = a_ref[...], bt_ref[...], ct_ref[...]
+    lane = lax.broadcasted_iota(jnp.int32, bt.shape, 1)
+
+    # The chunk's states again, from the state that entered it:
+    # ``states_ref[t]`` is s_{t-1}, ``states_ref[t + 1]`` is s_t.
+    states_ref[0] = hs_ref[...]
+
+    def recompute(t, s):
+        row = pl.ds(t, 1)
+        s = _advance(
+            s, a, df_ref[row, :], xf_ref[row, :], _column(bt, lane == t)
+        )
+        states_ref[t + 1] = s
+        return s
+
+    _walk(chunk, unroll, recompute, hs_ref[...])
+
+    def step(i, carry):
+        h, d_a, d_bt, d_ct = carry
+        t = chunk - 1 - i
+        row = pl.ds(t, 1)
+        pick = lane == t
+        d_t, x_t, dy_t = df_ref[row, :], xf_ref[row, :], dyf_ref[row, :]
+        b_t = _column(bt, pick)
+        decay = jnp.exp(d_t * a)
+        g = dy_t * _column(ct, pick) + h  # dL/ds_t
+        d_ct = jnp.where(
+            pick, jnp.sum(dy_t * states_ref[t + 1], axis=1, keepdims=True),
+            d_ct,
+        )
+        d_bt = jnp.where(
+            pick, jnp.sum(g * (d_t * x_t), axis=1, keepdims=True), d_bt
+        )
+        d_u = jnp.sum(g * b_t, axis=0, keepdims=True)  # to delta_t * x_t
+        d_decay = g * states_ref[t] * decay  # dL/d(delta_t A), elementwise
+        ddf_ref[row, :] = (
+            jnp.sum(d_decay * a, axis=0, keepdims=True) + d_u * x_t
+        )
+        dxf_ref[row, :] = d_u * d_t
+        return decay * g, d_a + d_decay * d_t, d_bt, d_ct
+
+    zeros = jnp.zeros_like(bt)
+    h, d_a, d_bt, d_ct = _walk(
+        chunk, unroll, step, (g_ref[...], jnp.zeros_like(a), zeros, zeros)
+    )
+    g_ref[...] = h
+    da_ref[...] += d_a
+    dbt_ref[...] = d_bt
+    dct_ref[...] = d_ct
+    dd_ref[...] += jnp.sum(dyf_ref[...] * xf_ref[...], axis=0, keepdims=True)
+    dx_ref[...] = (dxf_ref[...] + d_ref[...] * dyf_ref[...]).astype(
+        dx_ref.dtype
+    )
+    ddt_ref[...] = ddf_ref[...].astype(ddt_ref.dtype)
+
+
+def _grid(x, a):
+    """``(sequences, channel blocks, chunks), (chunk, block), sequences a
+    group`` for ``x [S, T, E]`` and ``a [G, N, E]``."""
+    seqs, steps, channels = x.shape
+    chunk, block = chunk_length(steps), channel_block(channels)
+    return (
+        (seqs, channels // block, steps // chunk), (chunk, block),
+        seqs // a.shape[0],
+    )
+
+
+def _params():
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
+
+
+def _folding_peers(fn):
+    """``fn`` over kernel-layout arrays with the ``vmap`` rule of the module
+    docstring: each operand's peer axis is folded into its leading axis
+    (sequences, or groups) by a reshape, one call is made, and the results
+    (all led by sequences) are unfolded.  An operand the ``vmap`` did not
+    batch is the same for every peer and is repeated."""
+    fn = custom_batching.custom_vmap(fn)
+
+    @fn.def_vmap
+    def over_peers(axis_size, in_batched, *args):
+        folded = [
+            (v if batched else jnp.broadcast_to(v, (axis_size,) + v.shape))
+            .reshape((-1,) + v.shape[(2 if batched else 1):])
+            for v, batched in zip(args, in_batched)
+        ]
+        outs = fn(*folded)
+        return tuple(
+            o.reshape((axis_size, -1) + o.shape[1:]) for o in outs
+        ), (True,) * len(outs)
+
+    return fn
+
+
+def _forward_call(interpret: bool, x, delta, a, bt, ct, d):
+    """``(y [S, T, E], states [S, chunks, N, E])``: the scan, and the state
+    entering each chunk."""
+    grid, (chunk, block), per = _grid(x, a)
+    n = a.shape[1]
+    seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, c, j))
+    col = pl.BlockSpec((None, n, chunk), lambda s, j, c: (s, 0, c))
+    scratch = lambda *shape: pltpu.VMEM(shape, F32)
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, chunk=chunk, unroll=_unroll(chunk)),
+        grid=grid,
+        in_specs=[
+            seq, seq,
+            pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
+            col, col,
+            pl.BlockSpec((None, 1, block), lambda s, j, c: (s // per, 0, j)),
+        ],
+        out_specs=[
+            seq,
+            pl.BlockSpec(
+                (None, None, n, block), lambda s, j, c: (s, c, 0, j)
+            ),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((grid[0], grid[2], n, x.shape[2]), F32),
+        ],
+        scratch_shapes=[
+            scratch(n, block), scratch(chunk, block), scratch(chunk, block),
+            scratch(chunk, block),
+        ],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="dpwa_selective_scan_fwd",
+    )(x, delta, a, bt, ct, d)
+
+
+def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
+    """The six gradients, each led by sequences: ``dx``, ``ddelta [S, T,
+    E]``, ``da [S, N, E]`` (a sequence's own sum over time), ``dbt``, ``dct
+    [S, channel blocks, N, T]`` (partial sums a channel block), ``dd [S, 1,
+    E]``."""
+    grid, (chunk, block), per = _grid(x, a)
+    n, last = a.shape[1], grid[2] - 1
+    seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, last - c, j))
+    col = pl.BlockSpec((None, n, chunk), lambda s, j, c: (s, 0, last - c))
+    partial_col = pl.BlockSpec(
+        (None, None, n, chunk), lambda s, j, c: (s, j, 0, last - c)
+    )
+    scratch = lambda *shape: pltpu.VMEM(shape, F32)
+    rows = scratch(chunk, block)
+    seqs, steps, channels = x.shape
+    return pl.pallas_call(
+        functools.partial(
+            _backward_kernel, chunk=chunk, unroll=_unroll(chunk)
+        ),
+        grid=grid,
+        in_specs=[
+            seq, seq,
+            pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
+            col, col,
+            pl.BlockSpec((None, 1, block), lambda s, j, c: (s // per, 0, j)),
+            pl.BlockSpec(
+                (None, None, n, block), lambda s, j, c: (s, last - c, 0, j)
+            ),
+            seq,
+        ],
+        out_specs=[
+            seq, seq,
+            pl.BlockSpec((None, n, block), lambda s, j, c: (s, 0, j)),
+            partial_col, partial_col,
+            pl.BlockSpec((None, 1, block), lambda s, j, c: (s, 0, j)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(delta.shape, delta.dtype),
+            jax.ShapeDtypeStruct((seqs, n, channels), F32),
+            jax.ShapeDtypeStruct((seqs, grid[1], n, steps), F32),
+            jax.ShapeDtypeStruct((seqs, grid[1], n, steps), F32),
+            jax.ShapeDtypeStruct((seqs, 1, channels), F32),
+        ],
+        scratch_shapes=[
+            scratch(n, block), scratch(chunk + 1, n, block),
+            rows, rows, rows, rows, rows,
+        ],
+        compiler_params=_params(),
+        interpret=interpret,
+        name="dpwa_selective_scan_bwd",
+    )(x, delta, a, bt, ct, d, states, dy)
+
+
+def _differentiable(interpret: bool):
+    """:func:`selective_scan`'s signature on the two kernels."""
+    forward = _folding_peers(functools.partial(_forward_call, interpret))
+    backward = _folding_peers(functools.partial(_backward_call, interpret))
+
+    def laid_out(A, Bm, Cm, D):
+        over_lanes = lambda v: jnp.swapaxes(v.astype(F32), 1, 2)
+        return (
+            A.astype(F32).T[None], over_lanes(Bm), over_lanes(Cm),
+            D.astype(F32)[None, None],
+        )
+
+    @jax.custom_vjp
+    def scan(x, delta, A, Bm, Cm, D):
+        return forward(x, delta, *laid_out(A, Bm, Cm, D))[0]
+
+    def fwd(x, delta, A, Bm, Cm, D):
+        y, states = forward(x, delta, *laid_out(A, Bm, Cm, D))
+        return y, (x, delta, A, Bm, Cm, D, states)
+
+    def bwd(residuals, dy):
+        x, delta, A, Bm, Cm, D, states = residuals
+        # A custom gradient's instructions carry no name of the forward's.
+        with jax.named_scope(scopes.SSM_SCAN):
+            dx, ddelta, da, dbt, dct, dd = backward(
+                x, delta, *laid_out(A, Bm, Cm, D), states, dy
+            )
+            back = lambda partials, like: jnp.swapaxes(
+                partials.sum(1), 1, 2
+            ).astype(like.dtype)
+            return (
+                dx, ddelta, da.sum(0).T.astype(A.dtype), back(dbt, Bm),
+                back(dct, Cm), dd.sum((0, 1)).astype(D.dtype),
+            )
+
+    scan.defvjp(fwd, bwd)
+    return scan
+
+
+kernel_scan = _differentiable(interpret=False)
+kernel_scan.__doc__ = """:func:`selective_scan` by the Pallas kernels."""
+# The same kernels run by the Pallas interpreter, for tests off the TPU.
+interpreted_scan = _differentiable(interpret=True)

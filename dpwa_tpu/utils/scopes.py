@@ -33,6 +33,12 @@ MOE_SHARED = "dpwa.moe.shared"
 # projections with their norms, rope, the attention core, the output
 # projection.
 ATTN_LATENT = "dpwa.attn.latent"
+# A state-space mixer whole (``models/llama.MambaMixer``): projections,
+# convolution, inner norms, scan and gate; and inside it the selective scan
+# alone (``ops/ssm.py``: the discretisation and the recurrence, forward and
+# backward kernels).
+SSM = "dpwa.ssm"
+SSM_SCAN = "dpwa.ssm.scan"
 # Likewise nested: the cross-entropy over the vocabulary and its gradient.
 LOSS = "dpwa.loss"
 

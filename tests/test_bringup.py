@@ -329,6 +329,49 @@ def test_a_share_of_the_experts_compiles_a_call_a_peer(v5e_chips):
     assert "bf16[8192,7168]" in text  # the folded rows
 
 
+@pytest.mark.parametrize("T", [1024, 4096])  # the model check's, the cell's
+def test_the_selective_scan_compiles_with_its_state_off_the_hbm(v5e_chips, T):
+    """`ops/ssm.selective_scan` at the published widths (5,120 channels, 16
+    states, bfloat16 stream) under `vmap` over two peers, forward and all six
+    gradients: Mosaic compiles both kernels, the peers are folded into their
+    sequence axis, and no array of the compiled program comes near the `T x
+    5120 x 16` values of the state over time."""
+    import re
+    from unittest import mock
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops import ssm
+
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one
+    )
+
+    def loss(*args):
+        out = jax.vmap(ssm.selective_scan)(*args)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with _no_compile_cache(), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(6)))).lower(
+            shaped((2, 1, T, 5120), jnp.bfloat16), shaped((2, 1, T, 5120)),
+            shaped((2, 5120, 16)), shaped((2, 1, T, 16), jnp.bfloat16),
+            shaped((2, 1, T, 16), jnp.bfloat16), shaped((2, 5120)),
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "dpwa_selective_scan_fwd" in text and "dpwa_selective_scan_bwd" in text
+    assert f"bf16[2,{T},5120]" in text  # the folded sequences
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    ]
+    # The largest is a peer-stacked [2, 1, T, 5120]; the state over time of
+    # one sequence alone would be 8 times that.
+    assert max(sizes) == 2 * T * 5120 < T * 5120 * 16
+
+
 # ---------------------------------------------------------------------------
 # Compile cache
 # ---------------------------------------------------------------------------
