@@ -109,7 +109,9 @@ class LlamaConfig:
     experts_held: Optional[int] = None
     expert_offset: int = 0
     # ``jax.checkpoint`` around every block: a block's activations are
-    # recomputed in the backward pass and only its input is saved.
+    # recomputed in the backward pass and only its input is saved; a Mamba
+    # block also keeps its scan's output and boundary states
+    # (``_checkpoint_policy``).
     remat: bool = False
     # The type base leaves (kernels, norms, embedding, head) are created in;
     # adapters, the router and a Mamba mixer's ``A_log``, ``D`` and
@@ -726,6 +728,19 @@ class Block(nn.Module):
         return x + ffn(_norm(cfg, "mlp_norm")(x))
 
 
+def _checkpoint_policy(cfg: LlamaConfig, index: int):
+    """What ``jax.checkpoint`` around block ``index`` keeps beside the
+    block's input: nothing (None) for an attention block; for a Mamba block
+    what the scan's forward kernel alone produces (``ops/ssm.KEPT``: 105 MB
+    a block at the Jamba cell's shapes), so that the recomputation has
+    nothing to run that kernel for."""
+    from dpwa_tpu.ops import ssm
+
+    if cfg.is_attention_layer(index):
+        return None
+    return jax.checkpoint_policies.save_only_these_names(*ssm.KEPT)
+
+
 class Llama(nn.Module):
     """Decoder-only LM; returns logits [B, T, vocab]."""
 
@@ -759,10 +774,13 @@ class Llama(nn.Module):
         # not on the block's variables: a barrier on a frozen kernel makes a
         # caller that slices one replica out of a stacked tree
         # (``lax.map`` over peers) copy that replica's whole base.
-        block = Block
-        if cfg.remat:
-            block = nn.remat(Block, prevent_cse=(False, False, True, True))
         for i in range(cfg.n_layers):
+            block = Block
+            if cfg.remat:
+                block = nn.remat(
+                    Block, prevent_cse=(False, False, True, True),
+                    policy=_checkpoint_policy(cfg, i),
+                )
             x = block(cfg, i, name=f"layer_{i}")(x, positions)
         x = _norm(cfg, "final_norm")(x)
         if cfg.tie_embeddings:  # x E^T in float32, as the head below

@@ -21,6 +21,11 @@ backward kernel walks the chunks last to first: it recomputes a chunk's
 states from its boundary into VMEM, then walks the chunk backwards carrying
 ``dL/ds`` the other way.  ``dBm`` and ``dCm`` are sums over channels: the
 kernel writes one partial sum a channel block and they are added outside.
+Both of the forward kernel's products carry a ``checkpoint_name``
+(:data:`KEPT`): a ``jax.checkpoint`` whose policy saves those names has
+nothing left to run the forward kernel for when it recomputes, because the
+backward kernel reads the kept states and the inputs alone
+(``models/llama.py`` gives its Mamba blocks that policy).
 There is no division by a cumulative decay anywhere (``exp(-sum delta A)``
 overflows float32 inside one chunk at the published initial values), and the
 recurrence, ``exp`` and every accumulation are float32 whatever type ``x``
@@ -42,12 +47,18 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import custom_batching, lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from dpwa_tpu.utils import scopes
 
 F32 = jnp.float32
+# What the forward kernel alone produces, by the names a checkpoint policy
+# can keep them under: the scan's output and the states entering each chunk.
+KEPT_OUTPUT = "dpwa.ssm.scan.y"
+KEPT_STATES = "dpwa.ssm.scan.states"
+KEPT = (KEPT_OUTPUT, KEPT_STATES)
 
 
 def causal_conv1d(x, w, b):
@@ -391,6 +402,28 @@ def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
     )(x, delta, a, bt, ct, d, states, dy)
 
 
+def _named_bits(value, name: str):
+    """``value`` with ``checkpoint_name`` on an integer view of it.
+    ``jax.checkpoint`` rounds a floating-point array that it saves and the
+    forward pass goes on to use to the array's own type (``reduce_precision``,
+    against excess precision in what XLA fused before it), and then holds
+    the rounded copy beside the array: at the Jamba cell's shapes that put
+    ``y`` twice at the step's memory peak, 0.18 GB over all blocks.  A
+    kernel's output has no excess precision, it is written to memory in its
+    type, so the name goes on its bits, which are saved as they are.
+
+    This leans on jax 0.9.0's ``ad_checkpoint._insert_reduce_precision``,
+    which skips a residual whose type is not ``np.inexact``; it is no
+    documented promise.  ``tests/test_bringup.py`` compiles the step with the
+    name on the float and holds that it passes the memory bound this view
+    keeps: when that fails after an upgrade, name ``y`` itself here."""
+    bits = jnp.dtype(f"uint{8 * value.dtype.itemsize}")
+    return lax.bitcast_convert_type(
+        checkpoint_name(lax.bitcast_convert_type(value, bits), name),
+        value.dtype,
+    )
+
+
 def _differentiable(interpret: bool):
     """:func:`selective_scan`'s signature on the two kernels."""
     forward = _folding_peers(functools.partial(_forward_call, interpret))
@@ -409,7 +442,9 @@ def _differentiable(interpret: bool):
 
     def fwd(x, delta, A, Bm, Cm, D):
         y, states = forward(x, delta, *laid_out(A, Bm, Cm, D))
-        return y, (x, delta, A, Bm, Cm, D, states)
+        # The states go nowhere but to the backward rule: saved as they are.
+        states = checkpoint_name(states, KEPT_STATES)
+        return _named_bits(y, KEPT_OUTPUT), (x, delta, A, Bm, Cm, D, states)
 
     def bwd(residuals, dy):
         x, delta, A, Bm, Cm, D, states = residuals

@@ -372,6 +372,46 @@ def test_the_selective_scan_compiles_with_its_state_off_the_hbm(v5e_chips, T):
     assert max(sizes) == 2 * T * 5120 < T * 5120 * 16
 
 
+def test_what_the_mamba_blocks_keep_does_not_pile_up(v5e_chips, capsys):
+    """The Jamba cell's own step at four layers (Mamba blocks all, each
+    keeping its scan's output and boundary states) compiled for the v5e
+    holds no more than the same step with no block keeping anything plus
+    what the four keep: bfloat16 ``[2, 4096, 5120]`` and float32 ``[2, 32,
+    16, 5120]`` a block, 105 MB.  Not today's number: the guard against a
+    change that makes more than the kept arrays stand at the step's peak.
+    One such is planted: with the name on ``y`` itself ``jax.checkpoint``
+    (jax 0.9.0, ``ad_checkpoint._insert_reduce_precision``) rounds the
+    saved float and ``y`` stands there twice, which breaks the bound; it
+    is what ``ops/ssm._named_bits`` is for, and the day this half fails
+    that function has nothing left to do."""
+    from unittest import mock
+
+    from benchmark import rehearse_compile
+    from dpwa_tpu.models import llama
+    from dpwa_tpu.ops import ssm
+
+    def step_bytes():
+        with _no_compile_cache():
+            assert rehearse_compile.main([
+                "jamba2-lora-period14-stacked2", "--no-reference",
+                "--set", "num_hidden_layers=4",
+            ]) == 0
+        report = json.loads(capsys.readouterr().out.splitlines()[-1])
+        assert report["step"]["tpu_custom_call"]
+        return report["step"]["total_gb"] * 1e9
+
+    kept = step_bytes()
+    with mock.patch.object(ssm, "_named_bits", ssm.checkpoint_name):
+        kept_as_floats = step_bytes()
+    with mock.patch.object(llama, "_checkpoint_policy", lambda cfg, i: None):
+        nothing_kept = step_bytes()
+    a_block = 2 * 4096 * 5120 * 2 + 2 * 32 * 16 * 5120 * 4
+    assert kept <= nothing_kept + 4 * a_block, (kept, nothing_kept)
+    assert kept_as_floats > nothing_kept + 4 * a_block, (
+        kept_as_floats, nothing_kept
+    )
+
+
 # ---------------------------------------------------------------------------
 # Compile cache
 # ---------------------------------------------------------------------------

@@ -9,9 +9,12 @@ Tolerances.  Float32 against float32 differs by the order of summation alone:
 1e-4 of rms holds it (seen: some 1e-6) and fails a term left out (the
 convolution's bias, an inner norm, ``D``: hundredths and more)."""
 
+import contextlib
 import dataclasses
+import functools
 import os
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -94,19 +97,74 @@ def reference_loss(params, tokens, targets):
     return (jax.nn.logsumexp(logits, -1) - picked).mean()
 
 
-@pytest.mark.parametrize("remat", [True, False])
-def test_the_model_equals_the_reference_logits_loss_and_adapter_gradients(
-    seeded, remat
-):
+@contextlib.contextmanager
+def scan_and_checkpoint(scan: str, policy: bool):
+    """The model's scan by the kernels themselves (the Pallas interpreter,
+    four chunks of 8 steps, so that a chunk's boundary state is a computed
+    one) where ``scan`` is "kernels"; and a Mamba block's ``nn.remat``
+    without its policy, as every block had it before, where ``policy`` is
+    false."""
+    from dpwa_tpu.models import llama
+    from dpwa_tpu.ops import ssm
+
+    with contextlib.ExitStack() as stack:
+        if scan == "kernels":
+            stack.enter_context(
+                mock.patch.object(ssm, "selective_scan", ssm.interpreted_scan)
+            )
+            stack.enter_context(
+                mock.patch.object(ssm, "chunk_length", lambda steps: 8)
+            )
+        if not policy:
+            stack.enter_context(mock.patch.object(
+                llama, "_checkpoint_policy", lambda cfg, index: None
+            ))
+        yield
+
+
+# remat, the scan's path, a Mamba block's checkpoint with its policy
+CHECKPOINTS = {
+    "True": (True, "plain", True),
+    "False": (False, "plain", True),
+    "policy-less": (True, "plain", False),
+    "kernels": (True, "kernels", True),
+    "kernels-policy-less": (True, "kernels", False),
+}
+
+
+@pytest.fixture(scope="module")
+def computed(seeded):
+    """``case -> (logits, loss, gradients)`` of the model, each case
+    computed once for the two tests that read it."""
     params, tokens, targets = seeded
-    model = model_of(remat=remat)
-    assert relative(
-        model.apply(params, tokens), plain.forward(CONFIG, params, tokens)
-    ) < 1e-4
-    loss, grads = jax.value_and_grad(loss_of(model))(params, tokens, targets)
-    want, want_grads = jax.value_and_grad(reference_loss)(
-        params, tokens, targets
-    )
+
+    @functools.cache
+    def of(case):
+        remat, scan, policy = CHECKPOINTS[case]
+        model = model_of(remat=remat)
+        with scan_and_checkpoint(scan, policy):
+            return model.apply(params, tokens), *jax.value_and_grad(
+                loss_of(model)
+            )(params, tokens, targets)
+
+    return of
+
+
+@pytest.fixture(scope="module")
+def wanted(seeded):
+    params, tokens, targets = seeded
+    return plain.forward(CONFIG, params, tokens), *jax.value_and_grad(
+        reference_loss
+    )(params, tokens, targets)
+
+
+@pytest.mark.parametrize("case", list(CHECKPOINTS))
+def test_the_model_equals_the_reference_logits_loss_and_adapter_gradients(
+    computed, wanted, case
+):
+    logits, loss, grads = computed(case)
+    want_logits, want, want_grads = wanted
+    assert relative(logits, want_logits) < 1e-4
     assert abs(float(loss) - float(want)) < 1e-5 * float(want)
     got, want_grads = adapters(grads), adapters(want_grads)
     # a and b of: 4 projections x 13 mixers, 4 of the attention layer, 3 of
@@ -115,6 +173,100 @@ def test_the_model_equals_the_reference_logits_loss_and_adapter_gradients(
     for name, grad in got.items():
         assert relative(grad, want_grads[name]) < 1e-4, name
         assert float(jnp.abs(grad).max()) > 0, name
+
+
+@pytest.mark.parametrize("case, others", [
+    ("True", ("policy-less", "False")), ("kernels", ("kernels-policy-less",)),
+])
+def test_what_a_mamba_block_keeps_changes_no_bit_of_a_gradient(
+    computed, case, others
+):
+    """A kept ``y`` and kept boundary states are the first pass's own: the
+    backward pass reads the values a recomputation would have given it, so
+    loss and gradients with the policy, without it and without ``remat``
+    are the same arithmetic."""
+    for other in others:
+        for got, want in zip(
+            jax.tree.leaves(computed(case)), jax.tree.leaves(computed(other))
+        ):
+            np.testing.assert_array_equal(got, want)
+
+
+def lowered_for_the_chip(fn, *args) -> str:
+    """The StableHLO of ``fn`` lowered for a TPU, with the kernels'
+    dispatchers answered as the chip, locations (the ``op_name`` a trace
+    shows) included.  Lowering needs no chip and no TPU compiler."""
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        return jax.jit(fn).trace(*args).lower(
+            lowering_platforms=("tpu",)
+        ).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("policy, recomputed", [
+    (True, []), (False, [0, 1, 3]),
+])
+def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
+    policy, recomputed
+):
+    """Two stacked peers' gradients of a 4-layer hybrid (Mamba, Mamba,
+    attention, Mamba) under ``remat``: the forward scan kernel once a Mamba
+    layer in the forward pass and in no layer's recomputation (in every
+    Mamba layer's where the blocks' ``nn.remat`` has no policy, as before),
+    the backward kernel once a Mamba layer."""
+    import re
+
+    config, cell = builder.rehearse(PUBLISHED, dict(
+        seq_len=128, per_peer_batch=1, peers=2, exchange_filter="lora",
+    ))
+    config = dict(
+        config, hidden_size=128, num_attention_heads=1, attn_layer_period=4,
+        attn_layer_offset=2,
+    )
+    built = builder.build(config, dict(cell, seq_len=128))
+    assert config["assumed"]["remat"]
+    shapes = jax.eval_shape(
+        jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
+    )
+    tokens = jnp.zeros((2, 1, 128), jnp.int32)
+    with scan_and_checkpoint("chip", policy):
+        text = lowered_for_the_chip(
+            jax.vmap(jax.grad(built.loss_fn)), shapes, (tokens, tokens)
+        )
+    calls = re.findall(
+        r'loc\("([^"]*)/layer_(\d)/[^"]*dpwa_selective_scan_(fwd|bwd)/', text
+    )
+    where = lambda kernel, inside: sorted(
+        int(layer) for scope, layer, k in calls
+        if k == kernel and ("rematted_computation" in scope) == inside
+    )
+    assert where("fwd", False) == [0, 1, 3]
+    assert where("fwd", True) == recomputed
+    assert where("bwd", False) == [0, 1, 3] and where("bwd", True) == []
+    assert all(
+        ("transpose(jvp(" in scope) == (k == "bwd" or "remat" in scope)
+        for scope, _, k in calls
+    )
+    # What is kept is saved as it is: no pass over it to round it to its
+    # own type (``ops/ssm._named_bits``).
+    assert "reduce_precision" not in text
+
+
+@pytest.mark.parametrize("changes, keeping", [
+    (dict(), []),  # every accepted decoder: attention in every layer
+    (dict(attn_layer_period=14, attn_layer_offset=7, mamba_dt_rank=8),
+     [0, 1, 2, 3, 4, 5, 6, 8, 9, 10, 11, 12, 13]),
+    (dict(attn_layer_period=2, attn_layer_offset=0, mamba_dt_rank=8),
+     [1, 3, 5, 7, 9, 11, 13]),
+])
+def test_only_a_mamba_block_has_a_checkpoint_policy(
+    changes, keeping
+):
+    from dpwa_tpu.models.llama import _checkpoint_policy
+
+    cfg = LlamaConfig(n_layers=14, remat=True, rope_theta=1e4, **changes)
+    assert [
+        i for i in range(14) if _checkpoint_policy(cfg, i) is not None
+    ] == keeping
 
 
 @pytest.mark.parametrize("left_out", [
