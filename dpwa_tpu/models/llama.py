@@ -713,19 +713,32 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
+        from dpwa_tpu.utils import scopes
+
         cfg = self.cfg
+        # Latent attention, the Mamba mixer and the expert layer name
+        # themselves; plain attention and the dense feed-forward are named
+        # here, because the shared expert is an ``MLP`` too.  Norms and
+        # residual adds stay outside every name.
         if cfg.is_attention_layer(self.index):
-            attention = LatentAttention if cfg.kv_lora_rank else Attention
-            x = x + attention(cfg, name="attn")(
-                _norm(cfg, "attn_norm")(x), positions
-            )
+            h = _norm(cfg, "attn_norm")(x)
+            if cfg.kv_lora_rank:
+                h = LatentAttention(cfg, name="attn")(h, positions)
+            else:
+                with jax.named_scope(scopes.ATTN_GQA):
+                    h = Attention(cfg, name="attn")(h, positions)
+            x = x + h
         else:
             x = x + MambaMixer(cfg, name="mamba")(_norm(cfg, "mamba_norm")(x))
-        if self.index < cfg.n_dense_layers:
-            ffn = MLP(cfg, cfg.d_ff_dense, name="mlp")
+        h = _norm(cfg, "mlp_norm")(x)
+        leading = self.index < cfg.n_dense_layers
+        if cfg.n_experts > 0 and not leading:
+            h = MoE(cfg, name="mlp")(h)
         else:
-            ffn = (MoE if cfg.n_experts > 0 else MLP)(cfg, name="mlp")
-        return x + ffn(_norm(cfg, "mlp_norm")(x))
+            d_ff = cfg.d_ff_dense if leading else None
+            with jax.named_scope(scopes.MLP):
+                h = MLP(cfg, d_ff, name="mlp")(h)
+        return x + h
 
 
 def _checkpoint_policy(cfg: LlamaConfig, index: int):
@@ -748,6 +761,8 @@ class Llama(nn.Module):
 
     @nn.compact
     def __call__(self, tokens):
+        from dpwa_tpu.utils import scopes
+
         cfg = self.cfg
         B, T = tokens.shape
         # The rows are looked up in ``dtype`` and only they are widened: an
@@ -783,16 +798,16 @@ class Llama(nn.Module):
                 )
             x = block(cfg, i, name=f"layer_{i}")(x, positions)
         x = _norm(cfg, "final_norm")(x)
-        if cfg.tie_embeddings:  # x E^T in float32, as the head below
-            return jax.lax.dot_general(
-                x.astype(jnp.float32), embed.embedding.astype(jnp.float32),
-                (((x.ndim - 1,), (1,)), ((), ())),
-            )
-        logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-            param_dtype=cfg.param_dtype, name="lm_head",
-        )(x)
-        return logits
+        with jax.named_scope(scopes.HEAD):
+            if cfg.tie_embeddings:  # x E^T in float32, as the head below
+                return jax.lax.dot_general(
+                    x.astype(jnp.float32), embed.embedding.astype(jnp.float32),
+                    (((x.ndim - 1,), (1,)), ((), ())),
+                )
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, name="lm_head",
+            )(x)
 
 
 def routing_of(intermediates) -> dict:

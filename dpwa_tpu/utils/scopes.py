@@ -1,12 +1,20 @@
 """Names for the parts of a train step, as ``jax.named_scope`` metadata.
 
-Three scopes give four phases.  A scope's name becomes part of the
+Three scopes give the step's four phases: ``dpwa.forward`` (two of them, as
+below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Ten more lie inside the
+forward scope and name the parts of a decoder (``models/llama.py``):
+attention plain and latent, the dense feed-forward, the expert layer's three
+parts, the state-space mixer and its scan, the head, the loss.  The outer
+norms, the embedding and the residual adds carry none: they are what is left
+under ``dpwa.forward``.  A scope's name becomes a component of the
 ``op_name`` of every HLO instruction traced under it, and JAX wraps the name
 when it differentiates: with the loss call under ``dpwa.forward`` inside
 ``jax.value_and_grad``, forward instructions carry ``jvp(dpwa.forward)`` and
-backward instructions ``transpose(jvp(dpwa.forward))``.  The names are
-metadata only: they change no arithmetic and cost nothing when no profiler
-runs.  ``benchmark/scopes.py`` reads them back from a device trace.
+backward instructions ``transpose(jvp(dpwa.forward))``; what a
+``jax.checkpoint`` runs again carries ``rematted_computation`` besides.  The
+names are metadata only: they change no arithmetic and cost nothing when no
+profiler runs.  ``benchmark/scopes.py`` reads the phases back from a device
+trace and ``benchmark/block_scopes.py`` the parts of the decoder.
 
 Not a tracing system (that is :mod:`dpwa_tpu.obs`, on the host): only names.
 """
@@ -29,16 +37,29 @@ MOE_EXPERTS = "dpwa.moe.experts"
 # Beside them, the shared expert that every token takes (a dense SwiGLU); the
 # two names above keep their meaning for the experts a replica holds.
 MOE_SHARED = "dpwa.moe.shared"
+# A plain attention call whole (``models/llama.Attention``, put on in
+# ``Block``): ``wq wk wv`` with their adapters, ``q_norm`` / ``k_norm`` where
+# the configuration has them, rope, the core (``single_device_attention`` or
+# an sp strategy) and ``wo``.  Latent attention has its own name, not both.
+ATTN_GQA = "dpwa.attn.gqa"
 # Latent attention whole (``models/llama.LatentAttention``): the down and up
 # projections with their norms, rope, the attention core, the output
 # projection.
 ATTN_LATENT = "dpwa.attn.latent"
+# A dense SwiGLU feed-forward whole (``models/llama.MLP`` as a layer's
+# feed-forward, put on in ``Block``): ``w_gate``, ``w_up``, ``silu x up``,
+# ``w_down`` and their adapters.  The shared expert is an ``MLP`` too and
+# stays under ``dpwa.moe.shared`` alone.
+MLP = "dpwa.mlp"
 # A state-space mixer whole (``models/llama.MambaMixer``): projections,
 # convolution, inner norms, scan and gate; and inside it the selective scan
 # alone (``ops/ssm.py``: the discretisation and the recurrence, forward and
 # backward kernels).
 SSM = "dpwa.ssm"
 SSM_SCAN = "dpwa.ssm.scan"
+# The projection to the vocabulary (``models/llama.Llama``): ``lm_head``, or
+# ``x E^T`` where the embedding is tied.  ``final_norm`` stays outside.
+HEAD = "dpwa.head"
 # Likewise nested: the cross-entropy over the vocabulary and its gradient.
 LOSS = "dpwa.loss"
 

@@ -312,3 +312,175 @@ def test_scopes_change_no_arithmetic(lowered, builder, only):
     assert arithmetic(with_scopes) != arithmetic(
         lowered(builder, only, True).compile().as_text()
     )
+
+
+# ---- the decoder block names its parts (``models/llama.py``)
+
+# configuration -> what its builder's toy shape keeps of it.
+DECODERS = {
+    "mistral-7b-v0.3-lora": "plain attention, dense layers, an untied head",
+    "olmoe-1b-7b-0125-lora": "QK-norm, expert layers",
+    "axk1-lora": "latent attention, a leading dense layer, a shared expert",
+    "jamba2-3b-lora": "remat, mixers and one attention layer, a tied head",
+}
+BLOCK_NAMES = (scopes.ATTN_GQA, scopes.MLP, scopes.HEAD)
+MOE_NAMES = {scopes.MOE_ROUTE, scopes.MOE_EXPERTS, scopes.MOE_SHARED}
+
+
+def decoder_loss(name):
+    """``(value_and_grad of the loss under dpwa.forward, the builder's init,
+    a batch)`` of a decoder configuration at its builder's toy shape."""
+    import importlib
+
+    from tests.yardstick.yardstick_paths import MANIFEST, load
+
+    entry = next(c for c in MANIFEST["configs"] if c["name"] == name)
+    config = load(entry["file"])
+    family = importlib.import_module("benchmark.builders." + config["family"])
+    toy, cell = family.rehearse(config, dict(
+        seq_len=64, per_peer_batch=2, peers=2, exchange_filter="lora",
+    ))
+    built = family.build(toy, cell)
+    tokens = jax.random.randint(
+        jax.random.key(3), (2, cell["seq_len"] + 1), 0, toy["vocab_size"]
+    )
+    fn = jax.jit(jax.value_and_grad(scopes.scoped_loss(built.loss_fn)))
+    return fn, built.init_fn, (tokens[:, :-1], tokens[:, 1:])
+
+
+@pytest.fixture(scope="module")
+def decoder_dots():
+    """name -> the path components of every ``dot``'s op_name in the lowered
+    loss and gradient."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            fn, init_fn, batch = decoder_loss(name)
+            shapes = jax.eval_shape(init_fn, jax.random.key(0))
+            text = fn.lower(shapes, batch).as_text(dialect="hlo", debug_info=True)
+            cache[name] = [
+                op_name.split("/") for op, op_name in instructions(text)
+                if op == "dot"
+            ]
+        return cache[name]
+
+    return get
+
+
+def under(parts, module, leaves):
+    """Whether an op_name's components hold flax module ``module`` with one
+    of ``leaves`` right below it."""
+    return any(
+        a == module and b in leaves for a, b in zip(parts, parts[1:])
+    )
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_a_dense_feed_forward_lies_under_mlp_and_no_expert_does(
+    decoder_dots, name
+):
+    dots = decoder_dots(name)
+    dense = [p for p in dots if under(p, "mlp", ("w_gate", "w_up", "w_down"))]
+    named = [p for p in dots if scopes.MLP in p]
+    experts = [p for p in dots if MOE_NAMES & set(p)]
+    shared = [p for p in dots if "shared" in p]
+    # The module's own dots, its adapters' included, and nothing else.
+    assert all(scopes.MLP in p for p in dense)
+    assert all("mlp" in p[p.index(scopes.MLP):] for p in named)
+    assert not any(scopes.MLP in p for p in experts + shared)
+    assert all(scopes.MOE_SHARED in p for p in shared)
+    assert bool(dense) == (name != "olmoe-1b-7b-0125-lora")
+    assert bool(experts) == (name in ("olmoe-1b-7b-0125-lora", "axk1-lora"))
+    assert bool(shared) == (name == "axk1-lora")
+    if name == "axk1-lora":  # the leading dense layer alone
+        assert {p[p.index(scopes.MLP) - 1] for p in named} == {"layer_0"}
+    # Forward and backward carry the name alike.
+    assert not named or {True, False} == {
+        any("transpose(" in part for part in p) for p in named
+    }
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_plain_attention_lies_under_gqa_and_latent_attention_does_not(
+    decoder_dots, name
+):
+    dots = decoder_dots(name)
+    attention = [p for p in dots if "attn" in p]
+    named = [p for p in dots if scopes.ATTN_GQA in p]
+    assert attention
+    if name == "axk1-lora":
+        assert not named
+        assert all(scopes.ATTN_LATENT in p for p in attention)
+        return
+    assert not any(scopes.ATTN_LATENT in p for p in dots)
+    assert all(scopes.ATTN_GQA in p for p in attention)
+    assert all("attn" in p[p.index(scopes.ATTN_GQA):] for p in named)
+    for leaf in ("wq", "wk", "wv", "wo"):
+        found = [p for p in dots if under(p, "attn", (leaf,))]
+        assert found and all(scopes.ATTN_GQA in p for p in found), leaf
+    assert not any(scopes.MLP in p or MOE_NAMES & set(p) for p in named)
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_the_head_lies_under_head_tied_or_not(decoder_dots, name):
+    dots = decoder_dots(name)
+    named = [p for p in dots if scopes.HEAD in p]
+    tied = name == "jamba2-3b-lora"
+    # The projection to the vocabulary is the one dot right under the model;
+    # forward, and backward to the stream (a tied table is frozen here too).
+    assert len(named) >= 2
+    assert all(p[p.index(scopes.HEAD) - 1] == "Llama" for p in named)
+    assert all(("lm_head" in p) != tied for p in named)
+    assert all(scopes.HEAD in p for p in dots if "lm_head" in p)
+    others = set(BLOCK_NAMES[:2]) | MOE_NAMES | {
+        scopes.ATTN_LATENT, scopes.SSM, scopes.LOSS
+    }
+    assert not any(others & set(p) for p in named)
+    assert not any("final_norm" in p for p in named)
+
+
+def test_the_names_appear_in_what_a_checkpoint_recomputes(decoder_dots):
+    again = [
+        p for p in decoder_dots("jamba2-3b-lora")
+        if "rematted_computation" in p
+    ]
+    for name in (scopes.ATTN_GQA, scopes.MLP, scopes.SSM):
+        inside = [p for p in again if name in p]
+        assert inside and all(
+            p.index("rematted_computation") < p.index(name) for p in inside
+        ), name
+    # The head is outside every block's checkpoint.
+    assert not any(scopes.HEAD in p for p in again)
+    assert not any(
+        "rematted_computation" in p
+        for p in decoder_dots("mistral-7b-v0.3-lora")
+    )
+
+
+@pytest.mark.parametrize("name", DECODERS)
+def test_block_names_change_no_arithmetic(name):
+    """Loss and gradients equal to the bit, and the compiled program the
+    same, with the three names of the block patched to no-ops."""
+    named_scope = jax.named_scope
+
+    def compiled_and_run():
+        fn, init_fn, batch = decoder_loss(name)
+        params = jax.jit(init_fn)(jax.random.key(0))
+        compiled = fn.lower(params, batch).compile()
+        return compiled.as_text(), compiled(params, batch)
+
+    text, (loss, grads) = compiled_and_run()
+    assert scopes.HEAD in text and any(n in text for n in BLOCK_NAMES[:2])
+    with mock.patch.object(
+        jax, "named_scope",
+        lambda n: contextlib.nullcontext() if n in BLOCK_NAMES
+        else named_scope(n),
+    ):
+        bare_text, (bare_loss, bare_grads) = compiled_and_run()
+    assert not any(n in bare_text for n in BLOCK_NAMES)
+    assert "dpwa.forward" in bare_text
+    assert arithmetic(text) == arithmetic(bare_text)
+    assert float(loss) == float(bare_loss) and jnp.isfinite(loss)
+    for got, want in zip(jax.tree.leaves(grads), jax.tree.leaves(bare_grads)):
+        assert bool((got == want).all())
