@@ -15,13 +15,17 @@ values (1.34 GB a sequence at 4096 x 5120 x 16): nothing here writes it out.
 channel block]`` float32, channels on lanes, and walk time inside the kernel,
 a chunk of ``chunk_length(T)`` steps a grid step, over a grid of (sequence,
 channel block, chunk) whose last axis runs in order with the state kept in
-VMEM between chunks.  The forward kernel writes ``y`` and the state *entering*
-each chunk (``[T / chunk, N, E]``, the only residual beside the inputs).  The
-backward kernel walks the chunks last to first: it recomputes a chunk's
-states from its boundary into VMEM, then walks the chunk backwards carrying
-``dL/ds`` the other way.  ``dBm`` and ``dCm`` are sums over channels: the
-kernel writes one partial sum a channel block and they are added outside.
-Both of the forward kernel's products carry a ``checkpoint_name``
+VMEM between chunks.  A block is ``channel_block(E)`` channels, 1,024 at the
+published 5,120, and a turn of the kernels' loops 16 time steps: both from
+sweeps of the kernels alone on a v5e (PERF.md section 6, PR 35 and PR 40),
+both functions of the shape, and each call asks Mosaic for the VMEM its own
+shapes come to (:func:`vmem_limit`).  The forward kernel writes ``y`` and the
+state *entering* each chunk (``[T / chunk, N, E]``, the only residual beside
+the inputs).  The backward kernel walks the chunks last to first: it
+recomputes a chunk's states from its boundary into VMEM, then walks the chunk
+backwards carrying ``dL/ds`` the other way.  ``dBm`` and ``dCm`` are sums over
+channels: the kernel writes one partial sum a channel block and they are added
+outside.  Both of the forward kernel's products carry a ``checkpoint_name``
 (:data:`KEPT`): a ``jax.checkpoint`` whose policy saves those names has
 nothing left to run the forward kernel for when it recomputes, because the
 backward kernel reads the kept states and the inputs alone
@@ -43,6 +47,7 @@ same kernels on one peer.  Both do the same arithmetic in the same order.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -84,17 +89,37 @@ def chunk_length(steps: int) -> int:
 
 
 def channel_block(channels: int) -> int:
-    """Channels a grid step holds the state of: 512 where that divides them
-    (eight vector registers of state at ``N`` 16), else 256, 128, or all of
-    them where no multiple of the lane width does."""
-    return next((c for c in (512, 256, 128) if channels % c == 0), channels)
+    """Channels a grid step holds the state of: 1,024 where that divides
+    them (sixteen vector registers of state at ``N`` 16), else 512, 256,
+    128, or all of them where no multiple of the lane width does.
+
+    What a grid step and a time step cost whatever the channel count (the
+    pipeline's turn, the casts' set-up, the two column picks, the row
+    addressing) is paid once a block, so the widest block wins until its
+    scratch outgrows the VMEM: on a v5e at the published widths (one layer's
+    call under ``vmap``, 2 x 1 x 4096 x 5120, forward / forward + backward
+    ms, 8 steps a turn) 256 channels 3.42 / 12.20, 512 2.53 / 8.74, 1,024
+    2.20 / 7.58 (PERF.md section 6, PR 35), and by another harness 512 3.07
+    / 10.33, 1,024 2.74 / 9.24 (section 6, PR 40).  Past 1,024 nothing
+    divides 5,120, and the backward kernel's scratch doubles again
+    (:func:`vmem_limit`)."""
+    return next(
+        (c for c in (1024, 512, 256, 128) if channels % c == 0), channels
+    )
 
 
 def _unroll(chunk: int) -> int:
-    """Time steps a turn of the kernels' loops holds: on a v5e at the
-    published widths 8 took three quarters of the time of 4, which took a
-    third of 1 (PERF.md section 6, PR 35)."""
-    return min(chunk, 8)
+    """Time steps a turn of the kernels' loops holds.  On a v5e at the
+    published widths and 512 channels a block 8 took three quarters of the
+    time of 4, which took a third of 1 (PERF.md section 6, PR 35); at 1,024
+    channels (forward / forward + backward ms of one layer's call, PR 40's
+    harness) 8 reads 2.74 / 9.24, 16 2.56 / 8.49 and 32 2.42 / 8.39, the
+    kernels' compile 3.3, 5.2 and 11 s (PERF.md section 6, PR 40): 16,
+    because the backward kernel gains nothing more from 32 and pays twice
+    the compile; in the Jamba cell 16 costs 3 s of set-up from the cache and
+    buys 1.2 % of the step.  The arithmetic and its order are the same at
+    any value."""
+    return min(chunk, 16)
 
 
 def _use_kernels(steps: int, channels: int) -> bool:
@@ -282,10 +307,71 @@ def _grid(x, a):
     )
 
 
-def _params():
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+# Mosaic's own scoped limit on a v5e, of the 128 MiB a core has.
+DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
+
+
+def _forward_scratch(chunk: int, n: int, block: int) -> list:
+    """The forward kernel's float32 scratch, as shapes: the state, and a
+    chunk's rows of ``x``, ``delta`` and ``y``."""
+    return [(n, block)] + 3 * [(chunk, block)]
+
+
+def _backward_scratch(chunk: int, n: int, block: int) -> list:
+    """The backward kernel's: the state's gradient, a chunk's recomputed
+    states with the one that entered it, and a chunk's rows of ``x``,
+    ``delta``, ``dy``, ``dx`` and ``ddelta``."""
+    return [(n, block), (chunk + 1, n, block)] + 5 * [(chunk, block)]
+
+
+def vmem_need(scratch, specs, types) -> int:
+    """Bytes of VMEM a call holds by its shapes: its float32 ``scratch``,
+    and the block of every operand and result (``specs`` with their
+    ``types``) twice, the pipeline fetching one grid step's while the kernel
+    works on another's."""
+    return sum(4 * math.prod(shape) for shape in scratch) + 2 * sum(
+        math.prod(d for d in spec.block_shape if d is not None)
+        * jnp.dtype(t).itemsize
+        for spec, t in zip(specs, types)
     )
+
+
+def vmem_limit(need: int) -> int:
+    """What a call asks Mosaic for: a quarter over what its shapes need, for
+    what the compiler adds of its own (spilled registers, the loops'
+    temporaries), and never under Mosaic's default.  At 1,024 channels a
+    block, a chunk of 128 and ``N`` 16 the backward call's shapes come to
+    15.3 MB and Mosaic allots 16.2 (compile-only, PERF.md section 6, PR
+    40): under the default by 0.6 MB, so the call names its own limit, 19.1
+    MB, and a wider state (``N`` 32) or another row buffer moves the limit
+    with it instead of failing on the chip."""
+    return max(DEFAULT_VMEM_LIMIT, need + need // 4)
+
+
+def _kernel_call(
+    kernel, name, interpret, grid, chunk, scratch, operands,
+    *, in_specs, out_specs, out_shape,
+):
+    """One ``pallas_call`` of ``kernel`` over ``grid`` with float32
+    ``scratch`` (shapes) and the VMEM limit its own shapes come to."""
+    need = vmem_need(
+        scratch, in_specs + out_specs,
+        [v.dtype for v in (*operands, *out_shape)],
+    )
+    return pl.pallas_call(
+        functools.partial(kernel, chunk=chunk, unroll=_unroll(chunk)),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM(shape, F32) for shape in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit(need),
+        ),
+        interpret=interpret,
+        name=name,
+    )(*operands)
 
 
 def _folding_peers(fn):
@@ -318,10 +404,9 @@ def _forward_call(interpret: bool, x, delta, a, bt, ct, d):
     n = a.shape[1]
     seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, c, j))
     col = pl.BlockSpec((None, n, chunk), lambda s, j, c: (s, 0, c))
-    scratch = lambda *shape: pltpu.VMEM(shape, F32)
-    return pl.pallas_call(
-        functools.partial(_forward_kernel, chunk=chunk, unroll=_unroll(chunk)),
-        grid=grid,
+    return _kernel_call(
+        _forward_kernel, "dpwa_selective_scan_fwd", interpret, grid, chunk,
+        _forward_scratch(chunk, n, block), (x, delta, a, bt, ct, d),
         in_specs=[
             seq, seq,
             pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
@@ -338,14 +423,7 @@ def _forward_call(interpret: bool, x, delta, a, bt, ct, d):
             jax.ShapeDtypeStruct(x.shape, x.dtype),
             jax.ShapeDtypeStruct((grid[0], grid[2], n, x.shape[2]), F32),
         ],
-        scratch_shapes=[
-            scratch(n, block), scratch(chunk, block), scratch(chunk, block),
-            scratch(chunk, block),
-        ],
-        compiler_params=_params(),
-        interpret=interpret,
-        name="dpwa_selective_scan_fwd",
-    )(x, delta, a, bt, ct, d)
+    )
 
 
 def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
@@ -360,14 +438,11 @@ def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
     partial_col = pl.BlockSpec(
         (None, None, n, chunk), lambda s, j, c: (s, j, 0, last - c)
     )
-    scratch = lambda *shape: pltpu.VMEM(shape, F32)
-    rows = scratch(chunk, block)
     seqs, steps, channels = x.shape
-    return pl.pallas_call(
-        functools.partial(
-            _backward_kernel, chunk=chunk, unroll=_unroll(chunk)
-        ),
-        grid=grid,
+    return _kernel_call(
+        _backward_kernel, "dpwa_selective_scan_bwd", interpret, grid, chunk,
+        _backward_scratch(chunk, n, block),
+        (x, delta, a, bt, ct, d, states, dy),
         in_specs=[
             seq, seq,
             pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
@@ -392,14 +467,7 @@ def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
             jax.ShapeDtypeStruct((seqs, grid[1], n, steps), F32),
             jax.ShapeDtypeStruct((seqs, 1, channels), F32),
         ],
-        scratch_shapes=[
-            scratch(n, block), scratch(chunk + 1, n, block),
-            rows, rows, rows, rows, rows,
-        ],
-        compiler_params=_params(),
-        interpret=interpret,
-        name="dpwa_selective_scan_bwd",
-    )(x, delta, a, bt, ct, d, states, dy)
+    )
 
 
 def _named_bits(value, name: str):

@@ -251,6 +251,96 @@ def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
     assert "reduce_precision" not in text
 
 
+def pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from pallas_calls(sub)
+
+
+def test_both_scan_kernels_carry_the_rules_block_and_a_limit_over_their_shapes():
+    """At the published shape (2 peers x 1 x 4096 x 5120, ``N`` 16; shapes
+    alone, no chip and no TPU compiler) both scan kernels are called with
+    the channel block ``ops/ssm.channel_block`` gives, 1,024, and ask Mosaic
+    for more VMEM than their own shapes come to: the scratch each call
+    really has (read off the traced call, not off ``ops/ssm``'s reckoning)
+    plus every operand's and result's block twice.  A scratch buffer added
+    or widened without the reckoning following it fails here and not on the
+    chip; so does one that outgrows the VMEM a v5e core has."""
+    import re
+
+    from dpwa_tpu.ops import ssm
+
+    steps, channels, states = 4096, 5120, 16
+    shaped = jax.ShapeDtypeStruct
+    args = (
+        shaped((2, 1, steps, channels), jnp.bfloat16),
+        shaped((2, 1, steps, channels), jnp.float32),
+        shaped((2, channels, states), jnp.float32),
+        shaped((2, 1, steps, states), jnp.bfloat16),
+        shaped((2, 1, steps, states), jnp.bfloat16),
+        shaped((2, channels), jnp.float32),
+    )
+
+    def loss(*a):
+        return jax.vmap(ssm.selective_scan)(*a).astype(jnp.float32).sum()
+
+    grad = jax.grad(loss, argnums=tuple(range(6)))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        calls = {
+            eqn.params["name"]: eqn.params
+            for eqn in pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr)
+        }
+    text = lowered_for_the_chip(grad, *args)
+    assert sorted(calls) == [
+        "dpwa_selective_scan_bwd", "dpwa_selective_scan_fwd",
+    ]
+    chunk, block = ssm.chunk_length(steps), ssm.channel_block(channels)
+    assert (chunk, block) == (128, 1024)
+    size = lambda shape: int(np.prod(shape))
+    asked = {}
+    for name, reckoned in (
+        ("dpwa_selective_scan_fwd", ssm._forward_scratch),
+        ("dpwa_selective_scan_bwd", ssm._backward_scratch),
+    ):
+        mapping = calls[name]["grid_mapping"]
+        assert mapping.grid == (2, channels // block, steps // chunk)
+        blocks = [
+            (
+                [d.block_size for d in b.block_shape if hasattr(d, "block_size")],
+                b.array_aval.dtype,
+            )
+            for b in mapping.block_mappings
+        ]
+        # x, delta (and dy, dx, ddelta) move a chunk of the block's channels.
+        assert blocks[0] == ([chunk, block], jnp.bfloat16)
+        assert blocks[1] == ([chunk, block], jnp.float32)
+        assert all(shape[-1] in (block, chunk) for shape, _ in blocks)
+        scratch = [
+            v.aval for v in
+            calls[name]["jaxpr"].invars[-mapping.num_scratch_operands:]
+        ]
+        assert [a.shape for a in scratch] == reckoned(chunk, states, block)
+        held = sum(size(a.shape) * a.dtype.itemsize for a in scratch) + 2 * sum(
+            size(shape) * dtype.itemsize for shape, dtype in blocks
+        )
+        limit = calls[name]["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+        # A v5e core has 128 MiB of VMEM; Mosaic's default scope is 16.
+        assert held < limit <= 128 * 2 ** 20, (name, held, limit)
+        assert limit == ssm.vmem_limit(held) >= ssm.DEFAULT_VMEM_LIMIT
+        asked[name] = (held, limit)
+    # The states of a chunk are most of it: (chunk + 1) x N x block float32.
+    assert asked["dpwa_selective_scan_bwd"][0] > 129 * 16 * 1024 * 4
+    # What was asked reaches the compiler: the lowered calls carry it, as
+    # the size of their scoped memory.
+    assert sorted(
+        int(n) for n in
+        re.findall(r'scoped_memory_configs[^\]]*?size\\22: (\d+)', text)
+    ) == sorted(limit for _, limit in asked.values())
+
+
 @pytest.mark.parametrize("changes, keeping", [
     (dict(), []),  # every accepted decoder: attention in every layer
     (dict(attn_layer_period=14, attn_layer_offset=7, mamba_dt_rank=8),

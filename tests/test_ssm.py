@@ -2,6 +2,8 @@
 plain twin against a token-by-token reference, forward and all six gradients;
 the vmap rule; the causal convolution."""
 
+from unittest import mock
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -136,8 +138,74 @@ def test_vmap_over_two_peers_equals_a_loop_over_them(implementation):
             assert same(t[i], g), name
 
 
+def two_peers_of_two_wide_blocks():
+    """Two peers x 1 x T 16 x E 2,048, N 16: two channel blocks of 1,024."""
+    peers = [arguments(seed, 1, 16, 2048, 16) for seed in (11, 12)]
+    stacked = [jnp.stack(pair) for pair in zip(*peers)]
+    weights = jax.random.normal(jax.random.key(13), stacked[0].shape)
+    return stacked, weights
+
+
+def stacked_value_and_grads(fn, stacked, weights):
+    """``(y, (value, grads))`` of ``fn`` under ``vmap`` over the peers, each
+    call traced afresh (the block is read when the kernels are traced)."""
+    both = lambda *a: jax.vmap(fn)(*a)
+    return jax.jit(both)(*stacked), jax.jit(jax.value_and_grad(
+        lambda *a: (both(*a) * weights).sum(), argnums=tuple(range(6)),
+    ))(*stacked)
+
+
+def test_two_blocks_of_1024_channels_under_vmap_against_the_plain_twin():
+    """The widest block the rule gives, twice over the channels, two peers
+    folded into the sequence axis: the value and every gradient as tightly
+    as one narrow block is held above.  ``dBm`` and ``dCm`` are sums over
+    2,048 channels, here two partial sums of 1,024 lanes added outside the
+    kernel: float32 rounding."""
+    stacked, weights = two_peers_of_two_wide_blocks()
+    assert stacked[0].shape[-1] // ssm.channel_block(2048) == 2
+    got, (value, grads) = stacked_value_and_grads(
+        ssm.interpreted_scan, stacked, weights
+    )
+    want, (want_value, want_grads) = stacked_value_and_grads(
+        ssm.plain_scan, stacked, weights
+    )
+    assert off(got, want) < TOLERANCE
+    assert abs(float(value - want_value)) < TOLERANCE * float(
+        jnp.abs(want * weights).sum()
+    )
+    for name, g, w in zip(NAMES, grads, want_grads):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert off(g, w) < (2e-6 if name in ("Bm", "Cm") else TOLERANCE), name
+
+
+def test_the_block_moves_no_bit_but_in_the_sums_over_channels():
+    """Channels are independent in the recurrence: at 512 channels a block
+    (the rule until PR 40) and at 1,024 the kernels give the same ``y``,
+    ``dx``, ``ddelta``, ``dA`` and ``dD`` to the bit; ``dBm`` and ``dCm``
+    are four partial sums added outside where they are two, so they differ
+    by the order of a float32 sum and nothing else."""
+    stacked, weights = two_peers_of_two_wide_blocks()
+    wide_y, (_, wide) = stacked_value_and_grads(
+        ssm.interpreted_scan, stacked, weights
+    )
+    with mock.patch.object(ssm, "channel_block", lambda channels: 512):
+        narrow_y, (_, narrow) = stacked_value_and_grads(
+            ssm.interpreted_scan, stacked, weights
+        )
+    assert bool((wide_y == narrow_y).all())
+    for name, g, w in zip(NAMES, wide, narrow):
+        if name in ("Bm", "Cm"):
+            # Another order of the sum did move some bit: two programs.
+            assert 0 < off(g, w) < 1e-6, name
+        else:
+            assert bool((g == w).all()), name
+
+
 def test_an_operand_the_vmap_did_not_batch_is_every_peers():
-    x, delta, A, Bm, Cm, D = arguments(3, 1, 16, 128, 4)
+    # Two turns of the kernels' loop: where a chunk is one turn (8 steps
+    # until PR 40, 16 since) XLA's CPU backend inlines the loop and fuses
+    # the folded call and the single one differently, by one rounding.
+    x, delta, A, Bm, Cm, D = arguments(3, 1, 2 * ssm._unroll(128), 128, 4)
     xs = jnp.stack([x, 2 * x])
     got = jax.vmap(
         ssm.interpreted_scan, in_axes=(0, None, None, None, None, None)
@@ -182,7 +250,8 @@ def test_the_chunk_is_a_function_of_the_shape(steps, chunk):
 
 
 @pytest.mark.parametrize("channels,block", [
-    (5120, 512), (768, 256), (384, 128), (64, 64),
+    (5120, 1024), (2048, 1024), (1536, 512), (768, 256), (384, 128),
+    (64, 64),
 ])
 def test_the_channel_block_is_a_function_of_the_shape(channels, block):
     assert ssm.channel_block(channels) == block
