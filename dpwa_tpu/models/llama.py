@@ -139,6 +139,25 @@ class LlamaConfig:
     mamba_expand: int = 2
     # The head is the embedding (``logits = x E^T``): no ``lm_head`` leaf.
     tie_embeddings: bool = False
+    # EVA attention (the published EvaByte block): ``eva_window`` > 0 replaces
+    # :class:`Attention` by :class:`EvaAttention`: a query sees the positions
+    # of its own window of ``eva_window`` exactly and of every earlier window
+    # one summary a chunk of ``eva_chunk`` positions, under one softmax
+    # (``ops/eva.py``).
+    eva_window: int = 0
+    eva_chunk: int = 0
+    # The head has ``vocab_size x n_pred_heads`` columns and the logits come
+    # back ``[B, T, n_pred_heads, vocab]``: head ``i`` at position ``t``
+    # predicts token ``t + 1 + i``.  1: ``[B, T, vocab]`` as ever.
+    n_pred_heads: int = 1
+    # ``norm(x) = x / rms(x) * (1 + scale)`` with ``scale`` born zero, the
+    # product taken in float32 (``norm_add_unit_offset``).
+    norm_unit_offset: bool = False
+    # The residual stream and its two adds a block are float32 whatever
+    # ``dtype`` says, and everything else stays ``dtype``: the norms read the
+    # wide stream and hand on ``dtype`` (``fp32_skip_add`` with ``fp32_ln``
+    # false, as the EvaByte configuration publishes them).
+    fp32_skip_add: bool = False
 
     def __post_init__(self):
         if self.attn_layer_period:
@@ -171,8 +190,26 @@ class LlamaConfig:
             )
         if self.kv_lora_rank and self.sp_axis is not None:
             raise ValueError("latent attention has no sequence-parallel path")
+        if self.eva_window:
+            if self.eva_chunk < 1 or self.eva_window % self.eva_chunk:
+                raise ValueError(
+                    f"eva_chunk {self.eva_chunk} does not divide eva_window "
+                    f"{self.eva_window}"
+                )
+            if self.sp_axis is not None or self.kv_lora_rank:
+                raise ValueError(
+                    "EVA attention has no sequence-parallel path and is no "
+                    "latent attention"
+                )
         if self.activation_dtype is not None and not self.kv_lora_rank:
             raise ValueError("activation_dtype needs latent attention")
+        if self.n_pred_heads < 1 or (
+            self.n_pred_heads > 1 and self.tie_embeddings
+        ):
+            raise ValueError(
+                "n_pred_heads is at least 1, and more than one head needs an "
+                "lm_head of its own"
+            )
         if self.n_experts and not 0 < self.n_experts_per_tok <= self.n_experts:
             raise ValueError(
                 f"n_experts_per_tok must lie in 1..{self.n_experts}, got "
@@ -216,8 +253,14 @@ class LlamaConfig:
         return not period or index % period == self.attn_layer_offset
 
     @property
-    def stream_dtype(self) -> jnp.dtype:
+    def norm_dtype(self) -> jnp.dtype:
+        """What a norm hands on."""
         return self.activation_dtype or self.dtype
+
+    @property
+    def stream_dtype(self) -> jnp.dtype:
+        """Of the residual stream."""
+        return jnp.float32 if self.fp32_skip_add else self.norm_dtype
 
     @property
     def held_experts(self) -> int:
@@ -323,14 +366,23 @@ class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
     param_dtype: jnp.dtype = jnp.float32
+    # ``x / rms(x) * (1 + scale)``, ``scale`` born zero, one float32 product.
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
-            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+            "scale",
+            nn.initializers.zeros if self.unit_offset else nn.initializers.ones,
+            (x.shape[-1],), self.param_dtype,
         )
         var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        return (x * jax.lax.rsqrt(var + self.eps)).astype(self.dtype) * scale
+        normed = x * jax.lax.rsqrt(var + self.eps)
+        if self.unit_offset:
+            return (normed * (1.0 + scale.astype(jnp.float32))).astype(
+                self.dtype
+            )
+        return normed.astype(self.dtype) * scale
 
 
 def rope_frequencies(
@@ -376,6 +428,38 @@ def rope(
     return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
+def rope_in_place(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
+    """:func:`rope` without scaling, value for value, on ``x [..., H, T, D]``
+    (heads before positions) with every array kept that shape: a pair's
+    partner (``-x[2i+1]`` for lane ``2i``, ``x[2i]`` for lane ``2i+1``)
+    comes from one matmul by a constant ``[D, D]`` matrix of 0 and +-1,
+    which is exact, where :func:`rope` cuts the pairs apart (``x[...,
+    ::2]``) and stacks them back.  XLA:TPU lays the stacked ``[..., D/2,
+    2]`` arrays out padded fourfold and turns the strided slice's gradient
+    into scatters: at 2 x 16,384 x 32 x 128 that is 2 GiB for each 0.5 GiB
+    array (the step's memory report, PERF.md section 6, PR 42); a roll
+    along the lanes is written out as two padded slices likewise.  The
+    plain decoders keep :func:`rope`: their compiled programs are held to
+    the bit."""
+    d = x.shape[-1]
+    angles = positions[..., None].astype(jnp.float32) * rope_frequencies(
+        d, theta
+    )
+    per_lane = lambda z: jnp.repeat(z, 2, axis=-1)[..., None, :, :]  # [1, T, D]
+    lane = jnp.arange(d)
+    swap = (lane[:, None] ^ 1) == lane  # [from, to]: the pair's other lane
+    partner_of = jnp.where(swap, jnp.where(lane % 2 == 0, -1.0, 1.0), 0.0)
+    partner = jnp.dot(  # +-x: exact in x's own type
+        x, partner_of.astype(x.dtype), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=x.dtype,
+    )
+    out = (
+        x.astype(jnp.float32) * per_lane(jnp.cos(angles))
+        + partner.astype(jnp.float32) * per_lane(jnp.sin(angles))
+    )
+    return out.astype(x.dtype)
+
+
 def _dense(cfg: LlamaConfig, features: int, name: str) -> LoRADense:
     return LoRADense(
         features, cfg.lora_rank, cfg.lora_alpha, cfg.dtype, cfg.param_dtype,
@@ -384,7 +468,10 @@ def _dense(cfg: LlamaConfig, features: int, name: str) -> LoRADense:
 
 
 def _norm(cfg: LlamaConfig, name: str) -> RMSNorm:
-    return RMSNorm(cfg.norm_eps, cfg.stream_dtype, cfg.param_dtype, name=name)
+    return RMSNorm(
+        cfg.norm_eps, cfg.norm_dtype, cfg.param_dtype, cfg.norm_unit_offset,
+        name=name,
+    )
 
 
 class Attention(nn.Module):
@@ -510,6 +597,55 @@ class LatentAttention(nn.Module):
                 q, k, v, causal=True, impl=cfg.attn_impl, sm_scale=sm_scale
             )
             return _dense(cfg, cfg.d_model, "wo")(out.reshape(B, T, H * dv))
+
+
+def _eva_init(key, shape, dtype=jnp.float32):
+    """A standard normal clipped to +-1, times ``head size^-1/2``."""
+    draw = jnp.clip(jax.random.normal(key, shape, jnp.float32), -1.0, 1.0)
+    return (draw * shape[-1] ** -0.5).astype(dtype)
+
+
+class EvaAttention(nn.Module):
+    """EVA attention as a training step runs it (no cache): ``q``, ``k``,
+    ``v`` of ``n_heads`` heads each, rope on all of ``q`` and ``k``; of each
+    chunk of ``eva_chunk`` positions one pooled key and value, by a softmax
+    of ``k . adaptive_phi`` with ``adaptive_mu_k`` added to the pooled key
+    (both ``[heads, head size]``, base leaves); a query attends to its own
+    window of ``eva_window`` exactly and to the summaries of every earlier
+    window under one softmax (``ops/eva.py`` has the equations and the
+    kernels); ``W_o``.  Adapters on the four projections."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions):
+        from dpwa_tpu.ops import eva
+
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H, D = cfg.n_heads, cfg.head_dim
+        # Heads before positions from here to ``W_o``, as ``ops/eva.py``
+        # takes them: each of ``q``, ``k``, ``v`` and ``o`` is turned once.
+        heads = lambda name: jnp.swapaxes(
+            _dense(cfg, H * D, name)(x).reshape(B, T, H, D), 1, 2
+        )
+        q, k, v = heads("wq"), heads("wk"), heads("wv")
+        if cfg.rope_theta is not None:
+            q = rope_in_place(q, positions, cfg.rope_theta)
+            k = rope_in_place(k, positions, cfg.rope_theta)
+        learned = lambda name: self.param(
+            name, _eva_init, (H, D), cfg.param_dtype
+        )
+        ksum, vsum = eva.chunk_summaries(
+            k, v, learned("adaptive_phi"), learned("adaptive_mu_k"),
+            cfg.eva_chunk,
+        )
+        out = eva.eva_attention(
+            q, k, v, ksum, vsum, window=cfg.eva_window, chunk=cfg.eva_chunk
+        )
+        return _dense(cfg, cfg.d_model, "wo")(
+            jnp.swapaxes(out, 1, 2).reshape(B, T, H * D)
+        )
 
 
 def _dt_bias_init(key, shape, dtype=jnp.float32):
@@ -717,13 +853,16 @@ class Block(nn.Module):
 
         cfg = self.cfg
         # Latent attention, the Mamba mixer and the expert layer name
-        # themselves; plain attention and the dense feed-forward are named
-        # here, because the shared expert is an ``MLP`` too.  Norms and
+        # themselves; plain and EVA attention and the dense feed-forward are
+        # named here, because the shared expert is an ``MLP`` too.  Norms and
         # residual adds stay outside every name.
         if cfg.is_attention_layer(self.index):
             h = _norm(cfg, "attn_norm")(x)
             if cfg.kv_lora_rank:
                 h = LatentAttention(cfg, name="attn")(h, positions)
+            elif cfg.eva_window:
+                with jax.named_scope(scopes.ATTN_EVA.whole):
+                    h = EvaAttention(cfg, name="attn")(h, positions)
             else:
                 with jax.named_scope(scopes.ATTN_GQA):
                     h = Attention(cfg, name="attn")(h, positions)
@@ -755,7 +894,8 @@ def _checkpoint_policy(cfg: LlamaConfig, index: int):
 
 
 class Llama(nn.Module):
-    """Decoder-only LM; returns logits [B, T, vocab]."""
+    """Decoder-only LM; returns logits ``[B, T, vocab]``, or ``[B, T,
+    n_pred_heads, vocab]`` where the configuration has several heads."""
 
     cfg: LlamaConfig
 
@@ -804,10 +944,13 @@ class Llama(nn.Module):
                     x.astype(jnp.float32), embed.embedding.astype(jnp.float32),
                     (((x.ndim - 1,), (1,)), ((), ())),
                 )
-            return nn.Dense(
-                cfg.vocab_size, use_bias=False, dtype=jnp.float32,
-                param_dtype=cfg.param_dtype, name="lm_head",
+            logits = nn.Dense(
+                cfg.vocab_size * cfg.n_pred_heads, use_bias=False,
+                dtype=jnp.float32, param_dtype=cfg.param_dtype, name="lm_head",
             )(x)
+            if cfg.n_pred_heads == 1:
+                return logits
+            return logits.reshape(B, T, cfg.n_pred_heads, cfg.vocab_size)
 
 
 def routing_of(intermediates) -> dict:

@@ -374,7 +374,7 @@ def _kernel_call(
     )(*operands)
 
 
-def _folding_peers(fn):
+def folding_peers(fn):
     """``fn`` over kernel-layout arrays with the ``vmap`` rule of the module
     docstring: each operand's peer axis is folded into its leading axis
     (sequences, or groups) by a reshape, one call is made, and the results
@@ -494,8 +494,8 @@ def _named_bits(value, name: str):
 
 def _differentiable(interpret: bool):
     """:func:`selective_scan`'s signature on the two kernels."""
-    forward = _folding_peers(functools.partial(_forward_call, interpret))
-    backward = _folding_peers(functools.partial(_backward_call, interpret))
+    forward = folding_peers(functools.partial(_forward_call, interpret))
+    backward = folding_peers(functools.partial(_backward_call, interpret))
 
     def laid_out(A, Bm, Cm, D):
         over_lanes = lambda v: jnp.swapaxes(v.astype(F32), 1, 2)

