@@ -413,39 +413,28 @@ def rope_frequencies(
 
 def rope(
     x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-    scaling: Optional[YarnScaling] = None,
+    scaling: Optional[YarnScaling] = None, *, heads_axis: int = -2,
 ) -> jnp.ndarray:
-    """Rotary embedding over the last (head_dim) axis. x: [..., T, H, D]."""
-    freqs = rope_frequencies(x.shape[-1], theta, scaling)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # [T, D/2]
-    cos = jnp.cos(angles)[..., None, :]  # [T, 1, D/2]
-    sin = jnp.sin(angles)[..., None, :]
-    if scaling is not None:
-        cos, sin = (z * scaling.embedding_scale for z in (cos, sin))
-    x1, x2 = x[..., ::2], x[..., 1::2]
-    out1 = x1 * cos - x2 * sin
-    out2 = x1 * sin + x2 * cos
-    return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
-
-
-def rope_in_place(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
-    """:func:`rope` without scaling, value for value, on ``x [..., H, T, D]``
-    (heads before positions) with every array kept that shape: a pair's
-    partner (``-x[2i+1]`` for lane ``2i``, ``x[2i]`` for lane ``2i+1``)
-    comes from one matmul by a constant ``[D, D]`` matrix of 0 and +-1,
-    which is exact, where :func:`rope` cuts the pairs apart (``x[...,
-    ::2]``) and stacks them back.  XLA:TPU lays the stacked ``[..., D/2,
-    2]`` arrays out padded fourfold and turns the strided slice's gradient
-    into scatters: at 2 x 16,384 x 32 x 128 that is 2 GiB for each 0.5 GiB
-    array (the step's memory report, PERF.md section 6, PR 42); a roll
-    along the lanes is written out as two padded slices likewise.  The
-    plain decoders keep :func:`rope`: their compiled programs are held to
-    the bit."""
+    """Rotary embedding over the last (head_dim) axis of ``x [..., T, H, D]``,
+    or of ``x [..., H, T, D]`` (heads before positions) with ``heads_axis``
+    -3, with every array kept ``x``'s shape: a pair's partner (``-x[2i+1]``
+    for lane ``2i``, ``x[2i]`` for lane ``2i+1``) comes from one matmul by a
+    constant ``[D, D]`` matrix of 0 and +-1, which is exact, and not from
+    cutting the pairs apart by strided slices and stacking them back.
+    XLA:TPU lays a stacked ``[..., D/2, 2]`` array out padded fourfold and
+    turns a strided slice into a gather and its gradient into scatters: nine
+    passes over ``q`` a layer forward and as many backward at 2 x 4,096 x 32
+    x 128, 2 GiB for each 0.5 GiB array at 2 x 16,384 x 32 x 128 (PERF.md
+    section 6, PR 42 and PR 43); a roll along the lanes is written out as
+    two padded slices likewise."""
     d = x.shape[-1]
     angles = positions[..., None].astype(jnp.float32) * rope_frequencies(
-        d, theta
-    )
-    per_lane = lambda z: jnp.repeat(z, 2, axis=-1)[..., None, :, :]  # [1, T, D]
+        d, theta, scaling
+    )  # [..., T, D/2]
+    per_lane = lambda z: jnp.expand_dims(jnp.repeat(z, 2, axis=-1), heads_axis)
+    cos, sin = per_lane(jnp.cos(angles)), per_lane(jnp.sin(angles))
+    if scaling is not None:
+        cos, sin = (z * scaling.embedding_scale for z in (cos, sin))
     lane = jnp.arange(d)
     swap = (lane[:, None] ^ 1) == lane  # [from, to]: the pair's other lane
     partner_of = jnp.where(swap, jnp.where(lane % 2 == 0, -1.0, 1.0), 0.0)
@@ -453,10 +442,7 @@ def rope_in_place(x: jnp.ndarray, positions: jnp.ndarray, theta: float):
         x, partner_of.astype(x.dtype), precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=x.dtype,
     )
-    out = (
-        x.astype(jnp.float32) * per_lane(jnp.cos(angles))
-        + partner.astype(jnp.float32) * per_lane(jnp.sin(angles))
-    )
+    out = x.astype(jnp.float32) * cos + partner.astype(jnp.float32) * sin
     return out.astype(x.dtype)
 
 
@@ -631,8 +617,8 @@ class EvaAttention(nn.Module):
         )
         q, k, v = heads("wq"), heads("wk"), heads("wv")
         if cfg.rope_theta is not None:
-            q = rope_in_place(q, positions, cfg.rope_theta)
-            k = rope_in_place(k, positions, cfg.rope_theta)
+            q = rope(q, positions, cfg.rope_theta, heads_axis=-3)
+            k = rope(k, positions, cfg.rope_theta, heads_axis=-3)
         learned = lambda name: self.param(
             name, _eva_init, (H, D), cfg.param_dtype
         )

@@ -29,8 +29,8 @@ from benchmark.builders import eva_decoder as builder  # noqa: E402
 from benchmark.references import eva_decoder as plain  # noqa: E402
 from dpwa_tpu.config import make_local_config  # noqa: E402
 from dpwa_tpu.models.llama import (  # noqa: E402
-    EvaAttention, Llama, LlamaConfig, RMSNorm, lora_filter, lora_optimizer,
-    rope, rope_in_place,
+    Attention, EvaAttention, LatentAttention, Llama, LlamaConfig, RMSNorm,
+    YarnScaling, lora_filter, lora_optimizer, rope, rope_frequencies,
 )
 from dpwa_tpu.ops.cross_entropy import softmax_cross_entropy  # noqa: E402
 from tests.test_hybrid_ssm import (  # noqa: E402
@@ -180,23 +180,85 @@ def test_a_target_past_the_end_weighs_nothing():
     assert bool((grad[0, 7, 0] != 0).any()) and bool((grad[0, 5, 2] != 0).any())
 
 
-def test_rope_in_place_is_rope_with_the_heads_first():
-    x = jax.random.normal(jax.random.key(0), (2, 40, 4, 128))
-    turned = lambda z: jnp.swapaxes(z, 1, 2)
-    positions = jnp.arange(40)
-    for dtype in (jnp.float32, jnp.bfloat16):
-        np.testing.assert_array_equal(
-            rope(x.astype(dtype), positions, 1e5).astype(jnp.float32),
-            turned(rope_in_place(turned(x.astype(dtype)), positions, 1e5))
-            .astype(jnp.float32),
-        )
-    weights = jnp.arange(128.0)
-    grad = lambda fn: jax.grad(lambda z: (fn(z) ** 2 * weights).sum())(x)
-    np.testing.assert_allclose(
-        grad(lambda z: rope(z, positions, 1e5)),
-        grad(lambda z: turned(rope_in_place(turned(z), positions, 1e5))),
-        rtol=1e-6, atol=1e-6,
+def strided_rope(x, positions, theta, scaling=None):
+    """``rope`` as it was written until PR 43, on ``x [..., T, H, D]``: the
+    pairs cut apart by strided slices, turned, and stacked back."""
+    freqs = rope_frequencies(x.shape[-1], theta, scaling)
+    angles = positions[..., None].astype(jnp.float32) * freqs
+    cos, sin = jnp.cos(angles)[..., None, :], jnp.sin(angles)[..., None, :]
+    if scaling is not None:
+        cos, sin = (z * scaling.embedding_scale for z in (cos, sin))
+    x1, x2 = x[..., ::2], x[..., 1::2]
+    out1, out2 = x1 * cos - x2 * sin, x1 * sin + x2 * cos
+    return jnp.stack([out1, out2], axis=-1).reshape(x.shape).astype(x.dtype)
+
+
+@pytest.mark.parametrize("scaling", [
+    None, YarnScaling(32.0, 4096, mscale=1.0, mscale_all_dim=0.5),
+], ids=["plain", "yarn"])
+@pytest.mark.parametrize("head_size", [128, 64])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=str)
+@pytest.mark.parametrize("heads_axis", [-2, -3], ids=["BTHD", "BHTD"])
+def test_rope_is_the_strided_rope_in_either_layout(
+    heads_axis, dtype, head_size, scaling
+):
+    """Values to the bit and gradients to rounding, positions before heads
+    (``Attention``, ``LatentAttention``) and heads before positions
+    (``EvaAttention``)."""
+    assert scaling is None or scaling.embedding_scale != 1
+    x = jax.random.normal(jax.random.key(0), (2, 40, 4, head_size)).astype(dtype)
+    positions = 3 + jnp.arange(40)
+    new = lambda z: jnp.moveaxis(
+        rope(
+            jnp.moveaxis(z, -2, heads_axis), positions, 1e5, scaling,
+            heads_axis=heads_axis,
+        ),
+        heads_axis, -2,
     )
+    old = lambda z: strided_rope(z, positions, 1e5, scaling)
+    assert new(x).dtype == dtype
+    np.testing.assert_array_equal(
+        new(x).astype(jnp.float32), old(x).astype(jnp.float32)
+    )
+    weights = jnp.arange(float(head_size))
+    grad = lambda fn: jax.grad(
+        lambda z: (fn(z).astype(jnp.float32) ** 2 * weights).sum()
+    )(x).astype(jnp.float32)
+    np.testing.assert_allclose(grad(new), grad(old), rtol=1e-6, atol=1e-6)
+
+
+def primitive_names(jaxpr):
+    """The name of every equation's primitive, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from primitive_names(inner)
+
+
+@pytest.mark.parametrize("latent", [False, True], ids=["gqa", "latent"])
+def test_no_gather_and_no_scatter_is_left_in_attention(latent):
+    """The strided rope and its gradient traced to 4 ``gather`` and 4
+    ``scatter-add`` in either module (until PR 43) and nothing else in them
+    traces to one: the partner matmul engages in every caller or this is
+    red."""
+    cfg = LlamaConfig(
+        vocab_size=64, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=48, lora_rank=4, rope_theta=1e4, attn_impl="dense",
+    )
+    module = Attention(cfg)
+    if latent:
+        module = LatentAttention(dataclasses.replace(
+            cfg, q_lora_rank=16, kv_lora_rank=8, qk_nope_head_dim=8,
+            qk_rope_head_dim=8, v_head_dim=8,
+            rope_scaling=YarnScaling(32.0, 4096),
+        ))
+    x, positions = jnp.ones((2, 16, 32)), jnp.arange(16)
+    params = module.init(jax.random.key(0), x, positions)
+    loss = lambda p, z: (module.apply(p, z, positions) ** 2).sum()
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x)
+    found = set(primitive_names(traced.jaxpr))
+    assert "dot_general" in found
+    assert not {p for p in found if "gather" in p or "scatter" in p}
 
 
 def test_the_norm_with_a_unit_offset_is_born_an_identity_scale():
