@@ -18,11 +18,15 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+
+# What a layer mixes its tokens by (``LlamaConfig.layer_mixers``).
+MIXERS = ("attention", "conv", "mamba")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,10 +162,50 @@ class LlamaConfig:
     # wide stream and hand on ``dtype`` (``fp32_skip_add`` with ``fp32_ln``
     # false, as the EvaByte configuration publishes them).
     fp32_skip_add: bool = False
+    # A list of kinds a layer (the published ``layer_types`` of LFM2, which no
+    # period and offset can say): layer ``i``'s mixer is ``layer_mixers[i]``,
+    # one of :data:`MIXERS`: ``"attention"`` (whichever attention the other
+    # fields select), ``"conv"`` (a :class:`ShortConv` of ``conv_taps`` taps)
+    # or ``"mamba"``.  None: ``attn_layer_period`` / ``attn_layer_offset``
+    # say, as above.
+    layer_mixers: Optional[Tuple[str, ...]] = None
+    conv_taps: int = 3
+    # An RMSNorm over ``head_dim`` on every head of q and of k after the
+    # split into heads and before rope, one weight ``[head_dim]`` for q and
+    # one for k (``qk_norm`` is the other form: over the whole projected
+    # width before the split).
+    qk_norm_per_head: bool = False
+    # The gate chooses a token's experts by ``scores + expert_bias`` and
+    # weighs them by ``scores`` alone; ``expert_bias [n_experts]`` is a
+    # float32 base leaf beside ``router``.  ``norm_topk_eps`` is added to the
+    # sum that ``norm_topk_prob`` divides by.
+    router_bias: bool = False
+    norm_topk_eps: float = 0.0
 
     def __post_init__(self):
-        if self.attn_layer_period:
-            if not 0 <= self.attn_layer_offset < self.attn_layer_period:
+        mixers = self.layer_mixers
+        if mixers is not None:
+            if self.attn_layer_period:
+                raise ValueError(
+                    "layer_mixers and attn_layer_period both say which "
+                    "layers keep attention: give one"
+                )
+            if len(mixers) != self.n_layers or set(mixers) - set(MIXERS):
+                raise ValueError(
+                    f"layer_mixers names one of {MIXERS} for each of the "
+                    f"{self.n_layers} layers, got {mixers!r}"
+                )
+            if "conv" in mixers and self.conv_taps < 1:
+                raise ValueError("a convolution needs at least one tap")
+            if "conv" in mixers and self.sp_axis is not None:
+                raise ValueError(
+                    "a short convolution has no sequence-parallel path: a "
+                    "rank's first positions need the rank before's last"
+                )
+        if self.attn_layer_period or "mamba" in (mixers or ()):
+            if self.attn_layer_period and not (
+                0 <= self.attn_layer_offset < self.attn_layer_period
+            ):
                 raise ValueError(
                     f"attn_layer_offset {self.attn_layer_offset} is not in "
                     f"0..{self.attn_layer_period - 1}"
@@ -174,6 +218,13 @@ class LlamaConfig:
                     "a Mamba layer has no sequence-parallel path: the scan "
                     "hands no state from one rank to the next"
                 )
+        if self.qk_norm and self.qk_norm_per_head:
+            raise ValueError(
+                "qk_norm (over the projected width) and qk_norm_per_head "
+                "(over a head) are two forms of one norm: give one"
+            )
+        if self.router_bias and self.experts_held is not None:
+            raise ValueError("a share of the experts has no router bias yet")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"router_scoring must be softmax|sigmoid, got "
@@ -248,9 +299,17 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
-    def is_attention_layer(self, index: int) -> bool:
+    def mixer_of(self, index: int) -> str:
+        """Layer ``index``'s mixer, one of :data:`MIXERS`."""
+        if self.layer_mixers is not None:
+            return self.layer_mixers[index]
         period = self.attn_layer_period
-        return not period or index % period == self.attn_layer_offset
+        if not period or index % period == self.attn_layer_offset:
+            return "attention"
+        return "mamba"
+
+    def is_attention_layer(self, index: int) -> bool:
+        return self.mixer_of(index) == "attention"
 
     @property
     def norm_dtype(self) -> jnp.dtype:
@@ -473,6 +532,8 @@ class Attention(nn.Module):
         if cfg.qk_norm:
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         q, k = q.reshape(B, T, H, D), k.reshape(B, T, KV, D)
+        if cfg.qk_norm_per_head:
+            q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         v = dense(KV * D, "wv")(x).reshape(B, T, KV, D)
         if cfg.rope_theta is not None:
             q = rope(q, positions, cfg.rope_theta)
@@ -706,6 +767,43 @@ class MambaMixer(nn.Module):
             return _dense(cfg, cfg.d_model, "out_proj")(y * nn.silu(z))
 
 
+class ShortConv(nn.Module):
+    """The gated short convolution of LFM2, ``x [B, T, D]``:
+
+        [b, c, u] = x W_in                      D -> 3 D, no bias
+        z = conv(b * u)                         causal, depthwise, no bias:
+                                                z_t = sum_j w[j] (b u)_{t-(K-1)+j}
+        out = (c * z) W_out                     D -> D, no bias
+
+    with ``K = conv_taps`` and zeros before the sequence
+    (``ops/ssm.causal_conv1d``, float32 multiply-adds).  Adapters on the two
+    projections; ``conv_kernel [K, D]`` is a base leaf.  No state crosses the
+    taps' reach, so nothing here knows a peer axis: under ``vmap`` it is the
+    same elementwise and dense work on one more axis."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        from dpwa_tpu.ops import ssm
+        from dpwa_tpu.utils import scopes
+
+        cfg = self.cfg
+        D, K = cfg.d_model, cfg.conv_taps
+        with jax.named_scope(scopes.CONV.whole):
+            bcu = _dense(cfg, 3 * D, "in_proj")(x)
+            kernel = self.param(
+                "conv_kernel", _conv_init(K), (K, D), cfg.param_dtype
+            )
+            with jax.named_scope(scopes.CONV.gate):
+                b, c, u = jnp.split(bcu, 3, -1)
+                z = ssm.causal_conv1d(
+                    b * u, kernel, jnp.zeros((D,), jnp.float32)
+                )
+                gated = c * z
+            return _dense(cfg, D, "out_proj")(gated)
+
+
 class MLP(nn.Module):
     cfg: LlamaConfig
     d_ff: Optional[int] = None  # None: the configuration's ``d_ff``
@@ -761,7 +859,9 @@ class MoE(nn.Module):
 
     Sows each call's routing into the ``intermediates`` collection
     (``router_input [N, D]``, ``experts [N, k]``, ``counts [E]``, ``prob_mean
-    [E]``, router ``logits [N, E]``, and of the held experts ``held_counts [held]``, ``held_share``
+    [E]``, router ``logits [N, E]``, with a router bias ``bias_moved`` = the
+    share of the N x k assignments that are not among the top k of the scores
+    alone, and of the held experts ``held_counts [held]``, ``held_share``
     = their part of all N x k assignments, ``held_max_over_mean``,
     ``held_over_cap``): what :func:`moe_loss` and a reference that verifies
     the routing read; nothing is computed for it when the collection is not
@@ -789,6 +889,13 @@ class MoE(nn.Module):
         E, k = cfg.n_experts, cfg.n_experts_per_tok
         held, offset = cfg.held_experts, cfg.expert_offset
         router = self.param("router", nn.initializers.lecun_normal(), (D, E))
+        bias = None
+        if cfg.router_bias:
+            # Born off zero so that it moves some choices; the published
+            # model moves it by the experts' load, not by the gradient.
+            bias = self.param(
+                "expert_bias", nn.initializers.normal(stddev=0.05), (E,)
+            )
         expert = lambda d_in, d_out, name: ExpertDense(
             held, d_in, d_out, cfg.lora_rank, cfg.param_dtype, name=name
         )()
@@ -796,7 +903,7 @@ class MoE(nn.Module):
         with jax.named_scope(scopes.MOE_ROUTE):
             weights, experts, logits = moe.route(
                 tokens, router, k, cfg.router_scoring, cfg.norm_topk_prob,
-                cfg.routed_scaling_factor,
+                cfg.routed_scaling_factor, bias, cfg.norm_topk_eps,
             )
             counts = moe.assignment_counts(experts, E)
             self.sow("intermediates", "router_input", tokens)
@@ -812,6 +919,10 @@ class MoE(nn.Module):
                      here.max() * held / jnp.maximum(here.sum(), 1))
             self.sow("intermediates", "held_over_cap",
                      moe.over_cap(here.sum(), experts.size, held, E))
+            if bias is not None:
+                self.sow("intermediates", "bias_moved", moe.choices_moved(
+                    moe.router_scores(logits, cfg.router_scoring), experts
+                ))
         out = moe.moe_ffn(
             tokens, (weights, experts),
             expert(D, cfg.d_ff, "w_gate"), expert(D, cfg.d_ff, "w_up"),
@@ -829,8 +940,8 @@ class MoE(nn.Module):
 
 class Block(nn.Module):
     cfg: LlamaConfig
-    # Of the layer: the first ``n_dense_layers`` are dense, and with an
-    # ``attn_layer_period`` it says which layers keep attention.
+    # Of the layer: the first ``n_dense_layers`` are dense, and it picks the
+    # layer's mixer (``LlamaConfig.mixer_of``).
     index: int = 0
 
     @nn.compact
@@ -838,11 +949,12 @@ class Block(nn.Module):
         from dpwa_tpu.utils import scopes
 
         cfg = self.cfg
-        # Latent attention, the Mamba mixer and the expert layer name
-        # themselves; plain and EVA attention and the dense feed-forward are
-        # named here, because the shared expert is an ``MLP`` too.  Norms and
-        # residual adds stay outside every name.
-        if cfg.is_attention_layer(self.index):
+        # Latent attention, the Mamba mixer, the short convolution and the
+        # expert layer name themselves; plain and EVA attention and the dense
+        # feed-forward are named here, because the shared expert is an
+        # ``MLP`` too.  Norms and residual adds stay outside every name.
+        mixer = cfg.mixer_of(self.index)
+        if mixer == "attention":
             h = _norm(cfg, "attn_norm")(x)
             if cfg.kv_lora_rank:
                 h = LatentAttention(cfg, name="attn")(h, positions)
@@ -853,6 +965,8 @@ class Block(nn.Module):
                 with jax.named_scope(scopes.ATTN_GQA):
                     h = Attention(cfg, name="attn")(h, positions)
             x = x + h
+        elif mixer == "conv":
+            x = x + ShortConv(cfg, name="conv")(_norm(cfg, "conv_norm")(x))
         else:
             x = x + MambaMixer(cfg, name="mamba")(_norm(cfg, "mamba_norm")(x))
         h = _norm(cfg, "mlp_norm")(x)
@@ -868,13 +982,14 @@ class Block(nn.Module):
 
 def _checkpoint_policy(cfg: LlamaConfig, index: int):
     """What ``jax.checkpoint`` around block ``index`` keeps beside the
-    block's input: nothing (None) for an attention block; for a Mamba block
+    block's input: nothing (None) for an attention or a convolution block;
+    for a Mamba block
     what the scan's forward kernel alone produces (``ops/ssm.KEPT``: 105 MB
     a block at the Jamba cell's shapes), so that the recomputation has
     nothing to run that kernel for."""
     from dpwa_tpu.ops import ssm
 
-    if cfg.is_attention_layer(index):
+    if cfg.mixer_of(index) != "mamba":
         return None
     return jax.checkpoint_policies.save_only_these_names(*ssm.KEPT)
 
@@ -943,7 +1058,8 @@ def routing_of(intermediates) -> dict:
     """The sown routing of every expert layer, layers stacked in order:
     ``{"router_input": [L, N, D], "experts": [L, N, k], "counts": [L, E],
     "prob_mean": [L, E], "logits": [L, N, E], "held_counts": [L, held], "held_share": [L],
-    "held_max_over_mean": [L], "held_over_cap": [L]}``, from the ``intermediates`` collection that
+    "held_max_over_mean": [L], "held_over_cap": [L]}`` (and ``"bias_moved":
+    [L]`` where the router has a bias), from the ``intermediates`` collection that
     ``Llama.apply(..., mutable=["intermediates"])`` returns.  A dense layer
     sows nothing and is not among them."""
     layers = intermediates["intermediates"]

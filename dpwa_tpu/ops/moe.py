@@ -113,20 +113,40 @@ def _use_kernels(rows: int) -> bool:
     return jax.default_backend() == "tpu" and rows % 512 == 0
 
 
-def _tiling(k: int, n: int):
+def _tiling(k: int, n: int, contracts_k: bool = True):
     """``(tm, tk, tn)`` for the ``megablox`` kernels from the two dense
-    dimensions, by the sweep on the v5e at 65,536 rows in 128 groups
-    (PERF.md section 6, PR 27): 256 rows by up to 1024 x 1024 for the wide
-    matmuls; for a rank-16 adapter (one side under 128) 512 rows, its narrow
-    side padded to one 128-lane tile, and the wide side up to 2048 when it is
-    contracted.  Larger tiles are refused by Mosaic (scoped VMEM)."""
+    dimensions ``k x n`` of a group's weights (``gmm`` contracts ``k``; in
+    ``tgmm`` they are the result's, ``contracts_k`` false, and the tile ``tk
+    x tn`` is the float32 accumulator).
+
+    Where 1,024 divides what it tiles, by the sweep on the v5e at 65,536 rows
+    in 128 groups (PERF.md section 6, PR 27): 256 rows by up to 1024 x 1024
+    for the wide matmuls; for a rank-16 adapter (one side under 128) 512 rows,
+    its narrow side padded to one 128-lane tile, and the wide side up to 2048
+    when it is contracted.  (1024, 1024, 1024) is refused by Mosaic (scoped
+    VMEM).
+
+    Where it does not (an expert width of 1,792 = 7 x 256), by the sweep at
+    32,768 rows in 64 groups (PERF.md section 6, PR 44): a 1,024 tile over
+    1,792 pays a second tile that is three quarters full or masked, so ``n``
+    takes the largest multiple of 128 that divides it (896), and a contracted
+    ``k`` is taken whole where the weights' tile stays within 2,048 x 1,024
+    values (2.21 ms a product against 2.88 to 3.06 with 1,024 x 1,024, and
+    2.73 to 2.77 with tiles that only divide)."""
     lanes = lambda v: -(-v // 128) * 128
-    narrow = min(k, n) < 128
-    return (
-        512 if narrow else 256,
-        min(lanes(k), 2048 if n < 128 else 1024),
-        min(lanes(n), 1024),
+    if min(k, n) < 128:
+        tk = min(lanes(k), 2048 if n < 128 else 1024)
+        return 512, tk, min(lanes(n), 1024)
+    k, n = lanes(k), lanes(n)
+    if k % min(k, 1024) == 0 and n % min(n, 1024) == 0:
+        return 256, min(k, 1024), min(n, 1024)
+    # (A divisor under 512 pays more grid steps than a masked tile wastes.)
+    divisor = lambda v, cap: max(
+        (t for t in range(512, min(v, cap) + 1, 128) if v % t == 0),
+        default=min(v, 1024),
     )
+    tn = divisor(n, 1024)
+    return 256, divisor(k, 2048 * 1024 // tn if contracts_k else 1024), tn
 
 
 def _kernels():
@@ -190,7 +210,7 @@ def _tgmm(lhs, grad, group_sizes):
         )
     return _kernels().tgmm(
         lhs.swapaxes(0, 1), grad, group_sizes, lhs.dtype,
-        _tiling(lhs.shape[1], grad.shape[1]),
+        _tiling(lhs.shape[1], grad.shape[1], contracts_k=False),
     )
 
 
@@ -292,8 +312,8 @@ def _kernel_tgmm(lhs, grad, group_sizes, skip):
     sizes = jnp.concatenate([jnp.full((1,), skip, jnp.int32), group_sizes])
     return _kernels().tgmm(
         lhs.swapaxes(0, 1), grad, sizes, lhs.dtype,
-        _tiling(lhs.shape[1], grad.shape[1]), group_offset=jnp.int32(1),
-        num_actual_groups=group_sizes.shape[0],
+        _tiling(lhs.shape[1], grad.shape[1], contracts_k=False),
+        group_offset=jnp.int32(1), num_actual_groups=group_sizes.shape[0],
     )
 
 
@@ -492,22 +512,38 @@ def router_scores(logits, scoring: str = "softmax"):
 
 
 def route(x, router_kernel, k: int, scoring: str = "softmax",
-          norm_topk_prob: bool = False, scale: float = 1.0):
+          norm_topk_prob: bool = False, scale: float = 1.0, bias=None,
+          norm_eps: float = 0.0):
     """Router of ``x [N, D]``: ``(weights [N, k] float32, experts [N, k]
     int32, logits [N, E] float32)``.  Logits and scores are float32 at the
-    highest matmul precision whatever ``x``'s type; the weights are the top-k
-    scores' own values, divided by their sum with ``norm_topk_prob``, times
-    ``scale``."""
+    highest matmul precision whatever ``x``'s type; the experts are the top k
+    by score, or with a ``bias [E]`` by ``score + bias``; the weights are
+    those experts' scores (never the bias), divided by their sum ``+
+    norm_eps`` with ``norm_topk_prob``, times ``scale``."""
     logits = jnp.dot(
         x.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=HIGHEST,
     )
-    weights, experts = lax.top_k(router_scores(logits, scoring), k)
+    scores = router_scores(logits, scoring)
+    if bias is None:
+        weights, experts = lax.top_k(scores, k)
+    else:
+        experts = lax.top_k(scores + bias.astype(jnp.float32), k)[1]
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / weights.sum(-1, keepdims=True)
+        total = weights.sum(-1, keepdims=True)
+        weights = weights / (total + norm_eps if norm_eps else total)
     if scale != 1.0:
         weights = weights * scale
     return weights, experts.astype(jnp.int32), logits
+
+
+def choices_moved(scores, experts):
+    """The share of the ``experts [N, k]`` assignments that are not among the
+    top k of ``scores [N, E]`` alone: what a router bias changed."""
+    plain = lax.top_k(scores, experts.shape[-1])[1]
+    kept = jnp.any(experts[:, :, None] == plain[:, None, :], axis=-1)
+    return 1.0 - kept.mean()
 
 
 def assignment_counts(experts, n_experts: int):

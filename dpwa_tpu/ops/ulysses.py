@@ -132,16 +132,20 @@ def single_device_attention(
     share one head size and ``v`` may have another (latent attention: 192
     and 128); ``sm_scale`` multiplies the scores (``1 / sqrt(q's head size)``
     where not given).  ``impl``: "flash" forces the Pallas kernel, "auto"
-    uses it on TPU when shapes fit its tiling (T a multiple of 128, and the
-    head sizes multiples of 128 or different from each other), anything else
+    uses it on TPU when T fits its tiling (a multiple of 128), anything else
     runs the masked-softmax einsum with f32 accumulation.
 
-    The library's kernels take one head size, a multiple of 128.  Where the
-    head sizes differ, q, k and v are zero-padded to the next such multiple
-    and the output is sliced back: exact, because a zero column adds nothing
-    to a score or to a value, and paid for in the kernels' arithmetic (192 /
-    128 run as 256 / 256).  That shape never falls to the einsum silently on
-    a TPU whose tiling T fits.
+    The library's kernels take one head size: a multiple of 128, or 64 as it
+    is (a block's 64 lanes are half a register row; on the v5e at 2 x 32 x
+    4,096 x 64 they give what the same heads zero-padded to 128 give, bit for
+    bit in a whole step's loss, in the same 16.7 ms forward and backward, and
+    the step saves the pad and the slice: PERF.md section 6, PR 44).  Where
+    the head sizes differ or are neither, q, k and v are zero-padded to the
+    next multiple of 128 and the output is sliced back: exact, because a zero
+    column adds nothing to a score or to a value, and paid for in the
+    kernels' arithmetic (192 / 128 run as 256 / 256).  No head size falls to
+    the einsum silently on a TPU whose tiling T fits: at T 4,096 its float32
+    scores are 4.3 GB for two sequences of 32 heads.
 
     The library's forward, dkv and dq kernels run with the block sizes
     :func:`_flash_block_sizes` picks from this call's own ``T`` and
@@ -156,18 +160,16 @@ def single_device_attention(
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
     use_flash = impl == "flash" or (
-        impl == "auto"
-        and jax.default_backend() == "tpu"
-        and (D % 128 == 0 or D != Dv)
-        and T % 128 == 0
+        impl == "auto" and jax.default_backend() == "tpu" and T % 128 == 0
     )
     if use_flash:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention,
         )
 
-        if D != Dv:
-            padded = -(-max(D, Dv) // 128) * 128
+        padded = -(-max(D, Dv) // 128) * 128
+        as_it_is = D == Dv and D in (padded, 64)
+        if not as_it_is:
             q, k, v = (
                 jnp.pad(x, [(0, 0)] * 3 + [(0, padded - x.shape[-1])])
                 for x in (q, k, v)
@@ -180,7 +182,7 @@ def single_device_attention(
             sm_scale=float(1.0 / (D ** 0.5) if sm_scale is None else sm_scale),
             block_sizes=_flash_block_sizes(T, q.shape[-1]),
         ).transpose(0, 2, 1, 3)
-        return out if D == Dv else out[..., :Dv]
+        return out if as_it_is else out[..., :Dv]
     s = jnp.einsum(
         "bthd,bshd->bhts",
         q.astype(jnp.float32),

@@ -1,11 +1,11 @@
 """Names for the parts of a train step, as ``jax.named_scope`` metadata.
 
 Three scopes give the step's four phases: ``dpwa.forward`` (two of them, as
-below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Thirteen more lie inside
+below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Fifteen more lie inside
 the forward scope and name the parts of a decoder (``models/llama.py``):
-attention plain, latent and EVA (with its summaries and its core), the dense
-feed-forward, the expert layer's three parts, the state-space mixer and its
-scan, the head, the loss.  The outer
+attention plain, latent and EVA (with its summaries and its core), the gated
+short convolution and its gate, the dense feed-forward, the expert layer's
+three parts, the state-space mixer and its scan, the head, the loss.  The outer
 norms, the embedding and the residual adds carry none: they are what is left
 under ``dpwa.forward``.  A scope's name becomes a component of the
 ``op_name`` of every HLO instruction traced under it, and JAX wraps the name
@@ -65,6 +65,18 @@ class _EvaNames(NamedTuple):
 
 
 ATTN_EVA = _EvaNames()
+# A gated short convolution whole (``models/llama.ShortConv``): ``in_proj``
+# and ``out_proj`` with their adapters and everything between them; inside it
+# ``gate`` alone: ``b * u``, the taps of the causal convolution and ``c * z``,
+# elementwise work between the two projections.  One value of two fields, for
+# the reason ``ATTN_EVA`` is one of three (PERF.md section 7 asks for the
+# rows).
+class _ConvNames(NamedTuple):
+    whole: str = "dpwa.conv"
+    gate: str = "dpwa.conv.gate"
+
+
+CONV = _ConvNames()
 # A dense SwiGLU feed-forward whole (``models/llama.MLP`` as a layer's
 # feed-forward, put on in ``Block``): ``w_gate``, ``w_up``, ``silu x up``,
 # ``w_down`` and their adapters.  The shared expert is an ``MLP`` too and

@@ -460,6 +460,62 @@ def test_the_eva_cells_step_lowers_with_both_kernels_and_no_scores():
     assert max(sizes) == 2 * T * 11008 < 32 * T * (2048 + T // 16)
 
 
+def test_the_lfm2_cells_step_lowers_with_its_kernels_and_no_scores():
+    """The LFM2 cell's own loss under `vmap` over its two peers at the
+    published sizes (5 layers, T 4,096), differentiated and lowered for a TPU
+    (shapes alone: no chip, no TPU compiler): the expert layers' `gmm` and
+    `tgmm` and the library's three flash kernels are custom calls (the head
+    size of 64 goes to the kernels, as it is, and not to the einsum), the gate
+    of the four conv mixers is there under its name, and no tensor holds one
+    sequence's `T x T` scores over its 32 heads: the largest is the float32
+    logits `[2, 1, 4096, 65536]`, half of what the scores would be."""
+    import re
+    from unittest import mock
+
+    from tests.yardstick.yardstick_paths import cell_files
+
+    from benchmark.builders import conv_moe_decoder
+
+    _, config, cell = cell_files("lfm2-lora-stacked2-t4096")
+    assert cell["expect_hlo"] == ["tpu_custom_call"]
+    built = conv_moe_decoder.build(config, cell)
+    T = cell["seq_len"]
+    shapes = jax.eval_shape(
+        jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
+    )
+    tokens = jax.ShapeDtypeStruct((2, 1, T), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(jax.vmap(jax.grad(built.loss_fn))).trace(
+            shapes, (tokens, tokens)
+        ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert {
+        "_flash_attention_kernel", "_flash_attention_dkv_kernel",
+        "_flash_attention_dq_kernel",
+    } <= kernels
+    # The grouped products in the four expert layers (2 to 4 once more in a
+    # block's recomputation), `tgmm` in the backward pass alone.
+    calls = re.findall(
+        r'loc\("([^"]*)/layer_(\d)/mlp/[^"]*dpwa\.moe\.experts/jit\((t?gmm)\)',
+        text,
+    )
+    layers = lambda kernel: sorted({int(i) for _, i, k in calls if k == kernel})
+    assert layers("gmm") == layers("tgmm") == [1, 2, 3, 4]
+    assert all("transpose(jvp" in scope for scope, _, k in calls if k == "tgmm")
+    # The flash kernels see the heads of 64 as they are: nothing is padded.
+    assert "tensor<2x1x32x4096x64xbf16>" in text
+    assert "x128xbf16>" not in text
+    gate = re.findall(r'layer_(\d)/conv/dpwa\.conv/dpwa\.conv\.gate/', text)
+    assert sorted(set(map(int, gate))) == [0, 2, 3, 4]
+    sizes = [
+        int(np.prod([int(d) for d in dims.split("x")]))
+        for dims in re.findall(r"tensor<((?:\d+x)+)(?:f32|bf16|i32|i1)>", text)
+        for dims in [dims.rstrip("x")]
+    ]
+    assert max(sizes) == 2 * T * 65536 < 2 * 32 * T * T
+
+
 def test_what_the_mamba_blocks_keep_does_not_pile_up(v5e_chips, capsys):
     """The Jamba cell's own step at four layers (Mamba blocks all, each
     keeping its scan's output and boundary states) compiled for the v5e

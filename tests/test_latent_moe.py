@@ -152,8 +152,10 @@ def test_attention_with_a_qk_size_unlike_the_v_size_and_a_scale():
 
 def test_the_kernel_path_takes_two_head_sizes_where_t_tiles(monkeypatch):
     """On a TPU at T a multiple of 128 the 192 / 128 shape goes to the
-    kernels (padded), and an equal head size that is no multiple of 128
-    still goes to the einsum as before."""
+    kernels (padded to 256), and since PR 44 so does an equal head size that
+    is no multiple of 128: 64 / 64 as it is, 96 / 96 padded to 128 with the
+    scale still that of 96; no head size falls to the einsum's ``T x T``
+    scores silently."""
     from jax.experimental.pallas.ops.tpu import flash_attention as library
 
     seen = []
@@ -170,8 +172,19 @@ def test_the_kernel_path_takes_two_head_sizes_where_t_tiles(monkeypatch):
     )
     assert out.shape == (1, 128, 2, 128)
     assert seen == [((1, 2, 128, 256),) * 3 + (0.1309,)]
-    single_device_attention(q[..., :64], q[..., :64], q[..., :64], causal=True)
-    assert len(seen) == 1
+    out = single_device_attention(
+        q[..., :64], q[..., :64], q[..., :64], causal=True
+    )
+    assert out.shape == (1, 128, 2, 64)
+    assert seen[1:] == [((1, 2, 128, 64),) * 3 + (0.125,)]
+    out = single_device_attention(
+        q[..., :96], q[..., :96], q[..., :96], causal=True
+    )
+    assert out.shape == (1, 128, 2, 96)
+    assert seen[2:] == [((1, 2, 128, 128),) * 3 + (float(1.0 / 96 ** 0.5),)]
+    # Where T does not tile, the einsum, as before.
+    single_device_attention(q[:, :100], q[:, :100], q[:, :100], causal=True)
+    assert len(seen) == 3
 
 
 # ---- latent attention and the whole model against the reference
