@@ -45,9 +45,10 @@ plain masked products unbatched: the comment above it says why.)  On the
 v5e ``ragged_dot`` runs XLA's own grouped kernel at 70 TFLOP/s on the cell's
 shapes, needs a 0.5 GB transposed copy of the kernels for the gradient to the
 activations (48 TFLOP/s with it) and loses the instruction's ``op_name``, so
-no scope finds it in a trace; ``megablox`` at ``(256, 1024, 1024)`` runs 89 to
-100 TFLOP/s in all three and keeps its name; at its default ``(128, 128,
-128)`` it runs 9 (PERF.md section 6, PR 27, has the sweep).
+no scope finds it in a trace; ``megablox`` keeps its name and runs 107 to 113
+TFLOP/s forward and to the activations at :func:`_tiling`'s tiles (88 to 103
+at PR 27's ``(256, 1024, 1024)``, 9 at the library's default ``(128, 128,
+128)``; PERF.md section 6, PR 27 and PR 45, has the sweeps).
 
 **What the adapters share** (PR 30).  A rank-r factor's grouped matmul reads
 or writes a whole sorted ``[N x k, D]`` array for r columns and runs at the
@@ -117,36 +118,36 @@ def _tiling(k: int, n: int, contracts_k: bool = True):
     """``(tm, tk, tn)`` for the ``megablox`` kernels from the two dense
     dimensions ``k x n`` of a group's weights (``gmm`` contracts ``k``; in
     ``tgmm`` they are the result's, ``contracts_k`` false, and the tile ``tk
-    x tn`` is the float32 accumulator).
+    x tn`` is the float32 accumulator).  Each clause is a sweep on the v5e
+    (PERF.md section 6: PR 27 and PR 45 at 65,536 rows in 128 groups, PR 44
+    at 32,768 in 64 with an expert width of 1,792 = 7 x 256).
 
-    Where 1,024 divides what it tiles, by the sweep on the v5e at 65,536 rows
-    in 128 groups (PERF.md section 6, PR 27): 256 rows by up to 1024 x 1024
-    for the wide matmuls; for a rank-16 adapter (one side under 128) 512 rows,
-    its narrow side padded to one 128-lane tile, and the wide side up to 2048
-    when it is contracted.  (1024, 1024, 1024) is refused by Mosaic (scoped
-    VMEM).
+    A rank-16 adapter (one side under 128): 512 rows, its narrow side padded
+    to one 128-lane tile, the wide side up to 2048 when it is contracted.
 
-    Where it does not (an expert width of 1,792 = 7 x 256), by the sweep at
-    32,768 rows in 64 groups (PERF.md section 6, PR 44): a 1,024 tile over
-    1,792 pays a second tile that is three quarters full or masked, so ``n``
-    takes the largest multiple of 128 that divides it (896), and a contracted
-    ``k`` is taken whole where the weights' tile stays within 2,048 x 1,024
-    values (2.21 ms a product against 2.88 to 3.06 with 1,024 x 1,024, and
-    2.73 to 2.77 with tiles that only divide)."""
+    The wide matmuls: 256 rows by a tile of the weights within 2,048 x 1,024
+    values, filled ``k`` first.  A contracted ``k`` up to 2,048 is whole: in
+    two tiles the weights' tile changes at every grid step and is read again
+    for every tile of rows (3.00 to 3.13 ms a product in two, 2.43 whole, PR
+    45); a longer one, and either side in ``tgmm`` (Mosaic refuses a whole
+    2,048 there), by its divisor up to 1,024.  ``n`` by the largest multiple of
+    128 that divides it within the tile and 2,048 (the accumulator is ``tm x
+    tn``: Mosaic refuses 256 x 8,192): 2,048 over a contracted 1,024 reads the
+    rows once (2.55 ms against 2.68, PR 45), 896 over 1,792 pays no second tile
+    that is a quarter empty (2.21 against 2.41, PR 44)."""
     lanes = lambda v: -(-v // 128) * 128
     if min(k, n) < 128:
         tk = min(lanes(k), 2048 if n < 128 else 1024)
         return 512, tk, min(lanes(n), 1024)
     k, n = lanes(k), lanes(n)
-    if k % min(k, 1024) == 0 and n % min(n, 1024) == 0:
-        return 256, min(k, 1024), min(n, 1024)
     # (A divisor under 512 pays more grid steps than a masked tile wastes.)
     divisor = lambda v, cap: max(
         (t for t in range(512, min(v, cap) + 1, 128) if v % t == 0),
         default=min(v, 1024),
     )
-    tn = divisor(n, 1024)
-    return 256, divisor(k, 2048 * 1024 // tn if contracts_k else 1024), tn
+    tk = k if contracts_k and k <= 2048 else divisor(k, 1024)
+    cap = min(2048, 2048 * 1024 // tk) if contracts_k else 1024
+    return 256, tk, divisor(n, cap)
 
 
 def _kernels():

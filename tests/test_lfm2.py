@@ -256,23 +256,29 @@ def test_a_wrong_choice_is_refused_by_the_reference(seeded):
     assert bool(jnp.isnan(rounded).any())
 
 
-# ---- the grouped kernels' tiles at an expert width of 1,792
+# ---- the grouped kernels' tiles
 
 
-@pytest.mark.parametrize("shape, tiles", [
-    # What the OLMoE and A.X-K1 cells pass: PR 27's picks, unmoved.
-    ((2048, 1024), (256, 1024, 1024)), ((1024, 2048), (256, 1024, 1024)),
-    ((7168, 2048), (256, 1024, 1024)), ((2048, 7168), (256, 1024, 1024)),
-    ((2048, 32), (512, 2048, 128)), ((7168, 32), (512, 2048, 128)),
-    ((16, 1024), (512, 128, 1024)), ((1024, 16), (512, 1024, 128)),
-    ((16, 2048), (512, 128, 1024)), ((2048, 16), (512, 2048, 128)),
-    ((16, 7168), (512, 128, 1024)), ((7168, 16), (512, 2048, 128)),
-    # This cell's adapters: as the rule gave them before.
-    ((1792, 16), (512, 1792, 128)), ((16, 1792), (512, 128, 1024)),
+@pytest.mark.parametrize("shape, tiles, accumulator", [
+    # What the OLMoE and A.X-K1 cells pass: a contracted 2,048 whole, one
+    # ``n`` tile of 2,048 over a contracted 1,024 (PERF.md section 6, PR 45);
+    # ``tgmm``, whose tile is the accumulator, at PR 27's picks.
+    ((2048, 1024), (256, 2048, 1024), (256, 1024, 1024)),
+    ((1024, 2048), (256, 1024, 2048), (256, 1024, 1024)),
+    ((7168, 2048), (256, 1024, 2048), (256, 1024, 1024)),
+    ((2048, 7168), (256, 2048, 1024), (256, 1024, 1024)),
+    # The adapters, theirs and the LFM2 cell's: one answer for both kernels.
+    ((2048, 32), (512, 2048, 128), None), ((7168, 32), (512, 2048, 128), None),
+    ((16, 1024), (512, 128, 1024), None), ((1024, 16), (512, 1024, 128), None),
+    ((16, 2048), (512, 128, 1024), None), ((2048, 16), (512, 2048, 128), None),
+    ((16, 7168), (512, 128, 1024), None), ((7168, 16), (512, 2048, 128), None),
+    ((1792, 16), (512, 1792, 128), None), ((16, 1792), (512, 128, 1024), None),
 ])
-def test_where_1024_divides_the_tiles_are_what_they_were(shape, tiles):
+def test_the_tiles_where_1024_divides_and_the_adapters(
+    shape, tiles, accumulator
+):
     assert moe._tiling(*shape) == tiles
-    assert moe._tiling(*shape, contracts_k=False) == tiles
+    assert moe._tiling(*shape, contracts_k=False) == (accumulator or tiles)
 
 
 def test_the_tiles_at_a_width_1024_does_not_divide():
@@ -286,20 +292,29 @@ def test_the_tiles_at_a_width_1024_does_not_divide():
     # No divisor of 1,408 = 11 x 128 reaches 512: a masked 1,024 tile.
     assert moe._tiling(2048, 1408) == (256, 2048, 1024)
     assert moe._tiling(1408, 2048, contracts_k=False) == (256, 1024, 1024)
+    # A short contracted ``k`` leaves the weights' tile room for more, but
+    # the accumulator is ``tm x tn``: Mosaic refuses (256, 256, 8192).
+    assert moe._tiling(256, 8192) == (256, 256, 2048)
 
 
-def test_the_kernels_at_a_width_1024_does_not_divide_equal_ragged_dot(
-    monkeypatch
+@pytest.mark.parametrize("k, n", [
+    # LFM2's down projection: forward (256, 1792, 1024), to the rows (256,
+    # 2048, 896), to the weights (256, 896, 1024).
+    (1792, 2048),
+    # OLMoE's gate: forward (256, 2048, 1024), to the rows (256, 1024, 2048),
+    # to the weights (256, 1024, 1024).
+    (2048, 1024),
+])
+def test_the_kernels_under_the_tiles_picked_equal_ragged_dot(
+    monkeypatch, k, n
 ):
     """The library's kernels, interpreted, under the tiles ``_tiling`` picks
-    for an expert of 1,792 (one whole contracted tile forward, an ``n`` tile of
-    896 to the rows, ``tgmm`` at 896 x 1,024), against ``lax.ragged_dot``:
-    values and both gradients, an empty group among them."""
+    (a contracted dimension whole in one tile forward, ``n`` by its divisor
+    within the weights' tile), against ``lax.ragged_dot``: values and both
+    gradients, an empty group among them."""
     from jax.experimental.pallas import tpu as pltpu
 
-    # The down projection's shape: forward (256, 1792, 1024), to the rows
-    # (256, 2048, 896), to the weights (256, 896, 1024).
-    m, k, n = 512, 1792, 2048
+    m = 512
     sizes = jnp.array([200, 0, 312], jnp.int32)
     lhs = jax.random.normal(jax.random.key(0), (m, k))
     rhs = jax.random.normal(jax.random.key(1), (3, k, n)) * k ** -0.5
