@@ -540,7 +540,8 @@ def pair_merge_leg(sz: Sizes, platform: str) -> dict:
     assert {dev.platform for dev in x.devices()} == {platform}
     del x
 
-    # The library flash kernel against the dense branch (GQA, causal).
+    # The flash kernels `single_device_attention` picks for the shape (a head
+    # of 128: the EVA core's two) against the dense branch (GQA, causal).
     T, cfg = sz.attn_t, sz.llama
     H, KV = cfg["n_heads"], cfg["n_kv_heads"]
     D = cfg["d_model"] // H

@@ -31,23 +31,42 @@ the head's whole ``ksum``, ``vsum`` ``[T / C, D]`` in VMEM (256 kB each at T
 16,384: fetched once a head, their block index does not move with the
 window) and walks the window in blocks of :func:`sub_block` rows.  A query
 block visits its own window's key blocks up to the diagonal (the causal mask
-on the diagonal block only), then the summaries of windows ``< w``, one
-window's ``W / C`` at a time in a loop of ``w`` turns: no mask inside a
-block, and the summaries of later windows are never multiplied.  One running
-maximum, sum and accumulator in VMEM serve both kinds of key.  The forward
-kernel writes ``o`` and the log-sum-exp of each query as one row ``[1, T]``
-a head.  The backward kernel recomputes a block's probabilities from that
-row, in the transposed orientation (keys on sublanes, queries on lanes), so
-that the row broadcasts as it lies and ``dk``, ``dv``, ``dksum``, ``dvsum``
-need no transpose; ``dq`` takes one.  ``dksum`` and ``dvsum`` of a head are
-float32 blocks that stay in VMEM over the head's windows.  Scores, maximum,
-sums and accumulators are float32, matmul operands the type ``q`` came in
-(``mixedp_attn``).  The gradient to ``ksum`` and ``vsum`` flows on through
-:func:`chunk_summaries` by JAX.
+on the diagonal block only), then what the windows ``< w`` hand over, at most
+a block's rows a turn (one window's ``W / C`` summaries) in a loop of ``w``
+turns: no mask inside a block, and what later windows hand over is never
+multiplied.  One running maximum, sum and accumulator in VMEM serve both
+kinds of key.  The forward kernel writes ``o`` and the log-sum-exp of each
+query as one row ``[1, T]`` a head.  The backward kernel recomputes a block's
+probabilities from that row, in the transposed orientation (keys on
+sublanes, queries on lanes), so that the row broadcasts as it lies and
+``dk``, ``dv``, ``dksum``, ``dvsum`` need no transpose; ``dq`` takes one.
+``dksum`` and ``dvsum`` of a head are float32 blocks that stay in VMEM over
+the head's windows.  Scores, maximum, sums and accumulators are float32,
+matmul operands the type ``q`` came in (``mixedp_attn``).  The gradient to
+``ksum`` and ``vsum`` flows on through :func:`chunk_summaries` by JAX.
 
 **Off the TPU**, or where ``T`` is no multiple of the window or the shapes
 miss the kernels' tiling, the same function is its plain twin: a loop over
 windows with dense ``[W, W + (W / C) w]`` scores a window, under autodiff.
+
+**Causal attention on the same two kernels** (:func:`causal_attention`; what
+``ops/ulysses.single_device_attention`` runs on a TPU wherever
+:func:`causal_kernels_take` the shape).  With ``ksum = k``, ``vsum = v`` and a
+chunk of one position the core above *is* causal softmax attention: "of
+every window before ``w`` every chunk's summary" is then every earlier key.
+So the kernels take what the earlier windows hand over as their parameter:
+summaries, ``W / C`` rows a window beside the window's own ``k``, ``v``; or
+the keys and values themselves, ``W`` rows a window, 512 a turn, and then no
+operand of the window's own: its rows are read out of the head's whole ``k``,
+``v`` ``[T, D]``, and its ``dk``, ``dv`` are added into the head's whole
+float32 block, where the later windows' queries add theirs.  Grouped keys are
+read as they are: query head ``h`` takes block ``h // (H / KV)``, which stays
+in VMEM over the group's heads (laid one after another in the grid), and the
+group's ``dk``, ``dv`` are summed there in float32 and rounded once.  The
+window is a function of ``T`` (:func:`causal_window`: one window up to 2,048
+positions, two at 4,096), the scale an argument, and the two calls are named
+``flash_attention_fwd_dpwa`` / ``flash_mha_bwd_dpwa``: the prefixes by which a
+trace's readers know a flash-attention kernel.
 
 **Under ``vmap``** (the stacked step's peer axis) a ``custom_vmap`` rule folds
 the peer axis into the kernels' sequence axis (``ops/ssm.folding_peers``).
@@ -55,6 +74,7 @@ the peer axis into the kernels' sequence axis (``ops/ssm.folding_peers``).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -152,10 +172,17 @@ def plain_eva_attention(q, k, v, ksum, vsum, window: int, chunk: int):
     return jnp.concatenate(out, 2).astype(q.dtype)
 
 
-# The kernels.  ``q``, ``k``, ``v`` (``o``, ``do``) are ``[S, h, T, D]`` over S
-# sequences and ``ksum``, ``vsum`` ``[S, h, T / chunk, D]``, as the module's
-# functions take them; the log-sum-exp and ``di = sum_d do o`` are ``[S, h, 1,
-# T]`` float32 rows.
+# The kernels.  ``q`` (``o``, ``do``) is ``[S, h, T, D]`` over S sequences; the
+# log-sum-exp and ``di = sum_d do o`` are ``[S, h, 1, T]`` float32 rows.  What
+# a query's earlier windows hand over is a head's *whole* operand, ``per_window``
+# rows a window:
+#
+# - summaries (the EVA core): ``ksum``, ``vsum`` ``[S, h, T / chunk, D]``,
+#   ``window / chunk`` rows a window, beside the window's own block of ``k``,
+#   ``v`` ``[S, h, T, D]``;
+# - the keys themselves (causal attention): ``k``, ``v`` ``[S, kv, T, D]``,
+#   ``window`` rows a window, and no block of the window's own: its rows lie
+#   in the head's, ``h // (h / kv)`` where the keys are grouped.
 
 
 def _lanes(column, width: int):
@@ -166,12 +193,25 @@ def _lanes(column, width: int):
     return jnp.broadcast_to(column[:, :1], (column.shape[0], width))
 
 
-def _forward_kernel(
-    q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, lse_ref,
-    m_ref, l_ref, acc_ref, *, block, per_window, scale,
-):
+def _own_window(refs, w, window: int):
+    """Views of the head's whole ``refs``: the rows of window ``w``."""
+    here = pl.ds(pl.multiple_of(w * window, window), window)
+    return [ref.at[here] for ref in refs]
+
+
+def _earlier(w, per_window: int, turn: int):
+    """``(turns, rows of a turn)`` of the loop over what the ``w`` earlier
+    windows hand over, ``turn`` rows of the head's whole operand at a time."""
+    turns = w if turn == per_window else w * (per_window // turn)
+    return turns, lambda c: pl.ds(pl.multiple_of(c * turn, turn), turn)
+
+
+def _forward_kernel(q_ref, *refs, block, per_window, scale):
+    *own, ks_ref, vs_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(2)
     window, d = q_ref.shape
+    k_ref, v_ref = own or _own_window((ks_ref, vs_ref), w, window)
+    turns, seen = _earlier(w, per_window, min(per_window, block))
     shape = (block, block)
     on_or_under = (
         lax.broadcasted_iota(jnp.int32, shape, 1)
@@ -209,29 +249,40 @@ def _forward_kernel(
             )
 
         def earlier_window(c, carry, q=q):
-            seen = pl.ds(pl.multiple_of(c * per_window, per_window), per_window)
-            attend(q, ks_ref[seen, :], vs_ref[seen, :], None)
+            rows = seen(c)
+            attend(q, ks_ref[rows, :], vs_ref[rows, :], None)
             return carry
 
-        lax.fori_loop(0, w, earlier_window, 0)
+        lax.fori_loop(0, turns, earlier_window, 0)
         total = l_ref[...]
         o_ref[rows, :] = (acc_ref[...] / _lanes(total, d)).astype(o_ref.dtype)
         # One value a row along the lanes -> one row of the block's queries.
         lse_ref[:, rows] = (m_ref[...] + jnp.log(total)).T[:1]
 
 
-def _backward_kernel(
-    q_ref, k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref, di_ref,
-    dq_ref, dk_ref, dv_ref, dks_ref, dvs_ref, dq_acc,
-    *, block, per_window, scale,
-):
+def _backward_kernel(q_ref, *refs, block, per_window, scale, group, own):
     # Everything here is transposed: a block of keys on the sublanes, a
     # block of queries on the lanes.
     w = pl.program_id(2)
     window, d = q_ref.shape
     blocks = window // block
+    if own:
+        (k_ref, v_ref, ks_ref, vs_ref, do_ref, lse_ref, di_ref,
+         dq_ref, dk_ref, dv_ref, dks_ref, dvs_ref, dq_acc) = refs
+    else:
+        (ks_ref, vs_ref, do_ref, lse_ref, di_ref,
+         dq_ref, dks_ref, dvs_ref, dq_acc) = refs
+        k_ref, v_ref, dk_ref, dv_ref = _own_window(
+            (ks_ref, vs_ref, dks_ref, dvs_ref), w, window
+        )
+    turns, seen = _earlier(w, per_window, min(per_window, block))
+    # The float32 blocks of a head's whole operand stay in VMEM over the
+    # head's windows and, where ``group`` query heads share them, over those.
+    first = w == 0
+    if group > 1:
+        first = jnp.logical_and(first, pl.program_id(1) % group == 0)
 
-    @pl.when(w == 0)
+    @pl.when(first)
     def _():
         dks_ref[...] = jnp.zeros_like(dks_ref)
         dvs_ref[...] = jnp.zeros_like(dvs_ref)
@@ -279,119 +330,155 @@ def _backward_kernel(
     for j in range(blocks):
         rows = pl.ds(j * block, block)
         d_keys, d_values = gathered(k_ref[rows, :], v_ref[rows, :], j, True)
-        dk_ref[rows, :] = d_keys.astype(dk_ref.dtype)
-        dv_ref[rows, :] = d_values.astype(dv_ref.dtype)
+        if own:
+            dk_ref[rows, :] = d_keys.astype(dk_ref.dtype)
+            dv_ref[rows, :] = d_values.astype(dv_ref.dtype)
+        else:
+            dk_ref[rows, :] += d_keys
+            dv_ref[rows, :] += d_values
 
     def earlier_window(c, carry):
-        seen = pl.ds(pl.multiple_of(c * per_window, per_window), per_window)
-        d_keys, d_values = gathered(ks_ref[seen, :], vs_ref[seen, :], 0, False)
-        dks_ref[seen, :] += d_keys
-        dvs_ref[seen, :] += d_values
+        rows = seen(c)
+        d_keys, d_values = gathered(ks_ref[rows, :], vs_ref[rows, :], 0, False)
+        dks_ref[rows, :] += d_keys
+        dvs_ref[rows, :] += d_values
         return carry
 
-    lax.fori_loop(0, w, earlier_window, 0)
+    lax.fori_loop(0, turns, earlier_window, 0)
     dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _specs(q, ksum, window: int):
-    """``(grid, a window's block, a head's summaries, a window's row)``."""
+def _layout(window: int, q, k, v, *summaries, backward: bool):
+    """``(float32 scratch shapes, float32 score tiles alive in a turn, what
+    pallas_call is told of grid, blocks and results)`` for the forward or the
+    backward kernel, from its operands' shapes and types alone (arrays or
+    ``jax.ShapeDtypeStruct``): ``q [S, h, T, D]``; with ``summaries`` the EVA
+    core's five operands, without them ``k``, ``v`` ``[S, kv, T, D]`` are the
+    head's whole operand."""
     seqs, heads, steps, d = q.shape
-    return (
-        (seqs, heads, steps // window),
-        pl.BlockSpec((None, None, window, d), lambda s, h, w: (s, h, w, 0)),
-        pl.BlockSpec(
-            (None, None, ksum.shape[2], d), lambda s, h, w: (s, h, 0, 0)
-        ),
-        pl.BlockSpec((None, None, 1, window), lambda s, h, w: (s, h, 0, w)),
+    ksum, vsum = summaries or (k, v)
+    group = heads // ksum.shape[1]
+    block = sub_block(window)
+    seq = pl.BlockSpec((None, None, window, d), lambda s, h, w: (s, h, w, 0))
+    row = pl.BlockSpec((None, None, 1, window), lambda s, h, w: (s, h, 0, w))
+    # A head's whole operand: its block index does not move with the window,
+    # nor with the query head inside a group, so it is fetched once.
+    whole = pl.BlockSpec(
+        (None, None, ksum.shape[2], d),
+        (lambda s, h, w: (s, h, 0, 0)) if group == 1
+        else (lambda s, h, w: (s, h // group, 0, 0)),
+    )
+    keys = bool(summaries) * [seq, seq] + [whole, whole]
+    like = lambda z, dtype: jax.ShapeDtypeStruct(z.shape, dtype)
+    grid = (seqs, heads, steps // window)
+    if not backward:
+        return 2 * [(block, LANES)] + [(block, d)], 3, dict(
+            grid=grid,
+            in_specs=[seq, *keys],
+            out_specs=[seq, row],
+            out_shape=[
+                like(q, q.dtype),
+                jax.ShapeDtypeStruct((seqs, heads, 1, steps), F32),
+            ],
+        )
+    own = bool(summaries) * [like(k, k.dtype), like(v, v.dtype)]
+    return [(window, d)], 6, dict(
+        grid=grid,
+        in_specs=[seq, *keys, seq, row, row],
+        out_specs=[seq, *keys],
+        out_shape=[like(q, q.dtype), *own, like(ksum, F32), like(vsum, F32)],
     )
 
 
-def _kernel_call(
-    kernel, name, interpret, grid, window, chunk, scratch, tiles, operands,
-    *, in_specs, out_specs, out_shape,
-):
-    """One ``pallas_call`` of ``kernel`` over ``grid`` with float32
-    ``scratch`` (shapes), and the VMEM limit its shapes come to with
-    ``tiles`` float32 score tiles alive in a turn of its loops."""
-    block, d = sub_block(window), operands[0].shape[-1]
-    need = vmem_need(
-        scratch + tiles * [(block, block)], in_specs + out_specs,
-        [v.dtype for v in (*operands, *out_shape)],
+def _vmem_need(window: int, scratch, tiles, call, operands) -> int:
+    block = sub_block(window)
+    return vmem_need(
+        scratch + tiles * [(block, block)],
+        call["in_specs"] + call["out_specs"],
+        [z.dtype for z in (*operands, *call["out_shape"])],
     )
+
+
+# (forward, backward) by whether the earlier windows hand over summaries.
+# The causal calls' names lie under the two prefixes by which a trace's
+# readers know a flash-attention kernel (``benchmark/tracered.FLASH_KERNEL``).
+KERNEL_NAMES = {
+    True: ("dpwa_eva_attention_fwd", "dpwa_eva_attention_bwd"),
+    False: ("flash_attention_fwd_dpwa", "flash_mha_bwd_dpwa"),
+}
+
+
+def _kernel_call(backward, interpret, window, per_window, scale, *operands):
+    """One ``pallas_call`` of the forward or the backward kernel on
+    ``operands`` (``q k v``, the summaries where there are any, and the
+    backward kernel's ``do lse di``), with the VMEM limit its shapes come to
+    with its score tiles alive in a turn of its loops.  Forward: ``(o [S, h,
+    T, D], lse [S, h, 1, T])``.  Backward: the gradients of ``q k v`` and of
+    the summaries, each in its argument's shape: with summaries ``dq dk dv``
+    in ``q``'s type and ``dksum dvsum`` float32; without them ``dq`` and
+    float32 ``dk dv``."""
+    keys = operands[:len(operands) - 3 * backward]
+    own = len(keys) == 5
+    group = operands[0].shape[1] // operands[1].shape[1]
+    scratch, tiles, call = _layout(window, *keys, backward=backward)
+    static = dict(block=sub_block(window), per_window=per_window, scale=scale)
+    if backward:
+        static.update(group=group, own=own)
     return pl.pallas_call(
         functools.partial(
-            kernel, block=block, per_window=window // chunk, scale=d ** -0.5
+            _backward_kernel if backward else _forward_kernel, **static
         ),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        **call,
         scratch_shapes=[pltpu.VMEM(shape, F32) for shape in scratch],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=vmem_limit(need),
+            # The query heads of a group add into one float32 block.
+            dimension_semantics=(
+                "parallel",
+                "arbitrary" if backward and group > 1 else "parallel",
+                "arbitrary",
+            ),
+            vmem_limit_bytes=vmem_limit(
+                _vmem_need(window, scratch, tiles, call, operands)
+            ),
         ),
         interpret=interpret,
-        name=name,
+        name=KERNEL_NAMES[own][backward],
     )(*operands)
 
 
-def _forward_call(interpret, window, chunk, q, k, v, ksum, vsum):
-    """``(o [S, h, T, D], lse [S, h, 1, T])``."""
-    grid, seq, summaries, row = _specs(q, ksum, window)
-    block, d = sub_block(window), q.shape[-1]
-    return _kernel_call(
-        _forward_kernel, "dpwa_eva_attention_fwd", interpret, grid, window,
-        chunk, 2 * [(block, LANES)] + [(block, d)], 3, (q, k, v, ksum, vsum),
-        in_specs=[seq, seq, seq, summaries, summaries],
-        out_specs=[seq, row],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(q.shape[:2] + (1, q.shape[2]), F32),
-        ],
-    )
-
-
-def _backward_call(interpret, window, chunk, q, k, v, ksum, vsum, do, lse, di):
-    """``dq``, ``dk``, ``dv`` in ``q``'s type and ``dksum``, ``dvsum``
-    float32, each in its argument's shape."""
-    grid, seq, summaries, row = _specs(q, ksum, window)
-    like = lambda z, dtype: jax.ShapeDtypeStruct(z.shape, dtype)
-    return _kernel_call(
-        _backward_kernel, "dpwa_eva_attention_bwd", interpret, grid, window,
-        chunk, [(window, q.shape[-1])], 6,
-        (q, k, v, ksum, vsum, do, lse, di),
-        in_specs=[seq, seq, seq, summaries, summaries, seq, row, row],
-        out_specs=[seq, seq, seq, summaries, summaries],
-        out_shape=[
-            like(q, q.dtype), like(k, k.dtype), like(v, v.dtype),
-            like(ksum, F32), like(vsum, F32),
-        ],
-    )
-
-
 @functools.lru_cache(maxsize=None)
-def _differentiable(interpret: bool, window: int, chunk: int):
-    """:func:`eva_attention`'s signature on the two kernels."""
-    forward = folding_peers(
-        functools.partial(_forward_call, interpret, window, chunk)
+def _differentiable(
+    interpret: bool, window: int, per_window: int, scale: float, scope,
+    jitted: bool,
+):
+    """``core(q, k, v, *summaries) -> o`` on the two kernels, differentiable
+    in every operand.  A custom gradient's instructions carry no name of the
+    forward's: ``scope`` names them where the forward has one of its own.
+    ``jitted`` puts each kernel call under ``jax.jit``, so that a model's
+    layers share one traced and lowered body a kernel, as the library's
+    ``flash_attention`` does for its own (four layers' unrolled block pairs
+    traced apart cost a step's set-up 5 s: PERF.md section 6, PR 46)."""
+    calls = (
+        functools.partial(
+            _kernel_call, backward, interpret, window, per_window, scale
+        )
+        for backward in (False, True)
     )
-    backward = folding_peers(
-        functools.partial(_backward_call, interpret, window, chunk)
+    forward, backward = (
+        folding_peers(jax.jit(call) if jitted else call) for call in calls
     )
 
     @jax.custom_vjp
-    def core(q, k, v, ksum, vsum):
-        return forward(q, k, v, ksum, vsum)[0]
+    def core(*inputs):
+        return forward(*inputs)[0]
 
-    def fwd(q, k, v, ksum, vsum):
-        o, lse = forward(q, k, v, ksum, vsum)
-        return o, (q, k, v, ksum, vsum, o, lse)
+    def fwd(*inputs):
+        o, lse = forward(*inputs)
+        return o, (inputs, o, lse)
 
     def bwd(residuals, do):
-        *inputs, o, lse = residuals
-        # A custom gradient's instructions carry no name of the forward's.
-        with jax.named_scope(scopes.ATTN_EVA.core):
+        inputs, o, lse = residuals
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
             di = (do.astype(F32) * o.astype(F32)).sum(-1)  # [B, h, T]
             grads = backward(*inputs, do, lse, di[:, :, None])
             return tuple(g.astype(z.dtype) for g, z in zip(grads, inputs))
@@ -400,11 +487,69 @@ def _differentiable(interpret: bool, window: int, chunk: int):
     return core
 
 
-def kernel_eva_attention(q, k, v, ksum, vsum, window: int, chunk: int):
+def kernel_eva_attention(
+    q, k, v, ksum, vsum, window: int, chunk: int, interpret: bool = False
+):
     """:func:`eva_attention` by the Pallas kernels."""
-    return _differentiable(False, window, chunk)(q, k, v, ksum, vsum)
+    # Not ``jitted``: the EvaByte step compiles to its accepted text; the PR
+    # that moves that cell can take the shorter set-up with it.
+    return _differentiable(
+        interpret, window, window // chunk, q.shape[-1] ** -0.5,
+        scopes.ATTN_EVA.core, False,
+    )(q, k, v, ksum, vsum)
 
 
 def interpreted_eva_attention(q, k, v, ksum, vsum, window: int, chunk: int):
     """The same kernels run by the Pallas interpreter, for tests off the TPU."""
-    return _differentiable(True, window, chunk)(q, k, v, ksum, vsum)
+    return kernel_eva_attention(q, k, v, ksum, vsum, window, chunk, True)
+
+
+# Causal attention on the same two kernels: every earlier key is "a summary"
+# of itself (``ksum = k``, ``vsum = v``, a chunk of one position).
+
+# What a call may ask Mosaic for: half of the 128 MiB a v5e core has.
+VMEM_CEILING = 64 * 2 ** 20
+
+
+def causal_window(T: int) -> int:
+    """The window causal attention over ``T`` positions runs in: the largest
+    multiple of the lanes that divides ``T``, within 2,048 positions and
+    eight of its blocks (a window's block pairs are unrolled in the kernels:
+    36 at eight).  One window of ``T`` up to 2,048 where its blocks allow,
+    two of 2,048 at 4,096; ``T`` itself where the lanes do not divide it."""
+    fitting = [
+        w for w in range(LANES, min(T, 2048) + 1, LANES)
+        if T % w == 0 and w // sub_block(w) <= 8
+    ]
+    return max(fitting, default=T)
+
+
+def causal_kernels_take(T: int, d: int, heads: int, kv_heads: int, dtype) -> bool:
+    """Whether the kernels run causal attention of ``heads`` query heads on
+    ``kv_heads`` heads of keys and values, all of size ``d``, over ``T``
+    positions: ``d`` and ``T`` in multiples of the lanes, ``kv_heads``
+    dividing ``heads``, and the backward call, whose float32 ``dk``, ``dv``
+    of a whole head grow with ``T``, inside :data:`VMEM_CEILING`."""
+    window = causal_window(T)
+    if d % LANES or T % LANES or T % window or heads % kv_heads:
+        return False
+    shaped = lambda h: jax.ShapeDtypeStruct((1, h, T, d), dtype)
+    q, k, rows = shaped(heads), shaped(kv_heads), jax.ShapeDtypeStruct(
+        (1, heads, 1, T), F32
+    )
+    need = _vmem_need(
+        window, *_layout(window, q, k, k, backward=True), (q, k, k, q, rows, rows)
+    )
+    return vmem_limit(need) <= VMEM_CEILING
+
+
+def causal_attention(q, k, v, sm_scale: float, interpret: bool = False):
+    """``o [B, h, T, D]`` of causal softmax attention with scores ``sm_scale
+    q . k``, for ``q [B, h, T, D]`` and ``k``, ``v`` ``[B, kv, T, D]`` that
+    :func:`causal_kernels_take`; differentiable in all three (``dk``, ``dv``
+    summed over a group's query heads in float32 inside the kernel).
+    ``interpret`` runs the kernels by the Pallas interpreter, off the TPU."""
+    window = causal_window(q.shape[2])
+    return _differentiable(
+        interpret, window, window, float(sm_scale), None, True
+    )(q, k, v)

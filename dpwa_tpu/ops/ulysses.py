@@ -10,8 +10,9 @@ SP re-shards around attention itself:
 2. ``lax.all_to_all`` re-shards q/k/v to HEAD-sharded with the FULL
    sequence per device (``[B, T_global, H/sp, D]``);
 3. each device runs ordinary single-device causal attention over its
-   heads — on TPU the same Pallas flash kernel as the single-device model
-   path, O(T) memory via VMEM score tiles;
+   heads — on TPU the same Pallas flash kernels as the single-device model
+   path (:func:`single_device_attention` picks them by shape), O(T) memory
+   via VMEM score tiles;
 4. a second ``all_to_all`` returns to sequence-sharded layout.
 
 Trade-offs vs the ring: two all-to-alls per attention instead of n
@@ -128,40 +129,64 @@ def single_device_attention(
 ):
     """THE single-device attention of the framework, shared by the Llama
     model's non-sp path and the a2a strategy's per-device compute:
-    [B, T, h, D] layout, GQA expanded here if still grouped.  ``q`` and ``k``
+    [B, T, h, D] layout, keys and values grouped or not.  ``q`` and ``k``
     share one head size and ``v`` may have another (latent attention: 192
     and 128); ``sm_scale`` multiplies the scores (``1 / sqrt(q's head size)``
-    where not given).  ``impl``: "flash" forces the Pallas kernel, "auto"
-    uses it on TPU when T fits its tiling (a multiple of 128), anything else
-    runs the masked-softmax einsum with f32 accumulation.
+    where not given).  ``impl``: "flash" forces the Pallas kernels, "auto"
+    uses them on TPU when T fits their tiling (a multiple of 128), anything
+    else runs the masked-softmax einsum with f32 accumulation.
 
-    The library's kernels take one head size: a multiple of 128, or 64 as it
-    is (a block's 64 lanes are half a register row; on the v5e at 2 x 32 x
-    4,096 x 64 they give what the same heads zero-padded to 128 give, bit for
-    bit in a whole step's loss, in the same 16.7 ms forward and backward, and
-    the step saves the pad and the slice: PERF.md section 6, PR 44).  Where
-    the head sizes differ or are neither, q, k and v are zero-padded to the
-    next multiple of 128 and the output is sliced back: exact, because a zero
-    column adds nothing to a score or to a value, and paid for in the
-    kernels' arithmetic (192 / 128 run as 256 / 256).  No head size falls to
-    the einsum silently on a TPU whose tiling T fits: at T 4,096 its float32
-    scores are 4.3 GB for two sequences of 32 heads.
+    Two families of kernels, picked by the call's shapes alone:
 
-    The library's forward, dkv and dq kernels run with the block sizes
-    :func:`_flash_block_sizes` picks from this call's own ``T`` and
-    ``head_dim`` (the library's default is 128 for every block, which at
-    T 4096 pays a grid step's overhead eight times for each step's
-    arithmetic); the sweep on the v5e behind the rule is PERF.md,
-    section 6, PR 25."""
+    - **ours** (``ops/eva.causal_attention``: the EVA core's two kernels with
+      a query's earlier windows seen by their keys themselves) where the
+      call is causal, ``q k v`` share a head size that is a multiple of 128,
+      ``T`` is a multiple of 128 and the backward call's blocks of a whole
+      head fit the VMEM it may ask for (``ops/eva.causal_kernels_take``: at a
+      head of 128 up to T 8,192).  ``q k v`` are turned heads first once, the
+      keys stay grouped (no ``jnp.repeat``: query head ``h`` reads block ``h
+      // (h / kv)``), one forward and one backward kernel (``QK^T`` once),
+      the log-sum-exp and ``di`` one ``[1, T]`` row a head, ``dk`` / ``dv``
+      summed over a group in float32 in VMEM (PERF.md section 6, PR 46).
+    - **the library's** flash kernels for every other shape (a head of 64,
+      192 / 128, ``causal=False``, a longer ``T``), GQA expanded here first.
+      They take one head size: a multiple of 128, or 64 as it is (a block's
+      64 lanes are half a register row; on the v5e at 2 x 32 x 4,096 x 64
+      they give what the same heads zero-padded to 128 give, bit for bit in
+      a whole step's loss, in the same 16.7 ms forward and backward, and the
+      step saves the pad and the slice: PERF.md section 6, PR 44).  Where the
+      head sizes differ or are neither, q, k and v are zero-padded to the
+      next multiple of 128 and the output is sliced back: exact, because a
+      zero column adds nothing to a score or to a value, and paid for in the
+      kernels' arithmetic (192 / 128 run as 256 / 256).  Their forward, dkv
+      and dq kernels run with the block sizes :func:`_flash_block_sizes`
+      picks from this call's own ``T`` and ``head_dim`` (the library's
+      default is 128 for every block, which at T 4096 pays a grid step's
+      overhead eight times for each step's arithmetic); the sweep on the v5e
+      behind the rule is PERF.md, section 6, PR 25.
+
+    No head size falls to the einsum silently on a TPU whose tiling T fits:
+    at T 4,096 its float32 scores are 4.3 GB for two sequences of 32
+    heads."""
+    from dpwa_tpu.ops import eva
+
     B, T, h, D = q.shape
     Dv = v.shape[-1]
+    use_flash = impl == "flash" or (
+        impl == "auto" and jax.default_backend() == "tpu" and T % 128 == 0
+    )
+    scale = float(1.0 / (D ** 0.5) if sm_scale is None else sm_scale)
+    if use_flash and causal and D == Dv and eva.causal_kernels_take(
+        T, D, h, k.shape[2], q.dtype
+    ):
+        heads_first = lambda x: x.transpose(0, 2, 1, 3)
+        return heads_first(
+            eva.causal_attention(*map(heads_first, (q, k, v)), scale)
+        )
     if k.shape[2] != h:
         rep = h // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
         v = jnp.repeat(v, rep, axis=2)
-    use_flash = impl == "flash" or (
-        impl == "auto" and jax.default_backend() == "tpu" and T % 128 == 0
-    )
     if use_flash:
         from jax.experimental.pallas.ops.tpu.flash_attention import (
             flash_attention,
@@ -179,7 +204,7 @@ def single_device_attention(
             k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3),
             causal=causal,
-            sm_scale=float(1.0 / (D ** 0.5) if sm_scale is None else sm_scale),
+            sm_scale=scale,
             block_sizes=_flash_block_sizes(T, q.shape[-1]),
         ).transpose(0, 2, 1, 3)
         return out if as_it_is else out[..., :Dv]

@@ -215,23 +215,30 @@ def test_the_tpu_compiler_keeps_the_scopes_on_its_fusions(
         (8, 512, 128),  # mistral7b-lora-stacked2-t512
         (2, 1024, 128),  # chip_smoke.py
         (1, 4096, 128),  # mistral7b-lora-stacked2-t4096
-        (1, 16384, 128),
+        (1, 8192, 128),
         (1, 512, 256),
         (1, 4096, 256),
-        (1, 4096, 512),  # tiles grow with the head size: the caps shrink
+        (1, 4096, 512),  # dk, dv of a whole head over the ceiling: the library,
+                         # whose tiles grow with the head size: the caps shrink
+        (1, 16384, 128),  # likewise
+        (2, 512, 64),  # a head of 64 stays on the library
+        (1, 4096, 64),  # lfm2-lora-stacked2-t4096
     ],
 )
 def test_the_chosen_flash_blocks_fit_the_v5e(v5e_chips, B, T, head_dim):
-    """Mosaic compiles forward, dkv and dq with the blocks
-    `single_device_attention` chooses (a candidate that does not fit the
-    scoped VMEM is refused here, with no chip), under `vmap` over peers as
-    the stacked step runs them, and the backward kernels carry the chosen
-    sizes in their names, which is how a trace shows them."""
+    """Mosaic compiles the forward and backward kernels of the family
+    `single_device_attention` picks for the shape, with the window and the
+    blocks it chooses (a candidate that does not fit the VMEM it may ask for
+    is refused here, with no chip), under `vmap` over peers as the stacked
+    step runs them.  Our causal kernels carry the two prefixes the trace's
+    readers know a flash kernel by; the library's backward kernels carry the
+    chosen sizes in their names, which is how a trace shows them."""
     import dataclasses
     import functools
 
     from jax.sharding import SingleDeviceSharding
 
+    from dpwa_tpu.ops import eva
     from dpwa_tpu.ops.ulysses import (
         _flash_block_sizes, single_device_attention,
     )
@@ -248,6 +255,16 @@ def test_the_chosen_flash_blocks_fit_the_v5e(v5e_chips, B, T, head_dim):
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             shaped(8), shaped(2), shaped(2)
         ).compile().as_text()
+    ours = eva.causal_kernels_take(T, head_dim, 8, 2, jnp.bfloat16)
+    assert ours == (head_dim % 128 == 0 and T * head_dim < 16384 * 128)
+    if ours:
+        assert text.count("tpu_custom_call") == 2
+        for name in eva.KERNEL_NAMES[False]:
+            assert name in text, name
+        assert "flash_mha_bwd_dq" not in text
+        # Keys and values stay grouped: no array of eight heads of them.
+        assert f"bf16[{2 * B},2,{T},{head_dim}]" in text
+        return
     assert text.count("tpu_custom_call") >= 3
     b = dataclasses.asdict(_flash_block_sizes(T, head_dim))
     for name in (
@@ -677,9 +694,16 @@ def _abstract_llama_state(n, sharding):
     return opt, state
 
 
-def _kernels_traced(step_fn, state, batch) -> int:
-    jaxpr = jax.make_jaxpr(step_fn)(state, batch)
-    return str(jaxpr).count("pallas_call")
+def _kernels_traced(step_fn, state, batch):
+    """``(pallas calls in the step, whether two of them are the forward and
+    the backward kernel of causal attention at a head of 128)``:
+    `ops/eva.causal_attention`'s; the library's family, which ran here before
+    PR 46, has three."""
+    from dpwa_tpu.ops import eva
+
+    text = str(jax.make_jaxpr(step_fn)(state, batch))
+    ours = all(f"name={name}" in text for name in eva.KERNEL_NAMES[False])
+    return text.count("pallas_call"), ours
 
 
 def test_llama_1d_step_traces_with_the_flash_kernel_inside_shard_map():
@@ -704,8 +728,9 @@ def test_llama_1d_step_traces_with_the_flash_kernel_inside_shard_map():
         loss_fn, opt, transport, exchange_filter=lora_filter
     )
     tokens = jax.ShapeDtypeStruct((n, 1, T), jnp.int32, sharding=sh)
-    # Forward, dq and dkv kernels: refused outright by a checked map.
-    assert _kernels_traced(step_fn, state, (tokens, tokens)) >= 3
+    # Forward and backward kernels: refused outright by a checked map.
+    calls, ours = _kernels_traced(step_fn, state, (tokens, tokens))
+    assert calls >= 2 and ours
 
 
 @pytest.mark.parametrize(
@@ -752,7 +777,11 @@ def test_llama_2d_step_traces_with_pallas_hops_inside_shard_map(
     tokens = jax.ShapeDtypeStruct(
         (n, 1, sp * T_local), jnp.int32, sharding=sp_batch_sharding(mesh)
     )
-    assert _kernels_traced(step_fn, state, (tokens, tokens)) >= 3
+    # The ring's hops are a forward, a dq and a dkv kernel; the a2a strategy
+    # runs `single_device_attention`'s forward and backward kernel.
+    a2a = variant.get("sp_strategy") == "a2a"
+    calls, ours = _kernels_traced(step_fn, state, (tokens, tokens))
+    assert calls >= (2 if a2a else 3) and ours == a2a
 
 
 # ---------------------------------------------------------------------------

@@ -538,8 +538,10 @@ def test_the_tpu_kernels_equal_ragged_dot(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pltpu.force_tpu_interpret_mode():
         assert "pallas_call" in str(jax.make_jaxpr(one)(lhs[0], rhs[0], sizes[0]))
-        alone = one(lhs[0], rhs[0], sizes[0])
-        folded = jax.vmap(one)(lhs, rhs, sizes)
+        # At rest before the next dispatch: the TPU interpreter's callbacks
+        # run JAX operations of their own (tests/test_lfm2.py has the story).
+        alone = jax.block_until_ready(one(lhs[0], rhs[0], sizes[0]))
+        folded = jax.block_until_ready(jax.vmap(one)(lhs, rhs, sizes))
     for got, ref in zip(jax.tree.leaves(folded), jax.tree.leaves(want)):
         np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-3)
     for got, ref in zip(jax.tree.leaves(alone), jax.tree.leaves(want)):
