@@ -21,8 +21,6 @@ unless ``jax.devices()[0].platform == "tpu"``.  Each leg prints one JSON line
 from __future__ import annotations
 
 import argparse
-import dataclasses
-import functools
 import json
 import os
 import statistics
@@ -52,11 +50,6 @@ class Sizes:
                 d_ff=256, n_layers=1, dtype=jnp.float32,
             )
             self.seq_len, self.llama_batch = 128, 1
-            self.merge_d, self.attn_t = 8 * 1024, 128
-            self.experts = dict(
-                n=8, top=2, d_model=64, d_ff=64, rank=4, tokens=64,
-                dtype=jnp.float32,
-            )
             self.steps = 2
         else:
             # ResNet-50 and the decoder at published width; only the
@@ -69,12 +62,6 @@ class Sizes:
                 d_ff=3072, n_layers=2, dtype=jnp.bfloat16,
             )
             self.seq_len, self.llama_batch = 1024, 2
-            self.merge_d, self.attn_t = 24 * 2**20, 1024  # bench.py's d
-            # OLMoE-1B-7B's expert layer at its published widths.
-            self.experts = dict(
-                n=64, top=8, d_model=2048, d_ff=1024, rank=16, tokens=256,
-                dtype=jnp.bfloat16,
-            )
             self.steps = 5
 
 
@@ -497,164 +484,8 @@ def sp_llama_leg(sz: Sizes, platform: str) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Kernels alone, and the host path onto the device
+# The host path onto the device
 # ---------------------------------------------------------------------------
-
-
-def pair_merge_leg(sz: Sizes, platform: str) -> dict:
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from dpwa_tpu.ops.merge import (
-        involution_pairs,
-        pallas_pair_merge,
-        xla_pairwise_merge,
-    )
-    from dpwa_tpu.ops.ulysses import (
-        _flash_block_sizes,
-        single_device_attention,
-    )
-    from dpwa_tpu.parallel.schedules import _ring_even, _ring_odd
-
-    n, d = 8, sz.merge_d
-    interpret = sz.rehearsal
-    x = jax.random.normal(jax.random.key(SEED + 3), (n, d // 128, 128))
-    alpha = jnp.asarray(
-        np.random.default_rng(SEED).uniform(0.1, 0.9, n), jnp.float32
-    )
-    merge = functools.partial(pallas_pair_merge, interpret=interpret)
-    worst, text = 0.0, ""
-    for phase in (_ring_even(n), _ring_odd(n)):
-        left, right = (jnp.asarray(v) for v in involution_pairs(phase))
-        want = jax.jit(xla_pairwise_merge)(
-            x.reshape(n, d), jnp.asarray(phase), alpha
-        ).reshape(x.shape)
-        text = text or hlo_text(merge, x, left, right, alpha)
-        x = merge(x, left, right, alpha)  # donated, merged in place
-        worst = max(worst, float(jnp.max(jnp.abs(x - want))))
-        del want
-    # f32 lerps of N(0,1) values; the two may contract mul+add differently.
-    assert worst <= 1e-5, f"pair kernel off the XLA merge by {worst}"
-    assert ("tpu_custom_call" in text) != interpret, "wrong kernel mode"
-    assert {dev.platform for dev in x.devices()} == {platform}
-    del x
-
-    # The flash kernels `single_device_attention` picks for the shape (a head
-    # of 128: the EVA core's two) against the dense branch (GQA, causal).
-    T, cfg = sz.attn_t, sz.llama
-    H, KV = cfg["n_heads"], cfg["n_kv_heads"]
-    D = cfg["d_model"] // H
-    ks = jax.random.split(jax.random.key(SEED + 4), 3)
-    q = jax.random.normal(ks[0], (1, T, H, D), cfg["dtype"])
-    k, v = (
-        jax.random.normal(kk, (1, T, KV, D), cfg["dtype"]) for kk in ks[1:]
-    )
-    attn = lambda impl: functools.partial(
-        single_device_attention, causal=True, impl=impl
-    )
-    with kernel_mode(sz):
-        flash_text = hlo_text(attn("flash"), q, k, v)
-        got = jax.jit(attn("flash"))(q, k, v)
-    dense = jax.jit(attn("dense"))(q, k, v)
-    attn_err = float(
-        jnp.max(jnp.abs(got.astype(jnp.float32) - dense.astype(jnp.float32)))
-    )
-    # bf16 outputs up to |v| ~ 4 are spaced 2**-6 apart.
-    assert attn_err <= (1e-4 if interpret else 5e-2), (
-        f"flash off dense by {attn_err}"
-    )
-    assert ("tpu_custom_call" in flash_text) != interpret, "wrong kernel mode"
-    return dict(
-        merge_d=d, merge_max_abs_err=worst, attention_t=T,
-        attention_max_abs_err=attn_err, interpret=interpret,
-        attention_blocks=dataclasses.asdict(_flash_block_sizes(T, D)),
-        **expert_layer_check(sz),
-    )
-
-
-def expert_layer_check(sz: Sizes) -> dict:
-    """The dropless expert layer (``ops/moe.py``: sort, gathers, grouped
-    matmuls with adapters, combine) against every expert computed densely
-    for every token in float32 (``benchmark/references/moe_decoder.py``),
-    under one routing: the output, and the gradients to the tokens and to
-    every adapter."""
-    import jax
-    import jax.numpy as jnp
-
-    from benchmark.references import moe_decoder as plain
-    from dpwa_tpu.ops import moe
-
-    t0 = time.perf_counter()
-    e = sz.experts
-    n, top, d, f, r, dtype = (
-        e["n"], e["top"], e["d_model"], e["d_ff"], e["rank"], e["dtype"]
-    )
-    ks = iter(jax.random.split(jax.random.key(SEED + 5), 12))
-    x = jax.random.normal(next(ks), (e["tokens"], d), jnp.float32)
-    router = jax.random.normal(next(ks), (d, n)) / d ** 0.5
-    names, shapes = ("w_gate", "w_up", "w_down"), ((d, f), (d, f), (f, d))
-    kernels = [
-        (jax.random.normal(next(ks), (n,) + s) / s[0] ** 0.5).astype(dtype)
-        for s in shapes
-    ]
-    adapters = [
-        (0.02 * jax.random.normal(next(ks), (n, s[0], r)),
-         0.02 * jax.random.normal(next(ks), (n, r, s[1])))
-        for s in shapes
-    ]
-    weights, experts, _ = moe.route(x, router, top)
-    cot = jax.random.normal(next(ks), (e["tokens"], d), jnp.float32)
-
-    # The kernels are arguments: closed over, 0.8 GB of them would be
-    # constants of the lowered programs (and of their text).
-    def program(x, adapters, kernels):
-        out = moe.moe_ffn(
-            x, (weights, experts),
-            *[(k, a, b) for k, (a, b) in zip(kernels, adapters)], 1.0, dtype,
-        )
-        return jnp.sum(out.astype(jnp.float32) * cot), out
-
-    def dense(x, adapters, kernels):
-        named = {
-            name: dict(kernel=k, lora_a=a, lora_b=b)
-            for name, k, (a, b) in zip(names, kernels, adapters)
-        }
-        combine = plain.combine_of(weights, experts, n)
-        out = plain.dense_experts(x, named, combine, 1.0)
-        return jnp.sum(out * cot), out
-
-    grad = lambda fn: jax.jit(jax.value_and_grad(fn, (0, 1), has_aux=True))
-    expert_text = hlo_text(
-        lambda x, a, k: program(x, a, k)[1], x, adapters, kernels
-    )
-    (_, got), got_grads = grad(program)(x, adapters, kernels)
-    (_, want), want_grads = grad(dense)(x, adapters, kernels)
-    rms = lambda v: float(jnp.sqrt(jnp.mean(jnp.square(v.astype(jnp.float32)))))
-    worst_grad = max(
-        rms(g - w) / rms(w) for g, w in
-        zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads))
-    )
-    out_err = float(jnp.max(jnp.abs(got.astype(jnp.float32) - want)))
-    # bf16 rows through three matmuls against float32: about 1 % of rms.
-    limit = 1e-4 if sz.rehearsal else 3e-2
-    assert rms(got - want) <= limit * rms(want), (
-        f"expert layer off the dense reference by {rms(got - want)} rms"
-    )
-    assert worst_grad <= limit, f"expert gradients off by {worst_grad}"
-    stats = moe.routing_stats(experts, n)
-    assert int(stats["dropped"]) == 0
-    # On the chip the grouped matmuls are the library's Pallas kernels.
-    assert ("tpu_custom_call" in expert_text) != sz.rehearsal, (
-        "wrong grouped matmul"
-    )
-    return dict(
-        expert_tokens=e["tokens"], expert_max_abs_err=out_err,
-        expert_rel_rms_err=rms(got - want) / rms(want),
-        expert_grad_worst_rel_rms_err=worst_grad,
-        expert_fullest_over_mean=float(stats["max_over_mean"]),
-        expert_check_s=round(time.perf_counter() - t0, 1),
-    )
 
 
 def host_device_merge_leg(sz: Sizes, platform: str) -> dict:
@@ -753,7 +584,6 @@ def main(argv=None) -> int:
         # (name, chips needed, body)
         ("stacked_resnet50", 1,
          lambda: resnet_leg(sz, "stacked", 8, platform)),
-        ("pair_merge", 1, lambda: pair_merge_leg(sz, platform)),
         ("host_device_merge", 1, lambda: host_device_merge_leg(sz, platform)),
         ("ici_exchange", 2, lambda: ici_exchange_leg(sz, mesh_n, platform)),
         ("ici_resnet50", 2, lambda: resnet_leg(sz, "ici", mesh_n, platform)),

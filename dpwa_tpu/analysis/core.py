@@ -48,7 +48,7 @@ from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
 from dpwa_tpu.analysis.rules import RULE_IDS
 
-DEFAULT_TARGETS = ("dpwa_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("dpwa_tpu", "tools")
 _PRUNE_DIRS = {"__pycache__", ".git", "artifacts", "fixtures"}
 
 

@@ -32,7 +32,7 @@ _DECISION_MARKERS = (
     "parallel/schedules.py",
     "trust/",
     "membership/",
-    "parallel/interpolation.py",
+    "dpwa_tpu/interpolation.py",
     "parallel/async_loop.py",
     "run/",
     "tune/",

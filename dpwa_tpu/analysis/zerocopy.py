@@ -4,7 +4,7 @@ The frame hot path (docs/transport.md "The zero-copy landing zone")
 moves payload bytes from socket to merge as memoryviews over ring
 buffers; one stray ``.tobytes()`` or ``bytes(...)`` silently
 reintroduces a payload-sized copy per frame and the perf regression is
-invisible until a bench run.  ``zerocopy-tobytes`` makes the copy
+invisible until someone times the path.  ``zerocopy-tobytes`` makes the copy
 discipline structural: on the frame-path modules listed below, every
 ``.tobytes()`` attribute call and every ``bytes(...)`` constructor call
 is an error unless annotated with the standard suppression grammar and

@@ -22,8 +22,8 @@ three parts (docs/device.md):
   mirror (readback only at publish/checkpoint/trust boundaries).
 
 Importable without a JAX backend — jax loads inside the kernel
-builders and handoff calls, never at module scope (the bench-harness
-contract shared with ``parallel/tcp.py``).
+builders and handoff calls, never at module scope (the contract shared
+with ``parallel/tcp.py``).
 """
 
 from dpwa_tpu.device.engine import (

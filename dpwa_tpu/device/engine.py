@@ -1,8 +1,7 @@
 """The merge engine: codec-aware fused merges over a device replica.
 
 :class:`MergeEngine` owns the numpy↔JAX seam for the gossip merge —
-``TcpTransport.exchange_on_device`` and the bench harness are thin
-callers.  Every ``merge_*`` method takes the device-resident local
+``TcpTransport.exchange_on_device`` is a thin caller.  Every ``merge_*`` method takes the device-resident local
 replica plus a decoded frame's RAW parts (dense view, u16 bf16 view,
 int8 q+scale views, top-k index/value pair, shard slice), crosses them
 through :mod:`~dpwa_tpu.device.handoff` exactly once, and dispatches
@@ -164,8 +163,8 @@ class MergeEngine:
 
     def merge(self, local_dev, remote, alpha: float):
         """Dispatch a decoded frame by its payload type — the thin-
-        caller entry :meth:`~dpwa_tpu.parallel.tcp.TcpTransport`-side
-        substrates and the bench harness share."""
+        caller entry the :meth:`~dpwa_tpu.parallel.tcp.TcpTransport`-side
+        substrates share."""
         if isinstance(remote, TopkPayload):
             return self.merge_topk(
                 local_dev, remote.indices, remote.values, alpha
@@ -255,7 +254,7 @@ def device_snapshot() -> dict:
 
 
 def reset_device_stats() -> None:
-    """Test/bench hook: fresh default engine + zeroed handoff tally."""
+    """Test hook: fresh default engine + zeroed handoff tally."""
     global _DEFAULT_ENGINE
     with _DEFAULT_LOCK:
         _DEFAULT_ENGINE = None

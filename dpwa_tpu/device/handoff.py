@@ -31,7 +31,7 @@ other ``np.asarray(device_array)`` in a merge-path module is a lint
 error (``device-host-roundtrip``).
 
 Pure-python tallies only at import: jax loads inside the functions, so
-the module is importable without a backend (the bench harness contract).
+the module is importable without a backend.
 """
 
 from __future__ import annotations
@@ -125,7 +125,7 @@ def handoff_stats() -> dict:
 
 
 def reset_handoff_stats() -> None:
-    """Test/bench hook: zero the process-wide tally."""
+    """Test hook: zero the process-wide tally."""
     global _H2D_ZERO_COPY, _H2D_COPIED, _H2D_BYTES
     global _D2H_READBACKS, _D2H_BYTES
     with _LOCK:

@@ -577,8 +577,8 @@ class FleetOrchestrator:
 
         Returns entry counts and an approximate resident byte figure
         (``sys.getsizeof`` sums over the per-peer containers) for the
-        scoreboard and membership planes — the quantity the fleet bench
-        leg records per node to prove the ``membership.view``
+        scoreboard and membership planes — the quantity the slow soaks in
+        ``tests/test_fleet.py`` read per node to prove the ``membership.view``
         ``state_cap`` bound holds at 4096 (docs/membership.md).  The
         byte figure is an approximation, but a consistent one across N,
         which is all an O(sample)-vs-O(N) verdict needs.
@@ -668,7 +668,7 @@ class FleetOrchestrator:
         if self.membership_cfg.view.enabled:
             # View-only optional fields (legacy episodes byte-identical):
             # worst-case residency across live nodes — the O(state_cap)
-            # figures the fleet bench gate rides on (docs/membership.md).
+            # figures the soaks bound (docs/membership.md).
             res = [self.residency_snapshot(p) for p in live]
             episode["view_max_resident_bytes"] = max(
                 (s["resident_bytes"] for s in res), default=0

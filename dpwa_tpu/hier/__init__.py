@@ -5,7 +5,7 @@ islands; each island averages internally over the fast fabric and
 delegates its wide-area voice to one threefry-elected leader.  This
 package holds the resolved topology view, the leader board
 (election + failover succession), the two-level TCP pairing schedule,
-and the in-process CPU simulator the tests and bench legs drive.
+and the in-process CPU simulator the tests drive.
 """
 
 from dpwa_tpu.hier.engine import HierGossipEngine

@@ -13,7 +13,7 @@ averages.  Per round (mirroring ``hier/schedule.py``'s cycle):
 2. **wide-area leg**: ONLY island leaders exchange, paired by the same
    round-robin island tournament the TCP pool compiles in; 2 wide-area
    frames per realized pair — this is the ~island_size× frame reduction
-   the bench ``--hier-leg`` measures;
+   ``tests/test_hier.py`` counts;
 3. **fan-back**: the leader's merged replica is re-broadcast in-island
    (ICI frames again), so every member re-enters the next round equal.
 

@@ -105,7 +105,7 @@ def build_hier_schedule(config: DpwaConfig) -> Schedule:
 
 def wide_slot_indices(schedule: Schedule, topo: Topology) -> tuple:
     """Pool-row indices whose pairings cross islands (the wide-area
-    slots) — the accounting hook bench's ``--hier-leg`` uses."""
+    slots) — the accounting hook ``tests/test_hier.py`` counts with."""
     wide = []
     for k, row in enumerate(schedule.pool):
         if any(

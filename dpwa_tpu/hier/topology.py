@@ -57,7 +57,7 @@ class Topology:
 
     @classmethod
     def uniform(cls, n_islands: int, island_size: int) -> "Topology":
-        """Synthetic even partition (bench sweeps / tests): island ``g``
+        """Synthetic even partition (tests): island ``g``
         owns peers ``[g*island_size, (g+1)*island_size)``."""
         if n_islands < 1 or island_size < 1:
             raise ValueError(

@@ -509,8 +509,7 @@ class MetricsLogger:
 
         ``status: "start"`` opens a node's stream with the leg shape;
         exactly one terminal ``"done"``/``"crashed"`` row carries the
-        outcome fields ``tools/run_report.py`` and the bench train leg
-        consume.  Bypasses ``every``: an envelope row dropped to a
+        outcome fields ``tools/run_report.py`` consumes.  Bypasses ``every``: an envelope row dropped to a
         sampling interval would orphan the whole stream."""
         self.flush()
         rec: dict[str, Any] = {

@@ -587,7 +587,7 @@ class Attention(nn.Module):
         # flash-vs-dense dispatch, f32 accumulation) — shared with the
         # a2a strategy's per-device compute.  Flash: O(T) memory, score
         # panels in VMEM tiles, never HBM (what makes long single-device
-        # sequences fit at all; artifacts/attention_memory.json).
+        # sequences fit at all).
         from dpwa_tpu.ops.ulysses import single_device_attention
 
         out = single_device_attention(

@@ -193,7 +193,7 @@ class Tracer:
 
     def stage_summary(self) -> Dict[str, dict]:
         """Per-stage ``{n, median_ms, mean_ms, total_s}`` over the recent
-        window — the bench's span breakdown and the /metrics gauges."""
+        window — the span breakdown behind the /metrics gauges."""
         out: Dict[str, dict] = {}
         self._drain_serves()
         with self._lock:

@@ -6,8 +6,8 @@ wire bytes than f32 (the bf16 wire halves them; this quarters them), with
 the local replica and all merge arithmetic staying f32.  The reference
 has no compression at all (its wire is pickled f64/f32 numpy — SURVEY.md
 §2 "TCP transport" row; mount empty); bf16 and int8 wires are rebuild
-extensions motivated by the DCN/TCP fabric being the gossip bottleneck
-(BASELINE.md: 0.15–0.3 GB/s TCP vs 645.9 GB/s on-chip).
+extensions motivated by the DCN/TCP fabric being the gossip
+bottleneck.
 
 Scheme: per-chunk absmax scaling, ``scale = max|chunk| / 127``, and
 **stochastic rounding** ``q = floor(v/scale + u)``, ``u ~ U[0,1)``.

@@ -454,8 +454,8 @@ class AsyncExchangeEngine:
         return len(dq) if dq is not None else 0
 
     def join_inflight(self, timeout_s: float = 5.0) -> None:
-        """Block until in-flight fetch slots land (tests/bench teardown
-        — never called on the round path)."""
+        """Block until in-flight fetch slots land (test teardown — never
+        called on the round path)."""
         with self._lock:
             slots = [self._inflight[p] for p in sorted(self._inflight)]
         for slot in slots:

@@ -301,7 +301,7 @@ def rx_stats() -> dict:
 
 
 def reset_rx_stats() -> None:
-    """Test/bench hook: zero the process-wide tally."""
+    """Test hook: zero the process-wide tally."""
     global _RX_FRAMES, _RX_COPIES
     with _RX_LOCK:
         _RX_FRAMES = 0

@@ -805,7 +805,7 @@ def fetch_blob_full(
     ownership: the payload's ring :class:`~dpwa_tpu.parallel.ingest
     .Lease` is appended on success and the CALLER must ``release()`` it
     once every view of the decoded vector is dead — the allocation-flat
-    steady state (the bench and the tracemalloc tier-1 test drive this).
+    steady state (the tracemalloc tier-1 test drives this).
     Without it, leases whose decode produced escaping views (dense /
     top-k / shard) are detached — correct but unpooled — and fully
     consumed payloads (int8) are released here.
@@ -1542,8 +1542,7 @@ class _OverlappedExchange:
 # engine's keyed LRU jit cache, and the per-frame jnp.asarray upload
 # became the zero-copy handoff.  The import stays deferred to the device
 # substrates so this module remains importable (and its CPU exchange
-# usable) without touching a JAX backend — bench.py's TCP leg runs it in
-# a backend-pinned subprocess for exactly that reason.
+# usable) without touching a JAX backend.
 def _merge_engine():
     from dpwa_tpu.device import default_engine
 
@@ -1670,7 +1669,7 @@ class TcpTransport:
         self._shard_tally: Dict[int, Dict[str, int]] = {}
         # Per-publish wire accounting: actual on-wire payload bytes vs
         # the dense f32 size, behind the ``compression_ratio`` health
-        # column and bench.py's codec sweep.  Guarded by _stats_lock:
+        # column.  Guarded by _stats_lock:
         # the training thread tallies while the healthz / metrics-scrape
         # threads read multi-key snapshots (unlocked, a scrape could see
         # frames from one publish and bytes from another — or hit a dict
@@ -3305,7 +3304,7 @@ class TcpTransport:
             # Partial-view accounting (membership.view): view sizes,
             # residency, evictions by cause, and the actual digest bytes
             # the last published frame carried — the O(sample) numbers
-            # the fleet bench gate watches.  Schema-frozen as the
+            # that stay bounded as the fleet grows.  Schema-frozen as the
             # ``view_*`` group (tools/schema_check.py); present exactly
             # when the view plane is on.
             vs = dict(self.membership.view_snapshot().get("view") or {})

@@ -16,9 +16,6 @@ Each leg drives real training through the real stack and renders a
   honest peers' time-to-loss when async rounds are on;
 - :func:`lora_leg` — the d≈100K adapter-only exchange (small-frame
   regime) learns through the zero-copy ring.
-
-``bench.py --train-leg`` runs the clean leg at BASELINE-ish shapes and
-records the ``train_gate`` verdict in ``artifacts/bench_history.jsonl``.
 """
 
 from __future__ import annotations
@@ -57,7 +54,7 @@ class LegResult:
     workdir: str
 
     def to_record(self) -> dict:
-        """The compact form bench.py embeds in its history record."""
+        """The compact form: leg, ok, verdict."""
         return {"leg": self.leg, "ok": self.ok, "verdict": self.verdict}
 
 
@@ -509,8 +506,7 @@ def lora_leg(
 ) -> LegResult:
     """Adapter-only exchange at d≈100K (~392 KiB frames) through the
     zero-copy ring: the small-frame regime must learn, exchange, and
-    stay incident-free.  (The O(header) decode-allocation gate for this
-    frame class lives in ``bench.py --copy-leg``.)"""
+    stay incident-free."""
     run = _run_block("lora", steps=steps)
     task_obj = make_task("lora", seed=seed)
     config = make_local_config(

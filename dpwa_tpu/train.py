@@ -364,8 +364,8 @@ def make_gossip_train_step(
     multi-device mesh XLA can schedule the collective-permute's ICI DMA
     concurrently with compute instead of serializing it after the
     optimizer.  (On the single-chip stacked layout there is no second
-    engine to hide the gather behind; measured recovery there is ~1 % —
-    artifacts/stacked_exchange_profile.json.)  Semantically this is one
+    engine to hide the gather behind, and the deferred form recovers
+    next to nothing there.)  Semantically this is one
     step of partner staleness: exactly what a free-running reference
     process sees when it pulls from a peer that has not finished its
     current step (SURVEY.md §3.2/§3.3 — the Rx thread serves the last

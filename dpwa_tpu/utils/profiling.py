@@ -5,18 +5,15 @@ here).
   TensorBoard-loadable trace of the training loop (XLA ops, collectives,
   host callbacks).
 - :func:`measure_exchange_bandwidth` — the GB/s/chip counter around the
-  averaging collective, the headline metric (BASELINE.json:2).  Used by
-  ``bench.py`` and available to users against their own models.
-- :func:`timed_loop` — the timing idiom shared by the bench and the
-  experiments: warm up, then a host clock around a loop that ends in
-  ``jax.block_until_ready``.
+  averaging collective, the headline metric (BASELINE.json:2), for users
+  to run against their own models.
 """
 
 from __future__ import annotations
 
 import contextlib
 import time
-from typing import Callable, Iterator
+from typing import Iterator
 
 import jax
 
@@ -29,22 +26,6 @@ def trace(log_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def timed_loop(run_iter: Callable, carry, iters: int, *, warmup: int = 3):
-    """Mean wall seconds per iteration of ``carry = run_iter(carry, k)``.
-
-    JAX dispatch is asynchronous, so the clock starts after the warm-up has
-    finished on the device and stops after ``jax.block_until_ready`` on the
-    last carry.  Returns ``(seconds_per_iter, final_carry)``."""
-    for k in range(warmup):
-        carry = run_iter(carry, k)
-    jax.block_until_ready(carry)
-    t0 = time.perf_counter()
-    for k in range(iters):
-        carry = run_iter(carry, k)
-    jax.block_until_ready(carry)
-    return (time.perf_counter() - t0) / iters, carry
 
 
 def measure_exchange_bandwidth(

@@ -59,7 +59,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-4)
     ap.add_argument("--tiny", action="store_true", help="tiny BERT (tests)")
     ap.add_argument("--bf16", action="store_true", help="bfloat16 compute "
-                    "(the MFU-honest dtype on TPU; BASELINE.md footnote 1)")
+                    "(the dtype the TPU's peak is quoted in)")
     ap.add_argument("--log-every", type=int, default=20)
     ap.add_argument("--certify", action="store_true",
                     help="run the chaos-certification clean leg "

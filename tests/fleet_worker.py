@@ -8,9 +8,7 @@ descriptors, not OS threads: fetchers are multiplexed over a small
 worker pool and holders are plain sockets, so a single test process
 can drive a 256-peer ring against one server (docs/transport.md).
 
-Used by tests/test_reactor.py; bench.py carries its own minimal copy of
-the hold/poll helpers so the benchmark stays runnable without the test
-tree on sys.path.
+Used by tests/test_reactor.py.
 """
 
 import socket

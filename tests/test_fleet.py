@@ -18,8 +18,6 @@ Covers the PR's headline claims:
   wrapper for every content fault (and the same RST behavior for
   drop/down), so `rx_server: reactor` + `chaos.enabled` is the same
   experiment;
-- bench's TCP-baseline regression gate classifies drift against the
-  recorded history (the falsifiable form of ``vs_baseline``);
 - slow: a 256-peer churn soak holds convergence, sub-linear membership
   convergence, bounded digests, and detected fault windows.
 """
@@ -59,7 +57,6 @@ from dpwa_tpu.trust.manager import TrustManager
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
-import bench  # noqa: E402
 from tools import fleet_report, incident_report, schema_check  # noqa: E402
 
 # Fast plane configs: suspicion trips in 2 bad rounds, quarantine
@@ -645,191 +642,6 @@ def test_reactor_chaos_partition_blocks_relay_guard():
         assert not srv.relay_guard(0)
     finally:
         srv.close()
-
-
-# ---------------------------------------------------------------------------
-# Bench TCP-baseline regression gate (satellite 2)
-# ---------------------------------------------------------------------------
-
-
-def _hist(values, methodology=bench.BENCH_METHODOLOGY):
-    return [
-        {
-            "record": "bench",
-            "bench_methodology": methodology,
-            "tcp_baseline_gbps": v,
-        }
-        for v in values
-    ]
-
-
-def test_tcp_gate_classifies_drift():
-    hist = _hist([0.20, 0.22, 0.21, 0.23])
-    assert bench.tcp_gate(hist, 0.22)["verdict"] == "ok"
-    assert bench.tcp_gate(hist, 0.05)["verdict"] == "regressed"
-    assert bench.tcp_gate(hist, 0.90)["verdict"] == "improved"
-
-
-def test_tcp_gate_needs_history_and_a_measurement():
-    assert bench.tcp_gate([], 0.22)["verdict"] == "no_data"
-    assert bench.tcp_gate(_hist([0.2]), 0.22)["verdict"] == "no_data"
-    assert bench.tcp_gate(_hist([0.2, 0.2]), None)["verdict"] == "no_data"
-
-
-def test_tcp_gate_ignores_malformed_and_null_entries():
-    hist = _hist([0.20, 0.22]) + [
-        {"record": "bench", "tcp_baseline_gbps": None},
-        {"record": "bench", "tcp_baseline_gbps": True},
-        {"record": "trace"},
-        "garbage",
-    ]
-    gate = bench.tcp_gate(hist, 0.21)
-    assert gate["samples"] == 2
-    assert gate["verdict"] == "ok"
-
-
-def test_tcp_gate_windows_recent_history():
-    # Ancient fast baselines age out of the window: only the recent
-    # regime is the comparison population.
-    hist = _hist([9.0] * 10 + [0.2] * 8)
-    gate = bench.tcp_gate(hist, 0.21, window=8)
-    assert gate["median_gbps"] == 0.2
-    assert gate["verdict"] == "ok"
-
-
-def test_tcp_gate_compares_like_with_like_only():
-    # The unpinned pre-methodology era (no bench_methodology stamp) and
-    # older stamps never enter the window: a tail of 0.024 GB/s unpinned
-    # samples next to pinned 0.45 ones must not drag the median (the
-    # "verdict is always improved" bug) — and alone they mean no_data,
-    # never a judgement against an incomparable era.
-    legacy = [{"record": "bench", "tcp_baseline_gbps": 0.024}] * 6
-    gate = bench.tcp_gate(legacy + _hist([0.45, 0.44]), 0.45)
-    assert gate["samples"] == 2
-    assert gate["verdict"] == "ok"
-    gate = bench.tcp_gate(legacy, 0.45)
-    assert gate["samples"] == 0
-    assert gate["verdict"] == "no_data"
-    old_stamp = _hist([0.024] * 4, methodology=bench.BENCH_METHODOLOGY - 1)
-    assert bench.tcp_gate(old_stamp, 0.45)["verdict"] == "no_data"
-
-
-def test_tcp_gate_flags_wobbling_baseline_as_unstable():
-    # A measurement whose passes disagree by more than the spread
-    # tolerance gets no band verdict at all: it could land anywhere in
-    # the band by luck, so "ok"/"regressed" would mean nothing.
-    hist = _hist([0.20, 0.22, 0.21, 0.23])
-    gate = bench.tcp_gate(hist, 0.22, spread_iqr_frac=0.40)
-    assert gate["verdict"] == "unstable"
-    assert gate["spread_iqr_frac"] == 0.40
-    # Unstable wins even over what would otherwise read "regressed",
-    # and even when history is too thin for a band verdict.
-    assert bench.tcp_gate(hist, 0.05, spread_iqr_frac=0.6)[
-        "verdict"
-    ] == "unstable"
-    assert bench.tcp_gate([], 0.22, spread_iqr_frac=0.6)[
-        "verdict"
-    ] == "unstable"
-    # At or under the tolerance the band logic is untouched; absent
-    # spread (older records, failed stats parse) behaves as before.
-    assert bench.tcp_gate(hist, 0.22, spread_iqr_frac=0.25)[
-        "verdict"
-    ] == "ok"
-    assert bench.tcp_gate(hist, 0.22)["verdict"] == "ok"
-    # No measurement at all stays no_data regardless of spread.
-    assert bench.tcp_gate(hist, None, spread_iqr_frac=0.6)[
-        "verdict"
-    ] == "no_data"
-
-
-def test_hier_gate_compares_like_with_like_only():
-    def mk(v, m):
-        e = {"record": "bench", "hier": {"wide_multiplier_min": v}}
-        if m is not None:
-            e["bench_methodology"] = m
-        return e
-
-    legacy = [mk(9.0, None)] * 5
-    cur = [mk(2.0, bench.BENCH_METHODOLOGY), mk(2.1, bench.BENCH_METHODOLOGY)]
-    gate = bench.hier_gate(legacy + cur, 2.0)
-    assert gate["samples"] == 2
-    assert gate["verdict"] == "ok"
-    assert bench.hier_gate(legacy, 2.0)["verdict"] == "no_data"
-
-
-def _fleet_hist(values, methodology=bench.BENCH_METHODOLOGY):
-    return [
-        {
-            "record": "bench",
-            "bench_methodology": methodology,
-            "fleet_resident_bytes": v,
-        }
-        for v in values
-    ]
-
-
-def test_fleet_gate_band_is_inverted_bytes_are_a_cost():
-    hist = _fleet_hist([8000, 8200, 7900, 8100])
-    assert bench.fleet_gate(hist, 8050)["verdict"] == "ok"
-    # MORE resident bytes is the regression (an O(N) map sneaking back
-    # in); fewer is the improvement.
-    assert bench.fleet_gate(hist, 20000)["verdict"] == "regressed"
-    assert bench.fleet_gate(hist, 2000)["verdict"] == "improved"
-
-
-def test_fleet_gate_needs_history_and_a_measurement():
-    assert bench.fleet_gate([], 8000)["verdict"] == "no_data"
-    assert bench.fleet_gate(_fleet_hist([8000]), 8000)["verdict"] == (
-        "no_data"
-    )
-    assert bench.fleet_gate(_fleet_hist([8000, 8100]), None)[
-        "verdict"
-    ] == "no_data"
-
-
-def test_fleet_gate_compares_like_with_like_only():
-    legacy = [{"record": "bench", "fleet_resident_bytes": 99999}] * 6
-    gate = bench.fleet_gate(legacy + _fleet_hist([8000, 8100]), 8050)
-    assert gate["samples"] == 2
-    assert gate["verdict"] == "ok"
-    old = _fleet_hist([99999] * 4, methodology=bench.BENCH_METHODOLOGY - 1)
-    assert bench.fleet_gate(old, 8050)["verdict"] == "no_data"
-    junk = _fleet_hist([8000, 8100]) + [
-        {"record": "bench", "fleet_resident_bytes": None},
-        {"record": "bench", "fleet_resident_bytes": True},
-        "garbage",
-    ]
-    assert bench.fleet_gate(junk, 8050)["samples"] == 2
-
-
-def test_bench_fleet_leg_measures_bounded_residency():
-    """A tiny two-point sweep proves the leg's plumbing: residency and
-    digest figures per N, the scaling headline, and the gate metric all
-    come out of a real orchestrator soak under the pinned view block."""
-    sweep = bench.bench_fleet([8, 24], rounds=8)
-    assert set(sweep["legs"]) == {"n8", "n24"}
-    cap = bench.FLEET_LEG_VIEW["state_cap"]
-    sample = bench.FLEET_LEG_VIEW["digest_sample"]
-    for leg in sweep["legs"].values():
-        assert leg["tracked_max"] <= cap
-        assert leg["digest_entries_max"] <= sample + 1
-        assert leg["resident_bytes_max"] > 0
-    assert sweep["peer_scaling"] == 3.0
-    assert sweep["fleet_resident_bytes"] == (
-        sweep["legs"]["n24"]["resident_bytes_max"]
-    )
-    assert bench.fleet_gate([], sweep["fleet_resident_bytes"])[
-        "verdict"
-    ] == "no_data"
-
-
-def test_read_bench_history_survives_junk(tmp_path):
-    p = tmp_path / "hist.jsonl"
-    p.write_text('{"record": "bench", "tcp_baseline_gbps": 0.2}\n'
-                 "not json\n")
-    entries = bench.read_bench_history(str(p))
-    assert len(entries) == 1
-    assert bench.read_bench_history(str(tmp_path / "missing")) == []
 
 
 # ---------------------------------------------------------------------------

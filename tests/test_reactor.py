@@ -118,7 +118,7 @@ def test_reactor_holds_256_idle_peers_and_still_serves():
             assert held_open(socks) == 256
             # A fresh probe is served while all 256 holds stay open —
             # the thread-per-connection server tops out at its 32-thread
-            # cap here (bench.py serve leg records both).
+            # cap here.
             got, outcome, *_ = fetch_blob_full("127.0.0.1", srv.port, 2000)
             assert outcome == Outcome.SUCCESS
             assert srv.reactor_snapshot()["peak_open"] >= 256

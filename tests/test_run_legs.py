@@ -2,8 +2,7 @@
 
 Each leg drives the REAL stack — TcpTransport, trust, health, obs,
 recovery — through real optimizer steps and judges the outcome in
-time-to-quality terms, exactly what ``bench.py --train-leg`` records
-into ``artifacts/bench_history.jsonl``.  The legs are seconds-to-a-
+time-to-quality terms.  The legs are seconds-to-a-
 minute soaks, so they ride under ``@pytest.mark.slow``; tier-1 covers
 the same machinery through the fast mini-train in
 tests/test_run_harness.py."""

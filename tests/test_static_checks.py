@@ -35,7 +35,6 @@ def test_tree_has_no_unregistered_emit_sites():
         [
             os.path.join(_ROOT, "dpwa_tpu"),
             os.path.join(_ROOT, "tools"),
-            os.path.join(_ROOT, "bench.py"),
         ]
     )
     assert errors == [], "\n".join(
@@ -102,7 +101,7 @@ def _valid_records():
             "t": 0.5, "partner": 1, "outcome": "refused",
             "alerts": ["peer_failure"],
         },
-        {"record": "bench", "t": 1.0, "merge_ms": 3.2},
+        {"record": "loss", "step": 3, "t": 1.0, "me": 0, "loss": 2.3},
         {
             "record": "island", "round": 4, "island": "island0",
             "term": 1, "live": 4, "rel_rms": 0.02, "leader": 3,
@@ -214,7 +213,6 @@ def test_dpwalint_tree_is_clean():
     targets = [
         os.path.join(_ROOT, "dpwa_tpu"),
         os.path.join(_ROOT, "tools"),
-        os.path.join(_ROOT, "bench.py"),
     ]
     files = analysis.load_files(analysis.iter_py_files(targets))
     result = analysis.run_checkers(

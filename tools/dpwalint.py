@@ -3,7 +3,7 @@
 
 Usage::
 
-    python tools/dpwalint.py                    # lint dpwa_tpu/ tools/ bench.py
+    python tools/dpwalint.py                    # lint dpwa_tpu/ tools/
     python tools/dpwalint.py path [...]         # lint specific files/dirs
     python tools/dpwalint.py --json             # machine-readable output
     python tools/dpwalint.py --list-rules       # enumerate rule ids
@@ -40,7 +40,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument(
         "paths", nargs="*",
-        help="files/dirs to lint (default: dpwa_tpu/ tools/ bench.py)",
+        help="files/dirs to lint (default: dpwa_tpu/ tools/)",
     )
     ap.add_argument(
         "--json", action="store_true", help="machine-readable output"

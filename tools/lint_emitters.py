@@ -30,7 +30,7 @@ except ImportError:  # run as a loose script outside the repo root
 from dpwa_tpu.analysis.core import iter_py_files, load_files  # noqa: E402
 from dpwa_tpu.analysis.emit_kinds import EmitKindsChecker  # noqa: E402
 
-DEFAULT_TARGETS = ("dpwa_tpu", "tools", "bench.py")
+DEFAULT_TARGETS = ("dpwa_tpu", "tools")
 
 
 def _to_legacy(findings) -> List[dict]:
@@ -64,7 +64,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     )
     ap.add_argument(
         "paths", nargs="*",
-        help="files/dirs to lint (default: dpwa_tpu/ tools/ bench.py)",
+        help="files/dirs to lint (default: dpwa_tpu/ tools/)",
     )
     ap.add_argument(
         "--json", action="store_true", help="machine-readable output"

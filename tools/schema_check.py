@@ -24,9 +24,6 @@ schemas:
   (docs/incidents.md), both closed-world;
 - ``record: "flight"``, ``kind: "meta" | "round"`` — the flight
   recorder's post-mortem dump header and per-round ring entries;
-- ``record: "bench"`` — bench.py's cumulative history entries
-  (``artifacts/bench_history.jsonl``): the envelope is pinned, the
-  result payload is bench-leg-defined;
 - ``record: "fleet"``, ``kind: "churn" | "round" | "episode"`` — the
   churn orchestrator's stream (docs/fleet.md): churn records are the
   deterministic bit-identity anchor (round counters and peer ids
@@ -133,9 +130,7 @@ _HEALTH_GROUPS: Dict[str, Dict[str, tuple]] = {
         "overlap_prefetched": (int,),
         "overlap_straddled": (int,),
     },
-    # Sharded wire (shard.k > 1).  Bench records carry the shard sweep
-    # (``shard_sweep`` / ``bench_methodology``) inside their open
-    # leg-defined payload — the bench envelope stays unversioned here.
+    # Sharded wire (shard.k > 1).
     "shard": {
         "shard_k": (int,),
         "shard_coverage": _NUM,
@@ -320,13 +315,6 @@ _FLIGHT_ROUND_OPTIONAL: Dict[str, tuple] = {
     "alerts": (list,),
 }
 
-# Bench history entries carry no step (one per RUN, not per round);
-# the result payload is bench-leg-defined by design.
-_BENCH_REQUIRED: Dict[str, tuple] = {
-    "t": _NUM,
-    "record": (str,),
-}
-
 # Fleet records carry ``round`` (gossip round), never ``t``: the churn
 # stream is the orchestrator's BIT-IDENTITY anchor (two runs of one
 # seed must produce byte-identical churn records), so wall time never
@@ -499,7 +487,7 @@ _TUNE_ACTIONS = frozenset(
 RECORD_KINDS = frozenset(
     {
         "health", "trace", "event", "alert", "incident", "flight",
-        "bench", "fleet", "island", "run", "loss", "tune",
+        "fleet", "island", "run", "loss", "tune",
     }
 )
 EVENT_KINDS = frozenset(
@@ -639,8 +627,6 @@ def check_record(rec: dict) -> List[str]:
                 closed=True,
             )
         return [f"unknown flight kind {fkind!r}"]
-    if kind == "bench":
-        return _check_fields(rec, _BENCH_REQUIRED)
     if kind == "fleet":
         fkind = rec.get("kind")
         if fkind == "churn":
