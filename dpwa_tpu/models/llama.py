@@ -16,6 +16,7 @@ the exchange (``dpwa_tpu.utils.pytree.partition``)."""
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Optional, Tuple
@@ -25,8 +26,12 @@ import jax
 import jax.numpy as jnp
 
 
-# What a layer mixes its tokens by (``LlamaConfig.layer_mixers``).
-MIXERS = ("attention", "conv", "mamba")
+# What a layer mixes its tokens by (``LlamaConfig.layer_mixers``).  The last
+# two are :class:`Attention` told its kind: behind a sliding window, or full
+# beside layers that have one; each kind may have a rope of its own.
+WINDOWED_KINDS = ("sliding_attention", "full_attention")
+ATTENTION_KINDS = ("attention",) + WINDOWED_KINDS
+MIXERS = ("attention", "conv", "mamba") + WINDOWED_KINDS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,9 +170,10 @@ class LlamaConfig:
     # A list of kinds a layer (the published ``layer_types`` of LFM2, which no
     # period and offset can say): layer ``i``'s mixer is ``layer_mixers[i]``,
     # one of :data:`MIXERS`: ``"attention"`` (whichever attention the other
-    # fields select), ``"conv"`` (a :class:`ShortConv` of ``conv_taps`` taps)
-    # or ``"mamba"``.  None: ``attn_layer_period`` / ``attn_layer_offset``
-    # say, as above.
+    # fields select), ``"conv"`` (a :class:`ShortConv` of ``conv_taps`` taps),
+    # ``"mamba"``, or one of the two kinds of plain attention further down
+    # (``sliding_window``).  None: ``attn_layer_period`` /
+    # ``attn_layer_offset`` say, as above.
     layer_mixers: Optional[Tuple[str, ...]] = None
     conv_taps: int = 3
     # An RMSNorm over ``head_dim`` on every head of q and of k after the
@@ -181,6 +187,17 @@ class LlamaConfig:
     # sum that ``norm_topk_prob`` divides by.
     router_bias: bool = False
     norm_topk_eps: float = 0.0
+    # A head's size where it is not ``d_model / n_heads`` (the projected width
+    # ``n_heads x head_size`` is then not the hidden size).
+    head_size: Optional[int] = None
+    # ``layer_mixers`` may name a layer ``"sliding_attention"``: query ``t``
+    # sees the keys ``t - sliding_window + 1 .. t``, its own among them; or
+    # ``"full_attention"``: every earlier key, as ``"attention"``, in a stack
+    # that has both.  ``rope_by_kind`` gives a kind a rope of its own, as
+    # ``(kind, theta, scaling)`` triples; a kind it does not name takes
+    # ``rope_theta`` / ``rope_scaling``.
+    sliding_window: int = 0
+    rope_by_kind: Tuple[Tuple[str, Optional[float], Optional["YarnScaling"]], ...] = ()
 
     def __post_init__(self):
         mixers = self.layer_mixers
@@ -202,6 +219,24 @@ class LlamaConfig:
                     "a short convolution has no sequence-parallel path: a "
                     "rank's first positions need the rank before's last"
                 )
+        if set(mixers or ()) & set(WINDOWED_KINDS):
+            if self.kv_lora_rank or self.eva_window or self.sp_axis is not None:
+                raise ValueError(
+                    "sliding_attention / full_attention are kinds of plain "
+                    "attention on one device: no latent or EVA attention, no "
+                    "sequence-parallel path (a window has none yet)"
+                )
+        if "sliding_attention" in (mixers or ()) and (
+            self.sliding_window < 128 or self.sliding_window % 128
+        ):
+            raise ValueError(
+                "a sliding_attention layer needs a sliding_window that is a "
+                f"multiple of 128, got {self.sliding_window}"
+            )
+        if set(kind for kind, _, _ in self.rope_by_kind) - set(MIXERS):
+            raise ValueError(
+                f"rope_by_kind names kinds of {MIXERS}, got {self.rope_by_kind!r}"
+            )
         if self.attn_layer_period or "mamba" in (mixers or ()):
             if self.attn_layer_period and not (
                 0 <= self.attn_layer_offset < self.attn_layer_period
@@ -297,7 +332,14 @@ class LlamaConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    def rope_of(self, kind: str):
+        """``(theta, scaling)`` of the rope a layer of ``kind`` turns by."""
+        for named, theta, scaling in self.rope_by_kind:
+            if named == kind:
+                return theta, scaling
+        return self.rope_theta, self.rope_scaling
 
     def mixer_of(self, index: int) -> str:
         """Layer ``index``'s mixer, one of :data:`MIXERS`."""
@@ -309,7 +351,7 @@ class LlamaConfig:
         return "mamba"
 
     def is_attention_layer(self, index: int) -> bool:
-        return self.mixer_of(index) == "attention"
+        return self.mixer_of(index) in ATTENTION_KINDS
 
     @property
     def norm_dtype(self) -> jnp.dtype:
@@ -328,7 +370,14 @@ class LlamaConfig:
 
 @dataclasses.dataclass(frozen=True)
 class YarnScaling:
-    """A ``rope_scaling`` group of type ``yarn``, under its published keys."""
+    """A ``rope_scaling`` group of type ``yarn``, under its published keys.
+    Two published forms say how much larger the scores get: ``mscale`` /
+    ``mscale_all_dim`` (the DeepSeek form: ``cos`` and ``sin`` times their
+    magnitudes' ratio, the scores times ``mscale_all_dim``'s squared), or an
+    ``attention_factor`` that multiplies ``cos`` and ``sin`` alone (so the
+    scores its square) and, where given, is all there is.  ``mscale`` 1 and
+    ``mscale_all_dim`` 1 are the second form at its default factor ``0.1 ln
+    factor + 1``."""
 
     factor: float
     original_max_position_embeddings: int
@@ -336,6 +385,7 @@ class YarnScaling:
     beta_slow: float = 1.0
     mscale: float = 1.0
     mscale_all_dim: float = 0.0
+    attention_factor: Optional[float] = None
 
     @staticmethod
     def magnitude(factor: float, mscale: float) -> float:
@@ -344,6 +394,8 @@ class YarnScaling:
     @property
     def embedding_scale(self) -> float:
         """What the rope's cos and sin are multiplied by."""
+        if self.attention_factor is not None:
+            return self.attention_factor
         return self.magnitude(self.factor, self.mscale) / self.magnitude(
             self.factor, self.mscale_all_dim
         )
@@ -351,6 +403,8 @@ class YarnScaling:
     @property
     def softmax_scale(self) -> float:
         """What the ``1 / sqrt(d)`` of the scores is multiplied by."""
+        if self.attention_factor is not None:
+            return 1.0
         return self.magnitude(self.factor, self.mscale_all_dim) ** 2
 
 
@@ -521,10 +575,14 @@ def _norm(cfg: LlamaConfig, name: str) -> RMSNorm:
 
 class Attention(nn.Module):
     cfg: LlamaConfig
+    # Of :data:`MIXERS`: ``"sliding_attention"`` sees ``cfg.sliding_window``
+    # keys, and each kind turns by its own rope (``LlamaConfig.rope_of``).
+    kind: str = "attention"
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.cfg
+        theta, scaling = cfg.rope_of(self.kind)
         B, T, _ = x.shape
         H, KV, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
         dense = lambda feats, name: _dense(cfg, feats, name)
@@ -535,9 +593,9 @@ class Attention(nn.Module):
         if cfg.qk_norm_per_head:
             q, k = _norm(cfg, "q_norm")(q), _norm(cfg, "k_norm")(k)
         v = dense(KV * D, "wv")(x).reshape(B, T, KV, D)
-        if cfg.rope_theta is not None:
-            q = rope(q, positions, cfg.rope_theta)
-            k = rope(k, positions, cfg.rope_theta)
+        if theta is not None:
+            q = rope(q, positions, theta, scaling)
+            k = rope(k, positions, theta, scaling)
         if cfg.sp_axis is not None:
             # Sequence-parallel: exact ring attention over the sp mesh
             # axis — K/V blocks rotate by ppermute, online softmax
@@ -590,8 +648,13 @@ class Attention(nn.Module):
         # sequences fit at all).
         from dpwa_tpu.ops.ulysses import single_device_attention
 
+        how = {}
+        if self.kind == "sliding_attention":
+            how.update(window=cfg.sliding_window)
+        if scaling is not None:
+            how.update(sm_scale=D ** -0.5 * scaling.softmax_scale)
         out = single_device_attention(
-            q, k, v, causal=True, impl=cfg.attn_impl
+            q, k, v, causal=True, impl=cfg.attn_impl, **how
         ).reshape(B, T, H * D)
         return dense(cfg.d_model, "wo")(out)
 
@@ -954,16 +1017,23 @@ class Block(nn.Module):
         # feed-forward are named here, because the shared expert is an
         # ``MLP`` too.  Norms and residual adds stay outside every name.
         mixer = cfg.mixer_of(self.index)
-        if mixer == "attention":
+        if mixer in ATTENTION_KINDS:
             h = _norm(cfg, "attn_norm")(x)
-            if cfg.kv_lora_rank:
+            if mixer == "attention" and cfg.kv_lora_rank:
                 h = LatentAttention(cfg, name="attn")(h, positions)
-            elif cfg.eva_window:
+            elif mixer == "attention" and cfg.eva_window:
                 with jax.named_scope(scopes.ATTN_EVA.whole):
                     h = EvaAttention(cfg, name="attn")(h, positions)
             else:
-                with jax.named_scope(scopes.ATTN_GQA):
-                    h = Attention(cfg, name="attn")(h, positions)
+                # Plain attention whole, of whichever kind; a sliding layer
+                # carries its own name inside that one.
+                with contextlib.ExitStack() as named:
+                    named.enter_context(jax.named_scope(scopes.ATTN_GQA))
+                    if mixer == "sliding_attention":
+                        named.enter_context(
+                            jax.named_scope(scopes.ATTN_WINDOW.whole)
+                        )
+                    h = Attention(cfg, mixer, name="attn")(h, positions)
             x = x + h
         elif mixer == "conv":
             x = x + ShortConv(cfg, name="conv")(_norm(cfg, "conv_norm")(x))

@@ -68,6 +68,26 @@ positions, two at 4,096), the scale an argument, and the two calls are named
 ``flash_attention_fwd_dpwa`` / ``flash_mha_bwd_dpwa``: the prefixes by which a
 trace's readers know a flash-attention kernel.
 
+**A band beside "every earlier window"** (``causal_attention(..., window=W)``:
+a model's sliding window, query ``t`` sees the keys ``t - W + 1 .. t``, its
+own among them).  The grid's window stays :func:`causal_window` of ``T``: the
+model's window and the grid's are two things.  A block of queries visits the
+key blocks that hold a key of its band and no other: its own block under the
+diagonal's mask, then the ``(W + block - 2) // block`` blocks behind it, the
+last of them (and any that the band's far edge crosses: a second diagonal)
+under that edge's mask, the ones between unmasked; at a window of 1,024 in
+blocks of 512, three key blocks a query block whatever ``T`` (21 block pairs a
+head at T 4,096 where the whole triangle has 36).  The blocks behind are read
+out of the head's whole ``k``, ``v`` by their rows: of the query's own grid
+window where it has them, of the grid window before under ``pl.when`` there
+is one.  The backward kernel walks the same pairs from the keys' side (a key
+block is seen by its own query block and the next few), adds ``dk``, ``dv``
+into the group's whole float32 block as ever, and so needs no second pass.
+The two calls carry a tail of their own on the same prefixes
+(``flash_attention_fwd_dpwa_window`` / ``flash_mha_bwd_dpwa_window``).  With
+``window=None`` nothing of this is traced: the program is the one above, to
+the text.
+
 **Under ``vmap``** (the stacked step's peer axis) a ``custom_vmap`` rule folds
 the peer axis into the kernels' sequence axis (``ops/ssm.folding_peers``).
 """
@@ -206,17 +226,51 @@ def _earlier(w, per_window: int, turn: int):
     return turns, lambda c: pl.ds(pl.multiple_of(c * turn, turn), turn)
 
 
-def _forward_kernel(q_ref, *refs, block, per_window, scale):
+def _band_reach(band: int, block: int, rows: int) -> int:
+    """How many blocks of keys behind a query block's own the band of
+    ``band`` keys (the query's own among them) still touches: query row ``r``
+    of a block sees key ``c`` of the block ``m`` before it iff ``0 <= m block
+    + r - c < band``, which some pair satisfies up to this ``m``; no further
+    than a head of ``rows`` keys has blocks."""
+    return min((band + block - 2) // block, rows // block - 1)
+
+
+def _band_mask(ahead, m: int, band: int, block: int):
+    """Which pairs of a query block and the key block ``m`` before it lie in
+    the band, from ``ahead`` = key index - query index inside the blocks;
+    None where all do.  Block 0 is cut by the diagonal, and a block is cut by
+    the band's far edge where its first key lies ``band`` or more behind the
+    query block's last row."""
+    mask = ahead <= 0 if m == 0 else None
+    if (m + 1) * block > band:
+        inside = ahead > m * block - band
+        mask = inside if mask is None else mask & inside
+    return mask
+
+
+def _block_before(w, blocks: int, back: int, block: int):
+    """The rows, in a head's whole operand, of the block ``back`` blocks
+    before the first of window ``w`` (``blocks`` blocks a window)."""
+    return pl.ds(pl.multiple_of((w * blocks - back) * block, block), block)
+
+
+def _forward_kernel(q_ref, *refs, block, per_window, scale, band=None):
     *own, ks_ref, vs_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref = refs
     w = pl.program_id(2)
     window, d = q_ref.shape
     k_ref, v_ref = own or _own_window((ks_ref, vs_ref), w, window)
     turns, seen = _earlier(w, per_window, min(per_window, block))
     shape = (block, block)
-    on_or_under = (
-        lax.broadcasted_iota(jnp.int32, shape, 1)
-        <= lax.broadcasted_iota(jnp.int32, shape, 0)
-    )
+    if band is None:
+        on_or_under = (
+            lax.broadcasted_iota(jnp.int32, shape, 1)
+            <= lax.broadcasted_iota(jnp.int32, shape, 0)
+        )
+    else:
+        ahead = (
+            lax.broadcasted_iota(jnp.int32, shape, 1)
+            - lax.broadcasted_iota(jnp.int32, shape, 0)
+        )
 
     def attend(q, keys, values, mask):
         """One block of keys of either kind into the running softmax."""
@@ -241,26 +295,46 @@ def _forward_kernel(q_ref, *refs, block, per_window, scale):
         m_ref[...] = jnp.full_like(m_ref, MASKED)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        for j in range(i + 1):
-            keys = pl.ds(j * block, block)
-            attend(
-                q, k_ref[keys, :], v_ref[keys, :],
-                on_or_under if j == i else None,
-            )
+        if band is None:
+            for j in range(i + 1):
+                keys = pl.ds(j * block, block)
+                attend(
+                    q, k_ref[keys, :], v_ref[keys, :],
+                    on_or_under if j == i else None,
+                )
 
-        def earlier_window(c, carry, q=q):
-            rows = seen(c)
-            attend(q, ks_ref[rows, :], vs_ref[rows, :], None)
-            return carry
+            def earlier_window(c, carry, q=q):
+                rows = seen(c)
+                attend(q, ks_ref[rows, :], vs_ref[rows, :], None)
+                return carry
 
-        lax.fori_loop(0, turns, earlier_window, 0)
+            lax.fori_loop(0, turns, earlier_window, 0)
+        else:
+            # The diagonal block first (every row has a key there), then the
+            # blocks behind it as far as the band reaches: of this window
+            # where it has them, else of the windows before, if there are.
+            blocks = window // block
+            for m in range(_band_reach(band, block, ks_ref.shape[0]) + 1):
+                mask = _band_mask(ahead, m, band, block)
+                if m <= i:
+                    keys = pl.ds((i - m) * block, block)
+                    attend(q, k_ref[keys, :], v_ref[keys, :], mask)
+                    continue
+
+                @pl.when(w * blocks >= m - i)
+                def _(q=q, mask=mask, back=m - i):
+                    keys = _block_before(w, blocks, back, block)
+                    attend(q, ks_ref[keys, :], vs_ref[keys, :], mask)
+
         total = l_ref[...]
         o_ref[rows, :] = (acc_ref[...] / _lanes(total, d)).astype(o_ref.dtype)
         # One value a row along the lanes -> one row of the block's queries.
         lse_ref[:, rows] = (m_ref[...] + jnp.log(total)).T[:1]
 
 
-def _backward_kernel(q_ref, *refs, block, per_window, scale, group, own):
+def _backward_kernel(
+    q_ref, *refs, block, per_window, scale, group, own, band=None
+):
     # Everything here is transposed: a block of keys on the sublanes, a
     # block of queries on the lanes.
     w = pl.program_id(2)
@@ -289,10 +363,16 @@ def _backward_kernel(q_ref, *refs, block, per_window, scale, group, own):
 
     dq_acc[...] = jnp.zeros_like(dq_acc)
     shape = (block, block)
-    on_or_under = (
-        lax.broadcasted_iota(jnp.int32, shape, 0)
-        <= lax.broadcasted_iota(jnp.int32, shape, 1)
-    )
+    if band is None:
+        on_or_under = (
+            lax.broadcasted_iota(jnp.int32, shape, 0)
+            <= lax.broadcasted_iota(jnp.int32, shape, 1)
+        )
+    else:
+        ahead = (
+            lax.broadcasted_iota(jnp.int32, shape, 0)
+            - lax.broadcasted_iota(jnp.int32, shape, 1)
+        )
 
     def pair(keys, values, i, mask):
         """``(d keys, d values)`` that query block ``i`` gives a block of
@@ -316,20 +396,32 @@ def _backward_kernel(q_ref, *refs, block, per_window, scale, group, own):
         )
         return d_keys, d_values
 
-    def gathered(keys, values, first, masked):
-        """The sum of :func:`pair` over the query blocks ``first ..``."""
+    def gathered(keys, values, seen_by):
+        """The sum of :func:`pair` over ``seen_by``: (query block, mask)s."""
         d_keys = jnp.zeros(keys.shape, F32)
         d_values = jnp.zeros(values.shape, F32)
-        for i in range(first, blocks):
-            dk, dv = pair(
-                keys, values, i, on_or_under if masked and i == first else None
-            )
+        for i, mask in seen_by:
+            dk, dv = pair(keys, values, i, mask)
             d_keys, d_values = d_keys + dk, d_values + dv
         return d_keys, d_values
 
+    if band is None:
+        under = lambda j: [
+            (i, on_or_under if i == j else None) for i in range(j, blocks)
+        ]
+    else:
+        # A block of keys is seen by its own query block and the ``reach``
+        # after it: key block ``j`` of this window by this window's, a key
+        # block ``back`` before the window by the window's first ones.
+        reach = _band_reach(band, block, ks_ref.shape[0])
+        under = lambda j: [
+            (i, _band_mask(ahead, i - j, band, block))
+            for i in range(max(j, 0), min(j + reach, blocks - 1) + 1)
+        ]
+
     for j in range(blocks):
         rows = pl.ds(j * block, block)
-        d_keys, d_values = gathered(k_ref[rows, :], v_ref[rows, :], j, True)
+        d_keys, d_values = gathered(k_ref[rows, :], v_ref[rows, :], under(j))
         if own:
             dk_ref[rows, :] = d_keys.astype(dk_ref.dtype)
             dv_ref[rows, :] = d_values.astype(dv_ref.dtype)
@@ -337,14 +429,29 @@ def _backward_kernel(q_ref, *refs, block, per_window, scale, group, own):
             dk_ref[rows, :] += d_keys
             dv_ref[rows, :] += d_values
 
-    def earlier_window(c, carry):
-        rows = seen(c)
-        d_keys, d_values = gathered(ks_ref[rows, :], vs_ref[rows, :], 0, False)
-        dks_ref[rows, :] += d_keys
-        dvs_ref[rows, :] += d_values
-        return carry
+    if band is None:
+        def earlier_window(c, carry):
+            rows = seen(c)
+            d_keys, d_values = gathered(
+                ks_ref[rows, :], vs_ref[rows, :],
+                [(i, None) for i in range(blocks)],
+            )
+            dks_ref[rows, :] += d_keys
+            dvs_ref[rows, :] += d_values
+            return carry
 
-    lax.fori_loop(0, turns, earlier_window, 0)
+        lax.fori_loop(0, turns, earlier_window, 0)
+    else:
+        for back in range(1, reach + 1):
+            @pl.when(w * blocks >= back)
+            def _(back=back):
+                rows = _block_before(w, blocks, back, block)
+                d_keys, d_values = gathered(
+                    ks_ref[rows, :], vs_ref[rows, :], under(-back)
+                )
+                dks_ref[rows, :] += d_keys
+                dvs_ref[rows, :] += d_values
+
     dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
@@ -406,9 +513,16 @@ KERNEL_NAMES = {
     True: ("dpwa_eva_attention_fwd", "dpwa_eva_attention_bwd"),
     False: ("flash_attention_fwd_dpwa", "flash_mha_bwd_dpwa"),
 }
+# The causal calls with a band: the same two prefixes, and a tail of their
+# own by which a reader tells them from the calls over the whole triangle.
+BAND_KERNEL_NAMES = tuple(
+    name + "_window" for name in KERNEL_NAMES[False]
+)
 
 
-def _kernel_call(backward, interpret, window, per_window, scale, *operands):
+def _kernel_call(
+    backward, interpret, window, per_window, scale, band, *operands
+):
     """One ``pallas_call`` of the forward or the backward kernel on
     ``operands`` (``q k v``, the summaries where there are any, and the
     backward kernel's ``do lse di``), with the VMEM limit its shapes come to
@@ -421,7 +535,9 @@ def _kernel_call(backward, interpret, window, per_window, scale, *operands):
     own = len(keys) == 5
     group = operands[0].shape[1] // operands[1].shape[1]
     scratch, tiles, call = _layout(window, *keys, backward=backward)
-    static = dict(block=sub_block(window), per_window=per_window, scale=scale)
+    static = dict(
+        block=sub_block(window), per_window=per_window, scale=scale, band=band
+    )
     if backward:
         static.update(group=group, own=own)
     return pl.pallas_call(
@@ -442,14 +558,16 @@ def _kernel_call(backward, interpret, window, per_window, scale, *operands):
             ),
         ),
         interpret=interpret,
-        name=KERNEL_NAMES[own][backward],
+        name=(
+            KERNEL_NAMES[own] if band is None else BAND_KERNEL_NAMES
+        )[backward],
     )(*operands)
 
 
 @functools.lru_cache(maxsize=None)
 def _differentiable(
     interpret: bool, window: int, per_window: int, scale: float, scope,
-    jitted: bool,
+    jitted: bool, band=None,
 ):
     """``core(q, k, v, *summaries) -> o`` on the two kernels, differentiable
     in every operand.  A custom gradient's instructions carry no name of the
@@ -460,7 +578,8 @@ def _differentiable(
     traced apart cost a step's set-up 5 s: PERF.md section 6, PR 46)."""
     calls = (
         functools.partial(
-            _kernel_call, backward, interpret, window, per_window, scale
+            _kernel_call, backward, interpret, window, per_window, scale,
+            band,
         )
         for backward in (False, True)
     )
@@ -524,14 +643,21 @@ def causal_window(T: int) -> int:
     return max(fitting, default=T)
 
 
-def causal_kernels_take(T: int, d: int, heads: int, kv_heads: int, dtype) -> bool:
+def causal_kernels_take(
+    T: int, d: int, heads: int, kv_heads: int, dtype, band=None
+) -> bool:
     """Whether the kernels run causal attention of ``heads`` query heads on
     ``kv_heads`` heads of keys and values, all of size ``d``, over ``T``
     positions: ``d`` and ``T`` in multiples of the lanes, ``kv_heads``
     dividing ``heads``, and the backward call, whose float32 ``dk``, ``dv``
-    of a whole head grow with ``T``, inside :data:`VMEM_CEILING`."""
+    of a whole head grow with ``T``, inside :data:`VMEM_CEILING`.  A ``band``
+    (a query sees its last ``band`` keys, its own among them) is a positive
+    multiple of the lanes; the blocks a call holds in VMEM are the same with
+    one and without."""
     window = causal_window(T)
     if d % LANES or T % LANES or T % window or heads % kv_heads:
+        return False
+    if band is not None and (band < LANES or band % LANES):
         return False
     shaped = lambda h: jax.ShapeDtypeStruct((1, h, T, d), dtype)
     q, k, rows = shaped(heads), shaped(kv_heads), jax.ShapeDtypeStruct(
@@ -543,13 +669,18 @@ def causal_kernels_take(T: int, d: int, heads: int, kv_heads: int, dtype) -> boo
     return vmem_limit(need) <= VMEM_CEILING
 
 
-def causal_attention(q, k, v, sm_scale: float, interpret: bool = False):
+def causal_attention(
+    q, k, v, sm_scale: float, interpret: bool = False, window=None
+):
     """``o [B, h, T, D]`` of causal softmax attention with scores ``sm_scale
     q . k``, for ``q [B, h, T, D]`` and ``k``, ``v`` ``[B, kv, T, D]`` that
     :func:`causal_kernels_take`; differentiable in all three (``dk``, ``dv``
-    summed over a group's query heads in float32 inside the kernel).
-    ``interpret`` runs the kernels by the Pallas interpreter, off the TPU."""
-    window = causal_window(q.shape[2])
+    summed over a group's query heads in float32 inside the kernel).  With a
+    ``window`` query ``t`` sees the keys ``t - window + 1 .. t`` alone (the
+    band of the module docstring).  ``interpret`` runs the kernels by the
+    Pallas interpreter, off the TPU."""
+    grid_window = causal_window(q.shape[2])
     return _differentiable(
-        interpret, window, window, float(sm_scale), None, True
+        interpret, grid_window, grid_window, float(sm_scale), None, True,
+        None if window is None else int(window),
     )(q, k, v)

@@ -120,20 +120,24 @@ def _tiling(k: int, n: int, contracts_k: bool = True):
     ``tgmm`` they are the result's, ``contracts_k`` false, and the tile ``tk
     x tn`` is the float32 accumulator).  Each clause is a sweep on the v5e
     (PERF.md section 6: PR 27 and PR 45 at 65,536 rows in 128 groups, PR 44
-    at 32,768 in 64 with an expert width of 1,792 = 7 x 256).
+    at 32,768 in 64 with an expert width of 1,792 = 7 x 256, PR 49 at 65,536
+    in 128 with 896 = 7 x 128 over a hidden size of 2,304 = 18 x 128).
 
     A rank-16 adapter (one side under 128): 512 rows, its narrow side padded
     to one 128-lane tile, the wide side up to 2048 when it is contracted.
 
     The wide matmuls: 256 rows by a tile of the weights within 2,048 x 1,024
-    values, filled ``k`` first.  A contracted ``k`` up to 2,048 is whole: in
-    two tiles the weights' tile changes at every grid step and is read again
-    for every tile of rows (3.00 to 3.13 ms a product in two, 2.43 whole, PR
-    45); a longer one, and either side in ``tgmm`` (Mosaic refuses a whole
-    2,048 there), by its divisor up to 1,024.  ``n`` by the largest multiple of
-    128 that divides it within the tile and 2,048 (the accumulator is ``tm x
-    tn``: Mosaic refuses 256 x 8,192): 2,048 over a contracted 1,024 reads the
-    rows once (2.55 ms against 2.68, PR 45), 896 over 1,792 pays no second tile
+    values, filled ``k`` first.  A contracted ``k`` up to 2,304, the longest
+    swept, is whole: in two tiles or three the weights' tile changes at every
+    grid step and is read again for every tile of rows (3.00 to 3.13 ms a
+    product in two tiles of 1,024 and 2.43 whole, PR 45; 3.07 in three of 768,
+    2.98 in two of 1,152 and 2.38 whole at 2,304 x 896, PR 49); a longer one,
+    and either side in ``tgmm`` (Mosaic refuses a whole 2,048 there), by its
+    divisor up to 1,024.  ``n`` by the largest multiple of 128 that divides it
+    within the tile and 2,304 (the accumulator is ``tm x tn``: Mosaic refuses
+    256 x 8,192): 2,048 over a contracted 1,024 reads the rows once (2.55 ms
+    against 2.68, PR 45), a whole 2,304 over a contracted 896 likewise (2.54
+    against 2.67 in two of 1,152, PR 49), 896 over 1,792 pays no second tile
     that is a quarter empty (2.21 against 2.41, PR 44)."""
     lanes = lambda v: -(-v // 128) * 128
     if min(k, n) < 128:
@@ -145,8 +149,8 @@ def _tiling(k: int, n: int, contracts_k: bool = True):
         (t for t in range(512, min(v, cap) + 1, 128) if v % t == 0),
         default=min(v, 1024),
     )
-    tk = k if contracts_k and k <= 2048 else divisor(k, 1024)
-    cap = min(2048, 2048 * 1024 // tk) if contracts_k else 1024
+    tk = k if contracts_k and k <= 2304 else divisor(k, 1024)
+    cap = min(2304, 2048 * 1024 // tk) if contracts_k else 1024
     return 256, tk, divisor(n, cap)
 
 

@@ -125,7 +125,8 @@ def _flash_block_sizes(T: int, D: int):
 
 
 def single_device_attention(
-    q, k, v, *, causal: bool, impl: str = "auto", sm_scale=None
+    q, k, v, *, causal: bool, window: Optional[int] = None,
+    impl: str = "auto", sm_scale=None
 ):
     """THE single-device attention of the framework, shared by the Llama
     model's non-sp path and the a2a strategy's per-device compute:
@@ -167,22 +168,43 @@ def single_device_attention(
 
     No head size falls to the einsum silently on a TPU whose tiling T fits:
     at T 4,096 its float32 scores are 4.3 GB for two sequences of 32
-    heads."""
+    heads.
+
+    ``window`` (a sliding window: query ``t`` sees the keys ``t - window +
+    1 .. t``, its own among them; causal calls alone) is **our** family's:
+    where its kernels take the call (as above, and a window that is a
+    multiple of 128) they visit, for a block of queries, only the key blocks
+    that hold a key of the band, under the names
+    ``flash_attention_fwd_dpwa_window`` / ``flash_mha_bwd_dpwa_window``.  The
+    library's kernels know no window: every other windowed call (a head of
+    64, a ``T`` or a window off the tiling, off the TPU) is the masked
+    einsum, and ``impl="flash"`` on such a call is refused."""
     from dpwa_tpu.ops import eva
 
     B, T, h, D = q.shape
     Dv = v.shape[-1]
+    if window is not None and not (causal and window >= 1):
+        raise ValueError("a window is a causal call's, of at least one key")
     use_flash = impl == "flash" or (
         impl == "auto" and jax.default_backend() == "tpu" and T % 128 == 0
     )
     scale = float(1.0 / (D ** 0.5) if sm_scale is None else sm_scale)
     if use_flash and causal and D == Dv and eva.causal_kernels_take(
-        T, D, h, k.shape[2], q.dtype
+        T, D, h, k.shape[2], q.dtype, window
     ):
         heads_first = lambda x: x.transpose(0, 2, 1, 3)
+        # Without a window the call is made as it always was.
+        how = {} if window is None else dict(window=window)
         return heads_first(
-            eva.causal_attention(*map(heads_first, (q, k, v)), scale)
+            eva.causal_attention(*map(heads_first, (q, k, v)), scale, **how)
         )
+    if window is not None:
+        if impl == "flash":
+            raise ValueError(
+                f"no flash kernel takes a window of {window} at T {T}, heads "
+                f"of {D} / {Dv}"
+            )
+        use_flash = False
     if k.shape[2] != h:
         rep = h // k.shape[2]
         k = jnp.repeat(k, rep, axis=2)
@@ -219,6 +241,8 @@ def single_device_attention(
         s = s * jnp.float32(sm_scale)
     if causal:
         mask = jnp.tril(jnp.ones((T, T), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((T, T), bool), -window)
         s = jnp.where(mask[None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, -1)
     return jnp.einsum(

@@ -1,9 +1,10 @@
 """Names for the parts of a train step, as ``jax.named_scope`` metadata.
 
 Three scopes give the step's four phases: ``dpwa.forward`` (two of them, as
-below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Fifteen more lie inside
+below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Sixteen more lie inside
 the forward scope and name the parts of a decoder (``models/llama.py``):
-attention plain, latent and EVA (with its summaries and its core), the gated
+attention plain (a sliding-window layer under one more name inside it), latent
+and EVA (with its summaries and its core), the gated
 short convolution and its gate, the dense feed-forward, the expert layer's
 three parts, the state-space mixer and its scan, the head, the loss.  The outer
 norms, the embedding and the residual adds carry none: they are what is left
@@ -77,6 +78,16 @@ class _ConvNames(NamedTuple):
 
 
 CONV = _ConvNames()
+# A sliding-window attention layer whole (``models/llama.Attention`` with
+# ``kind="sliding_attention"``, put on in ``Block`` *inside* ``dpwa.attn.gqa``,
+# which stays around plain attention of either kind): the projections, the
+# norms a head, the layer's own rope, the windowed core and ``wo``.  A value
+# with a field, for the reason ``ATTN_EVA`` is one (PERF.md section 7).
+class _WindowNames(NamedTuple):
+    whole: str = "dpwa.attn.window"
+
+
+ATTN_WINDOW = _WindowNames()
 # A dense SwiGLU feed-forward whole (``models/llama.MLP`` as a layer's
 # feed-forward, put on in ``Block``): ``w_gate``, ``w_up``, ``silu x up``,
 # ``w_down`` and their adapters.  The shared expert is an ``MLP`` too and

@@ -531,6 +531,117 @@ def test_the_lfm2_cells_step_lowers_with_its_kernels_and_no_scores():
     assert max(sizes) == 2 * T * 65536 < 2 * 32 * T * T
 
 
+def test_the_mellum_cells_step_lowers_with_its_kernels_and_no_scores():
+    """The Mellum cell's own loss under `vmap` over its two peers at the
+    published sizes (4 layers, T 4,096, a window of 1,024), differentiated and
+    lowered for a TPU (shapes alone: no chip, no TPU compiler): the expert
+    layers' `gmm` and `tgmm` are custom calls, the three sliding layers run
+    the windowed forward and backward kernels and the full layer the causal
+    ones (each layer's forward once more in its block's recomputation), and
+    no tensor holds a head's `T x T` scores: the masked softmax was not
+    taken."""
+    import re
+    from unittest import mock
+
+    from tests.yardstick.yardstick_paths import cell_files
+
+    from benchmark.builders import window_moe_decoder
+    from dpwa_tpu.ops import eva
+
+    _, config, cell = cell_files("mellum2-lora-stacked2-t4096")
+    assert cell["expect_hlo"] == ["tpu_custom_call"]
+    built = window_moe_decoder.build(config, cell)
+    T = cell["seq_len"]
+    shapes = jax.eval_shape(
+        jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
+    )
+    tokens = jax.ShapeDtypeStruct((2, 1, T), jnp.int32)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        text = jax.jit(jax.vmap(jax.grad(built.loss_fn))).trace(
+            shapes, (tokens, tokens)
+        ).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in text
+    calls = re.findall(
+        r'loc\("([^"]*)/layer_(\d)/mlp/[^"]*dpwa\.moe\.experts/jit\((t?gmm)\)',
+        text,
+    )
+    layers = lambda kernel: sorted({int(i) for _, i, k in calls if k == kernel})
+    assert layers("gmm") == layers("tgmm") == [0, 1, 2, 3]
+    assert all("transpose(jvp" in scope for scope, _, k in calls if k == "tgmm")
+    # Which attention kernel each layer calls: a kernel's call is one shared
+    # body a kernel (``jax.jit`` around it), named by its ``kernel_name``,
+    # and a layer's call of that body carries the layer's scopes.
+    forward, backward = eva.KERNEL_NAMES[False]
+    alias = dict(re.findall(r'(#loc\d+) = loc\("([^"]*)"', text))
+    kernel_of = dict(re.findall(
+        r'func\.func private @"(<unknown>[^"]*)"(?:(?!func\.func).)*?'
+        r'kernel_name = "([^"]+)"', text, re.S,
+    ))
+    assert sorted(kernel_of.values()) == sorted(
+        (forward, backward) + eva.BAND_KERNEL_NAMES
+    )
+    called = [
+        (kernel_of[fn], alias[loc]) for fn, loc in re.findall(
+            r'call @"(<unknown>[^"]*)"\(.*?loc\((#loc\d+)\)\n', text
+        )
+    ]
+    assert len(called) == 4 * 3  # forward, recomputed and backward, a layer
+    by_layer = lambda i: {
+        name for name, where in called if f"/layer_{i}/dpwa.attn.gqa/" in where
+    }
+    for i in range(3):
+        assert by_layer(i) == set(eva.BAND_KERNEL_NAMES), i
+    assert by_layer(3) == {forward, backward}
+    for name, where in called:
+        assert ("dpwa.attn.gqa/dpwa.attn.window/attn/" in where) == (
+            name in eva.BAND_KERNEL_NAMES
+        )
+        assert ("transpose(jvp" in where) == (
+            name.startswith("flash_mha_bwd") or "rematted" in where
+        )
+    # Keys and values go to the kernels grouped, four heads of them.
+    assert "tensor<2x1x4x4096x128xbf16>" in text
+    sizes = [
+        int(np.prod([int(d) for d in dims.split("x")]))
+        for dims in re.findall(r"tensor<((?:\d+x)+)(?:f32|bf16|i32|i1)>", text)
+        for dims in [dims.rstrip("x")]
+    ]
+    # The largest are one projection of the two peers' 64 experts and the
+    # float32 logits: under half of one sequence's scores over its 32 heads.
+    assert max(sizes) == 2 * 64 * 2304 * 896 < 32 * T * T // 2
+    assert 2 * T * 24576 in sizes
+
+
+@pytest.mark.parametrize("T, window", [(4096, 1024), (8192, 1024), (1024, 256)])
+def test_the_windowed_kernels_fit_the_v5e(v5e_chips, T, window):
+    """Mosaic compiles the forward and backward kernels with a band at the
+    Mellum cell's heads (32 on 4 of 128) under `vmap` over two peers, at the
+    cell's T, at twice it and where the band is cut inside one block."""
+    import functools
+
+    from jax.sharding import SingleDeviceSharding
+
+    from dpwa_tpu.ops import eva
+    from dpwa_tpu.ops.ulysses import single_device_attention
+
+    attn = jax.vmap(functools.partial(
+        single_device_attention, causal=True, window=window, impl="flash"
+    ))
+    loss = lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32))
+    one = SingleDeviceSharding(v5e_chips[0])
+    shaped = lambda heads: jax.ShapeDtypeStruct(
+        (2, 1, T, heads, 128), jnp.bfloat16, sharding=one
+    )
+    with _no_compile_cache():
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            shaped(32), shaped(4), shaped(4)
+        ).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    for name in eva.BAND_KERNEL_NAMES:
+        assert name in text, name
+    assert f"bf16[2,4,{T},128]" in text
+
+
 def test_what_the_mamba_blocks_keep_does_not_pile_up(v5e_chips, capsys):
     """The Jamba cell's own step at four layers (Mamba blocks all, each
     keeping its scan's output and boundary states) compiled for the v5e

@@ -173,7 +173,10 @@ def test_period_and_offset_pick_as_before():
     plain_cfg = LlamaConfig(n_layers=3)
     assert plain_cfg.layer_mixers is None and plain_cfg.conv_taps == 3
     assert all(plain_cfg.is_attention_layer(i) for i in range(3))
-    assert MIXERS == ("attention", "conv", "mamba")
+    # The three mixers, then the two kinds of plain attention (PR 49).
+    assert MIXERS == (
+        "attention", "conv", "mamba", "sliding_attention", "full_attention"
+    )
 
 
 def test_a_mamba_layer_can_be_named_in_the_list():
