@@ -70,6 +70,14 @@ is what is passed: (1) needs ``lora_a`` of one shape on both of gate and up
 (else :func:`expert_projection` for each, as the dense-expert tests run it),
 (3) an adapter on ``w_down``.
 
+**Where the combine reads its rows** (PR 50).  From the sorted array the
+down projection wrote, by one hand-written rule (:func:`_combine_sorted`):
+its residual is that array and not the copy gathered back to token order,
+because a block under ``jax.checkpoint`` runs its forward pass again to
+remake residuals and that copy has no other reader, and its gradient gathers
+``d_y``'s own rows into expert order where the einsum's widened ``d_y`` to
+``[N, k, D]`` for a permute to narrow again.
+
 **The rows a share works on** (PR 34).  A share that holds ``held`` of the
 router's ``E`` experts is sent ``N x k x held / E`` assignments on average,
 and only an imbalance no shape can rule out sends it all ``N x k``.  So a
@@ -379,24 +387,6 @@ may sum to less than M, and the rows past the groups come out zero (their
 gradient too)."""
 
 
-@jax.custom_vjp
-def _permute_rows(x, perm, inverse):
-    """``x[perm]`` for a permutation and its inverse, so that the gradient
-    is a gather too (``g[inverse]``) and not a scatter-add."""
-    return x[perm]
-
-
-def _permute_rows_fwd(x, perm, inverse):
-    return x[perm], inverse
-
-
-def _permute_rows_bwd(inverse, grad):
-    return grad[inverse], None, None
-
-
-_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def _dispatch_rows(x, order, inverse, k: int):
     """Tokens ``x [N, D]`` into expert order, ``[N x k, D]``: sorted row i is
@@ -484,6 +474,73 @@ def _combine_rows_bwd(n, out_dtype, residuals, grad):
 
 
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _combine_sorted(out, down, weights, order, inverse, out_dtype):
+    """The combine of all N x k sorted rows, ``(y, down in token order)``:
+    ``y[n] = sum_j weights[n, j] x out[inverse[n k + j]]``, the einsum over
+    the rows gathered back to token order, and ``down [N x k, r]`` (or None)
+    gathered likewise to ``[N, k, r]``.
+
+    The residual is ``out`` as the grouped matmul wrote it, sorted, and not
+    the gathered copy, which has no other reader: a forward pass run again
+    under ``jax.checkpoint`` makes neither that gather nor this sum.  The
+    gradient gathers as :func:`_combine_rows`'s does: sorted row i takes
+    ``d_y[order[i] // k]``, nothing is widened to ``[N, k, D]`` and permuted;
+    the weights' gradient is summed in float32 and rounded once.  A gather of
+    N x k single values costs more than one of 16-wide rows and nearly three
+    sorts of as many pairs (PERF.md section 6, PR 50), so the weights come to
+    sorted order as one more column of ``down``'s gradient, which takes the
+    same way, and their gradient goes back to token order by a sort on the
+    rows' assignments."""
+    n, k = weights.shape
+    to_tokens = lambda v: v[inverse].reshape(n, k, -1)
+    y = jnp.einsum(
+        "nkd,nk->nd", to_tokens(out), weights, preferred_element_type=out_dtype
+    )
+    return y, None if down is None else to_tokens(down)
+
+
+def _combine_sorted_fwd(out, down, weights, order, inverse, out_dtype):
+    return _combine_sorted(out, down, weights, order, inverse, out_dtype), (
+        out, weights, order
+    )
+
+
+def _combine_sorted_bwd(out_dtype, residuals, grads):
+    out, weights, order = residuals
+    grad, d_down = grads
+    with jax.named_scope(scopes.MOE_ROUTE):
+        narrow = weights.reshape(-1, 1)
+        if d_down is not None:
+            narrow = jnp.concatenate(
+                [d_down.reshape(narrow.shape[0], -1), narrow], axis=-1
+            )[order]
+            d_down = narrow[:, :-1].astype(d_down.dtype)
+        else:
+            narrow = narrow[order]
+        # Both products from one float32 copy of the gathered rows (a product
+        # of two values is rounded once either way): XLA then makes them in
+        # one pass over ``picked`` and ``out`` where ``out`` was kept.  In a
+        # recomputed block it multiplies by the weights at once and reads
+        # ``picked`` again when the down projection has been run again; a
+        # barrier that hands it both together makes the one pass there too,
+        # but parts the recomputed SwiGLU from its gradient, which costs more
+        # (PERF.md section 6, PR 50).
+        picked = grad[order // weights.shape[1]].astype(jnp.float32)
+        row_weights = narrow[:, -1:].astype(jnp.float32)
+        d_out = (picked * row_weights).astype(out.dtype)
+        d_weights = jnp.sum(picked * out.astype(jnp.float32), -1).astype(
+            weights.dtype
+        )
+        d_weights = lax.sort(  # (no two keys are equal)
+            (order, d_weights), num_keys=1, is_stable=False
+        )[1]
+    return d_out, d_down, d_weights.reshape(weights.shape), None, None
+
+
+_combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
 
 
 @jax.custom_vjp
@@ -715,24 +772,23 @@ def _swiglu_experts(rows, group_sizes, w_gate, w_up, w_down, how):
 
 def _all_rows(how, x, weights, experts, plan, expert_weights):
     """:func:`moe_ffn` given its dispatch ``plan``, over all N x k sorted
-    rows at once.  ``experts`` count from the first one held."""
+    rows at once.  ``experts`` count from the first one held.  The combine
+    reads the experts' output where the grouped matmul wrote it and keeps
+    that sorted array for the backward pass (:func:`_combine_sorted`): a
+    recomputed forward pass has then no gather back to token order to make."""
     lora_scale, dtype, _, out_dtype = how
     order, inverse, group_sizes = plan
-    n, k = experts.shape
     enter = _entering(dtype, out_dtype)
     with jax.named_scope(scopes.MOE_ROUTE):
-        rows = _dispatch_rows(enter(x), order, inverse, k)
+        rows = _dispatch_rows(enter(x), order, inverse, experts.shape[1])
     with jax.named_scope(scopes.MOE_EXPERTS):
         _, out, down = _swiglu_experts(rows, group_sizes, *expert_weights, how)
     with jax.named_scope(scopes.MOE_ROUTE):
-        to_tokens = lambda v: _permute_rows(v, inverse, order).reshape(n, k, -1)
-        y = jnp.einsum(
-            "nkd,nk->nd", to_tokens(out), enter(weights),
-            preferred_element_type=out_dtype,
+        y, down = _combine_sorted(
+            out, down, enter(weights), order, inverse, out_dtype
         )
         if down is None:
             return y
-        down = to_tokens(down)
     with jax.named_scope(scopes.MOE_EXPERTS):
         return y + _down_adapter_on_tokens(
             down, (weights, experts), expert_weights[2][2], lora_scale, dtype,

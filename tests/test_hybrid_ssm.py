@@ -562,7 +562,11 @@ def test_two_stacked_peers_match_the_references_local_update():
 # text: pin them again from a tree that is known good.  Every program with a
 # rope in it (all but ``LoRADense``) was pinned again at PR 43, which wrote
 # ``rope`` without its strided slices: ``tests/test_evabyte.py`` holds the
-# new body to the old one's values, bit for bit.
+# new body to the old one's values, bit for bit.  The two ``loss_grad``
+# programs that run ``ops/moe._all_rows`` were pinned again at PR 50, whose
+# combine keeps the sorted rows (the OLMoE one, and A.X-K1's at this toy
+# shape, where a share has no row cap; the forward programs did not move):
+# ``tests/test_moe.py`` holds the new combine to the old one's values.
 PROGRAMS_BEFORE = {
     "LoRADense": "af720f95b366931e",
     "Attention": "a4244af0960dfaa8",
@@ -571,9 +575,9 @@ PROGRAMS_BEFORE = {
     "mistral-7b-v0.3-lora": "72c81c1f1b155b21",
     "mistral-7b-v0.3-lora.loss_grad": "7dceb0d6fdeaaf08",
     "olmoe-1b-7b-0125-lora": "f99e0ef324d5a84b",
-    "olmoe-1b-7b-0125-lora.loss_grad": "9298422d436827a6",
+    "olmoe-1b-7b-0125-lora.loss_grad": "bf633c252b7cb8dc",
     "axk1-lora": "6f854f775b916b26",
-    "axk1-lora.loss_grad": "2c4f0b967d98e170",
+    "axk1-lora.loss_grad": "a9ed713cea62f01f",
 }
 
 

@@ -546,26 +546,31 @@ def test_the_tiles_at_this_models_widths_divide_them():
 # the kernels' serialised bodies taken out: they carry the checkout's path
 # and the callers' line numbers; ``tests/test_window_attention.py`` holds the
 # bodies of ``ops/eva.py``'s kernels by their jaxprs), taken on the parent
-# commit (2966108) by these lines.
+# commit (2966108) by these lines.  The three that run ``ops/moe._all_rows``
+# at this shape were taken again at PR 50, whose combine keeps the sorted
+# rows (``tests/test_moe.py`` holds it to the old one's values): the OLMoE
+# and LFM2 cells, and the A.X-K1 cell, whose toy share has no row cap; the
+# cell itself walks windows and did not move (``STEP_OF_THE_SHARE`` below).
 STEPS_AT_PARENT = {
     "resnet50-stacked8-fulltree": "9c86be813da01bc3",
     "resnet50-ici4-fulltree": "9c86be813da01bc3",
     "mistral7b-lora-stacked2-t4096": "fbaa370d5f1b8c05",
     "mistral7b-lora-stacked2-t512": "fbaa370d5f1b8c05",
-    "olmoe-lora-stacked2-t4096": "f7a242d5ba0b127f",
-    "axk1-lora-share8-stacked2": "9e436a9cd7f1a2ed",
+    "olmoe-lora-stacked2-t4096": "5f6449e11f3809f4",
+    "axk1-lora-share8-stacked2": "890ec4cea98beeac",
     "jamba2-lora-period14-stacked2": "ff0fc3c8b4ffeb22",
     "evabyte-lora-stacked2-t16384": "42fa7dbca930eafd",
-    "lfm2-lora-stacked2-t4096": "f0504b96973d0bb1",
+    "lfm2-lora-stacked2-t4096": "a7a7a2e1257c3eaa",
 }
 _BODY = re.compile(r'(\\22body\\22: \\22)[^\\]*')
 
 
-def lowered_step_digest(name):
+def lowered_step_digest(name, toy=True):
     _, config, cell = cell_files(name)
     family = importlib.import_module("benchmark.builders." + config["family"])
-    toy, cell = family.rehearse(config, cell)
-    built = family.build(toy, cell)
+    if toy:
+        config, cell = family.rehearse(config, cell)
+    built = family.build(config, cell)
     shapes = jax.eval_shape(
         jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
     )
@@ -585,6 +590,18 @@ def lowered_step_digest(name):
 @pytest.mark.parametrize("name", sorted(STEPS_AT_PARENT))
 def test_an_accepted_cells_step_lowers_to_the_parents_text(name):
     assert lowered_step_digest(name) == STEPS_AT_PARENT[name]
+
+
+# The A.X-K1 cell at its published shapes (a window of 1,024 rows,
+# ``ops/moe._capped_ffn``), taken on PR 50's parent (56a0c59) by these lines.
+STEP_OF_THE_SHARE = "b61bff4342ba4652"
+
+
+@pytest.mark.filterwarnings("ignore:held_matmul outside vmap")
+def test_the_share_cells_own_step_lowers_to_the_parents_text():
+    assert lowered_step_digest(
+        "axk1-lora-share8-stacked2", toy=False
+    ) == STEP_OF_THE_SHARE
 
 
 def test_every_accepted_cell_is_held():

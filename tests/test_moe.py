@@ -3,6 +3,7 @@ QK-norm) against its plain reference (``benchmark/references/moe_decoder.py``)
 at toy sizes on the CPU: experts 8, top 2, width 64."""
 
 import os
+import re
 import sys
 
 import jax
@@ -265,6 +266,21 @@ def test_no_token_is_dropped_at_any_skew(experts_of):
     )
 
 
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation and its inverse, so that the gradient is
+    a gather too (``g[inverse]``): how ``ops/moe.py`` moved whole arrays
+    between token and expert order until PR 50, kept here for the layers the
+    tests compare with."""
+    return x[perm]
+
+
+_permute_rows.defvjp(
+    lambda x, perm, inverse: (x[perm], inverse),
+    lambda inverse, grad: (grad[inverse], None, None),
+)
+
+
 def _per_projection_layer(x, routed, w_gate, w_up, w_down, lora_scale, dtype):
     """The layer as it was before the adapters shared their passes (PR 29):
     the tokens repeated k times and permuted, ``expert_projection`` for each
@@ -272,12 +288,12 @@ def _per_projection_layer(x, routed, w_gate, w_up, w_down, lora_scale, dtype):
     weights, experts = routed
     n, k = experts.shape
     order, inverse, sizes = moe.dispatch_plan(experts, w_gate[0].shape[0])
-    rows = moe._permute_rows(
+    rows = _permute_rows(
         jnp.repeat(x.astype(dtype), k, axis=0), order, inverse
     )
     project = lambda v, w: moe.expert_projection(v, w, sizes, lora_scale, dtype)
     hidden = jax.nn.silu(project(rows, w_gate)) * project(rows, w_up)
-    out = moe._permute_rows(project(hidden, w_down), inverse, order)
+    out = _permute_rows(project(hidden, w_down), inverse, order)
     return jnp.einsum(
         "nkd,nk->nd", out.reshape(n, k, -1), weights.astype(dtype)
     )
@@ -367,7 +383,7 @@ def test_dispatch_from_the_tokens_equals_permuting_their_repeats(peers):
 
     _assert_equal_over_peers(
         graded(lambda x, o, i: moe._dispatch_rows(x, o, i, K)),
-        graded(lambda x, o, i: moe._permute_rows(jnp.repeat(x, K, 0), o, i)),
+        graded(lambda x, o, i: _permute_rows(jnp.repeat(x, K, 0), o, i)),
         make_args, peers,
     )
 
@@ -395,14 +411,14 @@ def test_down_adapter_on_the_tokens_equals_the_expert_order_one(peers):
         return jax.value_and_grad(loss, (0, 1, 2, 3), has_aux=True)
 
     def on_tokens(down, lora_b, weights, experts, order, inverse, sizes):
-        down = moe._permute_rows(down, inverse, order).reshape(n, K, -1)
+        down = _permute_rows(down, inverse, order).reshape(n, K, -1)
         return moe._down_adapter_on_tokens(
             down, (weights, experts), lora_b, 2.0, jnp.float32
         )
 
     def in_expert_order(down, lora_b, weights, experts, order, inverse, sizes):
         out = moe.grouped_matmul(down, lora_b, sizes) * 2.0
-        per_choice = moe._permute_rows(out, inverse, order).reshape(n, K, -1)
+        per_choice = _permute_rows(out, inverse, order).reshape(n, K, -1)
         return jnp.einsum("nkd,nk->nd", per_choice, weights)
 
     _assert_equal_over_peers(
@@ -449,6 +465,127 @@ def test_layer_equals_the_per_projection_one(which, peers):
     _assert_equal_over_peers(
         graded(moe.moe_ffn), graded(_per_projection_layer), make_args, peers
     )
+
+
+def _einsum_over_the_gathered_copy(out, down, weights, order, inverse, out_dtype):
+    """The combine as it was before PR 50: the experts' output gathered back
+    to token order, that copy the einsum's residual; ``down`` by a permute of
+    its own."""
+    n, k = weights.shape
+    to_tokens = lambda v: _permute_rows(v, inverse, order).reshape(n, k, -1)
+    y = jnp.einsum(
+        "nkd,nk->nd", to_tokens(out), weights, preferred_element_type=out_dtype
+    )
+    return y, None if down is None else to_tokens(down)
+
+
+@PEERS
+@pytest.mark.parametrize("dtype, out_dtype", [
+    (jnp.float32, None), (jnp.bfloat16, None), (jnp.bfloat16, jnp.float32),
+], ids=["float32", "bfloat16", "bfloat16_wide"])
+def test_combine_from_the_sorted_rows_equals_the_einsum_over_the_gathered_copy(
+    peers, dtype, out_dtype
+):
+    """At a routing with two full groups and an empty one: ``y``, ``down`` in
+    token order and the gradients to the rows and to ``down`` to the bit, the
+    gradient to the weights against the same sum in float32 (the einsum may
+    round its products before it adds)."""
+    n, rank = 24, 4
+
+    def make_args(i):
+        keys = jax.random.split(jax.random.key(60 + i), 5)
+        weights, experts = _skewed_routing(keys[0], n)
+        order, inverse, sizes = moe.dispatch_plan(experts, E)
+        assert int(sizes.min()) == 0 and int(sizes.max()) > 2 * n * K // E
+        draw = lambda key, *shape: jax.random.normal(key, shape).astype(dtype)
+        return (draw(keys[1], n * K, D), draw(keys[2], n * K, rank),
+                weights.astype(dtype), order, inverse,
+                draw(keys[3], n, D).astype(out_dtype or dtype),
+                draw(keys[4], n, K, rank))
+
+    def graded(combine, out_dtype):
+        def run(out, down, weights, order, inverse, *cots):
+            results, pullback = jax.vjp(
+                lambda out, down, weights: combine(
+                    out, down, weights, order, inverse, out_dtype
+                ), out, down, weights,
+            )
+            return results + pullback(cots)  # y, down, d_out, d_down, d_w
+
+        return run
+
+    def wide(*args):
+        return graded(_einsum_over_the_gathered_copy, None)(*(
+            v.astype(jnp.float32) if v.dtype == dtype else v for v in args
+        ))
+
+    each = [make_args(i) for i in range(max(peers, 1))]
+    over_peers = lambda fn: (
+        jax.tree.map(lambda v: v[None], fn(*each[0])) if not peers
+        else jax.vmap(fn)(*jax.tree.map(lambda *v: jnp.stack(v), *each))
+    )
+    got = over_peers(graded(moe._combine_sorted, out_dtype))
+    was = over_peers(graded(_einsum_over_the_gathered_copy, out_dtype))
+    for g, w in zip(got, was):
+        assert g.dtype == w.dtype and g.shape == w.shape
+    for g, w in zip(got[:4], was[:4]):
+        np.testing.assert_array_equal(
+            np.asarray(g, np.float32), np.asarray(w, np.float32)
+        )
+    want = over_peers(wide)[4]
+    scale = float(jnp.abs(want).max())
+    # One rounding to the weights' type of a sum made in float32.
+    room = 2e-6 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(
+        np.asarray(got[4], np.float32), want, rtol=room, atol=room * scale
+    )
+
+
+def _wide_gathers(layer_grad, args, rows, width):
+    """How many gathers whose result is ``[rows, width]`` the lowered
+    program holds."""
+    text = jax.jit(layer_grad).lower(*args).as_text()
+    return len(re.findall(
+        rf"stablehlo\.gather.*-> tensor<{rows}x{width}xf32>", text
+    ))
+
+
+@pytest.mark.parametrize("checkpoint, gathers, before", [
+    (True, 5, 6), (False, 4, 4),
+], ids=["checkpoint", "plain"])
+def test_a_recomputed_layer_does_not_gather_its_output_again(
+    checkpoint, gathers, before, monkeypatch
+):
+    """The wide row gathers of a layer's gradient: the dispatch, the output
+    back to token order, the output's gradient from the tokens' rows, the
+    rows' gradient back.  Under ``jax.checkpoint`` the forward pass runs a
+    second time and the dispatch with it, but not the output's way back: the
+    combine's residual is the sorted array (with the einsum over the gathered
+    copy, ``before``, it was that copy, and was made again)."""
+    n = 24
+    weights, experts = _skewed_routing(jax.random.key(0), n)
+    x = jax.random.normal(jax.random.key(1), (n, D))
+    w = _layer_weights(jax.random.key(2))
+
+    def count():
+        # (A function of its own each time: a checkpoint's trace is kept by
+        # the function it wraps.)
+        def layer(x, weights, w):
+            # The tanh stands for the norm before a block's feed-forward: the
+            # checkpoint's input is not the layer's.
+            return moe.moe_ffn(
+                jnp.tanh(x), (weights, experts), *w, 2.0, jnp.float32
+            )
+
+        fn = jax.checkpoint(layer) if checkpoint else layer
+        # Squared, so that the backward pass needs the layer's result and
+        # the checkpoint has a forward pass to run before its second.
+        loss = lambda *args: jnp.sum(fn(*args) ** 2)
+        return _wide_gathers(jax.grad(loss, (0, 1, 2)), (x, weights, w), n * K, D)
+
+    assert count() == gathers
+    monkeypatch.setattr(moe, "_combine_sorted", _einsum_over_the_gathered_copy)
+    assert count() == before
 
 
 def _grouped_products(layer, which):
