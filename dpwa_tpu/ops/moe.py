@@ -70,13 +70,22 @@ is what is passed: (1) needs ``lora_a`` of one shape on both of gate and up
 (else :func:`expert_projection` for each, as the dense-expert tests run it),
 (3) an adapter on ``w_down``.
 
-**Where the combine reads its rows** (PR 50).  From the sorted array the
-down projection wrote, by one hand-written rule (:func:`_combine_sorted`):
-its residual is that array and not the copy gathered back to token order,
-because a block under ``jax.checkpoint`` runs its forward pass again to
-remake residuals and that copy has no other reader, and its gradient gathers
-``d_y``'s own rows into expert order where the einsum's widened ``d_y`` to
-``[N, k, D]`` for a permute to narrow again.
+**The down projection and the combine, one rule** (PR 50, PR 51).  The
+combine reads the experts' output where the frozen down projection wrote it,
+sorted, and the two share one hand-written gradient
+(:func:`_down_and_combine`).  Its residuals are ``hidden`` (the SwiGLU's
+result, which the down adapter keeps anyway), the kernel, the routing weights,
+``order`` and ``group_sizes``: neither the experts' output nor a copy of it in
+token order, because a block under ``jax.checkpoint`` runs its forward pass
+again to remake residuals, and whatever is one would be multiplied or gathered
+a second time for a single reader.  Backward, ``d_y``'s own rows are gathered
+into expert order (``picked``; nothing is widened to ``[N, k, D]``) and enter
+one product, ``g = picked W_down^T``, unweighted.  The weight enters after it,
+on the expert width: ``d_hidden = w x g``, the weights' gradient is the row
+dot ``<g, hidden>`` and the kernel's own ``(w x hidden)^T picked``; ``picked``
+is the one ``[N x k, D]`` array the rule writes.  A share's window
+(:func:`_window`) keeps two rules: it is a checkpoint of its own, and its
+combine a product by a placement matrix (:func:`_combine_rows`).
 
 **The rows a share works on** (PR 34).  A share that holds ``held`` of the
 router's ``E`` experts is sent ``N x k x held / E`` assignments on average,
@@ -229,7 +238,9 @@ def _tgmm(lhs, grad, group_sizes):
 
 def _differentiable(gmm, gmm_transposed, tgmm):
     """A grouped matmul differentiable in ``lhs`` and ``rhs`` from its three
-    products: forward, to the activations, to the weights."""
+    products: forward, to the activations, to the weights.  The two of the
+    backward pass are its ``to_rows`` and ``to_weights``, for a rule that
+    writes the gradient of more than the matmul (:func:`_down_and_combine`)."""
 
     @jax.custom_vjp
     def matmul(lhs, rhs, group_sizes):
@@ -246,6 +257,7 @@ def _differentiable(gmm, gmm_transposed, tgmm):
         return d_lhs.astype(lhs.dtype), d_rhs.astype(rhs.dtype), None
 
     matmul.defvjp(fwd, bwd)
+    matmul.to_rows, matmul.to_weights = gmm_transposed, tgmm
     return matmul
 
 
@@ -476,71 +488,104 @@ def _combine_rows_bwd(n, out_dtype, residuals, grad):
 _combine_rows.defvjp(_combine_rows_fwd, _combine_rows_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _combine_sorted(out, down, weights, order, inverse, out_dtype):
-    """The combine of all N x k sorted rows, ``(y, down in token order)``:
-    ``y[n] = sum_j weights[n, j] x out[inverse[n k + j]]``, the einsum over
-    the rows gathered back to token order, and ``down [N x k, r]`` (or None)
-    gathered likewise to ``[N, k, r]``.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _down_and_combine(hidden, kernel, down, weights, order, inverse,
+                      group_sizes, matmul, out_dtype):
+    """The frozen down projection of all N x k sorted rows and their combine,
+    ``(y, down in token order)``: ``out = matmul(hidden, kernel)``, ``y[n] =
+    sum_j weights[n, j] x out[inverse[n k + j]]``, the einsum over the rows
+    gathered back to token order, and ``down [N x k, r]`` (or None) gathered
+    likewise to ``[N, k, r]``.  ``hidden`` comes as the SwiGLU made it and is
+    rounded here, where it enters the product; ``matmul`` is
+    :func:`grouped_matmul` or :func:`held_matmul`.
 
-    The residual is ``out`` as the grouped matmul wrote it, sorted, and not
-    the gathered copy, which has no other reader: a forward pass run again
-    under ``jax.checkpoint`` makes neither that gather nor this sum.  The
-    gradient gathers as :func:`_combine_rows`'s does: sorted row i takes
-    ``d_y[order[i] // k]``, nothing is widened to ``[N, k, D]`` and permuted;
-    the weights' gradient is summed in float32 and rounded once.  A gather of
-    N x k single values costs more than one of 16-wide rows and nearly three
-    sorts of as many pairs (PERF.md section 6, PR 50), so the weights come to
-    sorted order as one more column of ``down``'s gradient, which takes the
-    same way, and their gradient goes back to token order by a sort on the
-    rows' assignments."""
+    One gradient rule for the two, because together they need less than
+    apart.  With ``picked[i] = d_y[order[i] // k]``, the tokens' gradient
+    rows in expert order, and ``g = picked W_down^T`` group by group (the
+    product to the rows, on the *unweighted* ``picked``):
+
+        d_hidden[i] = w[i] x g[i]         d_w[i] = <g[i], hidden[i]>
+
+    where the two rules apart made ``d_out = w x picked`` (an ``[N x k, D]``
+    array written for one reader) and ``d_w[i] = <picked[i], out[i]>``, which
+    kept ``out`` as a residual: a block under ``jax.checkpoint`` then ran the
+    down projection again for that row dot alone.  Here the residuals are
+    ``hidden`` (the down adapter's already), the kernel, the weights,
+    ``order`` and ``group_sizes``; neither ``out`` nor ``picked`` outlives
+    its one reader, and the row dot runs over the experts' width, not the
+    model's.  The kernel's own gradient carries the weight on the narrow
+    side too, ``(w x hidden)^T picked``.
+
+    What is rounded: ``g`` leaves the product in the matmul type, as
+    ``d_hidden`` did, and ``w x g`` goes back in the type ``hidden`` came in:
+    a sum and a product rounded once each, where it was the product, then
+    the sum (the float32 ``g`` of ISSUE 51 would save the first rounding for
+    twice the bytes of an ``[N x k, F]`` array; at equal seeds and steps the
+    step checks of the three cells that run this read as the two rules' did
+    with the narrow one, PERF.md section 6, PR 51).  With ``out_dtype`` ``d_y`` is rounded where it enters the
+    product, which the weights' gradient passes through now.  That gradient
+    is summed in float32 and rounded once.  A gather of N x k single values
+    costs more than one of 16-wide rows and nearly three sorts of as many
+    pairs (PERF.md section 6, PR 50), so the weights come to sorted order as
+    one more column of ``down``'s gradient, which takes the same way, and
+    their gradient goes back to token order by a sort on the rows'
+    assignments."""
     n, k = weights.shape
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        out = matmul(narrow(hidden, kernel.dtype), kernel, group_sizes)
     to_tokens = lambda v: v[inverse].reshape(n, k, -1)
-    y = jnp.einsum(
-        "nkd,nk->nd", to_tokens(out), weights, preferred_element_type=out_dtype
-    )
-    return y, None if down is None else to_tokens(down)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        y = jnp.einsum(
+            "nkd,nk->nd", to_tokens(out), weights,
+            preferred_element_type=out_dtype,
+        )
+        return y, None if down is None else to_tokens(down)
 
 
-def _combine_sorted_fwd(out, down, weights, order, inverse, out_dtype):
-    return _combine_sorted(out, down, weights, order, inverse, out_dtype), (
-        out, weights, order
-    )
+def _down_and_combine_fwd(hidden, kernel, down, weights, order, inverse,
+                          group_sizes, matmul, out_dtype):
+    # (The narrow ``hidden`` is what the backward pass multiplies; an empty
+    # array carries the type it came in.)
+    like_hidden = jnp.zeros((0,), hidden.dtype)
+    hidden = narrow(hidden, kernel.dtype)
+    return _down_and_combine(
+        hidden, kernel, down, weights, order, inverse, group_sizes, matmul,
+        out_dtype,
+    ), (hidden, kernel, weights, order, group_sizes, like_hidden)
 
 
-def _combine_sorted_bwd(out_dtype, residuals, grads):
-    out, weights, order = residuals
+def _down_and_combine_bwd(matmul, out_dtype, residuals, grads):
+    hidden, kernel, weights, order, group_sizes, like_hidden = residuals
     grad, d_down = grads
     with jax.named_scope(scopes.MOE_ROUTE):
-        narrow = weights.reshape(-1, 1)
+        narrow_columns = weights.reshape(-1, 1)
         if d_down is not None:
-            narrow = jnp.concatenate(
-                [d_down.reshape(narrow.shape[0], -1), narrow], axis=-1
+            narrow_columns = jnp.concatenate(
+                [d_down.reshape(narrow_columns.shape[0], -1), narrow_columns],
+                axis=-1,
             )[order]
-            d_down = narrow[:, :-1].astype(d_down.dtype)
+            d_down = narrow_columns[:, :-1].astype(d_down.dtype)
         else:
-            narrow = narrow[order]
-        # Both products from one float32 copy of the gathered rows (a product
-        # of two values is rounded once either way): XLA then makes them in
-        # one pass over ``picked`` and ``out`` where ``out`` was kept.  In a
-        # recomputed block it multiplies by the weights at once and reads
-        # ``picked`` again when the down projection has been run again; a
-        # barrier that hands it both together makes the one pass there too,
-        # but parts the recomputed SwiGLU from its gradient, which costs more
-        # (PERF.md section 6, PR 50).
-        picked = grad[order // weights.shape[1]].astype(jnp.float32)
-        row_weights = narrow[:, -1:].astype(jnp.float32)
-        d_out = (picked * row_weights).astype(out.dtype)
-        d_weights = jnp.sum(picked * out.astype(jnp.float32), -1).astype(
-            weights.dtype
-        )
+            narrow_columns = narrow_columns[order]
+        row_weights = narrow_columns[:, -1:].astype(jnp.float32)
+        picked = narrow(grad, hidden.dtype)[order // weights.shape[1]]
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        g = matmul.to_rows(picked, kernel, group_sizes).astype(jnp.float32)
+        wide_hidden = hidden.astype(jnp.float32)
+        d_hidden = (g * row_weights).astype(like_hidden.dtype)
+        d_weights = jnp.sum(g * wide_hidden, -1).astype(weights.dtype)
+        d_kernel = matmul.to_weights(
+            (wide_hidden * row_weights).astype(hidden.dtype), picked,
+            group_sizes,
+        ).astype(kernel.dtype)
+    with jax.named_scope(scopes.MOE_ROUTE):
         d_weights = lax.sort(  # (no two keys are equal)
             (order, d_weights), num_keys=1, is_stable=False
-        )[1]
-    return d_out, d_down, d_weights.reshape(weights.shape), None, None
+        )[1].reshape(weights.shape)
+    return d_hidden, d_kernel, d_down, d_weights, None, None, None
 
 
-_combine_sorted.defvjp(_combine_sorted_fwd, _combine_sorted_bwd)
+_down_and_combine.defvjp(_down_and_combine_fwd, _down_and_combine_bwd)
 
 
 @jax.custom_vjp
@@ -747,52 +792,74 @@ def moe_ffn(x, routed, w_gate, w_up, w_down, lora_scale: float, dtype,
     return _capped_ffn(how, cap, *operands)
 
 
+def _matmul_of(how):
+    """:func:`held_matmul` where the experts are a share."""
+    return held_matmul if how[2] else grouped_matmul
+
+
+def _swiglu_rows(rows, group_sizes, w_gate, w_up, how):
+    """The experts' SwiGLU on their sorted rows, ``[rows, F]`` in the type it
+    is computed in (``out_dtype``, else ``dtype``): not yet rounded for the
+    down projection."""
+    lora_scale, dtype, _, out_dtype = how
+    gate, up = gate_and_up(
+        rows, w_gate, w_up, group_sizes, lora_scale, dtype, _matmul_of(how)
+    )
+    if out_dtype is None:
+        return jax.nn.silu(gate) * up
+    return jax.nn.silu(gate.astype(out_dtype)) * up.astype(out_dtype)
+
+
+def _down_adapter_rows(hidden, group_sizes, w_down, how):
+    """``hidden A_down`` on the sorted rows, ``hidden`` in ``dtype``; None
+    without an adapter on ``w_down``."""
+    if w_down[1] is None:
+        return None
+    return _matmul_of(how)(hidden, w_down[1].astype(how[1]), group_sizes)
+
+
 def _swiglu_experts(rows, group_sizes, w_gate, w_up, w_down, how):
     """The experts on their sorted rows: ``(hidden, out, down)``, the SwiGLU's
     result, its down projection by the frozen kernels, and ``hidden A_down``
-    (None without an adapter on ``w_down``)."""
-    lora_scale, dtype, share, out_dtype = how
-    matmul = held_matmul if share else grouped_matmul
-    gate, up = gate_and_up(
-        rows, w_gate, w_up, group_sizes, lora_scale, dtype, matmul
-    )
-    if out_dtype is None:
-        hidden = jax.nn.silu(gate) * up
-    else:
-        hidden = narrow(
-            jax.nn.silu(gate.astype(out_dtype)) * up.astype(out_dtype), dtype
-        )
-    kernel_down, a_down, _ = w_down
-    out = matmul(hidden, kernel_down.astype(dtype), group_sizes)
-    down = None
-    if a_down is not None:
-        down = matmul(hidden, a_down.astype(dtype), group_sizes)
-    return hidden, out, down
+    (None without an adapter on ``w_down``).  A window alone reads ``out``
+    (:func:`_window`, whose combine is a product by a placement matrix with a
+    rule of its own); over all rows at once the down projection and the
+    combine are one rule and ``out`` is nobody's operand
+    (:func:`_down_and_combine`)."""
+    hidden = narrow(_swiglu_rows(rows, group_sizes, w_gate, w_up, how), how[1])
+    out = _matmul_of(how)(hidden, w_down[0].astype(how[1]), group_sizes)
+    return hidden, out, _down_adapter_rows(hidden, group_sizes, w_down, how)
 
 
 def _all_rows(how, x, weights, experts, plan, expert_weights):
     """:func:`moe_ffn` given its dispatch ``plan``, over all N x k sorted
-    rows at once.  ``experts`` count from the first one held.  The combine
-    reads the experts' output where the grouped matmul wrote it and keeps
-    that sorted array for the backward pass (:func:`_combine_sorted`): a
-    recomputed forward pass has then no gather back to token order to make."""
+    rows at once.  ``experts`` count from the first one held.  The frozen
+    down projection and the combine are one call with one gradient rule
+    (:func:`_down_and_combine`), which keeps ``hidden`` and not the experts'
+    output: a recomputed forward pass has then neither the down projection
+    nor the gather back to token order to make."""
     lora_scale, dtype, _, out_dtype = how
     order, inverse, group_sizes = plan
+    w_gate, w_up, w_down = expert_weights
     enter = _entering(dtype, out_dtype)
     with jax.named_scope(scopes.MOE_ROUTE):
         rows = _dispatch_rows(enter(x), order, inverse, experts.shape[1])
     with jax.named_scope(scopes.MOE_EXPERTS):
-        _, out, down = _swiglu_experts(rows, group_sizes, *expert_weights, how)
-    with jax.named_scope(scopes.MOE_ROUTE):
-        y, down = _combine_sorted(
-            out, down, enter(weights), order, inverse, out_dtype
+        hidden = _swiglu_rows(rows, group_sizes, w_gate, w_up, how)
+        down = _down_adapter_rows(
+            narrow(hidden, dtype), group_sizes, w_down, how
         )
-        if down is None:
-            return y
+    # (It names its own scopes, forward and backward: the product under
+    # ``dpwa.moe.experts``, the gathers and the sum under ``.route``.)
+    y, down = _down_and_combine(
+        hidden, w_down[0].astype(dtype), down, enter(weights), order, inverse,
+        group_sizes, _matmul_of(how), out_dtype,
+    )
+    if down is None:
+        return y
     with jax.named_scope(scopes.MOE_EXPERTS):
         return y + _down_adapter_on_tokens(
-            down, (weights, experts), expert_weights[2][2], lora_scale, dtype,
-            out_dtype,
+            down, (weights, experts), w_down[2], lora_scale, dtype, out_dtype,
         )
 
 

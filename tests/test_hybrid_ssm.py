@@ -567,6 +567,12 @@ def test_two_stacked_peers_match_the_references_local_update():
 # combine keeps the sorted rows (the OLMoE one, and A.X-K1's at this toy
 # shape, where a share has no row cap; the forward programs did not move):
 # ``tests/test_moe.py`` holds the new combine to the old one's values.
+# The same two configurations' four programs were pinned again at PR 51,
+# which put the frozen down projection and the combine under one gradient
+# rule: forward the same operations, the down adapter's A side traced before
+# the frozen product where it was traced after (``tests/test_moe.py`` holds
+# the layer's and the toy model's results to the bit, the gradients to the
+# two rules').
 PROGRAMS_BEFORE = {
     "LoRADense": "af720f95b366931e",
     "Attention": "a4244af0960dfaa8",
@@ -574,10 +580,10 @@ PROGRAMS_BEFORE = {
     "Llama": "622e73bbd68313ff",
     "mistral-7b-v0.3-lora": "72c81c1f1b155b21",
     "mistral-7b-v0.3-lora.loss_grad": "7dceb0d6fdeaaf08",
-    "olmoe-1b-7b-0125-lora": "f99e0ef324d5a84b",
-    "olmoe-1b-7b-0125-lora.loss_grad": "bf633c252b7cb8dc",
-    "axk1-lora": "6f854f775b916b26",
-    "axk1-lora.loss_grad": "a9ed713cea62f01f",
+    "olmoe-1b-7b-0125-lora": "f33098cd71527928",
+    "olmoe-1b-7b-0125-lora.loss_grad": "2b122a44eb5a2300",
+    "axk1-lora": "9c2a1aa8e10913ce",
+    "axk1-lora.loss_grad": "8adc1058a03a5797",
 }
 
 

@@ -546,26 +546,29 @@ def test_the_tiles_at_this_models_widths_divide_them():
 # the kernels' serialised bodies taken out: they carry the checkout's path
 # and the callers' line numbers; ``tests/test_window_attention.py`` holds the
 # bodies of ``ops/eva.py``'s kernels by their jaxprs), taken on the parent
-# commit (2966108) by these lines.  The three that run ``ops/moe._all_rows``
-# at this shape were taken again at PR 50, whose combine keeps the sorted
-# rows (``tests/test_moe.py`` holds it to the old one's values): the OLMoE
-# and LFM2 cells, and the A.X-K1 cell, whose toy share has no row cap; the
-# cell itself walks windows and did not move (``STEP_OF_THE_SHARE`` below).
+# commit (2966108) by these lines.  Those that run ``ops/moe._all_rows`` at
+# this shape were taken again at PR 50, whose combine keeps the sorted rows,
+# and at PR 51, which put the frozen down projection and the combine under
+# one gradient rule (``tests/test_moe.py`` holds each to the form before it,
+# the forward pass to the bit): the OLMoE and LFM2 cells, the Mellum2 cell
+# (held since PR 51), and the A.X-K1 cell, whose toy share has no row cap;
+# the cell itself walks windows and did not move (``STEP_OF_THE_SHARE``).
 STEPS_AT_PARENT = {
     "resnet50-stacked8-fulltree": "9c86be813da01bc3",
     "resnet50-ici4-fulltree": "9c86be813da01bc3",
     "mistral7b-lora-stacked2-t4096": "fbaa370d5f1b8c05",
     "mistral7b-lora-stacked2-t512": "fbaa370d5f1b8c05",
-    "olmoe-lora-stacked2-t4096": "5f6449e11f3809f4",
-    "axk1-lora-share8-stacked2": "890ec4cea98beeac",
+    "olmoe-lora-stacked2-t4096": "9f692ef4953a8eb5",
+    "axk1-lora-share8-stacked2": "871095af9a950f67",
     "jamba2-lora-period14-stacked2": "ff0fc3c8b4ffeb22",
     "evabyte-lora-stacked2-t16384": "42fa7dbca930eafd",
-    "lfm2-lora-stacked2-t4096": "a7a7a2e1257c3eaa",
+    "lfm2-lora-stacked2-t4096": "114566364614790a",
+    "mellum2-lora-stacked2-t4096": "5f6437cf9ac79d2d",
 }
 _BODY = re.compile(r'(\\22body\\22: \\22)[^\\]*')
 
 
-def lowered_step_digest(name, toy=True):
+def lowered_step_text(name, toy=True):
     _, config, cell = cell_files(name)
     family = importlib.import_module("benchmark.builders." + config["family"])
     if toy:
@@ -581,10 +584,14 @@ def lowered_step_digest(name, toy=True):
         jax.random.key(0), 0,
     )
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
-        text = jax.jit(jax.vmap(jax.grad(built.loss_fn))).trace(
+        return jax.jit(jax.vmap(jax.grad(built.loss_fn))).trace(
             shapes, batch
         ).lower(lowering_platforms=("tpu",)).as_text()
-    return hashlib.sha256(_BODY.sub(r"\1", text).encode()).hexdigest()[:16]
+
+
+def lowered_step_digest(name, toy=True):
+    text = _BODY.sub(r"\1", lowered_step_text(name, toy))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 @pytest.mark.parametrize("name", sorted(STEPS_AT_PARENT))
@@ -605,5 +612,49 @@ def test_the_share_cells_own_step_lowers_to_the_parents_text():
 
 
 def test_every_accepted_cell_is_held():
-    cells = {w["name"] for w in MANIFEST["workloads"]}
-    assert set(STEPS_AT_PARENT) == cells - {"mellum2-lora-stacked2-t4096"}
+    assert set(STEPS_AT_PARENT) == {w["name"] for w in MANIFEST["workloads"]}
+
+
+_GROUPED = re.compile(
+    r"call @gmm\w*\(.*?\) : \(tensor<(\d+)x\d+x\w+>, tensor<\d+x(\d+)x(\d+)x\w+>"
+)
+
+
+@pytest.mark.parametrize("name, layers, widths, products, before", [
+    ("mellum2-lora-stacked2-t4096", 4, (64, 48), 8, 9),
+    ("lfm2-lora-stacked2-t4096", 4, (64, 48), 8, 9),
+    ("olmoe-lora-stacked2-t4096", 2, (64, 64), 6, 6),
+])
+def test_a_recomputed_expert_layer_runs_no_second_down_projection(
+    name, layers, widths, products, before, monkeypatch
+):
+    """The grouped products by a frozen kernel (``widths``: the toy shape's
+    hidden and expert widths; an adapter's rank is neither) in a cell's
+    lowered step, an expert layer: gate and up forward, again under
+    ``jax.checkpoint`` and to the rows (2 + 2 + 2, or 2 + 2 in the OLMoE step,
+    which recomputes nothing), the down projection forward and the product to
+    the rows on the gathered ``d_y`` (1 + 1).  With the down projection and
+    the combine under two rules (``before``) a recomputed block ran the down
+    projection again, for the weights' gradient alone.  And no pass writes a
+    product of all ``N x k`` rows at the hidden width by the weights: the
+    weight enters on the expert width."""
+    from tests.test_moe import _the_two_rules_apart
+
+    def count():
+        text = lowered_step_text(name)
+        by_kernel = [
+            int(rows) for rows, k, n in _GROUPED.findall(text)
+            if {int(k), int(n)} == set(widths)
+        ]
+        assert len(set(by_kernel)) == 1  # both peers' N x k rows, folded
+        # (Where the two widths are one, the SwiGLU's own products have that
+        # shape: nothing to tell apart.)
+        wide = widths[0] != widths[1] and re.findall(
+            rf"stablehlo\.multiply.*tensor<2x{by_kernel[0] // 2}x{widths[0]}xf32>",
+            text,
+        )
+        return len(by_kernel) / layers, wide or []
+
+    assert count() == (products, [])
+    monkeypatch.setattr(moe, "_down_and_combine", _the_two_rules_apart)
+    assert count()[0] == before
