@@ -4,9 +4,11 @@ window, one softmax over both (the published EvaByte block's
 
 Per head, with ``s = D^-1/2``, a window of ``W`` positions and chunks of ``C``
 (``W / C`` chunks a window), for ``q``, ``k``, ``v`` ``[T, D]`` (rope applied).
-Every array here has its heads before its positions, ``[B, h, T, D]``: what
-the kernels read a window of, and what the summaries pool rows of; the model
-turns ``q``, ``k``, ``v`` once on the way in and ``o`` once on the way out.
+Every array of the EVA core has its heads before its positions, ``[B, h, T,
+D]``: what the kernels read a window of, and what the summaries pool rows of;
+the model turns ``q``, ``k``, ``v`` once on the way in and ``o`` once on the
+way out.  (Causal attention on the same kernels, below, takes ``v`` and
+gives ``o`` with positions first.)
 
 *Summaries* (:func:`chunk_summaries`).  Chunk ``c`` holds positions ``C c ..
 C c + C - 1``; with the head's learned ``phi``, ``mu`` ``[D]``
@@ -66,7 +68,11 @@ group's ``dk``, ``dv`` are summed there in float32 and rounded once.  The
 window is a function of ``T`` (:func:`causal_window`: one window up to 2,048
 positions, two at 4,096), the scale an argument, and the two calls are named
 ``flash_attention_fwd_dpwa`` / ``flash_mha_bwd_dpwa``: the prefixes by which a
-trace's readers know a flash-attention kernel.
+trace's readers know a flash-attention kernel.  ``q`` and ``k`` are read
+heads first, as the rope leaves them; ``v`` and ``do`` are read, and ``o`` and
+``dv`` written, as ``[B, T, h D]``, a head a block of ``D`` lanes, as the
+projections beside them hold them: the same tiles, and no turn of those four
+(:func:`causal_attention` says why each lies as it does).
 
 **A band beside "every earlier window"** (``causal_attention(..., window=W)``:
 a model's sliding window, query ``t`` sees the keys ``t - W + 1 .. t``, its
@@ -192,7 +198,8 @@ def plain_eva_attention(q, k, v, ksum, vsum, window: int, chunk: int):
     return jnp.concatenate(out, 2).astype(q.dtype)
 
 
-# The kernels.  ``q`` (``o``, ``do``) is ``[S, h, T, D]`` over S sequences; the
+# The kernels.  ``q`` is ``[S, h, T, D]`` over S sequences (``o``, ``do`` too
+# in the EVA core; ``[S, T, h D]`` in causal attention, like its ``v``); the
 # log-sum-exp and ``di = sum_d do o`` are ``[S, h, 1, T]`` float32 rows.  What
 # a query's earlier windows hand over is a head's *whole* operand, ``per_window``
 # rows a window:
@@ -200,9 +207,9 @@ def plain_eva_attention(q, k, v, ksum, vsum, window: int, chunk: int):
 # - summaries (the EVA core): ``ksum``, ``vsum`` ``[S, h, T / chunk, D]``,
 #   ``window / chunk`` rows a window, beside the window's own block of ``k``,
 #   ``v`` ``[S, h, T, D]``;
-# - the keys themselves (causal attention): ``k``, ``v`` ``[S, kv, T, D]``,
-#   ``window`` rows a window, and no block of the window's own: its rows lie
-#   in the head's, ``h // (h / kv)`` where the keys are grouped.
+# - the keys themselves (causal attention): ``k [S, kv, T, D]``, ``v [S, T,
+#   kv D]``, ``window`` rows a window, and no block of the window's own: its
+#   rows lie in the head's, ``h // (h / kv)`` where the keys are grouped.
 
 
 def _lanes(column, width: int):
@@ -459,9 +466,11 @@ def _layout(window: int, q, k, v, *summaries, backward: bool):
     """``(float32 scratch shapes, float32 score tiles alive in a turn, what
     pallas_call is told of grid, blocks and results)`` for the forward or the
     backward kernel, from its operands' shapes and types alone (arrays or
-    ``jax.ShapeDtypeStruct``): ``q [S, h, T, D]``; with ``summaries`` the EVA
-    core's five operands, without them ``k``, ``v`` ``[S, kv, T, D]`` are the
-    head's whole operand."""
+    ``jax.ShapeDtypeStruct``), as the kernel takes them: ``q [S, h, T, D]``;
+    with ``summaries`` the EVA core's five operands, all heads first; without
+    them causal attention's ``k [S, kv, T, D]`` and ``v [S, T, kv D]`` are
+    the head's whole operand, ``v`` (and ``o``, ``do``, ``dv``) with a head
+    as a block of ``D`` lanes (:func:`_kernel_call` says why)."""
     seqs, heads, steps, d = q.shape
     ksum, vsum = summaries or (k, v)
     group = heads // ksum.shape[1]
@@ -475,23 +484,28 @@ def _layout(window: int, q, k, v, *summaries, backward: bool):
         (lambda s, h, w: (s, h, 0, 0)) if group == 1
         else (lambda s, h, w: (s, h // group, 0, 0)),
     )
-    keys = bool(summaries) * [seq, seq] + [whole, whole]
     like = lambda z, dtype: jax.ShapeDtypeStruct(z.shape, dtype)
+    if summaries:
+        out, keys, o = seq, [seq, seq, whole, whole], like(q, q.dtype)
+    else:
+        # The same tiles out of [S, T, h D]: a window of a head, a head's whole.
+        out = pl.BlockSpec((None, window, d), lambda s, h, w: (s, w, h))
+        keys = [whole, pl.BlockSpec(
+            (None, steps, d), lambda s, h, w: (s, 0, h // group)
+        )]
+        o = jax.ShapeDtypeStruct((seqs, steps, heads * d), q.dtype)
     grid = (seqs, heads, steps // window)
     if not backward:
         return 2 * [(block, LANES)] + [(block, d)], 3, dict(
             grid=grid,
             in_specs=[seq, *keys],
-            out_specs=[seq, row],
-            out_shape=[
-                like(q, q.dtype),
-                jax.ShapeDtypeStruct((seqs, heads, 1, steps), F32),
-            ],
+            out_specs=[out, row],
+            out_shape=[o, jax.ShapeDtypeStruct((seqs, heads, 1, steps), F32)],
         )
     own = bool(summaries) * [like(k, k.dtype), like(v, v.dtype)]
     return [(window, d)], 6, dict(
         grid=grid,
-        in_specs=[seq, *keys, seq, row, row],
+        in_specs=[seq, *keys, out, row, row],
         out_specs=[seq, *keys],
         out_shape=[like(q, q.dtype), *own, like(ksum, F32), like(vsum, F32)],
     )
@@ -526,13 +540,25 @@ def _kernel_call(
     """One ``pallas_call`` of the forward or the backward kernel on
     ``operands`` (``q k v``, the summaries where there are any, and the
     backward kernel's ``do lse di``), with the VMEM limit its shapes come to
-    with its score tiles alive in a turn of its loops.  Forward: ``(o [S, h,
-    T, D], lse [S, h, 1, T])``.  Backward: the gradients of ``q k v`` and of
-    the summaries, each in its argument's shape: with summaries ``dq dk dv``
-    in ``q``'s type and ``dksum dvsum`` float32; without them ``dq`` and
-    float32 ``dk dv``."""
+    with its score tiles alive in a turn of its loops.  Forward: ``(o, lse
+    [S, h, 1, T])``.  Backward: the gradients of ``q k v`` and of the
+    summaries, each in its argument's shape: with summaries ``dq dk dv`` in
+    ``q``'s type and ``dksum dvsum`` float32; without them ``dq`` and float32
+    ``dk dv``.
+
+    With summaries every operand is heads first, ``o`` too.  Without them
+    (causal attention) ``q``, ``k`` are heads first and ``v``, ``do`` (and so
+    ``o``, ``dv``) ``[S, T, h, D]``, each as :func:`causal_attention` says its
+    neighbour in a model holds it; the positions-first four go to the kernel
+    as ``[S, T, h D]`` by a reshape, where a head is a block of ``D`` lanes:
+    the same tiles in VMEM."""
     keys = operands[:len(operands) - 3 * backward]
     own = len(keys) == 5
+    if not own:
+        lanes = lambda z: z.reshape(*z.shape[:2], -1)
+        q, k, v, *rest = operands
+        keys = (q, k, lanes(v))
+        operands = (*keys, *map(lanes, rest[:1]), *rest[1:])  # rest: do lse di
     group = operands[0].shape[1] // operands[1].shape[1]
     scratch, tiles, call = _layout(window, *keys, backward=backward)
     static = dict(
@@ -540,7 +566,7 @@ def _kernel_call(
     )
     if backward:
         static.update(group=group, own=own)
-    return pl.pallas_call(
+    results = pl.pallas_call(
         functools.partial(
             _backward_kernel if backward else _forward_kernel, **static
         ),
@@ -562,6 +588,35 @@ def _kernel_call(
             KERNEL_NAMES[own] if band is None else BAND_KERNEL_NAMES
         )[backward],
     )(*operands)
+    if own:
+        return results
+    seqs, heads, steps, d = q.shape
+    if not backward:
+        o, lse = results
+        return o.reshape(seqs, steps, heads, d), lse
+    dq, dk, dv = results
+    return dq, dk, dv.reshape(v.shape)
+
+
+def _row_sums(do, o):
+    """``di [B, h, 1, T]`` float32, ``sum_D do o`` as one row a head, of ``do``
+    and ``o`` ``[B, T, h, D]`` with ``T`` a multiple of 8.  The positions are
+    summed in their groups of eight: ``[B, T / 8, 8, h, D]`` is how the tiles
+    of a float32 ``[B, T, h D]`` lie in memory (eight positions by 128 lanes,
+    a head after a head), so one fusion reads ``do`` and ``o`` where the
+    output projection and the kernel left them.  Summed as ``[B, T, h, D]``
+    XLA:TPU first copies both, in float32, to tiles of heads by lanes; and
+    without the barrier it moves the peers' unfolding of ``o`` under the
+    widening, widens ``o`` alone in a pass of its own, in the forward pass,
+    and keeps that for the backward pass (0.54 GB of the T 512 step; PERF.md
+    section 6, PR 54).  What is turned is the result, a 128th of ``o``."""
+    B, T, h, D = do.shape
+    do, o = lax.optimization_barrier(
+        tuple(z.reshape(B, T, h * D) for z in (do, o))
+    )
+    lying = lambda z: z.reshape(B, T // 8, 8, h, D).astype(F32)
+    di = (lying(do) * lying(o)).sum(-1)
+    return di.transpose(0, 3, 1, 2).reshape(B, h, 1, T)
 
 
 @functools.lru_cache(maxsize=None)
@@ -598,8 +653,20 @@ def _differentiable(
     def bwd(residuals, do):
         inputs, o, lse = residuals
         with jax.named_scope(scope) if scope else contextlib.nullcontext():
-            di = (do.astype(F32) * o.astype(F32)).sum(-1)  # [B, h, T]
-            grads = backward(*inputs, do, lse, di[:, :, None])
+            if len(inputs) == 5:  # the EVA core, heads first all round
+                di = (do.astype(F32) * o.astype(F32)).sum(-1)  # [B, h, T]
+                grads = backward(*inputs, do, lse, di[:, :, None])
+            else:
+                dq, dk, dv = backward(*inputs, do, lse, _row_sums(do, o))
+                # ``dq`` and ``dk`` go back through the rope, which widens
+                # them and wants them laid out its way.  Left to itself at
+                # several sequences a peer, XLA:TPU widens first, in a pass of
+                # its own under the peers' unfolding, and turns the float32
+                # copy; held here as they left the kernel they are turned in
+                # their own type and widened in the rope's fusion (a third
+                # of the bytes; PERF.md section 6, PR 54).  Not ``dv``: it
+                # goes to a matmul as it lies.
+                grads = (*lax.optimization_barrier((dq, dk)), dv)
             return tuple(g.astype(z.dtype) for g, z in zip(grads, inputs))
 
     core.defvjp(fwd, bwd)
@@ -663,8 +730,9 @@ def causal_kernels_take(
     q, k, rows = shaped(heads), shaped(kv_heads), jax.ShapeDtypeStruct(
         (1, heads, 1, T), F32
     )
+    v = jax.ShapeDtypeStruct((1, T, kv_heads * d), dtype)
     need = _vmem_need(
-        window, *_layout(window, q, k, k, backward=True), (q, k, k, q, rows, rows)
+        window, *_layout(window, q, k, v, backward=True), (q, k, v, q, rows, rows)
     )
     return vmem_limit(need) <= VMEM_CEILING
 
@@ -672,13 +740,30 @@ def causal_kernels_take(
 def causal_attention(
     q, k, v, sm_scale: float, interpret: bool = False, window=None
 ):
-    """``o [B, h, T, D]`` of causal softmax attention with scores ``sm_scale
-    q . k``, for ``q [B, h, T, D]`` and ``k``, ``v`` ``[B, kv, T, D]`` that
-    :func:`causal_kernels_take`; differentiable in all three (``dk``, ``dv``
-    summed over a group's query heads in float32 inside the kernel).  With a
-    ``window`` query ``t`` sees the keys ``t - window + 1 .. t`` alone (the
-    band of the module docstring).  ``interpret`` runs the kernels by the
-    Pallas interpreter, off the TPU."""
+    """``o [B, T, h, D]`` of causal softmax attention with scores ``sm_scale
+    q . k``, for ``q [B, h, T, D]``, ``k [B, kv, T, D]`` and ``v [B, T, kv,
+    D]`` that :func:`causal_kernels_take`; differentiable in all three, each
+    gradient in its argument's layout (``dk``, ``dv`` summed over a group's
+    query heads in float32 inside the kernel).  With a ``window`` query ``t``
+    sees the keys ``t - window + 1 .. t`` alone (the band of the module
+    docstring).  ``interpret`` runs the kernels by the Pallas interpreter,
+    off the TPU.
+
+    **Why two layouts.**  A window of a head is the same ``[window, D]`` tile
+    in VMEM out of ``[B, h, T, D]`` or out of ``[B, T, h D]`` (a block of
+    ``D`` lanes), so each operand is taken where its neighbour in a model
+    holds it, and no pass over memory is spent on turning it.  ``v`` comes
+    from its projection, ``o`` goes to the output projection, ``do`` comes
+    from that one's gradient and ``dv`` goes to ``v``'s: positions first.
+    ``q`` and ``k`` come from the rope and ``dq``, ``dk`` go back through it,
+    and the rope's product over ``D`` is compiled by XLA:TPU as a convolution
+    that writes (and reads) heads first at a sequence a peer, so there the
+    caller's turn of ``q`` and ``k`` is a layout XLA picks and no pass; at
+    eight sequences a peer it writes positions last and a pass is paid to
+    whatever layout the kernel asks, this or another.  Taken positions first
+    too, ``q`` and ``k`` cost a pass a tensor more at T 4,096 (PERF.md section
+    6, PR 54).  A caller with no rope (the Jamba layer) pays its turn of
+    ``q`` and ``k`` as before."""
     grid_window = causal_window(q.shape[2])
     return _differentiable(
         interpret, grid_window, grid_window, float(sm_scale), None, True,

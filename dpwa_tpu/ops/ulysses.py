@@ -144,13 +144,18 @@ def single_device_attention(
       call is causal, ``q k v`` share a head size that is a multiple of 128,
       ``T`` is a multiple of 128 and the backward call's blocks of a whole
       head fit the VMEM it may ask for (``ops/eva.causal_kernels_take``: at a
-      head of 128 up to T 8,192).  ``q k v`` are turned heads first once, the
-      keys stay grouped (no ``jnp.repeat``: query head ``h`` reads block ``h
-      // (h / kv)``), one forward and one backward kernel (``QK^T`` once),
+      head of 128 up to T 8,192).  ``q`` and ``k`` are turned heads first,
+      which is how XLA:TPU lays the rope's result out anyway; ``v`` goes in as
+      it came and ``o`` comes back so, positions before heads, read and
+      written as ``[B, T, h D]`` with a head a block of ``D`` lanes, so no
+      pass turns ``v``, ``o`` or their gradients (PERF.md section 6, PR 54).
+      The keys stay grouped (no ``jnp.repeat``: query head ``h`` reads block
+      ``h // (h / kv)``), one forward and one backward kernel (``QK^T`` once),
       the log-sum-exp and ``di`` one ``[1, T]`` row a head, ``dk`` / ``dv``
       summed over a group in float32 in VMEM (PERF.md section 6, PR 46).
     - **the library's** flash kernels for every other shape (a head of 64,
-      192 / 128, ``causal=False``, a longer ``T``), GQA expanded here first.
+      192 / 128, ``causal=False``, a longer ``T``), GQA expanded here first,
+      ``q k v`` turned heads first and ``o`` back: its kernels want that.
       They take one head size: a multiple of 128, or 64 as it is (a block's
       64 lanes are half a register row; on the v5e at 2 x 32 x 4,096 x 64
       they give what the same heads zero-padded to 128 give, bit for bit in
@@ -195,8 +200,8 @@ def single_device_attention(
         heads_first = lambda x: x.transpose(0, 2, 1, 3)
         # Without a window the call is made as it always was.
         how = {} if window is None else dict(window=window)
-        return heads_first(
-            eva.causal_attention(*map(heads_first, (q, k, v)), scale, **how)
+        return eva.causal_attention(
+            heads_first(q), heads_first(k), v, scale, **how
         )
     if window is not None:
         if impl == "flash":

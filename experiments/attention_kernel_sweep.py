@@ -11,8 +11,10 @@ the Pallas interpreter on the CPU, to find a wrong argument before a chip
 call: its times mean nothing).
 
 ``--shape S H KV T D``: ``q [S, H, T, D]``, ``k``, ``v`` ``[S, KV, T, D]``
-bfloat16, heads first as both families' kernels take them (default: the four
-cells' shapes, the peer axis folded into ``S``).  For each shape, one JSON
+bfloat16, heads first as the library's kernels take them; ours take ``v``
+(and give ``o``, ``dv``) with positions first since PR 54, and are handed
+``v`` and the loss's weights turned so, outside what is timed (default: the
+four cells' shapes, the peer axis folded into ``S``).  For each shape, one JSON
 line a candidate with ``forward_ms`` and ``forward_backward_ms`` (``--reps``
 calls timed together after a warm one, the least of three such sets) and, for
 ours, the largest difference from the library's ``o dq dk dv`` over the
@@ -128,23 +130,29 @@ def main(argv=None) -> int:
                 block_sizes=_flash_block_sizes(T, D),
             )
 
+        turned = lambda z: z.swapaxes(1, 2)
+
         def ours(q, k, v):
             return eva.causal_attention(q, k, v, scale, not on_chip)
 
-        def measure(name, fn, want=None, **said):
+        def measure(name, fn, want=None, positions_first=False, **said):
+            """``positions_first``: ``v`` and the weights (so ``o``, ``dv``)
+            lie ``[S, T, h, D]``, as ours take and give them."""
             both = value_and_grads(fn)
+            lying = turned if positions_first else (lambda z: z)
+            operands = (lying(weights), q, k, lying(v))
             try:
-                (_, o), grads = both(weights, q, k, v)
-                jax.block_until_ready(grads)
+                (_, o), (dq, dk, dv) = both(*operands)
+                jax.block_until_ready(dv)
             except Exception as e:  # Mosaic refuses the shape: say so, go on
                 say(shape=[S, H, KV, T, D], candidate=name, refused=str(e)[-300:], **said)
                 return None
-            got = (o, *grads)
+            got = (lying(o), dq, dk, lying(dv))
             forward = jax.jit(lambda *a: fn(*a))  # a candidate, a program
             line = dict(
                 shape=[S, H, KV, T, D], candidate=name, **said,
-                forward_ms=timed(lambda: forward(q, k, v)),
-                forward_backward_ms=timed(lambda: both(weights, q, k, v)),
+                forward_ms=timed(lambda: forward(*operands[1:])),
+                forward_backward_ms=timed(lambda: both(*operands)),
             )
             if want is not None:
                 line["off_library"] = dict(
@@ -157,11 +165,14 @@ def main(argv=None) -> int:
 
         want = measure("library", by_library)
         rule = (eva.causal_window(T), eva.sub_block(eva.causal_window(T)))
-        measure("ours", ours, want, window=rule[0], block=rule[1], rule=True)
+        measure(
+            "ours", ours, want, True, window=rule[0], block=rule[1], rule=True
+        )
         if args.ungrouped and H != KV:
+            every = lambda z: jnp.repeat(z, H // KV, axis=2)
             measure(
-                "ours_ungrouped", lambda q, k, v: ours(q, full(k), full(v)),
-                want, window=rule[0], block=rule[1],
+                "ours_ungrouped", lambda q, k, v: ours(q, full(k), every(v)),
+                want, True, window=rule[0], block=rule[1],
             )
         for window, block in variants:
             if (window, block) == rule or T % window or window % block:
@@ -171,7 +182,10 @@ def main(argv=None) -> int:
                     mock.patch.object(eva, "sub_block", lambda w: block), \
                     mock.patch.object(eva, "vmem_limit", limit):
                 eva._differentiable.cache_clear()
-                measure("ours", ours, want, window=window, block=block, rule=False)
+                measure(
+                    "ours", ours, want, True, window=window, block=block,
+                    rule=False,
+                )
             eva._differentiable.cache_clear()
     return 0 if all(oks) else 1
 

@@ -10,9 +10,10 @@ Needs a TPU (exits 4 without one; ``--rehearse-cpu`` runs a toy shape through
 the Pallas interpreter on the CPU, to find a wrong argument before a chip
 call: its times mean nothing).
 
-``--shape S H KV T D``: ``q [S, H, T, D]``, ``k``, ``v`` ``[S, KV, T, D]``
-bfloat16, heads first as the kernels take them (default: the Mellum cell's, 2
-x 32 / 4 x 4,096 x 128, the peer axis folded into ``S``; and T 8,192).  For
+``--shape S H KV T D``: ``q [S, H, T, D]``, ``k [S, KV, T, D]`` heads first
+and ``v [S, T, KV, D]`` positions first, bfloat16, as the kernels take them
+since PR 54 (default: the Mellum cell's, 2 x 32 / 4 x 4,096 x 128, the peer
+axis folded into ``S``; and T 8,192).  For
 each shape, one JSON line a candidate with ``forward_ms`` and
 ``forward_backward_ms`` (``--reps`` calls timed together after a warm one, the
 least of three such sets):
@@ -166,11 +167,9 @@ def main(argv=None) -> int:
     for S, H, KV, T, D in shapes:
         keys = jax.random.split(jax.random.key(args.seed), 4)
         q = jax.random.normal(keys[0], (S, H, T, D), jnp.bfloat16)
-        k, v = (
-            jax.random.normal(key, (S, KV, T, D), jnp.bfloat16)
-            for key in keys[1:3]
-        )
-        weights = jax.random.normal(keys[3], q.shape, jnp.float32)
+        k = jax.random.normal(keys[1], (S, KV, T, D), jnp.bfloat16)
+        v = jax.random.normal(keys[2], (S, T, KV, D), jnp.bfloat16)
+        weights = jax.random.normal(keys[3], (S, T, H, D), jnp.float32)  # o's
         scale = D ** -0.5
         if not eva.causal_kernels_take(T, D, H, KV, q.dtype, window):
             say(shape=[S, H, KV, T, D], window=window, refused="not a shape the kernels take")
@@ -181,24 +180,30 @@ def main(argv=None) -> int:
             """``(o, dq, dk, dv)`` of one query head of one sequence on its
             head of keys, by the masked softmax in float32."""
             def plain(q, k, v):
-                return turned(single_device_attention(
-                    *(turned(wide(z)) for z in (q, k, v)), causal=True,
+                return single_device_attention(
+                    turned(wide(q)), turned(wide(k)), wide(v), causal=True,
                     window=window, impl="dense", sm_scale=scale,
-                ))
+                )
 
             (_, o), grads = value_and_grads(plain)(*head)
             return (o, *grads)
 
         def twin():
+            # A query head of a sequence at a time, each operand as it lies.
             heads = lambda z: jnp.repeat(z, H // z.shape[1], axis=1).reshape(
                 S * H, 1, 1, T, D
             )
-            o, dq, dk, dv = (
-                z.reshape(S, H, T, D)
-                for z in jax.lax.map(twin_head, tuple(map(heads, (weights, q, k, v))))
+            o, dq, dk, dv = jax.lax.map(
+                twin_head,
+                (turned(heads(turned(weights))), heads(q), heads(k),
+                 turned(heads(turned(v)))),
             )
+            first = lambda z: z.reshape(S, H, T, D)
             summed = lambda z: z.reshape(S, KV, H // KV, T, D).sum(2)
-            return o, dq, summed(dk), summed(dv)
+            return (
+                turned(first(turned(o))), first(dq), summed(first(dk)),
+                turned(summed(first(turned(dv)))),
+            )
 
         def measure(name, band, want=None):
             fn = lambda q, k, v: eva.causal_attention(

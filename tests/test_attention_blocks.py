@@ -87,11 +87,11 @@ def _families(monkeypatch):
     seen = []
 
     def ours(q, k, v, sm_scale):
-        seen.append(("ours", q.shape, k.shape, sm_scale))
-        return jnp.zeros_like(q)
+        seen.append(("ours", q.shape, k.shape, v.shape, sm_scale))
+        return jnp.zeros_like(q).swapaxes(1, 2)  # o comes positions first
 
     def theirs(q, k, v, **kwargs):
-        seen.append(("library", q.shape, k.shape, kwargs))
+        seen.append(("library", q.shape, k.shape, v.shape, kwargs))
         return jnp.zeros_like(q)
 
     monkeypatch.setattr(eva, "causal_attention", ours)
@@ -117,7 +117,8 @@ def test_which_family_a_shape_takes(
 ):
     """Under `auto` on a TPU.  The library is handed `_flash_block_sizes` of
     the call's own `T` and padded head, keys repeated to the query heads;
-    ours the grouped keys as they are, heads first."""
+    ours the grouped keys as they are, `q` and `k` heads first and `v` as it
+    came (PR 54: each where its neighbour in the model holds it)."""
     seen = _families(monkeypatch)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     shaped = lambda h, size: jax.ShapeDtypeStruct((1, T, h, size), jnp.bfloat16)
@@ -129,14 +130,15 @@ def test_which_family_a_shape_takes(
     if family == "einsum":
         assert not seen
         return
-    (name, q_shape, k_shape, rest), = seen
+    (name, q_shape, k_shape, v_shape, rest), = seen
     assert name == family
     if family == "ours":
         assert q_shape == (1, heads, T, d) and k_shape == (1, kv, T, d)
+        assert v_shape == (1, T, kv, d)
         assert rest == float(1.0 / d ** 0.5)
     else:
         padded = d if d == dv == 64 else -(-max(d, dv) // 128) * 128
-        assert q_shape == k_shape == (1, heads, T, padded)
+        assert q_shape == k_shape == v_shape == (1, heads, T, padded)
         assert rest["causal"] is causal
         assert rest["block_sizes"] == _flash_block_sizes(T, padded)
 

@@ -398,10 +398,12 @@ def test_a_head_that_is_no_multiple_of_128_equals_the_einsum(head):
 # kernels, as before.  128 / 128 and 256 / 256 were pinned again by PR 46
 # (the parent read 6539f3edda51cd71 and a87653e5f5829c36): causal attention
 # at a head that is a multiple of 128 is ``ops/eva.causal_attention``'s two
-# kernels now, by design.
+# kernels now, by design; and again by PR 54 (fd428cf9389094e6 and
+# 3c56bfbe2e9a18b1 until then): those kernels read ``v`` and write ``o`` as
+# ``[B, T, h D]`` and ``single_device_attention`` turns ``q`` and ``k`` alone.
 ATTENTION_AT_PARENT = {
-    (128, 128): "fd428cf9389094e6", (192, 128): "00eb3ba5f83afbf5",
-    (256, 256): "3c56bfbe2e9a18b1", (64, 64): "b1420bb77a86c94a",
+    (128, 128): "cd346854be42fc93", (192, 128): "00eb3ba5f83afbf5",
+    (256, 256): "7cfe7f1edd4c04db", (64, 64): "b1420bb77a86c94a",
 }
 
 

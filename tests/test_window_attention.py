@@ -26,10 +26,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:  # the benchmark's readers, for the kernels' names
     sys.path.insert(0, ROOT)
 
+from tests import test_eva_causal  # noqa: E402  (the heads-first reference)
+
 D = 128
 # In float32 the kernels differ from the einsum by the order of their sums.
 TOLERANCE = 2e-5
-turned = lambda z: jnp.swapaxes(z, -3, -2)
+turned = test_eva_causal.turned
 
 
 @pytest.fixture
@@ -51,15 +53,17 @@ def arguments(seed, steps, heads=(8, 1), dtype=jnp.float32):
     h, kv = heads
     keys = jax.random.split(jax.random.key(seed), 4)
     q = jax.random.normal(keys[0], (1, h, steps, D), dtype)
-    k, v = (jax.random.normal(key, (1, kv, steps, D), dtype) for key in keys[1:3])
-    return q, k, v, jax.random.normal(keys[3], q.shape, jnp.float32)
+    k = jax.random.normal(keys[1], (1, kv, steps, D), dtype)
+    v = jax.random.normal(keys[2], (1, steps, kv, D), dtype)
+    return q, k, v, jax.random.normal(keys[3], turned(q).shape, jnp.float32)
 
 
 def einsum(q, k, v, window):
-    """The masked-softmax einsum, heads first like the kernels."""
-    return turned(single_device_attention(
-        *map(turned, (q, k, v)), causal=True, window=window, impl="dense"
-    ))
+    """The masked-softmax einsum, on operands that lie as the kernels': ``q``
+    and ``k`` heads first, ``v`` and the result positions first."""
+    return single_device_attention(
+        turned(q), turned(k), v, causal=True, window=window, impl="dense"
+    )
 
 
 def kernels(q, k, v, window):
@@ -138,8 +142,8 @@ def test_the_two_edges_of_the_band(form, grid):
     t = 600  # in the third grid window, off every block's first row
     base = fn(q, k, v, window)
     for behind, seen in ((window - 1, True), (window, False)):
-        moved = fn(q, k, v.at[:, :, t - behind].add(1.0), window)
-        changed = jnp.abs(moved - base).max(axis=(0, 1, 3)) > 0  # [T]
+        moved = fn(q, k, v.at[:, t - behind].add(1.0), window)
+        changed = jnp.abs(moved - base).max(axis=(0, 2, 3)) > 0  # [T]
         assert bool(changed[t]) == seen, behind
         # Exactly the queries from the key's own to the last that sees it.
         first, last = t - behind, t - behind + window - 1
@@ -147,7 +151,7 @@ def test_the_two_edges_of_the_band(form, grid):
         np.testing.assert_array_equal(changed, want)
     # The same through the keys: the gradient of query t's output reaches
     # key t - window + 1 and not key t - window.
-    dk = jax.grad(lambda k: fn(q, k, v, window)[:, :, t].sum())(k)
+    dk = jax.grad(lambda k: fn(q, k, v, window)[:, t].sum())(k)  # [B, kv, T, D]
     reached = jnp.abs(dk).max(axis=(0, 1, 3)) > 0
     np.testing.assert_array_equal(
         reached, (jnp.arange(steps) > t - window) & (jnp.arange(steps) <= t)
@@ -158,7 +162,8 @@ def test_under_vmap_over_two_peers_it_is_a_loop_over_them(grid):
     grid(256, 128)
     keys = jax.random.split(jax.random.key(3), 3)
     q = jax.random.normal(keys[0], (2, 1, 4, 512, D))
-    k, v = (jax.random.normal(key, (2, 1, 2, 512, D)) for key in keys[1:])
+    k = jax.random.normal(keys[1], (2, 1, 2, 512, D))
+    v = jax.random.normal(keys[2], (2, 1, 512, 2, D))
     one = jax.grad(
         lambda q, k, v: kernels(q, k, v, 256).sum(), argnums=(0, 1, 2)
     )
@@ -169,6 +174,41 @@ def test_under_vmap_over_two_peers_it_is_a_loop_over_them(grid):
             np.testing.assert_allclose(a[peer], b, atol=1e-5)
 
 
+# query heads / heads of keys and values (groups of 1, 4 and 8) x positions
+# at a grid window of 256 in blocks of 128 (one window and two), a band of
+# 256 keys: a query block's own block, the one behind it and an edge in the
+# third, which in the second window's first block lies in the window before.
+BAND_CASES = {
+    f"{group}-{name}": (group, steps)
+    for group in test_eva_causal.GROUPS
+    for name, steps in (("one_window", 256), ("two_windows", 512))
+}
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_with_a_band_a_head_as_a_block_of_lanes_gives_the_heads_first_bits(
+    case, grid
+):
+    """The output and the three gradients of the windowed call, ``v o do dv``
+    read and written as blocks of lanes, are those of the same kernels over
+    heads-first operands all round (the layout until PR 54:
+    ``tests/test_eva_causal.heads_first_calls``), bit for bit, in bfloat16 at
+    two sequences."""
+    grid(256, 128)
+    args = test_eva_causal.groups_arguments(9, *BAND_CASES[case])
+    test_eva_causal.assert_equal_to_the_bit(
+        *test_eva_causal.lane_blocks_and_heads_first(*args, band=256)
+    )
+
+
+def test_with_a_band_the_heads_first_bits_under_vmap_over_two_peers(grid):
+    grid(256, 128)
+    args = test_eva_causal.groups_arguments(10, "group_4", 512, lead=(2, 1))
+    test_eva_causal.assert_equal_to_the_bit(
+        *test_eva_causal.lane_blocks_and_heads_first(*args, band=128)
+    )
+
+
 def test_without_a_window_the_call_is_the_causal_call_bit_for_bit(grid):
     grid(256, 128)
     q, k, v, _ = arguments(4, 512, (4, 2), jnp.bfloat16)
@@ -176,7 +216,7 @@ def test_without_a_window_the_call_is_the_causal_call_bit_for_bit(grid):
     same = eva.causal_attention(q, k, v, D ** -0.5, interpret=True, window=None)
     np.testing.assert_array_equal(plain, same)
     at = lambda *a, **kw: single_device_attention(
-        *map(turned, (q, k, v)), *a, causal=True, impl="dense", **kw
+        turned(q), turned(k), v, *a, causal=True, impl="dense", **kw
     )
     np.testing.assert_array_equal(at(), at(window=None))
     # A window over the whole sequence is the causal call's values, by
@@ -190,27 +230,30 @@ def test_without_a_window_the_call_is_the_causal_call_bit_for_bit(grid):
 # The first 16 hex digits of the SHA-256 of the jaxpr (kernel bodies, grids,
 # blocks and names written out; the addresses of functions taken out) of the
 # calls without a window, under ``vmap`` over two peers with their gradients,
-# taken on the parent commit (2966108) by these lines: with ``window=None``
-# the program is the parent's, to the text.
+# by these lines.  The EVA core's was taken on PR 49's parent (2966108) and
+# has held since: heads first all round, to the text.  The causal calls' were
+# re-taken on PR 54, whose change they are (``v o do dv`` as ``[S, T, h D]``,
+# a head a block of lanes, the row sums in groups of eight positions, ``dq``
+# and ``dk`` held as they leave the kernel; a9824a5f3db7c3c4 /
+# 1e70bbb553d5883a until then): with ``window=None`` nothing of the band is
+# traced.
 KERNELS_AT_PARENT = {
-    "causal_t512": "a9824a5f3db7c3c4",
-    "causal_t4096": "1e70bbb553d5883a",
+    "causal_t512": "33984e906ea35e55",
+    "causal_t4096": "5b2a4a392bd4411d",
     "eva_core": "0fa4745f970f5dc4",
 }
 
 
 def kernel_digest(name):
-    shaped = lambda h, T, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
-        (2, 1, h, T, D), dtype
-    )
+    shaped = lambda *shape: jax.ShapeDtypeStruct((2, 1, *shape, D), jnp.bfloat16)
     total = lambda out: out.astype(jnp.float32).sum()
-    if name == "eva_core":
+    if name == "eva_core":  # heads first
         fn = lambda *a: total(eva.kernel_eva_attention(*a, 256, 2))
         args = 3 * [shaped(4, 512)] + 2 * [shaped(4, 256)]
-    else:
+    else:  # q and k heads first, v as its projection writes it
         T = int(name.rsplit("_t", 1)[1])
         fn = lambda q, k, v: total(eva.causal_attention(q, k, v, 0.25))
-        args = [shaped(8, T), shaped(2, T), shaped(2, T)]
+        args = [shaped(8, T), shaped(2, T), shaped(T, 2)]
     grads = jax.vmap(jax.grad(fn, argnums=tuple(range(len(args)))))
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):
         text = str(jax.make_jaxpr(grads)(*args))
@@ -251,7 +294,7 @@ def test_a_call_of_an_accepted_cell_takes_the_branch_it_took(
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
         eva, "causal_attention",
-        lambda q, k, v, scale, *a, **kw: seen.append((a, kw)) or q,
+        lambda q, k, v, scale, *a, **kw: seen.append((a, kw)) or turned(q),
     )
     shaped = lambda heads: jax.ShapeDtypeStruct((S, T, heads, d), jnp.bfloat16)
     attend = functools.partial(single_device_attention, causal=True)
@@ -275,7 +318,7 @@ def test_a_windowed_call_takes_our_kernels_or_the_masked_einsum(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(
         eva, "causal_attention",
-        lambda q, k, v, scale, *a, **kw: seen.append(kw) or q,
+        lambda q, k, v, scale, *a, **kw: seen.append(kw) or turned(q),
     )
     shaped = lambda T, heads, d=128: jax.ShapeDtypeStruct(
         (1, T, heads, d), jnp.bfloat16
@@ -334,6 +377,9 @@ def test_the_windowed_calls_carry_names_under_the_readers_prefixes():
             lambda q, k, v: eva.causal_attention(
                 q, k, v, 0.1, window=128
             ).astype(jnp.float32).sum()
-        ))(*3 * [jax.ShapeDtypeStruct((1, 2, 256, D), jnp.bfloat16)]))
+        ))(
+            *2 * [jax.ShapeDtypeStruct((1, 2, 256, D), jnp.bfloat16)],
+            jax.ShapeDtypeStruct((1, 256, 2, D), jnp.bfloat16),
+        ))
     for name in eva.BAND_KERNEL_NAMES:
         assert name in text
