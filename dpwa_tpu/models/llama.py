@@ -798,36 +798,49 @@ class MambaMixer(nn.Module):
         cfg = self.cfg
         E = cfg.mamba_expand * cfg.d_model
         N, R, K = cfg.mamba_d_state, cfg.mamba_dt_rank, cfg.mamba_d_conv
+        parts = scopes.SSM_PARTS
         with jax.named_scope(scopes.SSM):
-            x, z = jnp.split(_dense(cfg, 2 * E, "in_proj")(u), 2, -1)
-            x = nn.silu(ssm.causal_conv1d(
-                x,
-                self.param("conv_kernel", _conv_init(K), (K, E),
-                           cfg.param_dtype),
-                self.param("conv_bias", _conv_init(K), (E,), cfg.param_dtype),
-            ))
-            dt, Bm, Cm = jnp.split(
-                _dense(cfg, R + 2 * N, "x_proj")(x), [R, R + N], -1
-            )
-            dt, Bm, Cm = (
-                _norm(cfg, name)(v) for name, v in
-                (("dt_norm", dt), ("b_norm", Bm), ("c_norm", Cm))
-            )
-            delta = jax.nn.softplus(
-                _dense(cfg, E, "dt_proj")(dt).astype(jnp.float32)
-                + self.param("dt_bias", _dt_bias_init, (E,))
-            )
-            A_log = self.param(
-                "A_log",
-                lambda key, shape: jnp.broadcast_to(
-                    jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), shape
-                ),
-                (E, N),
-            )
+            with jax.named_scope(parts.proj):
+                xz = _dense(cfg, 2 * E, "in_proj")(u)
+            with jax.named_scope(parts.conv):
+                x, z = jnp.split(xz, 2, -1)
+                x = nn.silu(ssm.causal_conv1d(
+                    x,
+                    self.param("conv_kernel", _conv_init(K), (K, E),
+                               cfg.param_dtype),
+                    self.param("conv_bias", _conv_init(K), (E,),
+                               cfg.param_dtype),
+                ))
+            with jax.named_scope(parts.proj):
+                dbc = _dense(cfg, R + 2 * N, "x_proj")(x)
+            with jax.named_scope(parts.dt):
+                dt, Bm, Cm = jnp.split(dbc, [R, R + N], -1)
+                dt, Bm, Cm = (
+                    _norm(cfg, name)(v) for name, v in
+                    (("dt_norm", dt), ("b_norm", Bm), ("c_norm", Cm))
+                )
+            with jax.named_scope(parts.proj):
+                dt = _dense(cfg, E, "dt_proj")(dt)
+            with jax.named_scope(parts.dt):
+                delta = jax.nn.softplus(
+                    dt.astype(jnp.float32)
+                    + self.param("dt_bias", _dt_bias_init, (E,))
+                )
+                A_log = self.param(
+                    "A_log",
+                    lambda key, shape: jnp.broadcast_to(
+                        jnp.log(jnp.arange(1, N + 1, dtype=jnp.float32)), shape
+                    ),
+                    (E, N),
+                )
+                A = -jnp.exp(A_log)
             D = self.param("D", nn.initializers.ones, (E,))
             with jax.named_scope(scopes.SSM_SCAN):
-                y = ssm.selective_scan(x, delta, -jnp.exp(A_log), Bm, Cm, D)
-            return _dense(cfg, cfg.d_model, "out_proj")(y * nn.silu(z))
+                y = ssm.selective_scan(x, delta, A, Bm, Cm, D)
+            with jax.named_scope(parts.gate):
+                gated = y * nn.silu(z)
+            with jax.named_scope(parts.proj):
+                return _dense(cfg, cfg.d_model, "out_proj")(gated)
 
 
 class ShortConv(nn.Module):

@@ -1,12 +1,13 @@
 """Names for the parts of a train step, as ``jax.named_scope`` metadata.
 
 Three scopes give the step's four phases: ``dpwa.forward`` (two of them, as
-below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Sixteen more lie inside
+below), ``dpwa.optimizer`` and ``dpwa.exchange``.  Twenty more lie inside
 the forward scope and name the parts of a decoder (``models/llama.py``):
 attention plain (a sliding-window layer under one more name inside it), latent
 and EVA (with its summaries and its core), the gated
 short convolution and its gate, the dense feed-forward, the expert layer's
-three parts, the state-space mixer and its scan, the head, the loss.  The outer
+three parts, the state-space mixer with its scan and the four parts around
+it (projections, convolution, step size, gate), the head, the loss.  The outer
 norms, the embedding and the residual adds carry none: they are what is left
 under ``dpwa.forward``.  A scope's name becomes a component of the
 ``op_name`` of every HLO instruction traced under it, and JAX wraps the name
@@ -99,6 +100,24 @@ MLP = "dpwa.mlp"
 # backward kernels).
 SSM = "dpwa.ssm"
 SSM_SCAN = "dpwa.ssm.scan"
+# What a mixer does around its scan, each inside ``dpwa.ssm`` and beside
+# ``dpwa.ssm.scan``: ``proj`` the four projections with their adapters
+# (``in_proj``, ``x_proj``, ``dt_proj``, ``out_proj``; the module's own name is
+# the next component of the ``op_name``); ``conv`` the split of ``in_proj``'s
+# product, the causal depthwise convolution and silu; ``dt`` the three inner
+# norms, the cast to float32, ``dt_bias``, softplus and ``-exp(A_log)``;
+# ``gate`` ``y * silu(z)``.  None holds ``dpwa.ssm.scan`` as a substring
+# (``benchmark/ssm_scopes.py`` matches by substring).  One value of four
+# fields, for the reason ``ATTN_EVA`` is one of three (PERF.md section 7 asks
+# for the rows).
+class _SsmPartNames(NamedTuple):
+    proj: str = "dpwa.ssm.proj"
+    conv: str = "dpwa.ssm.conv"
+    dt: str = "dpwa.ssm.dt"
+    gate: str = "dpwa.ssm.gate"
+
+
+SSM_PARTS = _SsmPartNames()
 # The projection to the vocabulary (``models/llama.Llama``): ``lm_head``, or
 # ``x E^T`` where the embedding is tied.  ``final_norm`` stays outside.
 HEAD = "dpwa.head"

@@ -15,7 +15,6 @@ from dpwa_tpu.parallel.distributed import (
     hierarchical_config_for_hosts,
 )
 from dpwa_tpu.parallel.mesh import make_mesh
-from dpwa_tpu.utils.profiling import measure_exchange_bandwidth
 
 
 def test_hierarchical_config_for_hosts():
@@ -99,15 +98,3 @@ def test_multiprocess_dcn_smoke():
             pytest.skip(f"jax.distributed unavailable: {out.splitlines()[-1]}")
         assert p.returncode == 0, out
         assert "DCN_OK" in out, out
-
-
-def test_measure_exchange_bandwidth():
-    from dpwa_tpu.parallel.ici import IciTransport
-
-    cfg = make_local_config(8)
-    t = IciTransport(cfg, mesh=make_mesh(cfg))
-    params = {"w": jnp.ones((8, 1024))}
-    meta = PeerMeta(jnp.ones(8), jnp.ones(8))
-    out = measure_exchange_bandwidth(t, params, meta, iters=3)
-    assert out["payload_bytes"] == 1024 * 4
-    assert out["gbps_per_chip"] > 0

@@ -202,19 +202,9 @@ def lowered_for_the_chip(fn, *args) -> str:
         ).as_text(debug_info=True)
 
 
-@pytest.mark.parametrize("policy, recomputed", [
-    (True, []), (False, [0, 1, 3]),
-])
-def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
-    policy, recomputed
-):
-    """Two stacked peers' gradients of a 4-layer hybrid (Mamba, Mamba,
-    attention, Mamba) under ``remat``: the forward scan kernel once a Mamba
-    layer in the forward pass and in no layer's recomputation (in every
-    Mamba layer's where the blocks' ``nn.remat`` has no policy, as before),
-    the backward kernel once a Mamba layer."""
-    import re
-
+def two_peers_gradient():
+    """Two stacked peers' gradient of the loss of a 4-layer hybrid (Mamba,
+    Mamba, attention, Mamba) under ``remat``, and shapes to lower it at."""
     config, cell = builder.rehearse(PUBLISHED, dict(
         seq_len=128, per_peer_batch=1, peers=2, exchange_filter="lora",
     ))
@@ -228,10 +218,24 @@ def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
         jax.vmap(built.init_fn), jax.random.split(jax.random.key(0), 2)
     )
     tokens = jnp.zeros((2, 1, 128), jnp.int32)
+    return jax.vmap(jax.grad(built.loss_fn)), shapes, (tokens, tokens)
+
+
+@pytest.mark.parametrize("policy, recomputed", [
+    (True, []), (False, [0, 1, 3]),
+])
+def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
+    policy, recomputed
+):
+    """Two stacked peers' gradients of a 4-layer hybrid (Mamba, Mamba,
+    attention, Mamba) under ``remat``: the forward scan kernel once a Mamba
+    layer in the forward pass and in no layer's recomputation (in every
+    Mamba layer's where the blocks' ``nn.remat`` has no policy, as before),
+    the backward kernel once a Mamba layer."""
+    import re
+
     with scan_and_checkpoint("chip", policy):
-        text = lowered_for_the_chip(
-            jax.vmap(jax.grad(built.loss_fn)), shapes, (tokens, tokens)
-        )
+        text = lowered_for_the_chip(*two_peers_gradient())
     calls = re.findall(
         r'loc\("([^"]*)/layer_(\d)/[^"]*dpwa_selective_scan_(fwd|bwd)/', text
     )
@@ -249,6 +253,103 @@ def test_the_recomputation_runs_the_forward_kernel_only_where_nothing_is_kept(
     # What is kept is saved as it is: no pass over it to round it to its
     # own type (``ops/ssm._named_bits``).
     assert "reduce_precision" not in text
+
+
+def without_names(names=None):
+    """``jax.named_scope`` as a null context for ``names`` (None: for every
+    name, flax's own among them), so that what is traced carries none."""
+    named_scope = jax.named_scope
+    return mock.patch.object(
+        jax, "named_scope",
+        lambda name: contextlib.nullcontext()
+        if names is None or name in names else named_scope(name),
+    )
+
+
+def test_the_mixers_parts_carry_their_names_in_the_lowered_loss():
+    """Forward, recomputed and backward alike: each projection's product
+    under ``dpwa.ssm.proj`` with the module's own name next, the taps and
+    silu under ``dpwa.ssm.conv``, the norms and softplus under
+    ``dpwa.ssm.dt``, the gate's product under ``dpwa.ssm.gate``; every one
+    of them inside ``dpwa.ssm``, none around or inside ``dpwa.ssm.scan``,
+    whose own instructions are the ones it had without the four."""
+    import re
+
+    from dpwa_tpu.utils import scopes
+
+    parts = scopes.SSM_PARTS
+    assert not any(scopes.SSM_SCAN in name for name in parts)
+    locations = lambda text: set(re.findall(r'loc\("([^"]*)"', text))
+    names = locations(lowered_for_the_chip(*two_peers_gradient()))
+    tails = lambda part: {
+        n.split(f"/{scopes.SSM}/{part}/", 1)[1] for n in names
+        if f"/{scopes.SSM}/{part}/" in n
+    }
+    for module in ("in_proj", "x_proj", "dt_proj", "out_proj"):
+        products = {
+            n for n in names
+            if "/mamba/" in n and n.endswith(module + "/dot_general")
+        }
+        passes = {
+            ("transpose(" in n, "rematted_computation" in n) for n in products
+        }
+        assert passes == {(False, False), (True, False), (True, True)}, module
+        assert all(
+            f"/{scopes.SSM}/{parts.proj}/{module}/" in n for n in products
+        ), module
+    assert tails(parts.proj) and all(
+        t.split("/")[0] in ("in_proj", "x_proj", "dt_proj", "out_proj")
+        for t in tails(parts.proj)
+    )
+    conv = tails(parts.conv)
+    assert {"split", "slice", "mul", "jit(silu)"} <= conv
+    assert any("pad" in t for t in conv)
+    step_size = tails(parts.dt)
+    assert {"jit(softplus)", "add", "exp", "neg"} <= step_size
+    assert {t.split("/")[0] for t in step_size if "_norm" in t} == {
+        "dt_norm", "b_norm", "c_norm",
+    }
+    assert {"mul", "jit(silu)"} <= tails(parts.gate)
+    # Nothing of a part stands outside the mixer's own name, and the three
+    # inner norms, softplus and silu stand nowhere else in a mixer.
+    for n in names:
+        for part in parts:
+            assert (part in n) == (f"/{scopes.SSM}/{part}/" in n), n
+        if "/mamba/" in n and any(
+            key in n for key in ("_norm/", "softplus", "silu")
+        ):
+            assert any(f"/{part}/" in n for part in parts), n
+    scan = {n for n in names if scopes.SSM_SCAN in n}
+    assert scan and not any(part in n for n in scan for part in parts)
+    with without_names(tuple(parts)):
+        before = locations(lowered_for_the_chip(*two_peers_gradient()))
+    assert not any(part in n for n in before for part in parts)
+    assert {n for n in before if scopes.SSM_SCAN in n} == scan
+
+
+def test_the_names_move_no_bit_of_a_mixers_output_or_gradients():
+    cfg = model_of().cfg
+    mixer = MambaMixer(cfg)
+    u = jax.random.normal(jax.random.key(3), (2, T, cfg.d_model))
+    params = perturbed(mixer.init(jax.random.key(4), u))
+
+    def computed():
+        # New functions a call: nothing traced under the other names is
+        # found again.
+        forward = lambda p, u: mixer.apply(p, u)
+        loss = lambda p, u: jnp.sum(jnp.sin(forward(p, u)))
+        text = jax.jit(forward).lower(params, u).as_text(debug_info=True)
+        return "dpwa.ssm" in text, jax.jit(forward)(params, u), jax.jit(
+            jax.grad(loss, argnums=(0, 1))
+        )(params, u)
+
+    with_names, *named = computed()
+    with without_names():
+        with_none, *bare = computed()
+    assert with_names and not with_none
+    assert float(jnp.abs(named[0]).max()) > 0
+    for got, want in zip(jax.tree.leaves(named), jax.tree.leaves(bare)):
+        np.testing.assert_array_equal(got, want)
 
 
 def pallas_calls(jaxpr):
