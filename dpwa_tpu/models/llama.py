@@ -786,7 +786,10 @@ class MambaMixer(nn.Module):
     Adapters on the four projections (five matrices: ``in_proj`` is the x
     and the z one side by side); ``conv_kernel``, ``conv_bias``, ``dt_bias``,
     ``A_log``, ``D`` and the norms are base leaves.  ``delta`` and everything
-    inside the scan are float32 whatever ``dtype`` says."""
+    inside the scan are float32 whatever ``dtype`` says.  The convolution
+    with its silu is one call, ``ops/ssm.conv_silu``: on a TPU a kernel a
+    pass in the activations' type with its own gradient rule, elsewhere
+    ``silu(causal_conv1d(...))``; the sum of the taps is float32 either way."""
 
     cfg: LlamaConfig
 
@@ -803,14 +806,17 @@ class MambaMixer(nn.Module):
             with jax.named_scope(parts.proj):
                 xz = _dense(cfg, 2 * E, "in_proj")(u)
             with jax.named_scope(parts.conv):
-                x, z = jnp.split(xz, 2, -1)
-                x = nn.silu(ssm.causal_conv1d(
-                    x,
+                # ``x`` is read where ``in_proj`` wrote it, the leading half
+                # of ``xz``: a kernel handed the half itself would be handed
+                # a copy.
+                x = ssm.conv_silu(
+                    xz,
                     self.param("conv_kernel", _conv_init(K), (K, E),
                                cfg.param_dtype),
                     self.param("conv_bias", _conv_init(K), (E,),
                                cfg.param_dtype),
-                ))
+                )
+                z = xz[..., E:]
             with jax.named_scope(parts.proj):
                 dbc = _dense(cfg, R + 2 * N, "x_proj")(x)
             with jax.named_scope(parts.dt):

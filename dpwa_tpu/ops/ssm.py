@@ -1,5 +1,8 @@
-"""The two sequence operations of a Mamba-1 mixer: a causal depthwise
-convolution and the selective scan, with the scan's gradient written by hand.
+"""The sequence operations of a Mamba-1 mixer, three entry points: the causal
+depthwise convolution (:func:`causal_conv1d`, plain ``jax.numpy``, also the
+gated short convolution's), the same convolution with silu behind it
+(:func:`conv_silu`) and the selective scan (:func:`selective_scan`), the last
+two with their gradients written by hand.
 
 The scan, per sequence, with ``x``, ``delta`` ``[T, E]``, ``A`` ``[E, N]``,
 ``Bm``, ``Cm`` ``[T, N]``, ``D`` ``[E]`` and a state ``s`` ``[E, N]``:
@@ -42,6 +45,25 @@ under autodiff.  The backend decides, as in ``single_device_attention``.
 folds the peer axis into the kernels' sequence axis, ``A`` and ``D`` indexed
 by the peer a sequence belongs to; unbatched (a loop over peers) it is the
 same kernels on one peer.  Both do the same arithmetic in the same order.
+
+**The convolution with silu**, ``y = silu(b + sum_j w[j] x_{t-(K-1)+j})``
+with ``x_{<0} = 0``, is a pass over ``x`` each way and nothing else, so on a
+TPU it is a kernel each way (the bottom of this file) built as the scan's
+are: a grid of (sequence, channel block, chunk) with the chunks in order,
+the peers folded by the same rule with ``w`` and ``b`` a peer's own.  Around
+the plain form XLA writes a float32 copy of ``x`` forward and, from the
+transposes of its pad and slices, four float32 arrays of ``x``'s size
+backward (PERF.md section 6, PR 56); the kernels read and write the
+activation type and widen in VMEM, and they read ``x`` where it lies: handed
+``in_proj``'s whole product they pick the blocks of its leading half.  The forward kernel keeps the last rows
+of a chunk for the next one (zeros at a sequence's start); the backward
+kernel walks the chunks last to first, makes the pre-activation again from
+``x``, and writes one array, ``dx_t = sum_j w[j] (g silu')_{t+(K-1)-j}``,
+keeping ``g silu'`` of a chunk's first rows for the chunk before it.  ``dw``
+and ``db`` are the plain form's own, beside the kernel: a step that trains
+neither (the LoRA cells) has XLA drop them.  The residuals are the three
+arguments.  Off the TPU, and at shapes the blocks do not divide, it is
+``silu(causal_conv1d(...))`` under autodiff.
 """
 
 from __future__ import annotations
@@ -349,17 +371,19 @@ def vmem_limit(need: int) -> int:
 
 
 def _kernel_call(
-    kernel, name, interpret, grid, chunk, scratch, operands,
-    *, in_specs, out_specs, out_shape,
+    kernel, name, interpret, grid, scratch, operands,
+    *, in_specs, out_specs, out_shape, aliases=None,
 ):
-    """One ``pallas_call`` of ``kernel`` over ``grid`` with float32
-    ``scratch`` (shapes) and the VMEM limit its own shapes come to."""
+    """One ``pallas_call`` of ``kernel`` over ``grid``, its last axis walked
+    in order, with float32 ``scratch`` (shapes) and the VMEM limit its own
+    shapes come to; ``aliases`` maps an operand to the result written over
+    it."""
     need = vmem_need(
         scratch, in_specs + out_specs,
         [v.dtype for v in (*operands, *out_shape)],
     )
     return pl.pallas_call(
-        functools.partial(kernel, chunk=chunk, unroll=_unroll(chunk)),
+        kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
@@ -369,9 +393,16 @@ def _kernel_call(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=vmem_limit(need),
         ),
+        input_output_aliases=aliases or {},
         interpret=interpret,
         name=name,
     )(*operands)
+
+
+def _own_spec(rows: int, block: int, per: int):
+    """The block of a ``[G, rows, E]`` operand that belongs to sequence
+    ``s``'s group, ``per`` sequences a group (a peer's own parameters)."""
+    return pl.BlockSpec((None, rows, block), lambda s, j, c: (s // per, 0, j))
 
 
 def folding_peers(fn):
@@ -405,13 +436,14 @@ def _forward_call(interpret: bool, x, delta, a, bt, ct, d):
     seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, c, j))
     col = pl.BlockSpec((None, n, chunk), lambda s, j, c: (s, 0, c))
     return _kernel_call(
-        _forward_kernel, "dpwa_selective_scan_fwd", interpret, grid, chunk,
+        functools.partial(_forward_kernel, chunk=chunk, unroll=_unroll(chunk)),
+        "dpwa_selective_scan_fwd", interpret, grid,
         _forward_scratch(chunk, n, block), (x, delta, a, bt, ct, d),
         in_specs=[
             seq, seq,
-            pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
+            _own_spec(n, block, per),
             col, col,
-            pl.BlockSpec((None, 1, block), lambda s, j, c: (s // per, 0, j)),
+            _own_spec(1, block, per),
         ],
         out_specs=[
             seq,
@@ -440,14 +472,17 @@ def _backward_call(interpret: bool, x, delta, a, bt, ct, d, states, dy):
     )
     seqs, steps, channels = x.shape
     return _kernel_call(
-        _backward_kernel, "dpwa_selective_scan_bwd", interpret, grid, chunk,
+        functools.partial(
+            _backward_kernel, chunk=chunk, unroll=_unroll(chunk)
+        ),
+        "dpwa_selective_scan_bwd", interpret, grid,
         _backward_scratch(chunk, n, block),
         (x, delta, a, bt, ct, d, states, dy),
         in_specs=[
             seq, seq,
-            pl.BlockSpec((None, n, block), lambda s, j, c: (s // per, 0, j)),
+            _own_spec(n, block, per),
             col, col,
-            pl.BlockSpec((None, 1, block), lambda s, j, c: (s // per, 0, j)),
+            _own_spec(1, block, per),
             pl.BlockSpec(
                 (None, None, n, block), lambda s, j, c: (s, last - c, 0, j)
             ),
@@ -537,3 +572,271 @@ kernel_scan = _differentiable(interpret=False)
 kernel_scan.__doc__ = """:func:`selective_scan` by the Pallas kernels."""
 # The same kernels run by the Pallas interpreter, for tests off the TPU.
 interpreted_scan = _differentiable(interpret=True)
+
+
+# The convolution with silu.  Kernel layout again: ``x [S, T, C >= E]``, ``g``
+# and the results ``[S, T, E]``; ``w [G, K, E]`` and ``b [G, 1, E]`` float32
+# over G groups of S / G sequences each.  A grid step holds ``conv_chunk(T)``
+# rows of ``conv_block(E)`` channels behind the HALO rows before them.
+
+# Rows kept of the chunk before: one whole tile of a bfloat16 array, two of a
+# float32 one, so that every copy of them is aligned; the taps reach K - 1.
+HALO = 16
+
+
+def conv_chunk(steps: int) -> int:
+    """Rows a grid step of the convolution's kernels holds: the largest of
+    512, 256, ... 16 that divides ``steps`` (0: none does, and the kernels
+    are not used)."""
+    return next(
+        (c for c in (512, 256, 128, 64, 32, HALO) if steps % c == 0), 0
+    )
+
+
+def conv_block(channels: int) -> int:
+    """Channels a grid step of the convolution's kernels holds."""
+    return next(
+        (c for c in (1024, 512, 256, 128) if channels % c == 0), channels
+    )
+
+
+def _use_conv_kernels(steps: int, channels: int, taps: int) -> bool:
+    return (
+        jax.default_backend() == "tpu" and channels % 128 == 0
+        and conv_chunk(steps) > 0 and taps <= HALO
+    )
+
+
+def conv_silu(x, w, b):
+    """``silu(causal_conv1d(x, w, b))`` for ``x [..., T, E]``, ``w [K, E]``,
+    ``b [E]``, differentiable in all three, the result in ``x``'s type.  On
+    a TPU one kernel a pass, with the gradient written by hand: the sum and
+    silu in float32, one rounding at the end (what XLA made of the plain
+    form inside one fusion, where it drops the rounding between the two).
+    Elsewhere the plain form under autodiff, which rounds the sum first.
+
+    ``x`` may be wider than ``w``, ``[..., T, C]`` with ``C > E``: its
+    leading ``E`` channels are convolved and the rest is not read (its
+    gradient is zero).  A caller whose ``x`` is the leading part of a wider
+    product hands the product over, and the kernels pick their blocks out of
+    it where a slice would be a copy."""
+    if _use_conv_kernels(x.shape[-2], w.shape[-1], w.shape[0]):
+        return kernel_conv_silu(x, w, b)
+    return plain_conv_silu(x, w, b)
+
+
+def plain_conv_silu(x, w, b):
+    """What runs off the TPU, and what the kernels are held to."""
+    return jax.nn.silu(causal_conv1d(x[..., :w.shape[-1]], w, b))
+
+
+# What a turn of the kernels' loops works on, rows by lanes.  A whole chunk
+# as one expression is some hundred vector registers an operand, which Mosaic
+# spills one by one (a store a bundle); a tile's eight stay in registers.  A
+# slice that starts between two tiles of sublanes is held in one register
+# more than its rows fill, and so is what is computed from it: the more rows
+# a tile has the less that costs.
+TILE = (64, 128)
+
+
+def _over_columns(block: int, column) -> None:
+    """``column(lanes)`` for every column of TILE lanes of a grid step's
+    block, a turn of a loop each: the kernel's text holds one column's
+    instructions."""
+    width = min(block, TILE[1])
+
+    def turn(i, carry):
+        column(pl.ds(pl.multiple_of(i * width, width), width))
+        return carry
+
+    lax.fori_loop(0, block // width, turn, None)
+
+
+def _row_tiles(chunk: int):
+    """``(first row, rows)`` of a column's tiles, in order."""
+    rows = min(chunk, TILE[0])
+    return [(at, rows) for at in range(0, chunk, rows)]
+
+
+def _sigmoid(v):
+    """``1 / (1 + exp(-v))`` as ``(1 + tanh(v / 2)) / 2``: one transcendental
+    and no division, which Mosaic would refine over a dozen instructions."""
+    return 0.5 * jnp.tanh(0.5 * v) + 0.5
+
+
+def _taps(rows_ref, w_ref, first: int, rows: int, lanes, out, flip=False):
+    """``out + sum_j w[j] * rows_ref[first + j + t]`` for ``t`` in ``rows``
+    (``w[K - 1 - j]`` with ``flip``), float32."""
+    taps = w_ref.shape[0]
+    for j in range(taps):
+        out = out + w_ref[pl.ds(taps - 1 - j if flip else j, 1), lanes] * (
+            rows_ref[pl.ds(first + j, rows), lanes]
+        )
+    return out
+
+
+def _conv_silu_forward_kernel(x_ref, w_ref, b_ref, y_ref, rows_ref, *, chunk):
+    taps, block = w_ref.shape[0], y_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():  # zeros before a sequence, not the sequence before
+        rows_ref[pl.ds(0, HALO), :] = jnp.zeros((HALO, block), F32)
+
+    def column(lanes):
+        for at, rows in _row_tiles(chunk):
+            here = pl.ds(at, rows)
+            rows_ref[pl.ds(HALO + at, rows), lanes] = (
+                x_ref[here, lanes].astype(F32)
+            )
+            # out_t = b + sum_j w[j] * x_{t - (K - 1) + j} and silu of it,
+            # float32 to the one rounding of the result.
+            pre = _taps(
+                rows_ref, w_ref, HALO + at - (taps - 1), rows, lanes,
+                b_ref[:, lanes],
+            )
+            y_ref[here, lanes] = (pre * _sigmoid(pre)).astype(y_ref.dtype)
+        rows_ref[pl.ds(0, HALO), lanes] = rows_ref[pl.ds(chunk, HALO), lanes]
+
+    _over_columns(block, column)
+
+
+def _conv_silu_backward_kernel(
+    x_ref, before_ref, g_ref, w_ref, b_ref, dx_ref, rows_ref, h_ref, head_ref,
+    *, chunk,
+):
+    # Grid step c of the last axis is chunk (chunks - 1 - c), as in the
+    # scan's backward kernel.  ``before_ref`` is the HALO rows of ``x`` before
+    # this chunk; ``head_ref`` carries the first HALO rows of ``g silu'`` of
+    # the chunk after it.
+    step, taps, block = pl.program_id(2), w_ref.shape[0], dx_ref.shape[1]
+
+    @pl.when(step == 0)
+    def _():  # nothing flows back from past a sequence's end
+        head_ref[...] = jnp.zeros_like(head_ref)
+
+    first = step == pl.num_programs(2) - 1  # zeros before a sequence
+
+    def column(lanes):
+        rows_ref[pl.ds(0, HALO), lanes] = jnp.where(
+            first, 0.0, before_ref[:, lanes].astype(F32)
+        )
+        rows_ref[pl.ds(HALO, chunk), lanes] = x_ref[:, lanes].astype(F32)
+        h_ref[pl.ds(chunk, HALO), lanes] = head_ref[:, lanes]
+        # Last tile first: a tile's ``dx`` reads the K - 1 rows after it.
+        for at, rows in reversed(_row_tiles(chunk)):
+            here = pl.ds(at, rows)
+            # The forward's pre-activation again, then silu's derivative.
+            pre = _taps(
+                rows_ref, w_ref, HALO + at - (taps - 1), rows, lanes,
+                b_ref[:, lanes],
+            )
+            sig = _sigmoid(pre)
+            h_ref[here, lanes] = g_ref[here, lanes].astype(F32) * (
+                sig * (1.0 + pre * (1.0 - sig))
+            )
+            # dx_t = sum_j w[j] * (g silu')_{t + (K - 1) - j}
+            dx_ref[here, lanes] = _taps(
+                h_ref, w_ref, at, rows, lanes, 0.0, flip=True
+            ).astype(dx_ref.dtype)
+        head_ref[:, lanes] = h_ref[pl.ds(0, HALO), lanes]
+
+    _over_columns(block, column)
+
+
+def _conv_grid(x, w):
+    """As :func:`_grid`, for the convolution's kernels: the channels are
+    ``w``'s, the leading ones of ``x``."""
+    seqs, steps, channels = *x.shape[:2], w.shape[-1]
+    chunk, block = conv_chunk(steps), conv_block(channels)
+    return (
+        (seqs, channels // block, steps // chunk), (chunk, block),
+        seqs // w.shape[0],
+    )
+
+
+def _conv_silu_forward_call(interpret: bool, x, w, b):
+    """``(y [S, T, E],)``."""
+    grid, (chunk, block), per = _conv_grid(x, w)
+    seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, c, j))
+    return _kernel_call(
+        functools.partial(_conv_silu_forward_kernel, chunk=chunk),
+        "dpwa_conv_silu_fwd", interpret, grid, [(HALO + chunk, block)],
+        (x, w, b),
+        in_specs=[
+            seq, _own_spec(w.shape[1], block, per), _own_spec(1, block, per),
+        ],
+        out_specs=[seq],
+        out_shape=[jax.ShapeDtypeStruct(x.shape[:2] + w.shape[-1:], x.dtype)],
+    )
+
+
+def _conv_silu_backward_call(interpret: bool, x, g, w, b):
+    """``(dx [S, T, E],)`` in ``x``'s type, written over ``g``: a block of
+    ``g`` is read once, before its block of ``dx`` is written."""
+    grid, (chunk, block), per = _conv_grid(x, w)
+    last, halos = grid[2] - 1, chunk // HALO
+    seq = pl.BlockSpec((None, chunk, block), lambda s, j, c: (s, last - c, j))
+    before = pl.BlockSpec(
+        (None, HALO, block),
+        lambda s, j, c: (s, jnp.maximum((last - c) * halos - 1, 0), j),
+    )
+    return _kernel_call(
+        functools.partial(_conv_silu_backward_kernel, chunk=chunk),
+        "dpwa_conv_silu_bwd", interpret, grid,
+        2 * [(HALO + chunk, block)] + [(HALO, block)], (x, x, g, w, b),
+        in_specs=[
+            seq, before, seq, _own_spec(w.shape[1], block, per),
+            _own_spec(1, block, per),
+        ],
+        out_specs=[seq], out_shape=[jax.ShapeDtypeStruct(g.shape, x.dtype)],
+        aliases={2: 0},
+    )
+
+
+def _differentiable_conv_silu(interpret: bool):
+    """:func:`conv_silu`'s signature on the two kernels."""
+    # Under ``jit``, so that a program with a call a layer traces and lowers
+    # each kernel once: a call's ``chunk`` and ``block`` are read when its
+    # shapes are first traced.
+    def jitted(call, name):
+        call = functools.partial(call, interpret)
+        call.__name__ = name  # what ``jit`` puts in the instructions' names
+        return jax.jit(call)
+
+    forward = folding_peers(jitted(_conv_silu_forward_call, "conv_silu_fwd"))
+    backward = folding_peers(
+        jitted(_conv_silu_backward_call, "conv_silu_bwd")
+    )
+    sequences = lambda v: v.reshape((-1,) + v.shape[-2:])
+    laid_out = lambda w, b: (w.astype(F32)[None], b.astype(F32)[None, None])
+
+    @jax.custom_vjp
+    def conv(x, w, b):
+        return forward(sequences(x), *laid_out(w, b))[0].reshape(
+            x.shape[:-1] + w.shape[-1:]
+        )
+
+    def fwd(x, w, b):
+        return conv(x, w, b), (x, w, b)
+
+    def bwd(residuals, g):
+        x, w, b = residuals
+        # A custom gradient's instructions carry no name of the forward's.
+        with jax.named_scope(scopes.SSM_PARTS.conv):
+            dx = backward(sequences(x), sequences(g), *laid_out(w, b))[0]
+            # The parameters' are plain sums beside the kernel: where both
+            # leaves are frozen nothing reads them and XLA drops them.
+            dw, db = jax.vjp(
+                lambda w, b: plain_conv_silu(x, w, b), w, b
+            )[1](g)
+            # Nothing flows to the channels of ``x`` that were not read.
+            unread = [(0, 0)] * (g.ndim - 1) + [(0, x.shape[-1] - g.shape[-1])]
+            return jnp.pad(dx.reshape(g.shape), unread), dw, db
+
+    conv.defvjp(fwd, bwd)
+    return conv
+
+
+kernel_conv_silu = _differentiable_conv_silu(interpret=False)
+kernel_conv_silu.__doc__ = """:func:`conv_silu` by the Pallas kernels."""
+interpreted_conv_silu = _differentiable_conv_silu(interpret=True)

@@ -642,44 +642,102 @@ def test_the_windowed_kernels_fit_the_v5e(v5e_chips, T, window):
     assert f"bf16[2,4,{T},128]" in text
 
 
-def test_what_the_mamba_blocks_keep_does_not_pile_up(v5e_chips, capsys):
-    """The Jamba cell's own step at four layers (Mamba blocks all, each
+MAMBA_LAYERS = 6
+
+
+def _jamba_step_of_mamba_blocks():
+    """``(bytes, compiled text)`` of the Jamba cell's own step at
+    ``MAMBA_LAYERS`` layers (Mamba blocks all: the attention layer is the
+    eighth) compiled for the described v5e, by
+    ``benchmark/rehearse_compile.py``: the bytes from its report, the text
+    caught on its way into it."""
+    import contextlib
+    import io
+    from unittest import mock
+
+    from benchmark import rehearse_compile
+
+    texts, as_text = [], jax.stages.Compiled.as_text
+
+    def caught(compiled, *args, **kwargs):
+        texts.append(as_text(compiled, *args, **kwargs))
+        return texts[-1]
+
+    printed = io.StringIO()
+    with _no_compile_cache(), mock.patch.object(
+        jax.stages.Compiled, "as_text", caught
+    ), contextlib.redirect_stdout(printed):
+        assert rehearse_compile.main([
+            "jamba2-lora-period14-stacked2", "--no-reference",
+            "--set", f"num_hidden_layers={MAMBA_LAYERS}",
+        ]) == 0
+    report = json.loads(printed.getvalue().splitlines()[-1])
+    assert report["step"]["tpu_custom_call"]
+    return report["step"]["total_gb"] * 1e9, texts[0]
+
+
+@pytest.fixture(scope="module")
+def jamba_step(v5e_chips):
+    """The step as the tree has it, compiled once for the tests below."""
+    return _jamba_step_of_mamba_blocks()
+
+
+def test_what_the_mamba_blocks_keep_does_not_pile_up(jamba_step):
+    """The Jamba cell's own step at six layers (Mamba blocks all, each
     keeping its scan's output and boundary states) compiled for the v5e
     holds no more than the same step with no block keeping anything plus
-    what the four keep: bfloat16 ``[2, 4096, 5120]`` and float32 ``[2, 32,
+    what the six keep: bfloat16 ``[2, 4096, 5120]`` and float32 ``[2, 32,
     16, 5120]`` a block, 105 MB.  Not today's number: the guard against a
     change that makes more than the kept arrays stand at the step's peak.
     One such is planted: with the name on ``y`` itself ``jax.checkpoint``
     (jax 0.9.0, ``ad_checkpoint._insert_reduce_precision``) rounds the
-    saved float and ``y`` stands there twice, which breaks the bound; it
-    is what ``ops/ssm._named_bits`` is for, and the day this half fails
-    that function has nothing left to do."""
+    saved float and a ``y`` stands at the peak twice; it is what
+    ``ops/ssm._named_bits`` is for, and the day this half fails that
+    function has nothing left to do.  (Four layers and "breaks the bound"
+    until PR 56: with the convolution's float32 arrays gone the peak of so
+    shallow a step lies where no second ``y`` reaches it, 5.632 GB either
+    way; at six layers one does, 7.331 against 7.243 GB, and at the cell's
+    fourteen 12.466 against 12.232 by the compiler's assignment.)"""
     from unittest import mock
 
-    from benchmark import rehearse_compile
     from dpwa_tpu.models import llama
     from dpwa_tpu.ops import ssm
 
-    def step_bytes():
-        with _no_compile_cache():
-            assert rehearse_compile.main([
-                "jamba2-lora-period14-stacked2", "--no-reference",
-                "--set", "num_hidden_layers=4",
-            ]) == 0
-        report = json.loads(capsys.readouterr().out.splitlines()[-1])
-        assert report["step"]["tpu_custom_call"]
-        return report["step"]["total_gb"] * 1e9
-
-    kept = step_bytes()
+    kept, _ = jamba_step
     with mock.patch.object(ssm, "_named_bits", ssm.checkpoint_name):
-        kept_as_floats = step_bytes()
+        kept_as_floats, _ = _jamba_step_of_mamba_blocks()
     with mock.patch.object(llama, "_checkpoint_policy", lambda cfg, i: None):
-        nothing_kept = step_bytes()
-    a_block = 2 * 4096 * 5120 * 2 + 2 * 32 * 16 * 5120 * 4
-    assert kept <= nothing_kept + 4 * a_block, (kept, nothing_kept)
-    assert kept_as_floats > nothing_kept + 4 * a_block, (
-        kept_as_floats, nothing_kept
-    )
+        nothing_kept, _ = _jamba_step_of_mamba_blocks()
+    a_y = 2 * 4096 * 5120 * 2
+    a_block = a_y + 2 * 32 * 16 * 5120 * 4
+    assert kept <= nothing_kept + MAMBA_LAYERS * a_block, (kept, nothing_kept)
+    assert kept_as_floats >= kept + a_y, (kept_as_floats, kept)
+
+
+def test_the_mamba_blocks_run_the_convolution_as_its_two_kernels(jamba_step):
+    """The same step's compiled text: the convolution with silu is
+    ``ops/ssm.conv_silu``'s two kernels, and nothing booked under
+    ``dpwa.ssm.conv`` writes a float32 array of ``x``'s size (the widened
+    copy and the four widened gradients XLA built around ``causal_conv1d``
+    until PR 56, 168 MB each)."""
+    import re
+
+    from dpwa_tpu.utils import scopes
+
+    _, text = jamba_step
+    # A layer: forward, recomputed, backward.
+    calls = lambda name: len(re.findall(name + r"[.\d]* = ", text))
+    assert calls("dpwa_conv_silu_fwd") == 2 * MAMBA_LAYERS
+    assert calls("dpwa_conv_silu_bwd") == MAMBA_LAYERS
+    entry = text[text.index("\nENTRY "):]
+    result = re.compile(r"\s*(?:ROOT )?%?[\w.\-]+ = (.*?) [\w\-]+\(")
+    wide = re.compile(r"f32\[2,(?:1,)?4096,5120\]")
+    written = [
+        line[:200] for line in entry.splitlines()
+        if scopes.SSM_PARTS.conv in line and (m := result.match(line))
+        and wide.search(m.group(1))
+    ]
+    assert not written, written
 
 
 # ---------------------------------------------------------------------------

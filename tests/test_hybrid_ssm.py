@@ -268,8 +268,9 @@ def without_names(names=None):
 
 def test_the_mixers_parts_carry_their_names_in_the_lowered_loss():
     """Forward, recomputed and backward alike: each projection's product
-    under ``dpwa.ssm.proj`` with the module's own name next, the taps and
-    silu under ``dpwa.ssm.conv``, the norms and softplus under
+    under ``dpwa.ssm.proj`` with the module's own name next, the
+    convolution's two kernels (PR 56; the taps and silu as XLA's own before)
+    and ``z``'s slice under ``dpwa.ssm.conv``, the norms and softplus under
     ``dpwa.ssm.dt``, the gate's product under ``dpwa.ssm.gate``; every one
     of them inside ``dpwa.ssm``, none around or inside ``dpwa.ssm.scan``,
     whose own instructions are the ones it had without the four."""
@@ -302,7 +303,13 @@ def test_the_mixers_parts_carry_their_names_in_the_lowered_loss():
         for t in tails(parts.proj)
     )
     conv = tails(parts.conv)
-    assert {"split", "slice", "mul", "jit(silu)"} <= conv
+    # The kernels' calls are jitted, lowered once and called a layer: the
+    # call carries the mixer's names, the kernel its own.
+    assert {"slice", "jit(conv_silu_fwd)"} <= conv
+    assert any(t.endswith("jit(conv_silu_bwd)") for t in conv)
+    assert {
+        "dpwa_conv_silu_fwd/pallas_call", "dpwa_conv_silu_bwd/pallas_call",
+    } <= names
     assert any("pad" in t for t in conv)
     step_size = tails(parts.dt)
     assert {"jit(softplus)", "add", "exp", "neg"} <= step_size

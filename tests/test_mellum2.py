@@ -553,6 +553,8 @@ def test_the_tiles_at_this_models_widths_divide_them():
 # the forward pass to the bit): the OLMoE and LFM2 cells, the Mellum2 cell
 # (held since PR 51), and the A.X-K1 cell, whose toy share has no row cap;
 # the cell itself walks windows and did not move (``STEP_OF_THE_SHARE``).
+# The Jamba cell's was taken again at PR 56, which runs the mixer's
+# convolution with silu as ``ops/ssm.conv_silu``'s two kernels.
 STEPS_AT_PARENT = {
     "resnet50-stacked8-fulltree": "9c86be813da01bc3",
     "resnet50-ici4-fulltree": "9c86be813da01bc3",
@@ -560,7 +562,7 @@ STEPS_AT_PARENT = {
     "mistral7b-lora-stacked2-t512": "fbaa370d5f1b8c05",
     "olmoe-lora-stacked2-t4096": "9f692ef4953a8eb5",
     "axk1-lora-share8-stacked2": "871095af9a950f67",
-    "jamba2-lora-period14-stacked2": "ff0fc3c8b4ffeb22",
+    "jamba2-lora-period14-stacked2": "6f2cb2b5a2edc1a2",
     "evabyte-lora-stacked2-t16384": "42fa7dbca930eafd",
     "lfm2-lora-stacked2-t4096": "114566364614790a",
     "mellum2-lora-stacked2-t4096": "5f6437cf9ac79d2d",

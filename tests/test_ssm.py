@@ -1,6 +1,7 @@
 """``ops/ssm.py``: the scan kernels (run by the Pallas interpreter) and their
 plain twin against a token-by-token reference, forward and all six gradients;
-the vmap rule; the causal convolution."""
+the vmap rule; the causal convolution; the convolution with silu as kernels
+(the interpreter again) against autodiff of its plain form."""
 
 from unittest import mock
 
@@ -283,3 +284,219 @@ def test_causal_conv_is_four_shifted_multiply_adds(dtype):
     # Causal: a later input moves no earlier output.
     later = x.at[:, 7].add(1.0)
     assert bool((ssm.causal_conv1d(later, w, b)[:, :7] == got[:, :7]).all())
+
+
+# ---------------------------------------------------------------------------
+# The convolution with silu: ``conv_silu``'s two kernels under the Pallas
+# interpreter, held to autodiff of ``silu(causal_conv1d(...))``.  One jitted
+# program a test, blocked before the next dispatch.
+# ---------------------------------------------------------------------------
+
+CONV_NAMES = ("x", "w", "b")
+
+
+def conv_arguments(seed, shape, taps, dtype=jnp.float32):
+    """``x``, ``w``, ``b`` and the weights of a scalar loss, ``x``'s shape."""
+    keys = jax.random.split(jax.random.key(seed), 4)
+    channels = shape[-1]
+    return (
+        jax.random.normal(keys[0], shape).astype(dtype),
+        jax.random.uniform(keys[1], (taps, channels), minval=-0.5, maxval=0.5),
+        jax.random.uniform(keys[2], (channels,), minval=-0.5, maxval=0.5),
+    ), jax.random.normal(keys[3], shape)
+
+
+def conv_value_and_grads(fn, weights):
+    """``(y, grads)`` of ``fn`` as float32, the loss ``(y * weights).sum()``."""
+
+    def both(x, w, b):
+        loss = lambda *a: (fn(*a).astype(jnp.float32) * weights).sum()
+        return fn(x, w, b), jax.grad(loss, argnums=(0, 1, 2))(x, w, b)
+
+    return both
+
+
+def kernels_and_plain(args, weights):
+    """Both implementations' ``(y, grads)`` from one jitted program."""
+    return jax.block_until_ready(jax.jit(lambda *a: (
+        conv_value_and_grads(ssm.interpreted_conv_silu, weights)(*a),
+        conv_value_and_grads(ssm.plain_conv_silu, weights)(*a),
+    ))(*args))
+
+
+@pytest.mark.parametrize("taps", [4, 3])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", [(2, 48, 128), (1, 1024, 256)])
+def test_conv_silu_and_its_three_gradients_against_autodiff_of_the_plain_form(
+    shape, dtype, taps
+):
+    """Three chunks of one tile, and two chunks of eight tiles by two."""
+    args, weights = conv_arguments(0, shape, taps, dtype)
+    steps, channels = shape[1:]
+    assert steps // ssm.conv_chunk(steps) >= 2
+    (y, grads), (want_y, want) = kernels_and_plain(args, weights)
+    assert y.dtype == dtype and y.shape == shape
+    # A rounding or two apart in bfloat16: the kernels keep the sum of the
+    # taps and ``g silu'`` float32 to one rounding of the result, where the
+    # plain form rounds the sum before silu and each factor of the gradient.
+    tolerance = 1e-6 if dtype == jnp.float32 else 2.0 ** -6
+    wide = lambda v: v.astype(jnp.float32)
+    assert off(wide(y), wide(want_y)) < tolerance
+    for name, g, w in zip(CONV_NAMES, grads, want):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        assert off(wide(g), wide(w)) < tolerance, name
+
+
+@pytest.mark.parametrize("taps", [4, 3])
+def test_an_impulse_at_a_chunks_end_crosses_into_the_next_and_back(taps):
+    """``x`` is one impulse in each of the last ``K - 1`` rows of the first
+    chunk: the rows after them, in the second chunk, see it through the rows
+    kept of the chunk before.  The loss reads the second chunk alone, so
+    every bit of ``dx`` in the first chunk came back across the boundary."""
+    steps, channels = 48, 128
+    chunk = ssm.conv_chunk(steps)
+    assert steps // chunk == 3
+    (_, w, b), _ = conv_arguments(1, (1, steps, channels), taps)
+    rows = jnp.arange(steps)[None, :, None]
+    x = jnp.where((rows >= chunk - (taps - 1)) & (rows < chunk), 1.0, 0.0)
+    x = x * jnp.ones((1, steps, channels))
+    weights = jnp.where(rows >= chunk, 1.0, 0.0) * jnp.ones_like(x)
+    (y, grads), (want_y, want) = kernels_and_plain((x, w, b), weights)
+    # Past the taps' reach the output is silu(b) again.
+    quiet = jax.nn.silu(b)
+    assert off(y[0, chunk + taps - 1:], jnp.broadcast_to(
+        quiet, (steps - chunk - taps + 1, channels)
+    )) < 1e-6
+    assert float(jnp.abs(y[0, chunk:chunk + taps - 1] - quiet).min()) > 1e-4
+    assert off(y, want_y) < 1e-6
+    dx, want_dx = grads[0], want[0]
+    assert float(jnp.abs(want_dx[0, chunk - (taps - 1):chunk]).min()) > 0
+    assert not bool(want_dx[0, :chunk - (taps - 1)].any())
+    assert not bool(dx[0, :chunk - (taps - 1)].any())
+    assert off(dx, want_dx) < 1e-6
+
+
+def test_a_sequences_first_rows_see_zeros_and_not_the_sequence_before():
+    """Two sequences a call, three chunks each: what the kernels carry from
+    chunk to chunk starts anew with a sequence, forward (the rows kept) and
+    backward (the gradient kept).  Moving the first sequence's last rows, or
+    the loss's weights on the second one's first rows, moves nothing across."""
+    (x, w, b), weights = conv_arguments(2, (2, 48, 128), 4)
+    loud = x.at[0, -3:].set(100.0)
+    heavy = weights.at[1, :3].set(100.0)
+
+    def both(x, w, b):
+        run = lambda x, weights: conv_value_and_grads(
+            ssm.interpreted_conv_silu, weights
+        )(x, w, b)
+        return (
+            run(x, weights), run(loud, weights), run(x, heavy),
+            conv_value_and_grads(ssm.plain_conv_silu, weights)(x, w, b),
+        )
+
+    (y, (dx, *_)), (loud_y, _), (_, (heavy_dx, *_)), (want_y, (want_dx, *_)) = (
+        jax.block_until_ready(jax.jit(both)(x, w, b))
+    )
+    assert bool((loud_y[1] == y[1]).all()) and not bool((loud_y[0] == y[0]).all())
+    assert bool((heavy_dx[0] == dx[0]).all())
+    assert not bool((heavy_dx[1] == dx[1]).all())
+    assert off(y, want_y) < 1e-6 and off(dx, want_dx) < 1e-6
+
+
+def test_conv_silu_under_vmap_over_two_peers_equals_a_loop_over_them():
+    """The stacked step's peer axis: each peer its own ``w`` and ``b``, the
+    peers folded into the kernels' sequence axis; bit for bit a call a peer."""
+    peers = [conv_arguments(seed, (2, 48, 256), 4) for seed in (3, 4)]
+    stacked = [jnp.stack(pair) for pair in zip(*(args for args, _ in peers))]
+    weights = jnp.stack([weights for _, weights in peers])
+    assert not bool((stacked[1][0] == stacked[1][1]).all())
+
+    def both(x, w, b):
+        run = lambda weights: conv_value_and_grads(
+            ssm.interpreted_conv_silu, weights
+        )
+        together = jax.vmap(
+            lambda weights, *a: run(weights)(*a)
+        )(weights, x, w, b)
+        alone = [run(weights[i])(x[i], w[i], b[i]) for i in range(2)]
+        return together, alone
+
+    (y, grads), alone = jax.block_until_ready(jax.jit(both)(*stacked))
+    for i, (want_y, want) in enumerate(alone):
+        assert bool((y[i] == want_y).all())
+        for name, g, w in zip(CONV_NAMES, grads, want):
+            assert bool((g[i] == w).all()), name
+
+
+def test_conv_silu_with_operands_the_vmap_did_not_batch():
+    """``w`` and ``b`` shared by the peers: every peer's."""
+    (x, w, b), weights = conv_arguments(5, (1, 48, 128), 3)
+    xs = jnp.stack([x, 2 * x])
+
+    def both(xs, w, b):
+        run = conv_value_and_grads(ssm.interpreted_conv_silu, weights)
+        return (
+            jax.vmap(run, in_axes=(0, None, None))(xs, w, b),
+            [run(xs[i], w, b) for i in range(2)],
+        )
+
+    (y, (dx, dw, db)), alone = jax.block_until_ready(jax.jit(both)(xs, w, b))
+    for i, (want_y, (want_dx, want_dw, want_db)) in enumerate(alone):
+        assert bool((y[i] == want_y).all()) and bool((dx[i] == want_dx).all())
+        assert off(dw[i], want_dw) < 1e-6 and off(db[i], want_db) < 1e-6
+
+
+def test_conv_silu_reads_the_leading_channels_of_a_wider_array():
+    """``x`` as the leading half of a wider product (``in_proj``'s ``[x,
+    z]``): the kernels pick their blocks out of it, the result is the
+    half's, and the gradient to the half that was not read is zero."""
+    (x, w, b), weights = conv_arguments(7, (2, 48, 256), 4)
+    xz = jnp.concatenate([x, 3.0 * x[..., ::-1]], -1)
+
+    def both(x, xz, w, b):
+        run = conv_value_and_grads(ssm.interpreted_conv_silu, weights)
+        plain = conv_value_and_grads(ssm.plain_conv_silu, weights)
+        return run(x, w, b), run(xz, w, b), plain(xz, w, b)
+
+    (y, (dx, dw, db)), (wide_y, (wide_dx, wide_dw, wide_db)), (_, want) = (
+        jax.block_until_ready(jax.jit(both)(x, xz, w, b))
+    )
+    assert wide_y.shape == x.shape and wide_dx.shape == xz.shape
+    assert bool((wide_y == y).all())
+    assert bool((wide_dx[..., :256] == dx).all())
+    assert not bool(wide_dx[..., 256:].any())
+    assert off(wide_dw, dw) < 1e-6 and off(wide_db, db) < 1e-6
+    for g, w_ in zip((wide_dx, wide_dw, wide_db), want):
+        assert g.shape == w_.shape and off(g, w_) < 1e-6
+
+
+def test_off_the_tpu_conv_silu_is_its_plain_twin():
+    (x, w, b), _ = conv_arguments(6, (1, 32, 128), 4)
+    assert jax.default_backend() != "tpu"
+    want = jax.nn.silu(ssm.causal_conv1d(x, w, b))
+    assert bool((ssm.conv_silu(x, w, b) == want).all())
+    # No argument picks the implementation.
+    import inspect
+
+    assert list(inspect.signature(ssm.conv_silu).parameters) == list(CONV_NAMES)
+    # On the TPU the shape does: lanes whole, a chunk that divides T, and
+    # taps within the rows kept.
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        assert ssm._use_conv_kernels(4096, 5120, 4)
+        assert not ssm._use_conv_kernels(4096, 5000, 4)
+        assert not ssm._use_conv_kernels(4090, 5120, 4)
+        assert not ssm._use_conv_kernels(4096, 5120, ssm.HALO + 2)
+
+
+@pytest.mark.parametrize("steps,chunk", [
+    (4096, 512), (1024, 512), (384, 128), (48, 16), (24, 0),
+])
+def test_the_convolutions_chunk_is_a_function_of_the_shape(steps, chunk):
+    assert ssm.conv_chunk(steps) == chunk
+
+
+@pytest.mark.parametrize("channels,block", [
+    (5120, 1024), (1536, 512), (384, 128), (64, 64),
+])
+def test_the_convolutions_block_is_a_function_of_the_shape(channels, block):
+    assert ssm.conv_block(channels) == block
